@@ -1,0 +1,546 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the two main paths once on one TPU chip, through the entry points a
+user calls, at the full width of models the repo has, and checks what comes
+out by the repo's own means. One process, no child that needs the chip.
+
+  stream   the README launch line (tensor_src → tensor_aggregator → queue →
+           tensor_filter mobilenet_v2:filter_model_u8 → tensor_decoder
+           image_labeling frames-in=64 → tensor_sink) at batch 64, 224×224,
+           plus the same model behind a tensor_transform so that a fused
+           segment runs;
+  serving  lm_serving.base.make_continuous(slots=8, paged=True) behind a
+           DecodeScheduler, twelve seeded requests (5..1500 prompt tokens,
+           a shared prefix, a page-aligned copy-on-write), then two
+           requests through the speculative engine (draft="ngram");
+  kernels  both Pallas kernels, Mosaic-lowered, at base geometry.
+
+It refuses to run anywhere but on a TPU, prints no result there, and exits
+non-zero. A failing leg is recorded, the other legs still run (a chip call
+is too dear to stop at the first fault), and the exit code is 1.
+
+Stdout is two JSON lines. The last is the result, these keys and no others:
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}``.
+The one before it is ``{"report": {...}}``: versions, the compile-cache
+directory, and per leg jax's own seconds of tracing and of XLA compilation
+(or cache load), the seconds of the steady part, and the counts checked;
+they say how long the smoke took and whether the compile cache was warm,
+they are not performance results.
+
+    python chip_smoke.py            # on the chip, through the chip tool
+"""
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+import traceback
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+# -- tolerances ---------------------------------------------------------------
+# Near-tie rule. On the MXU an f32 matmul runs bf16 passes by default, so two
+# differently shaped programs of the same math (paged vs dense, verify's
+# multiply-reduce vs step's matmuls, a fused segment vs the u8 entry) round
+# differently and an argmax over nearly equal logits can flip. A disagreement
+# passes only where the reference's own top-2 gap is below this; a larger gap
+# is a wrong program. The precision is NOT raised to make the check pass.
+#
+# LM: logits are rmsnorm(x) @ embed.T over dim 1024 with embed ~ N(0, 0.02²),
+# so they spread about 0.6; one bf16 rounding of each operand (2^-9 relative)
+# moves a logit by ~3e-3. Measured on the v5e (PR 21): 4 of 12 paged streams
+# leave the dense reference, at reference gaps of 0.0014 to 0.0019. 0.02 is
+# ten times that and a thirtieth of the spread.
+LM_NEAR_TIE_GAP = 0.02
+# Vision: MobileNet-v2 computes in bfloat16 end to end (2^-8 relative per op,
+# ~50 layers); logits of the random-weight model spread about 1. Measured
+# (PR 21): no label differs; the fused variant's logits differ by 8e-5.
+VISION_NEAR_TIE_GAP = 0.1
+# Pallas kernels vs the XLA oracle at precision=highest, outputs of order 1
+# (up to ~4 where few keys are visible). Mosaic, like XLA, runs a float32 dot
+# as bf16 passes by default, so scores carry a 2^-9 rounding of each operand
+# and a softmax weight over few keys moves by ~1e-2 of itself; bfloat16 inputs
+# add the rounding of the output. Measured on the v5e (PR 21): decode 1.0e-3
+# (f32) and 4.9e-4 (bf16), flash 1.5e-2 (f32) and 7.9e-3 (bf16). A masking,
+# indexing or scaling fault is an error of order 0.1 to 1.
+KERNEL_ATOL = 3e-2
+
+STREAM_LINE = (
+    "tensor_src num-buffers={frames} dimensions=3:224:224:1 types=uint8 "
+    "pattern=random seed=7 "
+    "! tensor_aggregator frames-out={batch} frames-dim=0 concat=true "
+    "! queue "
+    "! {stage} "
+    # the tee only lets the smoke see the filter's own output beside the
+    # decoded labels; the labels branch is the README line
+    "! tee name=t "
+    "t. ! tensor_decoder mode=image_labeling frames-in={batch} "
+    "! tensor_sink name=labels max-stored=1 "
+    "t. ! queue ! tensor_sink name=logits max-stored=1")
+U8_STAGE = ("tensor_filter framework=jax {custom}"
+            "model=nnstreamer_tpu.models.mobilenet_v2:filter_model_u8 name=f")
+FUSED_STAGE = ("tensor_transform mode=arithmetic "
+               "option=typecast:float32,div:127.5,add:-1.0 "
+               "! tensor_filter framework=jax "
+               "model=nnstreamer_tpu.models.mobilenet_v2:filter_model name=f")
+
+
+class CompileClock:
+    """Seconds jax itself reports for tracing and for XLA compilation (or
+    the load from the persistent cache that takes its place), and the
+    cache's hits and misses — from jax.monitoring, so a warm run shows as
+    what it is whatever else the wall time holds."""
+
+    TRACE = ("/jax/core/compile/jaxpr_trace_duration",
+             "/jax/core/compile/jaxpr_to_mlir_module_duration")
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+    HIT = "/jax/compilation_cache/cache_hits"
+    MISS = "/jax/compilation_cache/cache_misses"
+
+    def __init__(self):
+        import collections
+        import threading
+
+        import jax.monitoring
+
+        self._lock = threading.Lock()  # pipelines compile on their threads
+        self._sum = collections.Counter()
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event, duration, **_):
+        with self._lock:
+            self._sum[event] += duration
+
+    def _event(self, event, **_):
+        with self._lock:
+            self._sum[event] += 1
+
+    def read(self) -> dict:
+        with self._lock:
+            s = dict(self._sum)
+        return {"trace_s": sum(s.get(e, 0.0) for e in self.TRACE),
+                "compile_s": s.get(self.COMPILE, 0.0),
+                "cache_hits": s.get(self.HIT, 0),
+                "cache_misses": s.get(self.MISS, 0)}
+
+
+class CheckFailed(AssertionError):
+    """A leg's output is wrong (not a crash: the program ran and lied)."""
+
+
+def check(cond, msg: str) -> None:
+    if not cond:
+        raise CheckFailed(msg)
+
+
+def first_divergence(got, ref):
+    """Index of the first position where two token/label streams differ,
+    or None when the common prefix is the whole of both."""
+    n = min(len(got), len(ref))
+    for i in range(n):
+        if int(got[i]) != int(ref[i]):
+            return i
+    return None if len(got) == len(ref) else n
+
+
+def top2_gap(logits) -> float:
+    import numpy as np
+
+    top = np.sort(np.asarray(logits, np.float32).ravel())[-2:]
+    return float(top[1] - top[0])
+
+
+def near_tie(name: str, got, ref, ref_logits_at, tol: float) -> dict:
+    """The near-tie rule: ``got`` may leave ``ref`` only at a position
+    where the reference's own top-2 logit gap is below ``tol``.
+    ``ref_logits_at(i)`` → the reference's logits for position ``i``."""
+    check(len(got) == len(ref), f"{name}: {len(got)} outputs, reference "
+                                f"has {len(ref)}")
+    i = first_divergence(got, ref)
+    if i is None:
+        return {"first_diff": None}
+    gap = top2_gap(ref_logits_at(i))
+    print(f"chip_smoke: {name}: first difference at {i}: got {int(got[i])}, "
+          f"reference {int(ref[i])}, reference top-2 gap {gap:.5f} "
+          f"(tolerance {tol})", file=sys.stderr)
+    check(gap <= tol, f"{name}: differs from the reference at {i} where the "
+                      f"reference's top-2 gap is {gap:.5f} > {tol}: not a "
+                      "near tie, a wrong program")
+    return {"first_diff": i, "ref_top2_gap": round(gap, 6)}
+
+
+# -- stream -------------------------------------------------------------------
+
+def _run_stream(stage: str, batch: int, frames: int):
+    """Play one stream to EOS; returns (labels, logits buffers, pipe, times)."""
+    from nnstreamer_tpu.core import MessageType
+    from nnstreamer_tpu.runtime.parse import parse_launch
+
+    pipe = parse_launch(STREAM_LINE.format(frames=frames, batch=batch,
+                                           stage=stage))
+    labels, raw, stamps = [], [], []
+    pipe.get("labels").connect(lambda b: labels.append(b.meta["label_index"]))
+
+    def on_logits(buf):
+        raw.append(buf.tensors[0])
+        stamps.append(time.monotonic())
+
+    pipe.get("logits").connect(on_logits)
+    t0 = time.monotonic()
+    pipe.play()
+    try:
+        msg = pipe.bus.wait_for((MessageType.EOS, MessageType.ERROR),
+                                timeout=600)
+    finally:
+        pipe.stop()
+    t_end = time.monotonic()
+    check(msg is not None, "stream: no EOS within 600 s")
+    check(msg.type is MessageType.EOS,
+          f"stream: ERROR from {msg.source}: {msg.data}")
+    check(len(raw) == frames // batch and len(labels) == frames,
+          f"stream: {len(labels)} labels / {len(raw)} batches for "
+          f"{frames} frames")
+    # up to the first batch: model build, trace, compile or cache load, H2D
+    return labels, raw, pipe, (stamps[0] - t0, t_end - stamps[0])
+
+
+def stream_leg(batch: int = 64, frames: int = 256,
+               compute_dtype: str = "bfloat16") -> dict:
+    import numpy as np
+
+    import jax
+
+    from nnstreamer_tpu.models import mobilenet_v2
+    from nnstreamer_tpu.models._blocks import resolve_compute_dtype
+
+    resolved = resolve_compute_dtype("auto")
+    check(resolved == compute_dtype,
+          f"stream: compute dtype resolved to {resolved}, not {compute_dtype}")
+    n_dev = len(jax.devices())
+    platform = jax.devices()[0].platform
+    out = {"frames": frames, "batch": batch, "compute_dtype": resolved}
+
+    # the reference: a direct jax.jit call of the same entry on the same
+    # chip, on the first batch tensor_src will make (same seeded generator)
+    rng = np.random.default_rng(7)
+    batch0 = np.concatenate([
+        rng.integers(0, 127, (1, 224, 224, 3)).astype(np.uint8)
+        for _ in range(batch)])
+    ref = np.asarray(jax.jit(mobilenet_v2.filter_model_u8.make())(batch0))
+    ref_labels = ref.argmax(-1)
+
+    def checked(name: str, stage: str, n_frames: int) -> dict:
+        labels, raw, pipe, (first_batch_s, steady_s) = _run_stream(
+            stage, batch, n_frames)
+        check(all(0 <= lab < ref.shape[1] for lab in labels),
+              f"{name}: label out of range")
+        for arr in raw:
+            check(isinstance(arr, jax.Array),
+                  f"{name}: filter output is {type(arr).__name__}, "
+                  "not a jax.Array")
+            check({d.platform for d in arr.devices()} == {platform},
+                  f"{name}: filter output lives on {arr.devices()}")
+            check(arr.shape == ref.shape and arr.dtype == ref.dtype,
+                  f"{name}: filter output {arr.shape} {arr.dtype}")
+        got = np.asarray(raw[0])
+        check(bool(np.isfinite(got).all()), f"{name}: non-finite logits")
+        res = near_tie(name, labels[:batch], ref_labels,
+                       lambda i: ref[i], VISION_NEAR_TIE_GAP)
+        res.update(first_batch_s=round(first_batch_s, 2),
+                   steady_s=round(steady_s, 3),
+                   logits_max_abs_diff=float(np.abs(got - ref).max()),
+                   devices_per_output=len(raw[0].devices()),
+                   fused_segments=[dict(s.stats) for s in pipe.fused_segments])
+        return res
+
+    out["readme_line"] = checked(
+        "stream", U8_STAGE.format(custom=""), frames)
+    out["fused"] = fused = checked("stream-fused", FUSED_STAGE, 2 * batch)
+    segs = fused["fused_segments"]
+    check(len(segs) == 1 and segs[0]["dispatches"] > 0
+          and segs[0]["defused"] == 0,
+          f"stream-fused: fused segment stats {segs}")
+    if n_dev > 1:
+        # more than one chip here: the same line, batch-sharded over all
+        out["mesh"] = mesh = checked(
+            "stream-mesh", U8_STAGE.format(custom=f"custom=mesh:dp={n_dev} "),
+            frames)
+        check(mesh["devices_per_output"] == n_dev,
+              f"stream-mesh: output on {mesh['devices_per_output']} of "
+              f"{n_dev} devices")
+    out["steady_s"] = round(sum(
+        v["steady_s"] for v in out.values() if isinstance(v, dict)), 3)
+    return out
+
+
+# -- serving ------------------------------------------------------------------
+
+def _prompts(vocab: int, lengths, page_size: int, seed: int = 21):
+    """Seeded prompts of the given lengths. The last two share the first
+    one's full-page prefix: one adds its own tail (a registry hit), one IS
+    the page-aligned prefix (its last position lands in a shared page, so
+    the write copies it first)."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    prompts = [rng.integers(0, vocab, n).astype(np.int32) for n in lengths]
+    shared = (len(prompts[0]) // page_size) * page_size
+    check(2 * page_size <= shared < len(prompts[0]),
+          "first prompt must span several pages and end inside one")
+    tail = rng.integers(0, vocab, len(prompts[0]) - shared).astype(np.int32)
+    prompts.append(prompts[0][:shared].copy())
+    prompts.append(np.concatenate([prompts[0][:shared], tail]))
+    return prompts
+
+
+def _serve(engine, prompts, steps: int):
+    """Submit every prompt at once to a DecodeScheduler over ``engine``;
+    returns (token streams, metrics snapshot, seconds)."""
+    from nnstreamer_tpu.serving import DecodeScheduler
+
+    sched = DecodeScheduler(engine, name="chip-smoke")
+    t0 = time.monotonic()
+    try:
+        reqs = [sched.submit(p, steps=steps) for p in prompts]
+        streams = [r.result(timeout=600)[0] for r in reqs]
+        snap = sched.metrics_snapshot()
+    finally:
+        sched.close()  # releases every slot and closes the engine
+    return streams, snap, time.monotonic() - t0
+
+
+def serving_leg(entry=None, slots: int = 8, steps: int = 32,
+                lengths=(200, 5, 640, 1500, 5, 640, 1500, 200, 640, 5),
+                page_size: int = 16) -> dict:
+    import functools
+
+    import numpy as np
+
+    import jax
+
+    from nnstreamer_tpu.models import lm_serving
+    from nnstreamer_tpu.models.decoding import make_generate
+    from nnstreamer_tpu.models.transformer import forward
+
+    entry = entry or lm_serving.base
+    cfg = entry._cfg_serve
+    prompts = _prompts(cfg.vocab, lengths, page_size)
+    check(len(prompts) > slots, "more requests than slots, so slots churn")
+    out = {"requests": len(prompts), "slots": slots, "steps": steps}
+
+    engine = entry.make_continuous(slots=slots, paged=True,
+                                   page_size=page_size)
+    params = engine.params
+    # warm the two programs every request runs, so that their compile
+    # stays out of the run
+    t0 = time.monotonic()
+    engine.admit(0, prompts[1], 2)
+    engine.step()
+    engine.release(0)
+    out["warmup_s"] = round(time.monotonic() - t0, 2)
+
+    streams, snap, took = _serve(engine, prompts, steps)
+    out["steady_s"] = round(took, 2)
+    pool = snap["kv_pool"]
+    out.update(compile_count=snap["compile_count"],
+               prefix_hits_total=pool["prefix_hits_total"],
+               cow_copies_total=pool["cow_copies_total"],
+               completed=snap["completed"])
+    for i, toks in enumerate(streams):
+        check(len(toks) == steps, f"serving: request {i} got {len(toks)} "
+                                  f"tokens, wanted {steps}")
+        check(bool(((toks >= 0) & (toks < cfg.vocab)).all()),
+              f"serving: request {i} has out-of-vocabulary tokens")
+    check(pool["prefix_hits_total"] >= 1, f"serving: no prefix hit: {pool}")
+    check(pool["cow_copies_total"] >= 1, f"serving: no COW copy: {pool}")
+    check(engine.pool.used_pages == 0,
+          f"serving: {engine.pool.used_pages} pages still held after close")
+    # step + prefill_chunk + copy_page, whatever the prompt lengths were
+    check(snap["compile_count"] == 3,
+          f"serving: compile_count {snap['compile_count']}, expected 3")
+
+    # the dense reference: same params, same chip, one request at a time
+    generate = make_generate(cfg)
+    logits_of = jax.jit(functools.partial(forward, cfg))
+
+    def reference(prompt):
+        full = np.asarray(generate(params, prompt[None, :], steps))[0]
+
+        def logits_at(i):
+            # position P+i-1 of the reference's own sequence predicts its
+            # generated token i
+            return logits_of(params, full[None, :])[0, len(prompt) + i - 1]
+
+        return full[len(prompt):], logits_at
+
+    t0 = time.monotonic()
+    out["near_ties"] = ties = []
+    for i, (prompt, toks) in enumerate(zip(prompts, streams)):
+        ref, logits_at = reference(prompt)
+        res = near_tie(f"serving[{i}]", toks, ref, logits_at,
+                       LM_NEAR_TIE_GAP)
+        if res["first_diff"] is not None:
+            ties.append({"request": i, **res})
+
+    # speculative decode: _verify_commit on the chip, two requests
+    spec = entry.make_continuous(slots=2, paged=True, draft="ngram",
+                                 page_size=page_size)
+    spec_prompts = [np.tile(prompts[1], 8), prompts[0]]
+    streams, snap, spec_s = _serve(spec, spec_prompts, steps)
+    out.update(spec_rounds=snap["spec_rounds"],
+               spec_accepted=snap["spec_accepted"],
+               spec_compile_count=snap["compile_count"],
+               spec_s=round(spec_s, 2))
+    check(snap["spec_rounds"] > 0, "speculative: no verify round ran")
+    # prefill_chunk + verify_commit
+    check(snap["compile_count"] == 2,
+          f"speculative: compile_count {snap['compile_count']}, expected 2")
+    check(spec.pool.used_pages == 0, "speculative: pages held after close")
+    for i, (prompt, toks) in enumerate(zip(spec_prompts, streams)):
+        check(len(toks) == steps, f"speculative[{i}]: {len(toks)} tokens")
+        ref, logits_at = reference(prompt)
+        res = near_tie(f"speculative[{i}]", toks, ref, logits_at,
+                       LM_NEAR_TIE_GAP)
+        if res["first_diff"] is not None:
+            ties.append({"request": f"spec{i}", **res})
+    out["reference_s"] = round(time.monotonic() - t0 - spec_s, 2)
+    return out
+
+
+# -- kernels ------------------------------------------------------------------
+
+def kernels_leg(B: int = 8, H: int = 16, T: int = 2048, D: int = 64,
+                interpret: bool = False) -> dict:
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    from nnstreamer_tpu.ops.pallas_attention import (
+        dense_attention,
+        flash_attention,
+    )
+    from nnstreamer_tpu.ops.pallas_decode import (
+        cached_decode_attention,
+        dense_cached_decode,
+    )
+
+    rng = np.random.default_rng(3)
+    pos = T - T // 3  # a valid prefix that ends inside a block
+    out = {"geometry": [B, H, T, D], "pos": pos, "steady_s": 0.0}
+    wrong = []
+
+    def run(name, kernel, oracle, args):
+        lowered = jax.jit(kernel).lower(*args)
+        if not interpret:
+            check("tpu_custom_call" in lowered.as_text(),
+                  f"{name}: no tpu_custom_call in the lowered module")
+        compiled = lowered.compile()
+        t1 = time.monotonic()
+        got = np.asarray(compiled(*args).astype(jnp.float32))
+        t2 = time.monotonic()
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(jax.jit(oracle)(*(
+                jnp.asarray(a, jnp.float32) if a.ndim else a for a in args)))
+        err = float(np.abs(got - want).max())
+        out[name] = {"max_abs_err": err, "atol": KERNEL_ATOL}
+        out["steady_s"] = round(out["steady_s"] + t2 - t1, 3)
+        if not (np.isfinite(got).all() and err <= KERNEL_ATOL):
+            wrong.append(f"{name}: max |kernel - oracle| {err:.3e} "
+                         f"> {KERNEL_ATOL}")
+
+    for dt in (jnp.float32, jnp.bfloat16):
+        def arr(*shape):
+            return jnp.asarray(rng.standard_normal(shape), dt)
+
+        run(f"cached_decode_attention[{dt.__name__}]",
+            lambda q, k, v, p: cached_decode_attention(
+                q, k, v, p, block_k=128, interpret=interpret),
+            dense_cached_decode,
+            (arr(B, H, 1, D), arr(B, H, T, D), arr(B, H, T, D),
+             jnp.asarray(pos, jnp.int32)))
+        run(f"flash_attention[{dt.__name__}]",
+            lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                            interpret=interpret),
+            dense_attention,
+            (arr(1, H, T, D), arr(1, H, T, D), arr(1, H, T, D)))
+    check(not wrong, "; ".join(wrong))
+    return out
+
+
+# -- main ---------------------------------------------------------------------
+
+def result_line(summary: dict) -> dict:
+    """The last line of stdout: the verdict and the device as jax reports
+    it, these keys and no others. Everything else is in the report line."""
+    dev = summary["device"]
+    return {"ok": bool(summary["ok"]),
+            "device": {"platform": str(dev["platform"]),
+                       "kind": str(dev["kind"]),
+                       "count": int(dev["count"])}}
+
+
+def main() -> int:
+    t_start = time.monotonic()
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"chip_smoke: needs a TPU; jax selected platform "
+              f"{dev.platform!r} ({dev.device_kind}). Nothing was run.",
+              file=sys.stderr)
+        return 2
+
+    from importlib import metadata
+
+    import jaxlib
+
+    from nnstreamer_tpu import native
+    from nnstreamer_tpu.utils.hw_accel import enable_compilation_cache
+
+    if not native.available():
+        # g++ is installed: a pure-Python run would be something else
+        print("chip_smoke: the native library did not build or load. "
+              "Nothing was run.", file=sys.stderr)
+        return 1
+    cache_dir = enable_compilation_cache()
+    summary = {
+        "ok": False,
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
+        "versions": {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+                     "libtpu": metadata.version("libtpu")},
+        "compile_cache_dir": cache_dir,
+        "native_available": True,
+        "legs": {},
+    }
+    clock = CompileClock()
+    legs = {"kernels": kernels_leg, "stream": stream_leg,
+            "serving": serving_leg}
+    for name, leg in legs.items():
+        t0, before = time.monotonic(), clock.read()
+        try:
+            res = leg()
+            res["passed"] = True
+        except Exception as e:  # noqa: BLE001 — recorded, exit code 1 below
+            traceback.print_exc()
+            res = {"passed": False, "error": f"{type(e).__name__}: {e}"[:500]}
+        after = clock.read()
+        res.update({k: round(after[k] - before[k], 2) for k in after})
+        res["wall_s"] = round(time.monotonic() - t0, 2)
+        summary["legs"][name] = res
+        print(f"chip_smoke: {name}: "
+              f"{'passed' if res['passed'] else 'FAILED'} "
+              f"in {res['wall_s']} s", file=sys.stderr)
+    summary["ok"] = all(leg["passed"] for leg in summary["legs"].values())
+    summary["compile_s"] = round(clock.read()["compile_s"], 2)
+    summary["wall_s"] = round(time.monotonic() - t_start, 2)
+    print(json.dumps({"report": summary}))
+    print(json.dumps(result_line(summary)), flush=True)
+    return 0 if summary["ok"] else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

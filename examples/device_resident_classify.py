@@ -22,11 +22,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-import jax  # noqa: E402
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 from nnstreamer_tpu.runtime.parse import parse_launch  # noqa: E402
 
 BATCH = int(os.environ.get("BATCH", "8"))

@@ -10,7 +10,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")
+# before the first backend init; only the CPU backend reads it (the
+# 8-device virtual mesh of a JAX_PLATFORMS=cpu run)
 jax.config.update("jax_num_cpu_devices", 8)
 
 import jax.numpy as jnp  # noqa: E402

@@ -907,6 +907,13 @@ def main(argv=None) -> int:
     p.set_defaults(fn=run_lint)
 
     args = ap.parse_args(argv)
+    if args.cmd in ("launch", "serve", "replica"):
+        # the verbs that compile for the device: keep their XLA binaries
+        # across processes (before the first compile — jax latches the
+        # cache decision there)
+        from .utils.hw_accel import enable_compilation_cache
+
+        enable_compilation_cache()
     return args.fn(args)
 
 

@@ -17,9 +17,11 @@ Layout: ``<root>/aot-<topology>-<ctx>-<stage>.jaxexport`` (StableHLO
 bytes) + a ``.meta.json`` sidecar (key, stage, poly flag, avals, blob
 sha256). Loads verify the sha and quietly evict corrupt/truncated
 artifacts — a damaged cache degrades to a recompile, never a crash.
-``<root>/xla/`` additionally hosts jax's persistent XLA compilation
-cache (attached on first use), so a warm restart skips BOTH the Python
-trace (StableHLO artifact) and the XLA optimization pass (binary cache).
+The XLA binary cache is not this store's: it is the process-wide one
+(``utils.hw_accel.enable_compilation_cache``, or wherever
+``JAX_COMPILATION_CACHE_DIR`` points), so a warm restart of an entry
+point that enables it skips BOTH the Python trace (StableHLO artifact
+here) and the XLA optimization pass (binary cache there).
 
 GC mirrors ``ProfileStore``: ``NNS_AOT_CACHE_MAX`` bounds the artifact
 count, ``save()`` LRU-prunes by mtime, ``python -m nnstreamer_tpu aot
@@ -186,39 +188,6 @@ def backend_key(backend, in_shapes) -> Tuple[dict, str, str]:
 
 # -- the store ---------------------------------------------------------------
 
-_xla_attached: Optional[str] = None
-
-
-def _attach_xla_cache(root: str) -> None:
-    """Point jax's persistent compilation cache at ``<root>/xla`` (once
-    per process): the deserialized StableHLO's per-bucket XLA compiles
-    then hit disk across restarts — the second half of the cold-start
-    win (the artifact alone only skips the Python trace)."""
-    global _xla_attached
-    xdir = os.path.join(os.path.abspath(root), "xla")
-    if _xla_attached == xdir:
-        return
-    import jax
-
-    os.makedirs(xdir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", xdir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    _xla_attached = xdir
-
-
-def attach_xla_cache(root: Optional[str] = None) -> bool:
-    """Public attach point for planes that jit directly instead of going
-    through :class:`CompileCache` (the paged serving engine keys its
-    draft AND target executables here): point XLA's persistent cache at
-    the env-configured root. Returns False when the AOT plane is off."""
-    root = root or os.environ.get(CACHE_ENV, "").strip()
-    if not root:
-        return False
-    _attach_xla_cache(root)
-    return True
-
-
 class CompileCache:
     """On-disk store of exported stage programs, keyed by (topology,
     caps, model version, device signature, jax version) × (stage id,
@@ -287,7 +256,6 @@ class CompileCache:
 
     def save(self, key: dict, stage: str, digest: str, blob: bytes,
              meta: dict) -> str:
-        _attach_xla_cache(self.root)
         path = self.path_for(key, stage, digest)
         if not self._acquire_save_lock(path):
             logger.info("aot cache: concurrent writer holds %s — "
@@ -343,7 +311,6 @@ class CompileCache:
         """The servable program for this key, or None (miss / corrupt —
         corrupt artifacts are evicted so the recompile's re-export can
         replace them)."""
-        _attach_xla_cache(self.root)
         path = self.path_for(key, stage, digest)
         meta = self._read_meta(path)
         if meta is None:

@@ -321,9 +321,11 @@ class JaxBackend(FilterBackend):
             self._device_is_default = True
             return
         matching = [d for d in devices if d.platform.startswith(want)]
-        self._device = matching[0] if matching else devices[0]
         if not matching:
-            logger.warning("no %s device; falling back to %s", want, self._device)
+            raise ValueError(
+                f"accelerator={want}: no {want} devices present (have "
+                f"{sorted({d.platform for d in devices})})")
+        self._device = matching[0]
 
     @property
     def device(self):

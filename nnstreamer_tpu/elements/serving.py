@@ -22,6 +22,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+from ..analysis.sanitizer import named_lock
 from ..core import Buffer, Caps, tensors_info_from_caps
 from ..core.caps import caps_from_tensors_info
 from ..obs import context as obs_context
@@ -119,7 +120,11 @@ class TensorServing(TransformElement):
             raise ElementError(
                 f"{self.describe()}: on-shed must be drop|error")
         _parse_buckets(self.props["bucket_sizes"])  # validate early
-        self.scheduler = None
+        # the streaming thread (set_caps/chain) and a control thread
+        # (Service.attach_query_server) both ask for the scheduler; two
+        # winners would leave one scheduler thread nobody closes
+        self._sched_lock = named_lock(f"TensorServing._sched_lock:{self.name}")
+        self.scheduler = None            # guarded-by: _sched_lock
         self._shared_key: Optional[str] = None
         self._backend = None
         self._shed_warned = False
@@ -165,24 +170,25 @@ class TensorServing(TransformElement):
         return sched
 
     def _ensure_scheduler(self):
-        if self.scheduler is not None:
-            return self.scheduler
-        key = self.props["shared_key"]
-        if key:
-            from ..serving import get_shared_scheduler
+        with self._sched_lock:
+            if self.scheduler is not None:
+                return self.scheduler
+            key = self.props["shared_key"]
+            if key:
+                from ..serving import get_shared_scheduler
 
-            self.scheduler = get_shared_scheduler(
-                key, self._make_scheduler, self._signature())
-            self._shared_key = key
-            # when another element created the scheduler, adopt its
-            # backend so this element negotiates the same static caps
-            # (not the FLEXIBLE fallback) regardless of start order
-            self._backend = getattr(self.scheduler, "backend",
-                                    self._backend)
-            self._warn_ignored_shared_knobs(self.scheduler)
-        else:
-            self.scheduler = self._make_scheduler()
-        return self.scheduler
+                self.scheduler = get_shared_scheduler(
+                    key, self._make_scheduler, self._signature())
+                self._shared_key = key
+                # when another element created the scheduler, adopt its
+                # backend so this element negotiates the same static caps
+                # (not the FLEXIBLE fallback) regardless of start order
+                self._backend = getattr(self.scheduler, "backend",
+                                        self._backend)
+                self._warn_ignored_shared_knobs(self.scheduler)
+            else:
+                self.scheduler = self._make_scheduler()
+            return self.scheduler
 
     def _warn_ignored_shared_knobs(self, sched) -> None:
         """A joining element inherits the shared scheduler's queue and
@@ -204,19 +210,20 @@ class TensorServing(TransformElement):
                 "effective): %s", self.name, self._shared_key, ignored)
 
     def stop(self) -> None:
-        if self.scheduler is not None:
-            if self._shared_key:
+        with self._sched_lock:
+            sched, self.scheduler = self.scheduler, None
+            key, self._shared_key = self._shared_key, None
+            # the backend is closed by the scheduler's on_close (possibly
+            # later, when the last shared-key holder releases) — only
+            # drop our negotiation reference here
+            self._backend = None
+        if sched is not None:
+            if key:
                 from ..serving import release_shared_scheduler
 
-                release_shared_scheduler(self._shared_key)
-                self._shared_key = None
+                release_shared_scheduler(key)
             else:
-                self.scheduler.close()
-            self.scheduler = None
-        # the backend is closed by the scheduler's on_close (possibly
-        # later, when the last shared-key holder releases) — only drop
-        # our negotiation reference here
-        self._backend = None
+                sched.close()  # joins its thread: outside the lock
         super().stop()
 
     # -- negotiation ---------------------------------------------------------

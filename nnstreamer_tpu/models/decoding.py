@@ -71,12 +71,7 @@ def make_sp_cache_attention(cfg: TransformerConfig, mesh):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map
-        extra_kw = {}
-    except ImportError:  # older jax: experimental API needs check_rep off
-        from jax.experimental.shard_map import shard_map
-        extra_kw = {"check_rep": False}
+    from jax import shard_map
 
     if "sp" not in dict(mesh.shape):
         raise ValueError(
@@ -119,7 +114,6 @@ def make_sp_cache_attention(cfg: TransformerConfig, mesh):
         shard_fn, mesh=mesh,
         in_specs=(qspec, qspec, qspec, cspec, cspec, P()),
         out_specs=(qspec, cspec, cspec),
-        **extra_kw,
     )
 
 
@@ -302,17 +296,12 @@ def decode_step(cfg: TransformerConfig, params, token, pos, cache, mesh=None,
                 import math
 
                 from ..ops.pallas_decode import cached_decode_attention
+                from ..utils.hw_accel import pallas_interpret
 
-                # Mosaic lowering only on real TPU hardware; interpret
-                # elsewhere — a GPU backend must not get Triton-lowered
-                # TPU-kernel code
-                from ..utils.hw_accel import is_tpu_platform
-
-                interp = not is_tpu_platform(jax.devices()[0].platform)
                 o = cached_decode_attention(
                     q, ck, cv, pos,
                     block_k=math.gcd(cfg.max_seq, 128),
-                    interpret=interp)
+                    interpret=pallas_interpret(jax.devices()[0].platform))
                 o = o.transpose(0, 2, 1, 3).reshape(B, 1, cfg.dim)
             else:
                 att = (q @ ck.transpose(0, 1, 3, 2)) / jnp.sqrt(cfg.head_dim)

@@ -3,8 +3,9 @@
 The reference implements its allocator, queues, and dataset reader in C
 (gst/nnstreamer/tensor_allocator.c, GStreamer queue, gst/datarepo/). Our
 equivalents live in ``csrc/nns_core.cc`` — built on demand with g++ into
-``libnns_core.so`` and consumed through ctypes. Every consumer has a pure
-Python fallback: ``available()`` gates the fast path.
+``libnns_core-<source hash>.so`` (``_build.py``) and consumed through
+ctypes. Every consumer has a pure Python fallback: ``available()`` gates
+the fast path.
 
 Exposed wrappers:
   * :class:`BufferPool` — aligned, reusing host block pool (staging buffers).
@@ -24,7 +25,6 @@ import numpy as np
 from ._build import load_once
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_LIB_PATH = os.path.join(_HERE, "libnns_core.so")
 _SRC = os.path.join(_HERE, "csrc", "nns_core.cc")
 
 _lib = None
@@ -41,7 +41,7 @@ def _load() -> Optional[ctypes.CDLL]:
             return _lib
         if _build_failed:
             return None
-        lib = load_once(_SRC, _LIB_PATH, ABI_VERSION, "nns_abi_version",
+        lib = load_once(_SRC, "nns_core", ABI_VERSION, "nns_abi_version",
                         _bind, extra_args=("-lpthread",))
         if lib is None:
             _build_failed = True

@@ -7,7 +7,7 @@
 // interpreter's GEMMs, but XLA-CPU cannot fuse the requantize epilogue
 // into the GEMM library call — each layer pays an extra int32
 // materialization + elementwise pass (measured ~0.3-0.8 ms/layer on the
-// big early-network activations; PERF_PROFILE_r05.md). This engine
+// big early-network activations, a CPU timing). This engine
 // closes exactly that gap: the requantize (per-channel scale, round,
 // zero-point add, clamp, int8 pack) happens in registers inside the
 // GEMM epilogue, so each activation is written once, as int8.

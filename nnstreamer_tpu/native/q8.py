@@ -1,6 +1,6 @@
 """ctypes binding for the native int8 engine (``csrc/nns_q8.cc``).
 
-Build-on-demand into ``libnns_q8.so`` (same atomic-publish pattern as the
+Build-on-demand into ``libnns_q8-<source hash>.so`` (same atomic-publish pattern as the
 host-runtime core in ``__init__.py``). The engine is the CPU-side analog
 of the reference's native int8 interpreter path
 (ext/nnstreamer/tensor_filter/tensor_filter_tensorflow_lite.cc); see the
@@ -19,7 +19,6 @@ import numpy as np
 from ._build import load_once
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_LIB_PATH = os.path.join(_HERE, "libnns_q8.so")
 _SRC = os.path.join(_HERE, "csrc", "nns_q8.cc")
 
 _lib = None
@@ -72,7 +71,7 @@ def _load() -> Optional[ctypes.CDLL]:
             return _lib
         if _build_failed:
             return None
-        lib = load_once(_SRC, _LIB_PATH, ABI_VERSION, "nns_q8_abi", _bind)
+        lib = load_once(_SRC, "nns_q8", ABI_VERSION, "nns_q8_abi", _bind)
         if lib is None:
             _build_failed = True
             return None

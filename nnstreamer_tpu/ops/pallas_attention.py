@@ -10,12 +10,23 @@ denominator keep the result exact (flash-attention recurrence).
 
 Grid: one program per (batch, head, q-block); each program loops over
 K/V blocks with ``lax.fori_loop`` (bounded to the causal prefix).
-VMEM per program ≈ (block_q + 2·S_kv)·D·4 bytes — fine for S ≤ ~8k at
-D ≤ 128; shard longer sequences over ``sp`` first (parallel/context.py)
-so each shard's S_kv stays VMEM-resident.
+VMEM per program: the whole-sequence K and V blocks are double-buffered
+across grid steps and D pads to 128 lanes, so
+2·2·S_kv·max(D, 128)·itemsize plus the q/out blocks must stay under the
+16 MiB scoped-VMEM limit. What the v5e compiler accepts at D ≤ 128 and 16
+heads (compiled against the v5e topology, PR 21): float32 up to S = 4096
+(S = 8192 is refused, over by 128–256 KiB), bfloat16 up to S = 8192
+(S = 16384 is refused). Shard longer sequences over ``sp`` first so each
+shard's S_kv stays VMEM-resident.
+
+No package code calls this kernel: the context-parallel paths
+(parallel/context.py) compute their per-shard attention in plain jnp.
+It is covered by tests (interpret mode on CPU) and by ``chip_smoke.py``
+(Mosaic on the chip); whether it stays is ROADMAP D5/S8.
 
 ``flash_attention(..., interpret=True)`` runs the same kernel through the
 pallas interpreter on CPU — that is how tests cover it without a TPU.
+:func:`dense_attention` is the XLA oracle both compare against.
 """
 from __future__ import annotations
 
@@ -24,6 +35,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+
+def dense_attention(q, k, v, causal: bool = True):
+    """The XLA oracle: the O(S²) score matrix, materialized."""
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    if causal:
+        S = q.shape[2]
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None, None], s, -1e30)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,

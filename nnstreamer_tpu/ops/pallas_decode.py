@@ -14,7 +14,7 @@ Opt-in via ``TransformerConfig(decode_attn="pallas")`` — the XLA path
 stays the default and the equivalence oracle (test_pallas_ops pins the
 kernel against it; test_decoding pins generate() token-exactness).
 ``interpret=True`` runs the kernel on CPU — how tests cover it without
-a TPU.
+a TPU. :func:`dense_cached_decode` is the XLA oracle.
 """
 from __future__ import annotations
 
@@ -23,6 +23,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+
+def dense_cached_decode(q, ck, cv, pos):
+    """The XLA oracle: decode_step's masked dense path."""
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    T = ck.shape[2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, ck) * scale
+    visible = (jnp.arange(T) <= pos)[None, None, None, :]
+    s = jnp.where(visible, s, -1e30)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), cv)
 
 
 def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, *, block_k: int,

@@ -140,10 +140,7 @@ def make_context_attention(mesh, impl: str = "ring", causal: bool = True,
     """
     import jax
     from jax.sharding import PartitionSpec as P
-    try:  # jax>=0.6
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     if impl == "ring":
         fn = partial(ring_attention, axis_name=seq_axis, causal=causal)

@@ -89,10 +89,7 @@ def make_pipeline(stage_fn: Callable, num_stages: int, mesh=None,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     if (mesh is None) == (assignment is None):
         raise ValueError("pipeline: pass exactly one of mesh= or "
@@ -131,13 +128,11 @@ def make_pipeline(stage_fn: Callable, num_stages: int, mesh=None,
             ys = jnp.where(write, upd, ys)
             return (out, ys), None
 
-        init = (zeros, jnp.zeros_like(xs))
-        if hasattr(jax.lax, "pcast"):
-            # newer jax tracks varying-manual-axes: the carry becomes
-            # pp-varying after the first ppermute, so the init must be
-            # declared varying too
-            init = jax.tree_util.tree_map(
-                lambda a: jax.lax.pcast(a, (axis,), to="varying"), init)
+        # jax tracks varying-manual-axes: the carry becomes pp-varying
+        # after the first ppermute, so the init is declared varying too
+        init = jax.tree_util.tree_map(
+            lambda a: jax.lax.pcast(a, (axis,), to="varying"),
+            (zeros, jnp.zeros_like(xs)))
         (_, ys), _ = jax.lax.scan(
             tick, init, jnp.arange(M + num_stages - 1))
         # only the last stage's ys is real — replicate it to all stages
@@ -145,9 +140,5 @@ def make_pipeline(stage_fn: Callable, num_stages: int, mesh=None,
         return jax.lax.psum(ys * mask, axis)
 
     # P("pp") is a pytree-prefix spec: every param leaf leads with pp
-    try:
-        return shard_map(_run, mesh=mesh, in_specs=(P(axis), P()),
-                         out_specs=P())
-    except TypeError:  # older experimental API requires check_rep=False
-        return shard_map(_run, mesh=mesh, in_specs=(P(axis), P()),
-                         out_specs=P(), check_rep=False)
+    return shard_map(_run, mesh=mesh, in_specs=(P(axis), P()),
+                     out_specs=P())

@@ -71,9 +71,20 @@ from .fabric import FabricError, ReplicaPool
 #: warmed up and serving — everything before it is free-form logging
 READY_PREFIX = "NNS_REPLICA_READY "
 
+#: stdout sentinel + exit code (sysexits EX_UNAVAILABLE) of a runner whose
+#: jax backend would not initialize — on a one-chip host, the chip another
+#: process (a sibling replica, or a parent that touched jax) already holds
+NO_DEVICE_PREFIX = "NNS_REPLICA_NO_DEVICE "
+EXIT_NO_DEVICE = 69
+
 
 class ProcReplicaError(FabricError):
     """Subprocess replica lifecycle failure (spawn, readiness, respawn)."""
+
+
+class ReplicaDeviceError(ProcReplicaError):
+    """The replica process could not get a device: a chip belongs to one
+    process at a time, and this one was not it."""
 
 
 # ---------------------------------------------------------------------------
@@ -116,6 +127,7 @@ class ProcReplica:
         self.extra_args = list(extra_args or [])
         self.proc: Optional[subprocess.Popen] = None
         self.info: Optional[dict] = None   # the READY line's payload
+        self.no_device: Optional[str] = None  # the NO_DEVICE line's payload
         self._ready_evt = threading.Event()
         self._threads = ThreadRegistry()
         self._stdout_tail: List[str] = []  # last few lines, for errors
@@ -165,6 +177,8 @@ class ProcReplica:
                                      "%r", self.name, line[:200])
                         continue
                     self._ready_evt.set()
+                elif line.startswith(NO_DEVICE_PREFIX):
+                    self.no_device = line[len(NO_DEVICE_PREFIX):]
                 else:
                     self._stdout_tail.append(line)
                     del self._stdout_tail[:-8]
@@ -182,6 +196,12 @@ class ProcReplica:
         deadline = time.monotonic() + timeout
         while not self._ready_evt.wait(0.1):
             rc = self.proc.poll() if self.proc is not None else None
+            if rc == EXIT_NO_DEVICE:
+                self._threads.drain(timeout_per=2.0)  # the line is read
+                raise ReplicaDeviceError(
+                    f"replica '{self.name}' could not get a device (a chip "
+                    "belongs to one process at a time — a sibling replica "
+                    f"or this parent may hold it): {self.no_device}")
             if rc is not None:
                 raise ProcReplicaError(
                     f"replica '{self.name}' exited rc={rc} before READY "
@@ -717,6 +737,22 @@ def run_replica(args) -> int:
     signal.signal(signal.SIGINT, _on_signal)
     try:
         svc.start(wait=True)
+        device = None
+        if any(el.device_affinity() == "device"
+               for el in svc.pipeline.elements.values()):
+            # initialize the jax backend NOW, so a chip that another
+            # process holds fails the replica here — typed, before READY
+            # — instead of in its first request
+            import jax
+
+            try:
+                dev = jax.devices()[0]
+            except RuntimeError as e:
+                print(f"{NO_DEVICE_PREFIX}JAX_PLATFORMS="
+                      f"{os.environ.get('JAX_PLATFORMS', '')!r}: {e}",
+                      flush=True)
+                return EXIT_NO_DEVICE
+            device = f"{dev.platform}:{dev.id} ({dev.device_kind})"
         # the query server port binds during play(); resolve it the same
         # way ServiceFabric does for in-process replicas
         deadline = time.monotonic() + 30.0
@@ -753,7 +789,8 @@ def run_replica(args) -> int:
             hybrid.advertise(broker_host, int(broker_port), topic,
                              args.host, port)
         ready = {"name": args.name, "pid": os.getpid(), "host": args.host,
-                 "query_port": port, "control_port": server.port}
+                 "query_port": port, "control_port": server.port,
+                 "device": device}
         print(READY_PREFIX + json.dumps(ready), flush=True)
         from .manager import ServiceState
 

@@ -770,8 +770,7 @@ def from_entry(entry, slots: int = 4, mesh=None, paged: bool = False,
     dtype-cast per the entry's serve knobs; ``mesh`` reserved for
     sharded slot state — single-device only today). ``paged=True``
     builds the block-table :class:`PagedLMEngine` (``paged_kw``:
-    page_size/pages/chunk/share_prefixes); its executables key into the
-    PR 14 AOT cache when ``NNS_AOT_CACHE`` is set."""
+    page_size/pages/chunk/share_prefixes)."""
     if mesh is not None:
         raise NotImplementedError(
             "continuous decode is single-device today; shard the batch "
@@ -779,13 +778,5 @@ def from_entry(entry, slots: int = 4, mesh=None, paged: bool = False,
     cfg = entry._cfg_serve
     params, _ = entry._shard_params(None)
     if paged:
-        import os
-
-        from ..aot import cache as aot_cache
-
-        if os.environ.get(aot_cache.CACHE_ENV):
-            # draft AND target executables land in the same persistent
-            # XLA cache: a fleet restart replays both without retracing
-            aot_cache.attach_xla_cache()
         return PagedLMEngine(cfg, params, slots=slots, **paged_kw)
     return ContinuousLMEngine(cfg, params, slots=slots)

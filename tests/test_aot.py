@@ -23,20 +23,12 @@ FUSED_LINE = (SRC + f"! {ADD}! {SCALER}! tensor_sink name=out "
 
 @pytest.fixture
 def cache_root(tmp_path, monkeypatch):
-    """A fresh env-configured compile cache; the persistent XLA cache is
-    detached afterwards so the rest of the suite doesn't write into a
-    pytest tmp dir."""
-    from nnstreamer_tpu.aot import cache as cache_mod
-
+    """A fresh env-configured compile cache."""
     root = tmp_path / "aotcache"
     monkeypatch.setenv(aot.CACHE_ENV, str(root))
     monkeypatch.delenv(aot.CACHE_MAX_ENV, raising=False)
     aot.reset_stats()
     yield root
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir", None)
-    cache_mod._xla_attached = None
 
 
 def pull_bytes(pipe, name="out"):
@@ -95,7 +87,10 @@ class TestExport:
         import jax.numpy as jnp
 
         def model(x):
-            return (jnp.reshape(x, (8,)),)  # b*4 == 8 unprovable
+            # a host-built constant sized by the batch: np.arange needs
+            # the concrete value, which a symbolic dim cannot give
+            ramp = np.arange(x.shape[0], dtype=np.float32)
+            return (x * jnp.asarray(ramp)[:, None],)
 
         blob, meta, _ = aot.export_stage(
             model, (np.ones((2, 4), np.float32),), poly=True)

@@ -757,6 +757,31 @@ class TestProcReplicaRestartWindow:
             ps.stop()
 
 
+@pytest.mark.thread_leak_ok
+def test_replica_without_a_device_fails_typed_and_at_once(monkeypatch):
+    """A chip belongs to one process: a replica child whose jax backend
+    will not initialize exits naming what it could not get, and
+    wait_ready raises the typed error long before its timeout. (A
+    backend name jax does not know stands in for the held chip.)"""
+    from nnstreamer_tpu.service.procreplica import (
+        EXIT_NO_DEVICE,
+        ProcReplica,
+        ReplicaDeviceError,
+    )
+
+    monkeypatch.setenv("JAX_PLATFORMS", "nochip")  # the child inherits it
+    proc = ProcReplica("tensor_filter framework=jax "
+                       "model=builtin://scaler?factor=2", CAPS).spawn()
+    t0 = time.monotonic()
+    try:
+        with pytest.raises(ReplicaDeviceError, match="nochip"):
+            proc.wait_ready(timeout=120.0)
+        assert time.monotonic() - t0 < 60.0
+        assert proc.returncode == EXIT_NO_DEVICE
+    finally:
+        proc.terminate()
+
+
 class TestReplicaRunnerCLI:
     def test_replica_verb_wired(self):
         from nnstreamer_tpu.__main__ import main

@@ -398,6 +398,20 @@ class TestSingleShot:
             assert info.specs[0].shape == (2, 2)
         assert s.stats.total_invokes == 1
 
+    def test_accelerator_without_a_matching_device_raises(self):
+        """An explicit accelerator= request is a placement contract: with
+        no device of that platform among the ones jax selected it raises,
+        as custom=mesh does — never a quiet run on devices[0]."""
+        from nnstreamer_tpu.single import SingleShot
+
+        with pytest.raises(ValueError, match="accelerator=tpu: no tpu "
+                                             "devices present"):
+            SingleShot("jax", "builtin://scaler?factor=2",
+                       accelerator="tpu")
+        with SingleShot("jax", "builtin://scaler?factor=2",
+                        accelerator="cpu") as s:
+            assert s.invoke(np.ones((1, 2), np.float32))
+
 
 class TestShapeBucketing:
     def test_signature_tracking_and_warning(self, caplog):

@@ -488,7 +488,6 @@ class TestInvalidation:
         the old version's compiled program is evicted at commit and the
         stream still tracks the active model (never a stale artifact)."""
         from nnstreamer_tpu import aot
-        from nnstreamer_tpu.aot import cache as aot_cache
         from nnstreamer_tpu.service import ServiceManager
 
         monkeypatch.setenv(aot.CACHE_ENV, str(tmp_path / "aot"))
@@ -504,10 +503,6 @@ class TestInvalidation:
             assert aot.STATS["evictions"] >= 1
         finally:
             mgr.shutdown()
-            import jax
-
-            jax.config.update("jax_compilation_cache_dir", None)
-            aot_cache._xla_attached = None
 
 
 # ---------------------------------------------------------------------------

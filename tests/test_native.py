@@ -193,3 +193,34 @@ def test_datareposrc_replay_is_deterministic(tmp_path, use_native):
     got.clear()
     pipe.run(timeout=30.0)  # replay
     assert got == first and len(first) == 16
+
+
+def test_loader_ignores_a_library_of_other_source(tmp_path):
+    """Staleness is decided by content: the library's name carries a hash
+    of its source and compile command, so a binary built from anything
+    else — here garbage under another hash — is never opened."""
+    from nnstreamer_tpu.native import _build
+
+    src = os.path.join(os.path.dirname(_build.__file__), "csrc",
+                       "nns_core.cc")
+    want = _build.lib_path(src, "nns_core", ("-lpthread",))
+    assert os.path.basename(want).startswith("libnns_core-")
+    # the same source, compiled another way or edited: another name
+    assert _build.lib_path(src, "nns_core", ()) != want
+    edited = tmp_path / "nns_core.cc"
+    with open(src, "rb") as fh:
+        edited.write_bytes(fh.read() + b"\n// edited\n")
+    assert (os.path.basename(_build.lib_path(str(edited), "nns_core",
+                                             ("-lpthread",)))
+            != os.path.basename(want))
+    stale = os.path.join(os.path.dirname(want), "libnns_core-0000stale.so")
+    with open(stale, "wb") as fh:
+        fh.write(b"not an ELF file")
+    try:
+        lib = _build.load_once(src, "nns_core", native.ABI_VERSION,
+                               "nns_abi_version", lambda lib: None,
+                               extra_args=("-lpthread",))
+        assert lib is not None and lib._name == want
+    finally:
+        if os.path.exists(stale):
+            os.remove(stale)

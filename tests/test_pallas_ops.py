@@ -2,19 +2,13 @@
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
-from nnstreamer_tpu.ops.pallas_attention import flash_attention
-
-
-def dense_attention(q, k, v, causal=True):
-    scale = 1.0 / (q.shape[-1] ** 0.5)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
-    if causal:
-        S = q.shape[2]
-        s = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None, None], s, -1e30)
-    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+from nnstreamer_tpu.ops.pallas_attention import (
+    dense_attention,
+    flash_attention,
+)
+from nnstreamer_tpu.ops.pallas_decode import dense_cached_decode
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -38,16 +32,6 @@ def test_flash_rejects_ragged_seq():
 
 
 # -- cached-decode attention (ops/pallas_decode.py) --------------------------
-
-def dense_cached_decode(q, ck, cv, pos):
-    """The XLA oracle: decode_step's masked dense path."""
-    scale = 1.0 / (q.shape[-1] ** 0.5)
-    T = ck.shape[2]
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, ck) * scale
-    visible = (jnp.arange(T) <= pos)[None, None, None, :]
-    s = jnp.where(visible, s, -1e30)
-    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), cv)
-
 
 @pytest.mark.parametrize("pos", [0, 1, 31, 32, 63])
 @pytest.mark.parametrize("block_k", [16, 32, 64])

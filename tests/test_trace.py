@@ -116,31 +116,23 @@ class TestEnvActivation:
         assert "ENV_OK" in r.stdout, r.stderr
 
 
-class TestHwAccelProbe:
-    """Reference hw_accel.c analog: runtime capability check that cannot
-    hang the calling process (subprocess + timeout)."""
+class TestHwAccel:
+    """utils/hw_accel.py: what counts as the TPU, and where a Pallas TPU
+    kernel may run. Platform selection itself is jax's."""
 
-    def test_cpu_always_available(self):
-        from nnstreamer_tpu.utils.hw_accel import accel_available
+    def test_tpu_platform_is_stock_pjrt_only(self):
+        from nnstreamer_tpu.utils.hw_accel import TPU_PLATFORMS, is_tpu_platform
 
-        assert accel_available("cpu") is True
+        assert TPU_PLATFORMS == ("tpu",)
+        assert is_tpu_platform("tpu") and not is_tpu_platform("cpu")
 
-    def test_bogus_platform_unavailable(self):
-        from nnstreamer_tpu.utils.hw_accel import accel_available
+    def test_pallas_interprets_on_cpu_only(self):
+        from nnstreamer_tpu.utils.hw_accel import pallas_interpret
 
-        # False normally; None is legal if a loaded machine blows the
-        # probe timeout — only True would be wrong
-        assert accel_available("nonexistent_accel", timeout_s=60) is not True
-
-    def test_cache_hit_no_subprocess(self):
-        import subprocess as sp
-        from unittest import mock
-
-        from nnstreamer_tpu.utils.hw_accel import accel_available
-
-        primed = accel_available("nonexistent_accel")  # primes the cache
-        with mock.patch.object(sp, "run", side_effect=AssertionError):
-            assert accel_available("nonexistent_accel") is primed
+        assert pallas_interpret("tpu") is False   # Mosaic on the chip
+        assert pallas_interpret("cpu") is True    # the CPU tests
+        with pytest.raises(ValueError, match="gpu"):
+            pallas_interpret("gpu")               # never quietly interpret
 
 
 class TestChromeTrace:

@@ -229,6 +229,4 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    rc = main()
-    sys.stdout.flush()
-    os._exit(rc)  # skip backend teardown aborts (same stance as bench.py)
+    sys.exit(main())

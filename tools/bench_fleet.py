@@ -34,6 +34,12 @@ The wire legs' report lands in WIRE_r18.json (full mode).
 
     python tools/bench_fleet.py           # full bench, JSON report
     python tools/bench_fleet.py --smoke   # CI gate, short run
+
+Process layout: this tool starts several replica subprocesses that each
+initialize jax, and a TPU chip belongs to one process at a time. It is a
+CPU tool: run it with ``JAX_PLATFORMS=cpu`` (as CI does). On a one-chip
+host the second child cannot get the chip and ``wait_ready`` raises
+``ReplicaDeviceError``; one chip per child is ROADMAP R6.
 """
 from __future__ import annotations
 

@@ -118,9 +118,6 @@ def bench_sharded(n: int, frames: int) -> float:
 def main() -> None:
     import jax
 
-    from nnstreamer_tpu.utils.hw_accel import configure_default_platform
-
-    configure_default_platform(log=lambda m: print(m, file=sys.stderr))
     platform = jax.devices()[0].platform
 
     sizes = [int(a) for a in sys.argv[1:]] or [128, 512, 1024, 2048]

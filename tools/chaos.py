@@ -49,6 +49,12 @@ Usage::
 
 Exit nonzero when any scenario reports errors (or, under NNS_TSAN=1,
 when the sanitizer recorded a lock-order violation).
+
+Process layout: this tool starts several replica subprocesses that each
+initialize jax, and a TPU chip belongs to one process at a time. It is a
+CPU tool: run it with ``JAX_PLATFORMS=cpu`` (as CI does). On a one-chip
+host the second child cannot get the chip and ``wait_ready`` raises
+``ReplicaDeviceError``; one chip per child is ROADMAP R6.
 """
 from __future__ import annotations
 
@@ -749,6 +755,4 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    rc = main()
-    sys.stdout.flush()
-    os._exit(rc)  # skip backend teardown aborts (same stance as bench.py)
+    sys.exit(main())
