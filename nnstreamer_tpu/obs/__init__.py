@@ -11,9 +11,12 @@ cross-subsystem. Three pieces, one contract (near-zero cost when idle):
   fabric retries/hedges (child span per attempt), across the query wire
   (``meta["trace"]``), into the serving batcher (batch spans *link* to
   the N coalesced request spans) and fused device segments
-  (``fused:<head>..<tail>`` spans). Export: Perfetto/chrome-trace JSON,
-  next to ``utils.trace.jax_trace`` XPlanes. Gated on one module global
-  (:data:`~.context.TRACING`).
+  (``fused:<head>..<tail>`` spans). Export: Perfetto/chrome-trace JSON.
+  Gated on one module global (:data:`~.context.TRACING`). The serving
+  loop's program spans (:func:`~.context.span`: ``serving.pass`` and the
+  phases under it) are always on instead and also write into the JAX
+  profiler's trace, so they, and so far only they, lie in a
+  ``utils.trace.jax_trace`` XPlane on the device's time base.
 
 * :mod:`.metrics` — a Prometheus-style registry serving, service,
   fabric, queue, and fusion sources publish into; rendered at the
@@ -89,6 +92,7 @@ from .context import (  # noqa: F401
     export_chrome_trace,
     finished_spans,
     record_span,
+    span,
     spans_for_trace,
     start_span,
 )
@@ -151,6 +155,7 @@ __all__ = [
     "record_span",
     "render",
     "slo",
+    "span",
     "spans_for_trace",
     "start_span",
     "topology_hash",

@@ -18,10 +18,24 @@ paths check ONE module-global, :data:`TRACING`, and do nothing else when
 it is False. Spans use ``time.monotonic()`` so fabric/scheduler/fusion
 timestamps (already monotonic) pass straight through.
 
+Program spans (:func:`span`) are the exception to the gate: the serving
+plane's pass-level spans are recorded whenever a ``DecodeScheduler``
+runs, as ``ServingMetrics`` and the flight recorder are, because their
+rate is the device's (a pass is tens of milliseconds) and because a
+profiler session is started from outside the program and cannot flip a
+flag in it. Each enters a ``jax.profiler.TraceAnnotation("nns:<name>")``,
+so it lies in the profiler's host plane on the device trace's time base
+whenever a session runs, and lands in the same bounded ring on
+``time.monotonic``. Budget: a few microseconds a span with no session
+(``tools/microbench_overhead.py`` measures it), no id string, no flight
+event.
+
 Export: :func:`export_chrome_trace` writes chrome://tracing / Perfetto
 JSON (``X`` complete events); trace_id/span_id/parent_span_id/links ride
 each event's ``args`` so tooling (and tests) can reconstruct the tree.
-Device XPlanes from ``utils.trace.jax_trace`` line up next to it.
+That export is on this process's monotonic clock; what shares a time base
+with the device XPlanes of ``utils.trace.jax_trace`` is the program spans'
+annotations, inside the profiler's own trace.
 """
 from __future__ import annotations
 
@@ -47,8 +61,10 @@ _span_seq = itertools.count(1)
 
 # finished spans, bounded (deque append/iteration is thread-safe under
 # the GIL; oldest spans fall off — export is for recent activity, the
-# flight recorder keeps the tail even when tracing is later disabled)
-MAX_FINISHED = 16384
+# flight recorder keeps the tail even when tracing is later disabled).
+# Sized for the always-on program spans: about ten a scheduler pass, so
+# some minutes of passes of 60 ms and every request of that time
+MAX_FINISHED = 65536
 _finished: "collections.deque[Span]" = collections.deque(maxlen=MAX_FINISHED)
 _finished_seq = itertools.count(1)
 # the published total must never go BACKWARDS (Prometheus reads it as a
@@ -157,15 +173,20 @@ class Span:
                 f"{self.span_id} {self.status}>")
 
 
-def _record_finished(span: Span) -> None:
+def _append_finished(span) -> None:
+    """Into the ring, and into the published total."""
     global _finished_total
+    with _count_lock:
+        _finished_total = next(_finished_seq)
+    _finished.append(span)
+
+
+def _record_finished(span: Span) -> None:
     if _san.LEAK:
         # both terminal paths (Span.end and record_span's post-hoc
         # emission) funnel here: the span leaves the leak ledger
         _san.note_release("span", span.span_id)
-    with _count_lock:
-        _finished_total = next(_finished_seq)
-    _finished.append(span)
+    _append_finished(span)
     # spans land in the always-on flight recorder too, so a postmortem
     # dump shows the last requests even after tracing is switched off
     flight.record("span", f"{span.kind}:{span.name}",
@@ -177,7 +198,7 @@ def _record_finished(span: Span) -> None:
 def _coerce_parent(parent) -> Optional[TraceContext]:
     if parent is None:
         return None
-    if isinstance(parent, Span):
+    if isinstance(parent, (Span, ProgramSpan)):
         return parent.context()
     return TraceContext.from_meta(parent)
 
@@ -220,6 +241,132 @@ def record_span(name: str, kind: str = "span", parent=None,
     span.status = status
     _record_finished(span)
     return span.context()
+
+
+# -- program spans -----------------------------------------------------------
+
+# the name a program span has in the profiler's host plane: the prefix
+# tells the program's spans from anyone else's annotations in a trace
+ANNOTATION_PREFIX = "nns:"
+_annotation_names: Dict[str, str] = {}   # span name -> prefixed, built once
+_TraceAnnotation = None                  # jax.profiler's, loaded at first use
+_open = threading.local()                # .top: innermost open span, per thread
+
+
+class ProgramSpan:
+    """What :func:`span` returns: the context manager and the record in one
+    object, so a span costs one allocation. It reads like a :class:`Span`
+    (``trace_id``, ``span_id``, ``parent_id``, ``to_dict()``) for the
+    exporters, but keeps its parent as an object and makes the id strings
+    only when an exporter asks."""
+
+    __slots__ = ("name", "parent", "start_s", "dur_s", "status", "attrs",
+                 "tid", "_seq", "_prev", "_annotation")
+    kind = "program"
+    links = ()
+
+    def __init__(self, name: str, parent, attrs: dict):
+        self.name = name
+        self.parent = parent
+        self.start_s = self.dur_s = 0.0
+        self.status = "ok"
+        self.attrs = attrs
+        self.tid = threading.get_ident()
+        self._seq = next(_span_seq)
+        self._prev = self._annotation = None
+
+    def __enter__(self) -> "ProgramSpan":
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            from jax.profiler import TraceAnnotation as _TraceAnnotation
+        self._prev = getattr(_open, "top", None)
+        if self.parent is None:
+            self.parent = self._prev
+        _open.top = self
+        label = _annotation_names.get(self.name)
+        if label is None:
+            label = _annotation_names[self.name] = \
+                ANNOTATION_PREFIX + self.name
+        self._annotation = _TraceAnnotation(label)
+        self._annotation.__enter__()
+        self.start_s = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.dur_s = time.monotonic() - self.start_s
+        self._annotation.__exit__(exc_type, exc, tb)
+        _open.top = self._prev
+        self._prev = self._annotation = None
+        if exc_type is not None:
+            self.status = "error:" + exc_type.__name__
+        # the ring only: no leak ledger (a ``with`` cannot leak) and no
+        # flight event (a pass's spans would flush that ring's 512 events
+        # in seconds)
+        _append_finished(self)
+        return False
+
+    def record(self, start_s: float, end_s: float) -> "ProgramSpan":
+        """Write the span post hoc from two ``time.monotonic`` stamps
+        (a request's phases are known only when it is done). It is not in
+        the profiler's trace: an annotation cannot be made in the past."""
+        self.start_s = start_s
+        self.dur_s = max(0.0, end_s - start_s)
+        _append_finished(self)
+        return self
+
+    @property
+    def end_s(self) -> float:
+        return self.start_s + self.dur_s
+
+    @property
+    def span_id(self) -> str:
+        return f"p{self._seq:x}"
+
+    @property
+    def parent_id(self) -> Optional[str]:
+        return None if self.parent is None else self.parent.span_id
+
+    @property
+    def trace_id(self) -> str:
+        if self.parent is not None:
+            return self.parent.trace_id
+        return f"{_uniq}-p{self._seq:x}"
+
+    def context(self) -> TraceContext:
+        return TraceContext(self.trace_id, self.span_id)
+
+    def to_dict(self) -> dict:
+        return {
+            "name": self.name, "kind": self.kind,
+            "trace_id": self.trace_id, "span_id": self.span_id,
+            "parent_span_id": self.parent_id,
+            "start_s": self.start_s, "dur_s": self.dur_s,
+            "status": self.status, "attrs": dict(self.attrs), "links": [],
+        }
+
+    def __repr__(self):
+        return f"ProgramSpan<{self.name} {self.dur_s * 1e3:.3f} ms>"
+
+
+def span(name: str, parent=None, **attrs) -> ProgramSpan:
+    """A span of the program's own work, recorded whatever
+    :data:`TRACING` says (see the module's header for why and at what
+    cost)::
+
+        with obs_context.span("engine.step.pull", live=3) as sp:
+            ...
+        sp.dur_s            # seconds on time.monotonic
+
+    The parent is the span open on the same thread, unless ``parent``
+    names one: a :class:`ProgramSpan`, a :class:`Span`, a
+    :class:`TraceContext` or its meta dict (so a request's tree hangs
+    under the caller's trace). ``attrs`` may be added to until the span is
+    read (``sp.attrs["retired"] = 2``). A span whose times are known only
+    afterwards is written with :meth:`ProgramSpan.record` instead of
+    ``with``."""
+    if parent is not None and not isinstance(parent, ProgramSpan):
+        parent = _coerce_parent(parent)
+    return ProgramSpan(name, parent, attrs)
 
 
 # -- control -----------------------------------------------------------------

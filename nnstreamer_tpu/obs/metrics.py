@@ -393,10 +393,29 @@ def _collect_serving(reg: Registry) -> None:
     p99 = reg.gauge("nns_serving_latency_p99_seconds",
                     "total request latency p99 (recent window)",
                     ("scheduler",))
+    # the decode loop's passes (ServingMetrics.record_pass): counts, and
+    # the host wall of a pass split three ways; snapshot key -> counter
+    per_pass = {
+        key: reg.counter(f"nns_serving_{name}_total", text, ("scheduler",))
+        for key, name, text in (
+            ("passes", "passes", "decode-loop passes that did work"),
+            ("passes_with_step", "passes_with_step",
+             "passes that ran a decode step"),
+            ("passes_with_chunk", "passes_with_chunk",
+             "passes that ran a prefill chunk"),
+            ("passes_with_both", "passes_with_both",
+             "passes that ran a step and a chunk"),
+            ("prefill_chunks", "prefill_chunks", "prefill chunks ingested"),
+            ("host_sched_s", "host_sched_seconds",
+             "host wall of passes outside the engine's spans"),
+            ("host_engine_s", "host_engine_seconds",
+             "host wall under the engine's prepare and dispatch spans"),
+            ("pull_wait_s", "pull_wait_seconds",
+             "host wall under the engine's pull spans"))}
     # snapshot mirrors: repopulated from live schedulers each scrape, so
     # a garbage-collected scheduler's series disappears with it
     for inst in (subm, comp, fail, shedf, shedd, shedm, shedo, batches,
-                 depth, occ, wait, p99):
+                 depth, occ, wait, p99, *per_pass.values()):
         inst.clear()
     for name, sched in serving_metrics.iter_schedulers():
         try:
@@ -411,6 +430,8 @@ def _collect_serving(reg: Registry) -> None:
         shedm.set_total(snap.get("shed_memory", 0), scheduler=name)
         shedo.set_total(snap.get("shed_overload", 0), scheduler=name)
         batches.set_total(snap.get("batches", 0), scheduler=name)
+        for key, inst in per_pass.items():
+            inst.set_total(snap.get(key, 0), scheduler=name)
         depth.set(snap.get("queue_depth", 0), scheduler=name)
         occ.set(snap.get("batch_occupancy", 0.0), scheduler=name)
         wait.set(snap.get("estimated_wait_ms", 0.0) / 1e3, scheduler=name)

@@ -27,6 +27,13 @@ Join protocol (driven by ``DecodeScheduler``):
 
 Greedy (argmax) decoding only — sampling policy belongs to the caller's
 model entry; the scheduler contract is deterministic token streams.
+
+Spans (``obs.context.span``, always on, docs/observability.md): each call
+of a program is split where the host does three different things,
+``engine.<step|chunk>.prepare`` (page bookkeeping, padding), ``.dispatch``
+(uploads and the jitted call until it returns) and ``.pull`` (the device's
+answer brought to the host). ``host_s`` and ``pull_s`` are the running
+sums of the first two and of the third.
 """
 from __future__ import annotations
 
@@ -35,6 +42,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..obs import context as obs_context
 from ..obs import memory as obs_memory
 from .request import ServingError
 
@@ -59,6 +67,7 @@ class ContinuousLMEngine:
         self.params = params
         self.slots = slots
         self.compile_count = 0
+        self.host_s = self.pull_s = 0.0  # under the step's spans, summed
         self._jnp = jnp
 
         cache_dtype = params["embed"].dtype
@@ -161,13 +170,22 @@ class ContinuousLMEngine:
     def step(self) -> np.ndarray:
         """One decode step over every slot; returns (slots,) int32 (only
         active-slot entries are meaningful)."""
-        tok_dev, self._tok_dev, self._pos_dev, self._cache = self._step(
-            self._tok_dev, self._pos_dev, self._mask_dev, self._cache)
-        # nnlint: disable=NNL101 — one (slots,) pull per decode step: the
-        # scheduler needs host ints to append/retire (documented
-        # contract); explicit device_get, so it stays legal under the
-        # NNS_XFERCHECK disallow scopes and lands in the byte ledger
-        tok = self._jax.device_get(tok_dev)[:, 0]
+        live = self.active_slots
+        # the carry is device-resident, so nothing is prepared: the span
+        # keeps the tree the shape of the paged engine's
+        with obs_context.span("engine.step.prepare", live=live) as prepare:
+            pass
+        with obs_context.span("engine.step.dispatch", live=live) as dispatch:
+            tok_dev, self._tok_dev, self._pos_dev, self._cache = self._step(
+                self._tok_dev, self._pos_dev, self._mask_dev, self._cache)
+        with obs_context.span("engine.step.pull", live=live) as pull:
+            # nnlint: disable=NNL101 — one (slots,) pull per decode step:
+            # the scheduler needs host ints to append/retire (documented
+            # contract); explicit device_get, so it stays legal under the
+            # NNS_XFERCHECK disallow scopes and lands in the byte ledger
+            tok = self._jax.device_get(tok_dev)[:, 0]
+        self.host_s += prepare.dur_s + dispatch.dur_s
+        self.pull_s += pull.dur_s
         self._pos = self._pos + self._mask.astype(np.int32)
         self._tok[self._mask, 0] = tok[self._mask]
         return tok
@@ -251,6 +269,7 @@ class PagedLMEngine:
         self.chunk = min(chunk, cfg.max_seq)
         self.share_prefixes = share_prefixes
         self.compile_count = 0
+        self.host_s = self.pull_s = 0.0  # under the spans below, summed
         self._jnp = jnp
         self._jax = jax
 
@@ -273,6 +292,9 @@ class PagedLMEngine:
         self._pos = np.zeros((slots,), np.int32)
         self._mask = np.zeros((slots,), bool)
         self._pending: "dict[int, dict]" = {}  # slot -> chunked-prefill state
+        # slot -> [when its first chunk was dispatched, chunks so far]:
+        # outlives _pending, until the slot is released (prefill_stamp)
+        self._lane: "dict[int, list]" = {}
 
         self.cache_bytes = int(self._kpool.nbytes + self._vpool.nbytes)
         self.page_bytes = int(2 * L * H * page_size * Dh
@@ -535,6 +557,14 @@ class PagedLMEngine:
                 covered = min(covered, tokens.size - 1)
         self._pending[slot] = {"tokens": tokens, "next": covered,
                                "steps": steps}
+        self._lane.pop(slot, None)
+
+    def prefill_stamp(self, slot: int) -> "tuple[float, int]":
+        """``(first_chunk_t, chunks)`` of the prompt in ``slot``: when its
+        first chunk was dispatched (``time.monotonic``; until then it
+        waited in the lane behind older prompts) and how many chunks have
+        run. Kept until the slot is released."""
+        return tuple(self._lane[slot])
 
     def prefill_tick(self) -> "list[tuple[int, int]]":
         """Ingest ONE chunk of ONE pending prompt (oldest first);
@@ -548,19 +578,27 @@ class PagedLMEngine:
         st = self._pending[slot]
         tokens, start = st["tokens"], st["next"]
         n_valid = min(self.chunk, tokens.size - start)
-        self._ensure_writable(slot, start, start + n_valid)
-        padded = np.zeros((self.chunk,), np.int32)
-        padded[:n_valid] = tokens[start:start + n_valid]
-        logits, self._kpool, self._vpool = self._prefill_chunk(
-            jnp.asarray(padded), jnp.asarray(start, jnp.int32),
-            jnp.asarray(n_valid, jnp.int32), self._bt[slot],
-            self._kpool, self._vpool)
+        attrs = {"slot": slot, "start": start, "n_valid": n_valid}
+        with obs_context.span("engine.chunk.prepare", **attrs) as prepare:
+            self._ensure_writable(slot, start, start + n_valid)
+            padded = np.zeros((self.chunk,), np.int32)
+            padded[:n_valid] = tokens[start:start + n_valid]
+        with obs_context.span("engine.chunk.dispatch", **attrs) as dispatch:
+            logits, self._kpool, self._vpool = self._prefill_chunk(
+                jnp.asarray(padded), jnp.asarray(start, jnp.int32),
+                jnp.asarray(n_valid, jnp.int32), self._bt[slot],
+                self._kpool, self._vpool)
+        self.host_s += prepare.dur_s + dispatch.dur_s
+        lane = self._lane.setdefault(slot, [dispatch.start_s, 0])
+        lane[1] += 1
         st["next"] = start + n_valid
         if st["next"] < tokens.size:
             return []
         # prompt complete: seed the decode carry from the last REAL row
         del self._pending[slot]
-        first = int(np.argmax(np.asarray(logits[n_valid - 1])))
+        with obs_context.span("engine.chunk.pull", **attrs) as pull:
+            first = int(np.argmax(np.asarray(logits[n_valid - 1])))
+        self.pull_s += pull.dur_s
         self._tok[slot, 0] = first
         self._pos[slot] = tokens.size
         self._mask[slot] = True
@@ -595,17 +633,25 @@ class PagedLMEngine:
         """One paged decode step over every slot; may raise
         PagePoolExhausted when an active slot crosses into a page the
         pool cannot supply (scheduler preempts a victim and retries)."""
-        for s in np.flatnonzero(self._mask):
-            if self._pos[s] < self.cfg.max_seq:
-                self._ensure_writable(int(s), int(self._pos[s]),
-                                      int(self._pos[s]) + 1)
-        tok_dev, self._tok_dev, self._pos_dev, self._kpool, self._vpool = \
-            self._step(self._tok_dev, self._pos_dev, self._mask_dev,
-                       self._bt, self._kpool, self._vpool)
-        # nnlint: disable=NNL101 — one (slots,) pull per decode step: the
-        # scheduler needs host ints to append/retire (documented
-        # contract), matching the dense engine's ledger entry
-        tok = self._jax.device_get(tok_dev)
+        slots = np.flatnonzero(self._mask)
+        live = len(slots)
+        with obs_context.span("engine.step.prepare", live=live) as prepare:
+            for s in slots:
+                if self._pos[s] < self.cfg.max_seq:
+                    self._ensure_writable(int(s), int(self._pos[s]),
+                                          int(self._pos[s]) + 1)
+        with obs_context.span("engine.step.dispatch", live=live) as dispatch:
+            (tok_dev, self._tok_dev, self._pos_dev, self._kpool,
+             self._vpool) = self._step(
+                self._tok_dev, self._pos_dev, self._mask_dev,
+                self._bt, self._kpool, self._vpool)
+        with obs_context.span("engine.step.pull", live=live) as pull:
+            # nnlint: disable=NNL101 — one (slots,) pull per decode step:
+            # the scheduler needs host ints to append/retire (documented
+            # contract), matching the dense engine's ledger entry
+            tok = self._jax.device_get(tok_dev)
+        self.host_s += prepare.dur_s + dispatch.dur_s
+        self.pull_s += pull.dur_s
         self._pos = self._pos + self._mask.astype(np.int32)
         self._tok[self._mask, 0] = tok[self._mask]
         return tok
@@ -683,13 +729,15 @@ class PagedLMEngine:
         self._pos_dev = self._jnp.asarray(self._pos)
 
     def release(self, slot: int) -> None:
-        self._pending.pop(slot, None)
-        self.pool.release([int(p) for p in self._bt[slot] if p])  # pairs-with: alloc/ref (admit path)
-        self._bt[slot] = 0
-        self._mask[slot] = False
-        self._tok[slot, 0] = 0
-        self._pos[slot] = 0
-        self._sync_device_state()
+        with obs_context.span("engine.release", slot=slot):
+            self._pending.pop(slot, None)
+            self._lane.pop(slot, None)
+            self.pool.release([int(p) for p in self._bt[slot] if p])  # pairs-with: alloc/ref (admit path)
+            self._bt[slot] = 0
+            self._mask[slot] = False
+            self._tok[slot, 0] = 0
+            self._pos[slot] = 0
+            self._sync_device_state()
 
     # -- preemption -----------------------------------------------------------
     def preempt(self, slot: int) -> dict:

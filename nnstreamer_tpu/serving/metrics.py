@@ -1,15 +1,21 @@
 """Serving observability: per-scheduler aggregates + global snapshot (L6).
 
 Builds on the same primitives the filter layer reports through
-(``utils/stats.py`` — InvokeStats device/dispatch channels, and the new
-LatencyReservoir for tails) and feeds the tracer fan-out in
-``utils/trace.py`` (``notify_serving`` — batch spans land next to element
-spans in the chrome trace).
+(``utils/stats.py`` — InvokeStats device/dispatch channels, and the
+LatencyReservoir for tails). The counters here are counts and host-clock
+sums; the serving TRACE is elsewhere: the one-shot ``Scheduler`` reports
+each batch to the tracer fan-out in ``utils/trace.py``
+(``notify_serving``) and, with request tracing on, as a batch span;
+``DecodeScheduler`` calls neither and writes program spans
+(``obs.context.span``: ``serving.pass`` and the phases under it, always
+on), whose boundaries are the ones the pass counters below count at.
 
 Per-REQUEST metrics live on the request itself (``Request.metrics``:
-enqueue_time, batch_id, bucket, queue_wait_s, device_time_s, ttft_s,
-total_latency_s). This module aggregates across requests/batches and
-exposes ``serving.metrics_snapshot()`` over every live scheduler.
+enqueue_time, queue_wait_s, ttft_s, total_latency_s; one-shot batches
+add batch_id, bucket, device_time_s; decode requests add slot, admit_t,
+first_chunk_t, first_token_t, token_t, chunks). This module aggregates
+across requests/batches and exposes ``serving.metrics_snapshot()`` over
+every live scheduler.
 """
 from __future__ import annotations
 
@@ -85,6 +91,15 @@ class ServingMetrics:
         self.retired_early = 0     # decode: finished before max steps (eos)
         self.preempted = 0         # pages evicted to host (pressure)
         self.restored = 0          # preempted requests resumed
+        # decode loop, per pass that did work (scheduler.py ``_loop``)
+        self.passes = 0
+        self.passes_with_step = 0
+        self.passes_with_chunk = 0
+        self.passes_with_both = 0
+        self.prefill_chunks = 0
+        self.host_sched_s = 0.0    # passes less the engine's spans
+        self.host_engine_s = 0.0   # prepare + dispatch, both programs
+        self.pull_wait_s = 0.0     # the engine's pulls (tokens, logits)
         # device channel: batch execution time (dispatch+block, the
         # reference-comparable number); reservoirs: per-request tails
         self.device = InvokeStats()
@@ -138,12 +153,31 @@ class ServingMetrics:
 
     def record_decode_step(self, active: int, slots: int,
                            device_s: float) -> None:
+        """``device_s`` is host wall around the engine's ``step()``: its
+        dispatch and the pull of the tokens, not time on the device's
+        clock (the queue's service-time estimate reads the same number)."""
         with self._lock:
             self.decode_steps += 1
             self.batched_rows += active
             self.padded_rows += slots
         self.device.record(device_s)
         self.device.record_device(device_s)
+
+    def record_pass(self, step: bool, chunks: int, host_sched_s: float,
+                    host_engine_s: float, pull_wait_s: float) -> None:
+        """One pass of the decode loop that did work: whether it ran a
+        decode step, how many prefill chunks (today at most one), and its
+        host wall split three ways (the scheduler's own code, the engine's
+        prepare and dispatch, the engine's pulls)."""
+        with self._lock:
+            self.passes += 1
+            self.passes_with_step += step
+            self.passes_with_chunk += chunks > 0
+            self.passes_with_both += step and chunks > 0
+            self.prefill_chunks += chunks
+            self.host_sched_s += host_sched_s
+            self.host_engine_s += host_engine_s
+            self.pull_wait_s += pull_wait_s
 
     def record_early_retire(self) -> None:
         with self._lock:
@@ -176,6 +210,14 @@ class ServingMetrics:
                 "preempted": self.preempted,
                 "restored": self.restored,
                 "batch_occupancy": occupancy,
+                "passes": self.passes,
+                "passes_with_step": self.passes_with_step,
+                "passes_with_chunk": self.passes_with_chunk,
+                "passes_with_both": self.passes_with_both,
+                "prefill_chunks": self.prefill_chunks,
+                "host_sched_s": self.host_sched_s,
+                "host_engine_s": self.host_engine_s,
+                "pull_wait_s": self.pull_wait_s,
             }
         out["device"] = self.device.snapshot()
         out["queue_wait"] = self.queue_wait.snapshot()

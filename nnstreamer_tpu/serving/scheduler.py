@@ -11,8 +11,9 @@ Two loops over the same admission/queue/bucketing machinery:
   early and free their slot — the Hermes/Orca-style continuous batching
   loop (arxiv 2409.04249).
 
-Both record per-request metrics (queue wait, batch id, bucket, device
-time, ttft, total) and register with ``serving.metrics_snapshot()``.
+Both record per-request metrics (queue wait, batch id, bucket, ttft,
+total; the one-shot loop device time, the decode loop a stamp per token
+and per phase) and register with ``serving.metrics_snapshot()``.
 
 The executor's **compile-count hook** makes the no-recompile-storm
 property testable: ``JitExecutor`` counts XLA traces (the counter lives
@@ -40,6 +41,11 @@ from .request import (
     SchedulerClosedError,
     ServingError,
 )
+
+
+# a decode request's phases, in order, between the stamps on its metrics
+_REQUEST_PHASES = ("request.queue", "request.lane", "request.prefill",
+                   "request.decode")
 
 
 def _tensors_nbytes(tensors) -> int:
@@ -403,7 +409,14 @@ class DecodeScheduler:
       victim with the MOST deadline slack to host and requeues it;
       readmission restores byte-exact — the request is never dropped;
     * ``projected_page_bytes(tokens, steps)`` — the AdmissionGuard
-      reserves page-pool bytes instead of dense tensor bytes.
+      reserves page-pool bytes instead of dense tensor bytes;
+    * ``prefill_stamp(slot) -> (first_chunk_t, chunks)`` — when the
+      slot's first prompt chunk was dispatched and how many it took
+      (``Request.metrics``, the ``request.lane`` span);
+    * ``host_s``/``pull_s`` — running sums of the time under the
+      engine's prepare and dispatch spans, and under its pull spans
+      (``ServingMetrics.host_engine_s``/``pull_wait_s``; an engine
+      without them counts as scheduler time).
 
     Page-release invariant: EVERY request exit path — normal retire,
     deadline shed (queued or mid-decode), batch failure, close — goes
@@ -428,6 +441,9 @@ class DecodeScheduler:
         self._active: Dict[int, Request] = {}
         self._prefilling: Dict[int, Request] = {}  # chunked-prefill slots
         self._free: List[int] = list(range(engine.slots))[::-1]
+        self._has_chunked = getattr(engine, "prefill_tick", None) is not None
+        self._step_tokens = getattr(engine, "step_tokens", None)
+        self._pass_tokens = 0  # tokens emitted in the pass that is running
         self._running = threading.Event()
         self._closed = False
         self._thread: Optional[threading.Thread] = None
@@ -494,6 +510,7 @@ class DecodeScheduler:
         req = Request((tokens,), priority=priority, deadline=deadline,
                       steps=steps, eos_id=eos_id, on_done=on_done,
                       trace=trace)
+        req.metrics["token_t"] = []  # one time.monotonic per emitted token
         if obs_context.TRACING and trace is None:
             req._span = obs_context.start_span(
                 f"serving.request:{self.name}", kind="serving",
@@ -518,7 +535,35 @@ class DecodeScheduler:
     _fail_if_closed_after_put = Scheduler._fail_if_closed_after_put
     _reserve_mem = Scheduler._reserve_mem
     _release_mem = Scheduler._release_mem
-    _record_done = Scheduler._record_done
+
+    def _record_done(self, req: Request, failed: bool = False) -> None:
+        Scheduler._record_done(self, req, failed=failed)
+        self._write_request_tree(req, failed)
+
+    def _write_request_tree(self, req: Request, failed: bool) -> None:
+        """The request's span tree, written once from the stamps on
+        ``req.metrics``: ``request`` (enqueue → now) and, as far as the
+        request got, ``request.queue`` (enqueue → slot), ``request.lane``
+        (``admit_start`` → its first chunk dispatched), ``request.prefill``
+        (first chunk → first token), ``request.decode`` (first → last
+        token). It hangs under the caller's trace where one was passed."""
+        m = req.metrics
+        stamps = m["token_t"]
+        root = obs_context.span(
+            "request", parent=req.trace, request_id=req.id,
+            prompt_len=int(req.tensors[0].size), chunks=m.get("chunks", 0),
+            tokens=len(stamps), slot=m.get("slot", -1))
+        if failed:
+            root.status = "error"
+        root.record(m["enqueue_time"], time.monotonic())
+        marks = (m["enqueue_time"], m.get("admit_t"), m.get("first_chunk_t"),
+                 stamps[0] if stamps else None,
+                 stamps[-1] if stamps else None)
+        for name, t0, t1 in zip(_REQUEST_PHASES, marks, marks[1:]):
+            if t0 is None or t1 is None:
+                break
+            obs_context.span(name, parent=root, request_id=req.id).record(
+                t0, t1)
 
     def _projected_bytes(self, req: Request) -> int:
         """Paged engines reserve PAGES (what the request will actually
@@ -563,6 +608,7 @@ class DecodeScheduler:
         t0 = time.monotonic()
         req.metrics.setdefault("queue_wait_s",
                                t0 - req.metrics["enqueue_time"])
+        req.metrics.setdefault("admit_t", t0)
         blob = req.metrics.pop("_preempt_blob", None)
         if blob is not None:
             try:
@@ -618,7 +664,9 @@ class DecodeScheduler:
         req.metrics["slot"] = slot
         req.metrics["ttft_s"] = now - req.metrics["enqueue_time"]
         req.metrics["prefill_s"] = now - t0
-        req.tokens.append(first)
+        # a blocking admit has no prefill lane to wait in
+        req.metrics["first_chunk_t"] = t0
+        self._emit(req, first, now)
         if self._finished(req, first):
             self._retire(slot, req, early=False)
         else:
@@ -698,17 +746,17 @@ class DecodeScheduler:
         req.complete((np.asarray(req.tokens, np.int32),))
         self._record_done(req)
 
-    def _prefill_tick(self) -> None:
+    def _prefill_tick(self) -> bool:
         """Ingest ONE prompt chunk (chunked-prefill engines): long
         prompts advance one bounded chunk per loop pass, interleaved
-        with decode steps, instead of stalling the whole batch."""
+        with decode steps, instead of stalling the whole batch. True
+        when a chunk ran."""
         from .kv_pool import PagePoolExhausted
 
         # bounded retry IN THIS PASS: preempting a victim only helps if
         # the tick reclaims the freed pages before the admit phase
         # restores the victim (otherwise preempt/restore ping-pong
         # forever and the starved prompt never advances)
-        done = []
         for _ in range(self.engine.slots + 1):
             try:
                 done = self.engine.prefill_tick()
@@ -723,7 +771,7 @@ class DecodeScheduler:
                     req = self._prefilling.pop(slot)
                     self._fail_mem(req)
                     self._retire_slot_only(slot)
-                return
+                return False
             except Exception as e:  # noqa: BLE001 - fail that prompt, keep serving
                 logger.exception("serving %s: prefill chunk failed",
                                  self.name)
@@ -734,8 +782,11 @@ class DecodeScheduler:
                              else ServingError(f"decode prefill failed: {e}"))
                     self._record_done(req, failed=True)
                     self._retire_slot_only(slot)
-                return
+                return False
+        else:
+            return False  # every retry preempted a victim; none ran
         now = time.monotonic()
+        stamp = getattr(self.engine, "prefill_stamp", None)
         for slot, first in done:
             req = self._prefilling.pop(slot, None)
             if req is None:
@@ -743,11 +794,17 @@ class DecodeScheduler:
             req.metrics["ttft_s"] = now - req.metrics["enqueue_time"]
             req.metrics["prefill_s"] = now - req.metrics.pop(
                 "_prefill_t0", now)
-            req.tokens.append(int(first))
+            if stamp is not None:
+                # the engine owns the lane: when this prompt's first chunk
+                # was dispatched, and how many it took
+                (req.metrics["first_chunk_t"],
+                 req.metrics["chunks"]) = stamp(slot)
+            self._emit(req, int(first), now)
             if self._finished(req, int(first)):
                 self._retire(slot, req, early=False)
             else:
                 self._active[slot] = req
+        return True
 
     def _shed_expired_active(self) -> None:
         """Mid-decode deadline enforcement: a stream that cannot finish
@@ -765,83 +822,130 @@ class DecodeScheduler:
                 self._record_done(req, failed=True)
                 self._retire_slot_only(slot)
 
-    def _loop(self) -> None:
-        from .kv_pool import PagePoolExhausted
+    def _emit(self, req: Request, tok: int, now: float) -> None:
+        """One generated token: the token, and when it came out."""
+        req.tokens.append(tok)
+        stamps = req.metrics["token_t"]
+        if not stamps:
+            req.metrics["first_token_t"] = now
+        stamps.append(now)
+        self._pass_tokens += 1
 
-        has_chunked = getattr(self.engine, "prefill_tick", None) is not None
-        step_tokens = getattr(self.engine, "step_tokens", None)
+    def _loop(self) -> None:
+        """One pass: JOIN (fill free slots from the queue), one prefill
+        chunk, one decode step, ROUTE (append, retire). Each pass that
+        did work is a ``serving.pass`` span with the phases under it; a
+        wait on an empty queue with nothing live is ``serving.idle_wait``
+        (docs/observability.md has the tree)."""
+        engine, metrics = self.engine, self.metrics
         while self._running.is_set():
-            # JOIN: fill free slots from the queue between decode steps —
-            # block only when the whole batch is idle
+            first = None
+            if not (self._active or self._prefilling):
+                # block only when the whole batch is idle
+                with obs_context.span("serving.idle_wait"):
+                    first = self.queue.get(timeout=0.05)
+                if first is None:
+                    continue
+            # what the engine spent under its own spans, before and after
+            host0 = getattr(engine, "host_s", 0.0)
+            pull0 = getattr(engine, "pull_s", 0.0)
+            self._pass_tokens = 0
+            with obs_context.span("serving.pass", live=len(self._active),
+                                  prefilling=len(self._prefilling),
+                                  queue_depth=self.queue.depth()) as sp:
+                chunks, step = self._pass(first)
+                sp.attrs.update(chunks=chunks, steps=int(step),
+                                tokens=self._pass_tokens)
+            host_s = getattr(engine, "host_s", 0.0) - host0
+            pull_s = getattr(engine, "pull_s", 0.0) - pull0
+            metrics.record_pass(step, chunks, sp.dur_s - host_s - pull_s,
+                                host_s, pull_s)
+
+    def _pass(self, first: Optional[Request]) -> Tuple[int, bool]:
+        """The work of one pass; ``first`` is the request an idle wait
+        took. Returns (prefill chunks run, whether a decode step ran)."""
+        with obs_context.span("sched.admit") as admit:
+            # JOIN: fill free slots from the queue between decode steps
+            admitted, req = 0, first
             while self._free:
-                busy = self._active or self._prefilling
-                req = self.queue.get(timeout=0 if busy else 0.05)
                 if req is None:
-                    break
+                    req = self.queue.get(timeout=0)
+                    if req is None:
+                        break
                 if not self._admit_one(req):
                     break  # pool saturated this pass; retry next pass
-            if has_chunked and self._prefilling:
-                self._prefill_tick()
-            if not self._active:
-                continue
+                admitted, req = admitted + 1, None
+            admit.attrs["admitted"] = admitted
+        chunks = 0
+        if self._has_chunked and self._prefilling:
+            chunks = int(self._prefill_tick())
+        if self._active:
             self._shed_expired_active()
-            if not self._active:
-                continue
-            t0 = time.monotonic()
-            toks = bursts = None
-            stepped = False
-            # bounded retry IN THIS PASS (same reasoning as
-            # _prefill_tick): after a preemption the survivors must
-            # retry the step BEFORE the admit phase restores the victim,
-            # or the two sides ping-pong pages forever with zero decode
-            # progress. min_active=2 — preempting the only runner to
-            # feed itself is that same livelock in one slot.
-            for _ in range(self.engine.slots + 1):
-                try:
-                    if step_tokens is not None:
-                        bursts = step_tokens()  # 1..K tokens per slot
-                    else:
-                        # nnlint: disable=NNL101 — the decode loop's one
-                        # designed pull: (slots,) tokens must reach host
-                        # to route/retire
-                        toks = np.asarray(self.engine.step())
-                    stepped = True
-                    break
-                except PagePoolExhausted:
-                    # a running stream crossed into a page the pool
-                    # cannot supply: evict the slackest victim and retry
-                    # now; if nothing is preemptable the starved stream
-                    # sheds typed rather than OOM-ing the device
-                    if self._preempt_victim(min_active=2):
-                        continue
-                    if self._active:
-                        slot = next(iter(self._active))
-                        req = self._active.pop(slot)
-                        self._fail_mem(req)
-                        self._retire_slot_only(slot)
-                    break
-                except Exception as e:  # noqa: BLE001 - fail batch, keep serving
-                    err = ServingError(f"decode step failed: {e}")
-                    logger.exception("serving %s: decode step failed",
-                                     self.name)
-                    for slot, req in list(self._active.items()):
-                        req.fail(err)
-                        self._record_done(req, failed=True)
-                        self._retire_slot_only(slot)
-                    break
-            if not stepped:
-                continue
-            device_s = time.monotonic() - t0
+        return chunks, bool(self._active) and self._decode_step()
+
+    def _decode_step(self) -> bool:
+        """One decode step over the live slots and the routing of its
+        tokens. False when the step did not run (its requests were shed
+        or failed instead)."""
+        from .kv_pool import PagePoolExhausted
+
+        t0 = time.monotonic()
+        toks = bursts = None
+        stepped = False
+        # bounded retry IN THIS PASS (same reasoning as _prefill_tick):
+        # after a preemption the survivors must retry the step BEFORE the
+        # admit phase restores the victim, or the two sides ping-pong
+        # pages forever with zero decode progress. min_active=2 —
+        # preempting the only runner to feed itself is that same livelock
+        # in one slot.
+        for _ in range(self.engine.slots + 1):
+            try:
+                if self._step_tokens is not None:
+                    bursts = self._step_tokens()  # 1..K tokens per slot
+                else:
+                    # nnlint: disable=NNL101 — the decode loop's one
+                    # designed pull: (slots,) tokens must reach host
+                    # to route/retire
+                    toks = np.asarray(self.engine.step())
+                stepped = True
+                break
+            except PagePoolExhausted:
+                # a running stream crossed into a page the pool cannot
+                # supply: evict the slackest victim and retry now; if
+                # nothing is preemptable the starved stream sheds typed
+                # rather than OOM-ing the device
+                if self._preempt_victim(min_active=2):
+                    continue
+                if self._active:
+                    slot = next(iter(self._active))
+                    req = self._active.pop(slot)
+                    self._fail_mem(req)
+                    self._retire_slot_only(slot)
+                break
+            except Exception as e:  # noqa: BLE001 - fail batch, keep serving
+                err = ServingError(f"decode step failed: {e}")
+                logger.exception("serving %s: decode step failed",
+                                 self.name)
+                for slot, req in list(self._active.items()):
+                    req.fail(err)
+                    self._record_done(req, failed=True)
+                    self._retire_slot_only(slot)
+                break
+        if not stepped:
+            return False
+        now = time.monotonic()
+        with obs_context.span("sched.route") as route:
+            # host wall around the engine's dispatch and pull
+            device_s = now - t0
             self.queue.observe_service_time(device_s)
             self.metrics.record_decode_step(len(self._active),
                                             self.engine.slots, device_s)
+            retired = 0
             for slot, req in list(self._active.items()):
                 burst = ([int(toks[slot])] if bursts is None
                          else [int(t) for t in bursts[slot]])
-                req.metrics["device_time_s"] = \
-                    req.metrics.get("device_time_s", 0.0) + device_s
                 for tok in burst:
-                    req.tokens.append(tok)
+                    self._emit(req, tok, now)
                     if self._finished(req, tok):
                         # RETIRE early: the slot frees this step, not at
                         # the end of the longest sequence in the batch —
@@ -850,7 +954,10 @@ class DecodeScheduler:
                         # advanced past them)
                         self._retire(slot, req,
                                      early=len(req.tokens) < req.steps)
+                        retired += 1
                         break
+            route.attrs["retired"] = retired
+        return True
 
     def _retire_slot_only(self, slot: int) -> None:
         self._active.pop(slot, None)

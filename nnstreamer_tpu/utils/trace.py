@@ -16,7 +16,10 @@ Activation:
     (the reference's GST_DEBUG_DUMP_DOT_DIR).
 
 Device-side: ``jax_trace(logdir)`` context manager wraps
-``jax.profiler.trace`` so TPU XPlane traces line up with host tracer spans.
+``jax.profiler.trace``. What lines up with the TPU's XPlane there is the
+serving loop's program spans (``obs.context.span``: ``nns:`` events in the
+profiler's host plane, on the device trace's time base); the tracers
+above keep their own host clock and are not in that trace.
 """
 from __future__ import annotations
 
@@ -159,8 +162,9 @@ class QueueLevelTracer(Tracer):
 
 class ChromeTraceTracer(Tracer):
     """Complete-event trace viewable in chrome://tracing / Perfetto: one
-    'X' span per element chain per buffer, thread-separated, lining up
-    with ``jax_trace`` device XPlanes. Path from NNS_CHROME_TRACE
+    'X' span per element chain per buffer, thread-separated (its own
+    host clock: beside a ``jax_trace`` XPlane, not aligned with it). Path
+    from NNS_CHROME_TRACE
     (explicit file), else ``<NNS_TRACE_DIR or system tmp>/
     nns_trace-<pid>.json`` — an ARTIFACT path, never the working
     directory: env-activated runs used to drop ``nns_trace.json`` into
@@ -437,8 +441,10 @@ def dump_dot(pipeline, reason: str = "play") -> Optional[str]:
 
 @contextlib.contextmanager
 def jax_trace(logdir: str):
-    """Wrap a pipeline run in a JAX profiler trace (XPlane/TensorBoard) so
-    device timelines align with host tracer spans."""
+    """Wrap a pipeline run, or a few seconds of a live scheduler, in a JAX
+    profiler trace (XPlane/TensorBoard). The serving loop's program spans
+    (``obs.context.span``) are in it as ``nns:`` host events aligned with
+    the device timelines; the stream path's tracers are not."""
     import jax
 
     jax.profiler.start_trace(logdir)

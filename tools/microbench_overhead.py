@@ -45,6 +45,13 @@ fused dispatch itself: disabled = one module-global check at each choke
 point, gated <= 2%; enabled mode (transfer-guard scopes + byte ledger)
 reported alongside.
 
+The serving plane's program spans (``obs.context.span``) are the one
+instrument that is ALWAYS on, so their leg gates a cost, not a fast path:
+one ``with span(...)`` with no profiler session must stay within 5 us
+(ISSUE 24's budget: at most 12 a scheduler pass of 64 ms or more, under
+0.1% of it); what it costs while a ``jax.profiler`` session records the
+annotation is reported alongside.
+
 Usage:
   python tools/microbench_overhead.py [n_frames]      # full report
   python tools/microbench_overhead.py --json OUT.json # + machine-readable
@@ -166,6 +173,45 @@ def tracing_overhead_report(n_bufs: int, attempts: int = 3) -> dict:
         # reported, not gated: what turning tracing ON costs
         "enabled_overhead_frac": enabled / baseline - 1.0,
     }
+
+
+SPAN_BUDGET_US = 5.0
+
+
+def span_cost_report(n_spans: int = 20000, attempts: int = 5) -> dict:
+    """Cost of one program span (``obs.context.span``: an allocation, the
+    thread's stack, a ``TraceAnnotation`` and two clock reads, one ring
+    append), best of ``attempts`` batches: with no profiler session (gated
+    at :data:`SPAN_BUDGET_US`) and while a session records it (reported)."""
+    import shutil
+    import tempfile
+
+    from nnstreamer_tpu.obs import context as obs_context
+
+    def batch() -> float:
+        t0 = time.perf_counter()
+        for _ in range(n_spans):
+            with obs_context.span("engine.step.prepare", live=3):
+                pass
+        return (time.perf_counter() - t0) / n_spans * 1e6
+
+    batch()  # first use loads jax.profiler and fills the ring
+    idle = min(batch() for _ in range(attempts))
+    logdir = tempfile.mkdtemp(prefix="nns_span_cost_")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(logdir, profiler_options=opts)
+    try:
+        traced = min(batch() for _ in range(attempts))
+    finally:
+        jax.profiler.stop_trace()
+        shutil.rmtree(logdir, ignore_errors=True)
+        obs_context.reset()
+    return {"n_spans": n_spans, "attempts": attempts,
+            "no_session_us_per_span": idle, "budget_us": SPAN_BUDGET_US,
+            # reported, not gated: a session is a deliberate trade
+            "in_session_us_per_span": traced}
 
 
 def profiler_overhead_report(n_bufs: int, attempts: int = 3) -> dict:
@@ -519,6 +565,8 @@ def main() -> None:
         leakcheck = leakcheck_overhead_report(n_bufs=2000, attempts=4)
         xfercheck = xfercheck_overhead_report(n_bufs=1500, attempts=4)
         wirefuzz = wirefuzz_overhead_report(n_bufs=2000, attempts=4)
+        spans = span_cost_report()
+        best["span_cost"] = spans
         best["tracing_overhead"] = tracing
         best["profiler_overhead"] = profiling
         best["placement_overhead"] = placement
@@ -592,15 +640,25 @@ def main() -> None:
               f"{wirefuzz['disabled_overhead_frac'] * 100:+.2f}% vs "
               f"baseline (gate <= 2%), enabled mode "
               f"{wirefuzz['enabled_overhead_frac'] * 100:+.1f}% ({verdict})")
+        span_ok = spans["no_session_us_per_span"] <= SPAN_BUDGET_US
+        verdict = ("OK" if span_ok
+                   else "REGRESSION — a program span costs more than its "
+                        "budget")
+        print(f"smoke: program span {spans['no_session_us_per_span']:.2f} us "
+              f"with no profiler session (gate <= {SPAN_BUDGET_US:.0f} us), "
+              f"{spans['in_session_us_per_span']:.2f} us inside one "
+              f"({verdict})")
         sys.exit(0 if ok and trc_ok and prof_ok and plc_ok and mem_ok
-                 and qual_ok and leak_ok and xc_ok and wf_ok else 1)
+                 and qual_ok and leak_ok and xc_ok and wf_ok and span_ok
+                 else 1)
 
     n_bufs = args.n_frames
     report = {"n_frames": n_bufs, "host_chain": [], "device_chain": None,
               "tracing_overhead": None, "profiler_overhead": None,
               "placement_overhead": None, "memory_overhead": None,
               "quality_overhead": None, "leakcheck_overhead": None,
-              "xfercheck_overhead": None, "wirefuzz_overhead": None}
+              "xfercheck_overhead": None, "wirefuzz_overhead": None,
+              "span_cost": None}
     # before any other measurement: the baseline leg requires a process
     # where tracing has never been enabled
     report["tracing_overhead"] = tracing_overhead_report(
@@ -673,6 +731,12 @@ def main() -> None:
           f"({t['enabled_overhead_frac'] * 100:+.1f}%) | "
           f"disabled {t['disabled_us_per_frame']:8.1f} "
           f"({t['disabled_overhead_frac'] * 100:+.2f}%, gate <= 2%)")
+    report["span_cost"] = span_cost_report()
+    t = report["span_cost"]
+    print("— program span (obs.context.span, always on) —")
+    print(f"no profiler session {t['no_session_us_per_span']:6.2f} us/span "
+          f"(gate <= {t['budget_us']:.0f} us) | inside a session "
+          f"{t['in_session_us_per_span']:6.2f} us/span")
     print("— host chains (tensor_debug): pure pad-hop cost —")
     prev = None
     for n in (1, 2, 4, 8, 16, 32):
