@@ -15,11 +15,20 @@ The properties the paged data plane exists for, each asserted directly:
   alternating, real drafts), so speculation can change latency only;
 * compile discipline — the chunk size is the only compiled prefill
   shape, so compile_count is flat across prompt lengths;
+* pool in place — compiled for a described TPU v5e, the step and the
+  prefill chunk take the donated pool in and hand it back in one
+  layout: no copy and no slice of a pool's or a layer's size, both
+  pools aliased input to output; and on any backend a write routed to
+  the null page, a COW copy and a preempt/restore touch only the rows
+  they name, in every layer;
 * page lifecycle — every scheduler exit path (retire, close with
   in-flight work, deadline shed, batch failure) releases through
   ``engine.release`` and page refcounts reach zero (the NNS_LEAKCHECK
   ledger asserts the same pairing at the acquire/release sites).
 """
+import math
+import re
+
 import numpy as np
 import pytest
 
@@ -390,6 +399,199 @@ class TestSpeculativeParity:
         assert out[:steps] == base
         eng.release(0)
         eng.close()
+
+
+# ---------------------------------------------------------------------------
+# pool in place — the layout every program writes and reads as it is stored
+# ---------------------------------------------------------------------------
+def _pools(eng):
+    """Both pools on the host as (layers, pages+1, page, heads*head_dim):
+    the folded row axis split back into layer and page."""
+    L, pg = eng.cfg.layers, eng.page_size
+    return [np.array(a, np.float32).reshape(L, -1, pg, eng.cfg.dim)
+            for a in (eng._kpool, eng._vpool)]
+
+
+class TestPoolLayout:
+    def test_pool_shape_and_bytes(self):
+        cfg, params = _tiny()
+        eng = PagedLMEngine(cfg, params, slots=2, page_size=8, pages=16,
+                            chunk=16, share_prefixes=False)
+        rows = cfg.layers * (16 + 1)  # every layer keeps its null page 0
+        assert eng._kpool.shape == eng._vpool.shape == (rows, 8, cfg.dim)
+        item = eng._kpool.dtype.itemsize
+        assert eng.page_bytes == 2 * cfg.layers * 8 * cfg.dim * item
+        assert eng.cache_bytes == 2 * rows * 8 * cfg.dim * item
+
+    def test_null_page_write_changes_no_live_page(self):
+        # slot 1 is inactive: its step write routes to page 0 of every
+        # layer. Every row but the null pages and the one line slot 0
+        # writes in its own page must come out bit for bit
+        cfg, params = _tiny()
+        rng = np.random.default_rng(43)
+        eng = PagedLMEngine(cfg, params, slots=2, page_size=8, pages=16,
+                            chunk=16, share_prefixes=False)
+        eng.admit(0, rng.integers(0, cfg.vocab, 11).astype(np.int32), 6)
+        before = _pools(eng)
+        pos = int(eng._pos[0])
+        page, offs = int(eng._bt[0, pos // 8]), pos % 8
+        eng.step()
+        for b, a in zip(before, _pools(eng)):
+            assert np.any(a[:, page, offs] != b[:, page, offs]), \
+                "the live slot's line must have been written"
+            a[:, page, offs] = b[:, page, offs]
+            np.testing.assert_array_equal(a[:, 1:], b[:, 1:])
+        eng.release(0)
+
+    def test_cow_copy_leaves_the_siblings_pages_untouched(self):
+        # identical page-aligned prompts: slot 1 maps slot 0's pages and
+        # its first decode write COW-copies the last one. In every layer
+        # the sibling's pages keep their bytes and the copy starts as the
+        # page it was copied from
+        cfg, params = _tiny()
+        rng = np.random.default_rng(47)
+        prompt = rng.integers(0, cfg.vocab, 16).astype(np.int32)
+        eng = PagedLMEngine(cfg, params, slots=2, page_size=8, pages=16,
+                            chunk=16, share_prefixes=True)
+        eng.admit(0, prompt, 6)
+        eng.admit(1, prompt, 6)
+        shared = [int(p) for p in eng._bt[0, :2]]
+        before = _pools(eng)
+        cows = eng.pool.stats()["cow_copies_total"]
+        # the recomputed last prompt position already copied page 2 for
+        # slot 1 during its admit: the copy is what the block table names
+        assert cows >= 1 and int(eng._bt[1, 1]) != shared[1]
+        for pool in before:
+            np.testing.assert_array_equal(
+                pool[:, int(eng._bt[1, 1]), :7], pool[:, shared[1], :7])
+        eng.step()
+        for b, a in zip(before, _pools(eng)):
+            np.testing.assert_array_equal(a[:, shared], b[:, shared])
+        eng.release(0)
+        eng.release(1)
+        eng.close()
+
+    @pytest.mark.parametrize("prompt_len,steps_before", [
+        (8, 0),    # the carry sits on a page boundary: one full page held
+        (9, 2),    # mid-page: a page and 3 lines of the next
+        (13, 3),   # the next write crosses into a third page
+    ])
+    def test_preempt_blob_restores_byte_exact(self, prompt_len,
+                                              steps_before):
+        cfg, params = _tiny()
+        rng = np.random.default_rng(53)
+        prompt = rng.integers(0, cfg.vocab, prompt_len).astype(np.int32)
+        eng = PagedLMEngine(cfg, params, slots=2, page_size=8, pages=16,
+                            chunk=16, share_prefixes=False)
+        out = [eng.admit(0, prompt, 10)]
+        for _ in range(steps_before):
+            out.append(int(eng.step()[0]))
+        held = [int(p) for p in eng._bt[0] if p]
+        want = [pool[:, held] for pool in _pools(eng)]
+        blob = eng.preempt(0)
+        NB = eng.blocks_per_slot
+        assert blob["k"].shape == blob["v"].shape == \
+            (cfg.layers, NB, 8, cfg.dim), "blob: (layer, block, line, dim)"
+        # park another tenant on the freed pages so restore lands elsewhere
+        eng.admit(1, rng.integers(0, cfg.vocab, 20).astype(np.int32), 4)
+        eng.restore(0, blob)
+        fresh = [int(p) for p in eng._bt[0] if p]
+        assert len(fresh) == len(held)
+        for w, pool in zip(want, _pools(eng)):
+            np.testing.assert_array_equal(pool[:, fresh], w)
+        while len(out) < 10:
+            out.append(int(eng.step()[0]))
+        assert out == _dense_baseline(cfg, params, prompt, 10)
+        eng.release(0)
+        eng.release(1)
+        assert eng.pool.used_pages == 0
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a described (not attached) TPU v5e, or skip. Made inside
+    the fixture: only the worker that runs this file loads the TPU's
+    compiler, and every worker collects the same tests."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or it is held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _entry_results(hlo_text):
+    """(name, opcode, element counts of its results) for every instruction
+    of the optimised module's entry computation."""
+    entry = hlo_text[hlo_text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(",
+                     line)
+        if m:
+            counts = {math.prod(int(d) for d in dims.split(","))
+                      for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(2))}
+            yield m.group(1), m.group(3), counts
+
+
+class TestPoolInPlaceOnTpu:
+    @pytest.mark.parametrize("program", ["_step", "_prefill_chunk"])
+    def test_pool_goes_in_and_comes_out_in_one_layout(self, v5e_chip,
+                                                      program):
+        import functools
+
+        import jax
+        import jax.numpy as jnp
+
+        from nnstreamer_tpu.models.transformer import (
+            TransformerConfig,
+            init_params,
+        )
+
+        # OPT's head size and page size at a small depth and width. 2049
+        # rows a layer: no gathered context has a pool's or a layer's
+        # element count by accident, and at 17 MB a layer the pool is past
+        # what the compiler would prefetch whole into faster memory
+        cfg = TransformerConfig(vocab=512, dim=256, heads=4, layers=2,
+                                mlp_mult=4, max_seq=128)
+        S, pg, pages, C = 4, 16, 2048, 32
+        eng = PagedLMEngine(cfg, {"embed": jnp.zeros((1, 1), jnp.bfloat16)},
+                            slots=S, page_size=pg, pages=pages, chunk=C)
+
+        def shape(s, dt):
+            return jax.ShapeDtypeStruct(s, dt, sharding=v5e_chip)
+
+        params = jax.tree_util.tree_map(
+            lambda a: shape(a.shape, jnp.bfloat16),
+            jax.eval_shape(functools.partial(init_params, cfg)))
+        NB = cfg.max_seq // pg
+        pool = shape(eng._kpool.shape, jnp.bfloat16)
+        if program == "_step":
+            args = (shape((S, 1), jnp.int32), shape((S,), jnp.int32),
+                    shape((S,), jnp.bool_), shape((S, NB), jnp.int32))
+        else:
+            args = (shape((C,), jnp.int32), shape((), jnp.int32),
+                    shape((), jnp.int32), shape((NB,), jnp.int32))
+        compiled = getattr(eng, program).func.lower(
+            params, *args, pool, pool).compile()
+
+        pool_count = int(np.prod(eng._kpool.shape))
+        sizes = {pool_count, pool_count // cfg.layers}
+        moved = [f"{op} {name}"
+                 for name, op, counts in _entry_results(compiled.as_text())
+                 if counts & sizes
+                 and (op == "copy" or "slice" in name or "copy" in name)]
+        assert not moved, \
+            f"{program} copies or slices a pool or a layer of it: {moved}"
+        aliased = compiled.memory_analysis().alias_size_in_bytes
+        assert aliased >= 2 * pool_count * 2, \
+            f"{program} must alias both donated pools to its outputs"
 
 
 # ---------------------------------------------------------------------------
