@@ -13,6 +13,11 @@ out by the repo's own means. One process, no child that needs the chip.
            DecodeScheduler, twelve seeded requests (5..1500 prompt tokens,
            a shared prefix, a page-aligned copy-on-write), then two
            requests through the speculative engine (draft="ngram");
+  latent_serving
+           the second model family (DeepSeek-V3-shaped: latent attention,
+           one pool line a token, dropless experts) through the same engine
+           and scheduler at the benchmark configuration's rehearsal sizes,
+           served tokens against the plain reference under the near-tie rule;
   kernels  both Pallas kernels, Mosaic-lowered, at base geometry.
 
 It refuses to run anywhere but on a TPU, prints no result there, and exits
@@ -409,6 +414,81 @@ def serving_leg(entry=None, slots: int = 8, steps: int = 32,
     return out
 
 
+def latent_serving_leg(serve_dtype: str = "bfloat16", slots: int = 4,
+                       steps: int = 12, lengths=(37, 5, 20, 9, 30, 14),
+                       seed: int = 27) -> dict:
+    """The second model family through the same engine and scheduler: the
+    DeepSeek-V3-shaped block (latent attention, dropless experts) at the
+    benchmark configuration's rehearsal sizes, seeded weights, against the
+    plain reference (``benchmark/references/deepseek_v3_lm.py``, float32 at
+    ``highest``), teacher-forced on what was served: a served token may
+    leave the reference's best only by a near tie of its logits."""
+    import numpy as np
+
+    import jax.numpy as jnp
+
+    from benchmark.lib import harness
+    from benchmark.lib.weights import seed_key
+    from benchmark.references import deepseek_v3_lm as reference
+    from nnstreamer_tpu.models.deepseek_v3 import DeepseekV3Config
+    from nnstreamer_tpu.models.lm_serving import _LMServingEntry
+
+    _, config = harness.find_cell(harness.load_benchmark(),
+                                  "kanana2_decode_saturated")
+    config = {**config, **config["rehearsal"]}
+    cfg = DeepseekV3Config.from_published(config)
+    sizes, key = reference.sizes(config), seed_key(seed)
+    params = reference.program_params(key, sizes, jnp.dtype(serve_dtype))
+
+    class Seeded(_LMServingEntry):
+        def _shard_params(self, mesh):
+            return params, False
+
+    engine = Seeded(cfg, serve_dtype=serve_dtype).make_continuous(
+        paged=True, slots=slots, **{k: v for k, v in config["engine"].items()
+                                    if k != "slots"})
+    check(engine.family.name == "deepseek_v3" and len(engine._pools) == 1,
+          "latent: the engine took another family or geometry")
+    rng = np.random.default_rng(seed)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in lengths]
+    streams, snap, took = _serve(engine, prompts, steps)
+    out = {"requests": len(prompts), "steady_s": round(took, 2),
+           "completed": snap["completed"],
+           "compile_count": snap["compile_count"],
+           "line_widths": snap["kv_pool"]["line_widths"],
+           "moe_assignments": snap["moe_assignments"],
+           "moe_experts_touched": snap["moe_experts_touched"]}
+    check(snap["completed"] == len(prompts), f"latent: {snap['completed']} "
+                                             f"of {len(prompts)} completed")
+    check(engine.pool.used_pages == 0, "latent: pages held after close")
+    # every prompt token and every decoded row, top-k experts in each of
+    # the expert layers: nothing dropped
+    rows = sum(len(p) + steps - 1 for p in prompts)
+    per_row = cfg.num_experts_per_tok * (cfg.num_hidden_layers
+                                         - cfg.first_k_dense_replace)
+    check(snap["moe_assignments"] == rows * per_row,
+          f"latent: {snap['moe_assignments']} assignments for {rows} rows")
+    width = max(len(p) for p in prompts) + steps
+    tokens = np.zeros((len(prompts), width), np.int32)
+    at = np.zeros((len(prompts), steps), np.int32)
+    for i, (p, toks) in enumerate(zip(prompts, streams)):
+        check(len(toks) == steps, f"latent[{i}]: {len(toks)} tokens")
+        tokens[i, :len(p)] = p
+        tokens[i, len(p):len(p) + steps - 1] = toks[:-1]
+        at[i] = len(p) - 1 + np.arange(steps)
+    exact = reference.logits_for(key, sizes, tokens, at)["none"]
+    gaps = [exact[i].max(-1) - np.take_along_axis(
+        exact[i], np.asarray(toks)[:, None], 1)[:, 0]
+        for i, toks in enumerate(streams)]
+    out["served_gap_max"] = float(max(g.max() for g in gaps))
+    check(out["served_gap_max"] <= LM_NEAR_TIE_GAP,
+          f"latent: a served token lies {out['served_gap_max']:.5f} under "
+          f"the reference's best (tolerance {LM_NEAR_TIE_GAP}): not a near "
+          "tie, a wrong program")
+    return out
+
+
 # -- kernels ------------------------------------------------------------------
 
 def kernels_leg(B: int = 8, H: int = 16, T: int = 2048, D: int = 64,
@@ -518,7 +598,7 @@ def main() -> int:
     }
     clock = CompileClock()
     legs = {"kernels": kernels_leg, "stream": stream_leg,
-            "serving": serving_leg}
+            "serving": serving_leg, "latent_serving": latent_serving_leg}
     for name, leg in legs.items():
         t0, before = time.monotonic(), clock.read()
         try:

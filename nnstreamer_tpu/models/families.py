@@ -1,0 +1,175 @@
+"""Model families as the paged serving engine sees them (ROADMAP D1).
+
+``serving.lm_engine.PagedLMEngine`` owns slots, block tables, the page
+pool and the three programs' skeleton (write the new lines, gather a
+slot's lines, attend, feed forward, head). What a *layer* is and what it
+keeps per token comes from one object, the family, chosen by the type of
+the entry's configuration (:func:`family_of`); no flag names a model.
+
+A family says:
+
+* ``attention_scope`` — the ``jax.named_scope`` the engine puts around the
+  cache's write and gather, so that they carry attention's region.
+* ``cache_lines`` — one width per pool: the values a token keeps in a
+  layer. The GPT block keeps keys and values, two pools of
+  ``heads * head_dim``; the latent-attention block keeps one line that
+  every head reads as keys and as values (``models/deepseek_v3.py``).
+* ``embed(p, toks, pos)`` — tokens at positions → float32 activations.
+* ``blocks(p)`` — the per-layer parameter groups, in order.
+* ``project(blk, x, pos)`` — from a layer's input ``x (B, Q, D)``: the
+  query side and the lines to write, one per pool, each ``(B, Q, width)``.
+* ``attend(blk, q, ctxs, visible, mode)`` — the queries over the gathered
+  lines ``ctxs`` (one ``(B, ctx, width)`` per pool), output projection
+  applied: what the residual adds. ``mode`` is the program: ``"step"``
+  (one query a slot, ``visible (S, ctx)``), ``"chunk"`` (one slot's chunk,
+  ``visible (C, ctx)``) or ``"verify"`` (``visible (S, K, ctx)``).
+* ``ffn(blk, x, live)`` — norm and feed-forward of ``x (B, Q, D)`` →
+  ``(y, counts)``; ``live (B, Q)`` marks the rows that are real, and
+  ``counts`` is ``None`` or the int32 vector ``counters`` names.
+* ``head(p, x)`` — final norm and output head of rows ``x (n, D)``.
+"""
+from __future__ import annotations
+
+from .transformer import TransformerConfig, _rmsnorm
+
+
+class GPTFamily:
+    """The repo's GPT-2-shaped block (``models/transformer.py``): learned
+    positions, one fused ``wqkv``, full multi-head attention over keys and
+    values, a ReLU MLP (or the trainer's switch layer), a tied head."""
+
+    name = "gpt"
+    attention_scope = "attention"  # named scope of the cache's write, read
+    counters = ()          # nothing an expert layer would count
+    serves_verify = True   # speculative verification (``_verify``)
+
+    def __init__(self, cfg: TransformerConfig):
+        self.cfg = cfg
+        self.vocab = cfg.vocab
+        self.layers = cfg.layers
+        # the position table is a weight: the serving limit cannot pass it
+        self.max_positions = cfg.max_seq
+
+    @property
+    def cache_lines(self) -> tuple:
+        width = self.cfg.heads * self.cfg.head_dim
+        return (width, width)
+
+    def init_params(self, seed: int):
+        from .transformer import init_params
+
+        return init_params(self.cfg, seed=seed)
+
+    def with_positions(self, positions: int) -> "GPTFamily":
+        from dataclasses import replace
+
+        return GPTFamily(replace(self.cfg, max_seq=positions))
+
+    def embed(self, p, toks, pos):
+        import jax.numpy as jnp
+
+        return (p["embed"][toks] + p["pos"][pos]).astype(jnp.float32)
+
+    def blocks(self, p):
+        return p["blocks"]
+
+    def project(self, blk, x, pos):
+        import jax.numpy as jnp
+
+        q, k, v = jnp.split(_rmsnorm(x, blk["ln1"]) @ blk["wqkv"], 3, axis=-1)
+        return q, (k, v)
+
+    def attend(self, blk, q, ctxs, visible, mode):
+        return getattr(self, f"_attend_{mode}")(q, *ctxs, visible) @ blk["wo"]
+
+    def _attend_step(self, q, ck, cv, visible):
+        import jax
+        import jax.numpy as jnp
+
+        cfg = self.cfg
+        H, Dh, S = cfg.heads, cfg.head_dim, q.shape[0]
+        heads = jnp.arange(H)
+        # line element j belongs to head j // Dh
+        own = jnp.arange(H * Dh)[:, None] // Dh == heads[None, :]
+        exact = jax.lax.Precision.HIGHEST
+        # one query per slot against 2048 lines of 16 slots: the
+        # contexts are read where the take left them, whole lines
+        # against a block-diagonal q (column h holds head h's
+        # query and zeros), instead of being re-tiled by head
+        # first: 1.29 ms a layer against 6.09 on a v5e. The zeros
+        # add nothing, and HIGHEST keeps the float32 query and
+        # weights float32 on the MXU, so the scores and outputs
+        # are the per-head float32 ones (3.6e-7 apart on the chip)
+        qbd = jnp.where(own, q[:, 0, :, None], 0.0)  # (S, H*Dh, H)
+        att = (jnp.einsum("scj,sjh->shc", ck, qbd, precision=exact)
+               / jnp.sqrt(cfg.head_dim))
+        att = jnp.where(visible[:, None, :], att, -1e30)
+        att = jax.nn.softmax(att, axis=-1)           # (S, H, ctx)
+        o = jnp.einsum("shc,scj->shj", att, cv, precision=exact)
+        # row h of o is head h's weights over every head's values:
+        # its own block is the attention output
+        return o.reshape(S, H, H, Dh)[:, heads, heads].reshape(S, 1, cfg.dim)
+
+    def _attend_chunk(self, q, ck, cv, visible):
+        import jax
+        import jax.numpy as jnp
+
+        from .decoding import _split_heads
+
+        # the context is one slot's: splitting its lines by head (a
+        # re-tiled copy on a TPU) is cheap here and only here
+        cfg = self.cfg
+        H, Dh, ctx = cfg.heads, cfg.head_dim, ck.shape[1]
+        ck = ck.reshape(1, ctx, H, Dh)
+        cv = cv.reshape(1, ctx, H, Dh)
+        att = (jnp.einsum("shqd,schd->shqc", _split_heads(cfg, q), ck)
+               / jnp.sqrt(cfg.head_dim))
+        att = jnp.where(visible[None, None], att, -1e30)
+        att = jax.nn.softmax(att, axis=-1)
+        return jnp.einsum("shqc,schd->sqhd", att, cv).reshape(
+            1, q.shape[1], cfg.dim)
+
+    def _attend_verify(self, q, ck, cv, visible):
+        import jax
+        import jax.numpy as jnp
+
+        cfg = self.cfg
+        H, Dh = cfg.heads, cfg.head_dim
+        S, K = q.shape[0], q.shape[1]
+        ctx = ck.shape[1]
+        ck = ck.reshape(S, ctx, H, Dh)
+        cv = cv.reshape(S, ctx, H, Dh)
+        q = q.reshape(S, K, H, Dh)
+        # broadcast-multiply-reduce instead of batched matmul:
+        # XLA CPU lowers (S*H) tiny K x ctx GEMMs to per-batch
+        # library calls whose fixed cost dwarfs the math; the
+        # explicit reduce fuses into one loop (~30% off the
+        # whole program at K=4). Scores are (S, K, ctx, H): the
+        # context's own index order, so nothing is transposed
+        att = ((q[:, :, None] * ck[:, None]).sum(-1)
+               / jnp.sqrt(cfg.head_dim))
+        att = jnp.where(visible[..., None], att, -1e30)
+        att = jax.nn.softmax(att, axis=2)
+        o = (att[..., None] * cv[:, None]).sum(2)   # (S, K, H, Dh)
+        return o.reshape(S, K, cfg.dim)
+
+    def ffn(self, blk, x, live):
+        from .decoding import _ffn
+
+        return _ffn(blk, _rmsnorm(x, blk["ln2"]), None, self.cfg), None
+
+    def head(self, p, x):
+        return _rmsnorm(x, p["out_norm"]) @ p["embed"].T
+
+
+def family_of(cfg):
+    """The family of a configuration, by its type."""
+    if isinstance(cfg, TransformerConfig):
+        return GPTFamily(cfg)
+    from .deepseek_v3 import DeepseekV3Config, DeepseekV3Family
+
+    if isinstance(cfg, DeepseekV3Config):
+        return DeepseekV3Family(cfg)
+    raise TypeError(
+        f"no model family serves a configuration of type "
+        f"{type(cfg).__name__} (have TransformerConfig, DeepseekV3Config)")

@@ -58,15 +58,22 @@ class _LMServingEntry:
     cache_len: int = 0
 
     @property
-    def _cfg_serve(self) -> TransformerConfig:
-        if self.cache_len:
-            from dataclasses import replace
+    def _family(self):
+        """The model family of this entry's configuration, by its type
+        (models/families.py)."""
+        from .families import family_of
 
-            if self.cache_len > self.cfg.max_seq:
+        return family_of(self.cfg)
+
+    @property
+    def _cfg_serve(self):
+        if self.cache_len:
+            fam = self._family
+            if self.cache_len > fam.max_positions:
                 raise ValueError(
                     f"cache_len {self.cache_len} exceeds max_seq "
-                    f"{self.cfg.max_seq}")
-            return replace(self.cfg, max_seq=self.cache_len)
+                    f"{fam.max_positions}")
+            return fam.with_positions(self.cache_len).cfg
         return self.cfg
 
     def _shard_params(self, mesh):
@@ -77,9 +84,9 @@ class _LMServingEntry:
         token-exactness)."""
         import jax
 
-        from .transformer import init_params, param_pspecs
+        from .transformer import param_pspecs
 
-        params = init_params(self.cfg, seed=self.seed)
+        params = self._family.init_params(self.seed)
         if self.serve_dtype:
             import jax.numpy as jnp
 
@@ -90,6 +97,7 @@ class _LMServingEntry:
         use_tp = (mesh is not None and "tp" in mesh.axis_names
                   and mesh.shape["tp"] > 1)
         if use_tp:
+            self._gpt_only("tensor-parallel params")
             if self.cfg.heads % mesh.shape["tp"] != 0:
                 raise ValueError(
                     f"lm_serving: heads={self.cfg.heads} not divisible by "
@@ -103,9 +111,17 @@ class _LMServingEntry:
             params = jax.device_put(params, shardings)
         return params, use_tp
 
+    def _gpt_only(self, what: str) -> None:
+        if not isinstance(self.cfg, TransformerConfig):
+            raise NotImplementedError(
+                f"lm_serving: {what} serves the gpt family only; the "
+                f"{self._family.name} family is served by "
+                "make_continuous(paged=True)")
+
     def _build(self, mesh=None):
         from .decoding import make_generate
 
+        self._gpt_only("the whole-sequence generate path")
         params, use_tp = self._shard_params(mesh)
         # dp-only / single-device: params replicate as jit constants; the
         # backend's dp batch sharding alone parallelizes the batch
@@ -148,6 +164,7 @@ class _LMServingEntry:
             prefill_continue,
         )
 
+        self._gpt_only("the streaming generate path")
         cfg = self._cfg_serve
         params, use_tp = self._shard_params(mesh)
         step_mesh = mesh if use_tp else None
@@ -305,6 +322,11 @@ class _LMServingEntry:
         ``spec_k`` is the draft burst length verified per target call."""
         from ..serving.lm_engine import from_entry
 
+        fam = self._family
+        if draft is not None and not fam.serves_verify:
+            raise NotImplementedError(
+                f"lm_serving: speculative verification (_verify) does not "
+                f"serve the {fam.name} family yet; build it without draft=")
         eng = from_entry(self, slots=slots, mesh=mesh, paged=paged,
                          **paged_kw)
         if draft is None:

@@ -411,7 +411,17 @@ def _collect_serving(reg: Registry) -> None:
             ("host_engine_s", "host_engine_seconds",
              "host wall under the engine's prepare and dispatch spans"),
             ("pull_wait_s", "pull_wait_seconds",
-             "host wall under the engine's pull spans"))}
+             "host wall under the engine's pull spans"),
+            ("moe_experts_touched", "moe_experts_touched",
+             "experts that received a token, summed over expert layers "
+             "and program calls"),
+            ("moe_expert_slots", "moe_expert_slots",
+             "experts held times expert layers, summed over program calls"),
+            ("moe_assignments", "moe_assignments",
+             "(token, expert) assignments served"),
+            ("moe_max_load", "moe_max_load",
+             "largest number of tokens one expert received, summed over "
+             "expert layers and program calls"))}
     # snapshot mirrors: repopulated from live schedulers each scrape, so
     # a garbage-collected scheduler's series disappears with it
     for inst in (subm, comp, fail, shedf, shedd, shedm, shedo, batches,

@@ -69,7 +69,8 @@ class KVPagePool:
     """
 
     def __init__(self, pages: int, page_size: int,
-                 name: str = "kv_pool", prefix_capacity: int = 32):
+                 name: str = "kv_pool", prefix_capacity: int = 32,
+                 line_widths: Tuple[int, ...] = (), token_bytes: int = 0):
         if pages < 1:
             raise ValueError(f"pages={pages} must be >= 1")
         if page_size < 1 or (page_size & (page_size - 1)):
@@ -78,6 +79,12 @@ class KVPagePool:
         self.pages = pages
         self.page_size = page_size
         self.name = name
+        # what a token keeps on the device, from the engine's model family:
+        # the width of its line in each pool, and its bytes over all layers
+        # (0 when the owner did not say: the allocator itself counts pages)
+        self.line_widths = tuple(line_widths)
+        self.token_bytes = int(token_bytes)
+        self.page_bytes = self.token_bytes * page_size
         self._lock = named_lock(f"KVPagePool._lock:{name}")
         # index 0 = null page (never allocated, never freed)
         self._free: List[int] = list(range(pages, 0, -1))  # guarded-by: _lock
@@ -264,6 +271,11 @@ class KVPagePool:
                 "pages_free": len(self._free),
                 "pages_shared": sum(1 for c in self._ref.values() if c > 1),
                 "page_size": self.page_size,
+                "line_widths": list(self.line_widths),
+                "token_bytes": self.token_bytes,
+                "page_bytes": self.page_bytes,
+                "bytes_total": self.pages * self.page_bytes,
+                "bytes_used": used * self.page_bytes,
                 "prefix_entries": len(self._prefixes),
                 "prefix_hits_total": self.prefix_hits,
                 "cow_copies_total": self.cow_copies,

@@ -219,9 +219,16 @@ class PagedLMEngine:
     :class:`~.kv_pool.KVPagePool` and addresses them through per-slot
     block tables, gathered/scattered inside the jitted programs:
 
-    * **pool layout** — ``k/v: (layers * (pages+1), page, heads*head_dim)``
-      device arrays: one row per page of one layer, one contiguous
-      ``heads*head_dim`` line per token, so row-major order IS the order
+    * **model family** — what a layer is and what it keeps per token comes
+      from ``models/families.py`` (``family_of(cfg)``, by the
+      configuration's type): the embedding, per layer the query side and
+      the lines to write, attention over gathered lines, the feed-forward,
+      the head, and the pool's geometry (``cache_lines``: one width per
+      pool). The GPT block keeps keys and values (two pools); the
+      DeepSeek-V3 block keeps one latent line that every head reads.
+    * **pool layout** — per kind of line ``(layers * (pages+1), page,
+      width)`` device arrays: one row per page of one layer, one contiguous
+      ``width`` line per token, so row-major order IS the order
       every program touches it and the donated pool goes in and comes
       out of each program in the array's own layout (no relayout copy).
       Layer ``li`` owns rows ``li*(pages+1) ..``; its row 0 is that
@@ -230,6 +237,10 @@ class PagedLMEngine:
       ``(li*(pages+1) + block_table[p // page], p % page)``. No program
       slices a layer out of the pool: a write is a scatter on the two
       leading axes, a context read one ``take`` of the slot's rows.
+    * **serving limit** — ``max_seq``, the positions a slot may hold: the
+      family's ``max_positions`` (the ``gpt`` family's position table, the
+      latent family's ``max_position_embeddings``) or ``max_positions=``
+      below it. A step attends over that many padded positions.
     * **chunked prefill** — ``admit_start`` queues the prompt and
       ``prefill_tick`` ingests ONE fixed-size chunk per call, so a long
       prompt interleaves with running decode instead of stalling the
@@ -252,28 +263,39 @@ class PagedLMEngine:
 
     def __init__(self, cfg, params, slots: int = 4, page_size: int = 16,
                  pages: Optional[int] = None, chunk: int = 32,
-                 share_prefixes: bool = True, pool_name: Optional[str] = None):
+                 share_prefixes: bool = True, pool_name: Optional[str] = None,
+                 max_positions: Optional[int] = None):
         if slots < 1:
             raise ValueError(f"slots={slots} must be >= 1")
-        page_size = min(page_size, cfg.max_seq)
-        if cfg.max_seq % page_size:
-            raise ValueError(
-                f"max_seq {cfg.max_seq} must divide by page_size {page_size}")
         import functools
 
         import jax
         import jax.numpy as jnp
 
-        from ..models.decoding import _ffn, _split_heads
-        from ..models.transformer import _rmsnorm
+        from ..models.families import family_of
         from .kv_pool import KVPagePool
 
+        fam = family_of(cfg)
+        # the serving limit on positions is the engine's: at most what the
+        # family can address (a learned position table is a weight)
+        max_seq = (fam.max_positions if max_positions is None
+                   else max_positions)
+        if not 1 <= max_seq <= fam.max_positions:
+            raise ValueError(
+                f"max_positions={max_seq} outside 1..{fam.max_positions}, "
+                f"the {fam.name} family's limit for this configuration")
+        page_size = min(page_size, max_seq)
+        if max_seq % page_size:
+            raise ValueError(
+                f"max_seq {max_seq} must divide by page_size {page_size}")
         self.cfg = cfg
+        self.family = fam
+        self.max_seq = max_seq
         self.params = params
         self.slots = slots
         self.page_size = page_size
-        self.blocks_per_slot = cfg.max_seq // page_size
-        self.chunk = min(chunk, cfg.max_seq)
+        self.blocks_per_slot = max_seq // page_size
+        self.chunk = min(chunk, max_seq)
         self.share_prefixes = share_prefixes
         self.compile_count = 0
         self.host_s = self.pull_s = 0.0  # under the spans below, summed
@@ -283,14 +305,22 @@ class PagedLMEngine:
         if pages is None:
             pages = slots * self.blocks_per_slot  # dense-equivalent pool
         self._mem_name = pool_name or f"lm_engine#{next(_engine_ids)}"
-        self.pool = KVPagePool(pages, page_size, name=self._mem_name)
 
+        # the pool's geometry, from the family and from nowhere else: one
+        # device array per kind of line a token keeps in a layer
         cache_dtype = params["embed"].dtype
-        L, H, Dh = cfg.layers, cfg.heads, cfg.head_dim
+        L = fam.layers
         R = pages + 1  # rows of one layer: its null page 0, then the pages
-        pool_shape = (L * R, page_size, H * Dh)
-        self._kpool = jnp.zeros(pool_shape, cache_dtype)
-        self._vpool = jnp.zeros(pool_shape, cache_dtype)
+        self.line_widths = tuple(int(w) for w in fam.cache_lines)
+        self.token_bytes = (L * sum(self.line_widths)
+                            * jnp.dtype(cache_dtype).itemsize)
+        self.pool = KVPagePool(pages, page_size, name=self._mem_name,
+                               line_widths=self.line_widths,
+                               token_bytes=self.token_bytes)
+        self.page_bytes = self.pool.page_bytes
+        self._pools = tuple(jnp.zeros((L * R, page_size, w), cache_dtype)
+                            for w in self.line_widths)
+        P = len(self._pools)
         NB = self.blocks_per_slot
         ctx = NB * page_size  # == max_seq: dense-identical contraction
 
@@ -303,22 +333,27 @@ class PagedLMEngine:
         # slot -> [when its first chunk was dispatched, chunks so far]:
         # outlives _pending, until the slot is released (prefill_stamp)
         self._lane: "dict[int, list]" = {}
+        # what the family's expert layers counted (``family.counters``),
+        # running sums by program, and the device arrays of the chunks
+        # whose counts have not been pulled yet
+        self.layer_counts = {call: dict.fromkeys(fam.counters, 0)
+                             for call in ("step", "chunk")}
+        self._chunk_counts: list = []
 
-        self.cache_bytes = int(self._kpool.nbytes + self._vpool.nbytes)
-        self.page_bytes = int(2 * L * H * page_size * Dh
-                              * jnp.dtype(cache_dtype).itemsize)
+        self.cache_bytes = int(sum(p.nbytes for p in self._pools))
         self.param_bytes = obs_memory.tree_nbytes(params)
         obs_memory.track_serving(self)
 
         pg = page_size
+        NC = len(fam.counters)
 
         def _write(pool, li, dest, offs, rows):
-            # rows (..., H*Dh) -> position offs of page dest of layer li:
+            # rows (..., width) -> position offs of page dest of layer li:
             # a scatter on the two leading axes, one whole line per token
             return pool.at[li * R + dest, offs].set(rows.astype(pool.dtype))
 
         def _read_ctx(pool, li, bt):
-            # bt (S, NB) -> (S, ctx, H*Dh): logical position p of slot s is
+            # bt (S, NB) -> (S, ctx, width): logical position p of slot s is
             # line (s, p). One take of the slot's rows straight from the
             # pool, the layer's offset added to the block table on the
             # device. Block tables hold page ids the pool handed out, so
@@ -328,120 +363,106 @@ class PagedLMEngine:
             # a TPU (the copy is re-tiled with Dh padded to a full lane
             # row), so only the programs whose context is one slot's do it
             g = jnp.take(pool, li * R + bt, axis=0, mode="clip")
-            return g.reshape(bt.shape[0], ctx, H * Dh)
+            return g.reshape(bt.shape[0], ctx, pool.shape[-1])
 
-        def _step(p, token, pos, mask, bt, kpool, vpool):
+        def _layers(p, x, pos, live, mode, dest, offs, bt, visible, pools,
+                    unbatch):
+            # the skeleton every program shares: per layer, write the new
+            # lines, gather the slots' lines, attend, feed forward.
+            # ``unbatch`` strips the axis a program's lines do not have
+            counts = jnp.zeros((NC,), jnp.int32) if NC else None
+            for li, blk in enumerate(fam.blocks(p)):
+                q, lines = fam.project(blk, x, pos)
+                with jax.named_scope(fam.attention_scope):
+                    pools = tuple(_write(pool, li, dest, offs, unbatch(line))
+                                  for pool, line in zip(pools, lines))
+                    ctxs = tuple(_read_ctx(pool, li, bt) for pool in pools)
+                x = x + fam.attend(blk, q, ctxs, visible, mode)
+                y, c = fam.ffn(blk, x, live)
+                x = x + y
+                if c is not None:
+                    counts = counts + c
+            return x, pools, counts
+
+        def _step(p, token, pos, mask, bt, *pools):
             self.compile_count += 1  # trace-time only: one step program
             S = token.shape[0]
-            x = (p["embed"][token[:, 0]]
-                 + p["pos"][jnp.clip(pos, 0, cfg.max_seq - 1)]
-                 ).astype(jnp.float32)[:, None, :]  # (S,1,D)
+            lp = jnp.clip(pos, 0, max_seq - 1)
+            x = fam.embed(p, token[:, 0], lp)[:, None, :]  # (S,1,D)
             bidx = jnp.clip(pos // pg, 0, NB - 1)
-            dest = jnp.where(mask & (pos < cfg.max_seq),
+            dest = jnp.where(mask & (pos < max_seq),
                              bt[jnp.arange(S), bidx], 0)
             offs = pos % pg
             positions = jnp.arange(ctx)
             visible = (positions[None, :] <= pos[:, None])  # (S, ctx)
-            heads = jnp.arange(H)
-            # line element j belongs to head j // Dh
-            own = jnp.arange(H * Dh)[:, None] // Dh == heads[None, :]
-            exact = jax.lax.Precision.HIGHEST
-            for li, blk in enumerate(p["blocks"]):
-                h = _rmsnorm(x, blk["ln1"])
-                q, k, v = jnp.split(h @ blk["wqkv"], 3, axis=-1)
-                kpool = _write(kpool, li, dest, offs, k[:, 0])
-                vpool = _write(vpool, li, dest, offs, v[:, 0])
-                ck = _read_ctx(kpool, li, bt)
-                cv = _read_ctx(vpool, li, bt)
-                # one query per slot against 2048 lines of 16 slots: the
-                # contexts are read where the take left them, whole lines
-                # against a block-diagonal q (column h holds head h's
-                # query and zeros), instead of being re-tiled by head
-                # first: 1.29 ms a layer against 6.09 on a v5e. The zeros
-                # add nothing, and HIGHEST keeps the float32 query and
-                # weights float32 on the MXU, so the scores and outputs
-                # are the per-head float32 ones (3.6e-7 apart on the chip)
-                qbd = jnp.where(own, q[:, 0, :, None], 0.0)  # (S, H*Dh, H)
-                att = (jnp.einsum("scj,sjh->shc", ck, qbd, precision=exact)
-                       / jnp.sqrt(cfg.head_dim))
-                att = jnp.where(visible[:, None, :], att, -1e30)
-                att = jax.nn.softmax(att, axis=-1)           # (S, H, ctx)
-                o = jnp.einsum("shc,scj->shj", att, cv, precision=exact)
-                # row h of o is head h's weights over every head's values:
-                # its own block is the attention output
-                o = o.reshape(S, H, H, Dh)[:, heads, heads].reshape(
-                    S, 1, cfg.dim)
-                x = x + o @ blk["wo"]
-                x = x + _ffn(blk, _rmsnorm(x, blk["ln2"]), None, cfg)
-            logits = _rmsnorm(x[:, 0], p["out_norm"]) @ p["embed"].T
+            x, pools, counts = _layers(
+                p, x, lp[:, None], mask[:, None], "step", dest, offs, bt,
+                visible, pools, lambda line: line[:, 0])
+            with jax.named_scope("head"):
+                logits = fam.head(p, x[:, 0])
             out = jnp.argmax(logits, -1).astype(jnp.int32)
             token = jnp.where(mask[:, None], out[:, None], token)
             pos = pos + mask.astype(jnp.int32)
-            return out, token, pos, kpool, vpool
+            if NC:  # the counts ride home behind the tokens: one transfer
+                out = jnp.concatenate([out, counts])
+            return (out, token, pos, *pools)
 
         self._step = functools.partial(
-            jax.jit(_step, donate_argnums=(1, 2, 5, 6)), params)
+            jax.jit(_step, donate_argnums=(1, 2, *range(5, 5 + P))), params)
 
         C = self.chunk
 
-        def _prefill_chunk(p, toks, start, n_valid, bt, kpool, vpool):
+        def _prefill_chunk(p, toks, start, n_valid, bt, *pools):
             # toks (C,) padded; ingest positions start..start+n_valid-1 of
             # ONE slot. C is static — the only compiled prefill shape.
             self.compile_count += 1  # trace-time only: once per engine
             q_pos = start + jnp.arange(C)
             valid = jnp.arange(C) < n_valid
-            lp = jnp.clip(q_pos, 0, cfg.max_seq - 1)
+            lp = jnp.clip(q_pos, 0, max_seq - 1)
             dest = jnp.where(valid, bt[lp // pg], 0)
             offs = lp % pg
-            x = (p["embed"][toks] + p["pos"][lp]
-                 ).astype(jnp.float32)[None]        # (1, C, D)
+            x = fam.embed(p, toks, lp)[None]        # (1, C, D)
             positions = jnp.arange(ctx)
             visible = (positions[None, :] <= q_pos[:, None])  # (C, ctx)
-            for li, blk in enumerate(p["blocks"]):
-                h = _rmsnorm(x, blk["ln1"])
-                q, k, v = jnp.split(h @ blk["wqkv"], 3, axis=-1)
-                kpool = _write(kpool, li, dest, offs, k[0])
-                vpool = _write(vpool, li, dest, offs, v[0])
-                ck = _read_ctx(kpool, li, bt[None]).reshape(1, ctx, H, Dh)
-                cv = _read_ctx(vpool, li, bt[None]).reshape(1, ctx, H, Dh)
-                att = (jnp.einsum("shqd,schd->shqc", _split_heads(cfg, q), ck)
-                       / jnp.sqrt(cfg.head_dim))
-                att = jnp.where(visible[None, None], att, -1e30)
-                att = jax.nn.softmax(att, axis=-1)
-                o = jnp.einsum("shqc,schd->sqhd", att, cv).reshape(
-                    1, C, cfg.dim)
-                x = x + o @ blk["wo"]
-                x = x + _ffn(blk, _rmsnorm(x, blk["ln2"]), None, cfg)
-            logits = _rmsnorm(x[0], p["out_norm"]) @ p["embed"].T  # (C, V)
-            return logits, kpool, vpool
+            x, pools, counts = _layers(
+                p, x, lp[None], valid[None], "chunk", dest, offs, bt[None],
+                visible, pools, lambda line: line[0])
+            with jax.named_scope("head"):
+                logits = fam.head(p, x[0])  # (C, V)
+            if NC:
+                return (logits, counts, *pools)
+            return (logits, *pools)
 
         self._prefill_chunk = functools.partial(
-            jax.jit(_prefill_chunk, donate_argnums=(5, 6)), params)
+            jax.jit(_prefill_chunk, donate_argnums=tuple(range(5, 5 + P))),
+            params)
 
         layer_rows = jnp.arange(L) * R  # row of every layer's null page
 
-        def _copy_page(kpool, vpool, dst, src):
+        def _copy_page(dst, src, *pools):
             self.compile_count += 1  # trace-time only: the COW primitive
-            return (kpool.at[layer_rows + dst].set(kpool[layer_rows + src]),
-                    vpool.at[layer_rows + dst].set(vpool[layer_rows + src]))
+            return tuple(pool.at[layer_rows + dst].set(pool[layer_rows + src])
+                         for pool in pools)
 
-        self._copy_page = jax.jit(_copy_page, donate_argnums=(0, 1))
+        self._copy_page = jax.jit(_copy_page,
+                                  donate_argnums=tuple(range(2, 2 + P)))
 
-        def _gather_pages(kpool, vpool, pages_row):
-            # (NB,) page ids -> (L, NB, pg, H*Dh) blobs (preempt read)
+        def _gather_pages(pages_row, *pools):
+            # (NB,) page ids -> (L, NB, pg, width) blobs (preempt read)
             rows = layer_rows[:, None] + pages_row[None, :]
-            return kpool[rows], vpool[rows]
+            return tuple(pool[rows] for pool in pools)
 
         self._gather_pages = jax.jit(_gather_pages)
 
-        def _scatter_pages(kpool, vpool, dest_row, kblob, vblob):
+        def _scatter_pages(dest_row, blobs, *pools):
             rows = layer_rows[:, None] + dest_row[None, :]
-            return (kpool.at[rows].set(kblob.astype(kpool.dtype)),
-                    vpool.at[rows].set(vblob.astype(vpool.dtype)))
+            return tuple(pool.at[rows].set(blob.astype(pool.dtype))
+                         for pool, blob in zip(pools, blobs))
 
-        self._scatter_pages = jax.jit(_scatter_pages, donate_argnums=(0, 1))
+        self._scatter_pages = jax.jit(_scatter_pages,
+                                      donate_argnums=tuple(range(2, 2 + P)))
 
-        def _verify(p, toks, pos, mask, bt, kpool, vpool):
+        def _verify(p, toks, pos, mask, bt, *pools):
             # speculative verification: score K tokens per slot in ONE
             # call — toks (S, K) = [carry, draft...], positions
             # pos..pos+K-1. Writes their K/V (host rolls back rejected
@@ -450,55 +471,32 @@ class PagedLMEngine:
             self.compile_count += 1  # trace-time only: once per K
             S, K = toks.shape
             q_pos = pos[:, None] + jnp.arange(K)[None, :]     # (S, K)
-            lp = jnp.clip(q_pos, 0, cfg.max_seq - 1)
+            lp = jnp.clip(q_pos, 0, max_seq - 1)
             # overflow rows (q_pos >= max_seq) route to the null page so
             # they can never clobber the real tail position
-            dest = jnp.where(mask[:, None] & (q_pos < cfg.max_seq),
+            dest = jnp.where(mask[:, None] & (q_pos < max_seq),
                              bt[jnp.arange(S)[:, None], lp // pg], 0)
             offs = lp % pg
-            x = (p["embed"][toks] + p["pos"][lp]).astype(jnp.float32)
+            x = fam.embed(p, toks, lp)
             positions = jnp.arange(ctx)
             visible = (positions[None, None, :] <= q_pos[:, :, None])
-            for li, blk in enumerate(p["blocks"]):
-                h = _rmsnorm(x, blk["ln1"])
-                q, k, v = jnp.split(h @ blk["wqkv"], 3, axis=-1)
-                kpool = _write(kpool, li, dest, offs, k)
-                vpool = _write(vpool, li, dest, offs, v)
-                ck = _read_ctx(kpool, li, bt).reshape(S, ctx, H, Dh)
-                cv = _read_ctx(vpool, li, bt).reshape(S, ctx, H, Dh)
-                q = q.reshape(S, K, H, Dh)
-                # broadcast-multiply-reduce instead of batched matmul:
-                # XLA CPU lowers (S*H) tiny K x ctx GEMMs to per-batch
-                # library calls whose fixed cost dwarfs the math; the
-                # explicit reduce fuses into one loop (~30% off the
-                # whole program at K=4). Scores are (S, K, ctx, H): the
-                # context's own index order, so nothing is transposed
-                att = ((q[:, :, None] * ck[:, None]).sum(-1)
-                       / jnp.sqrt(cfg.head_dim))
-                att = jnp.where(visible[..., None], att, -1e30)
-                att = jax.nn.softmax(att, axis=2)
-                o = (att[..., None] * cv[:, None]).sum(2)   # (S, K, H, Dh)
-                o = o.reshape(S, K, cfg.dim)
-                x = x + o @ blk["wo"]
-                x = x + _ffn(blk, _rmsnorm(x, blk["ln2"]), None, cfg)
-            logits = _rmsnorm(x, p["out_norm"]) @ p["embed"].T  # (S, K, V)
-            return logits, kpool, vpool
+            x, pools, _ = _layers(
+                p, x, lp, jnp.broadcast_to(mask[:, None], (S, K)), "verify",
+                dest, offs, bt, visible, pools, lambda line: line)
+            logits = fam.head(p, x)  # (S, K, V)
+            return (logits, *pools)
 
-        self._verify = functools.partial(
-            jax.jit(_verify, donate_argnums=(5, 6)), params)
-
-        def _verify_commit(p, toks, pos, tok, mask, bt, kpool, vpool):
+        def _verify_commit(p, toks, pos, tok, mask, bt, *pools):
             # fused speculative round: verify K tokens AND resolve greedy
             # acceptance + carry advance on device. Greedy acceptance
             # emits the target's own argmax prefix (accepted drafts match
             # it by definition, the correction IS it), so the host needs
             # only (pred, n_emit) — two tiny int pulls, no logits
             # download, no carry re-upload.
-            logits, kpool, vpool = _verify(p, toks, pos, mask, bt,
-                                           kpool, vpool)
+            logits, *pools = _verify(p, toks, pos, mask, bt, *pools)
             S, K = toks.shape
             pred = jnp.argmax(logits, -1).astype(jnp.int32)   # (S, K)
-            budget = cfg.max_seq - pos                        # emit ceiling
+            budget = max_seq - pos                            # emit ceiling
             # accept proposal i (column i+1) while every earlier one
             # matched and the emit budget allows position i+1
             ok = ((toks[:, 1:] == pred[:, :-1])
@@ -511,11 +509,25 @@ class PagedLMEngine:
             # pack [n_emit | pred] into ONE (S, K+1) array: the host does
             # a single tiny pull per round instead of two
             out = jnp.concatenate([n_emit[:, None], pred], axis=1)
-            return out, tok, pos, kpool, vpool
+            return (out, tok, pos, *pools)
 
-        self._verify_commit = functools.partial(
-            jax.jit(_verify_commit, donate_argnums=(2, 3, 6, 7)), params)
+        if fam.serves_verify:
+            self._verify = functools.partial(
+                jax.jit(_verify, donate_argnums=tuple(range(5, 5 + P))),
+                params)
+            self._verify_commit = functools.partial(
+                jax.jit(_verify_commit,
+                        donate_argnums=(2, 3, *range(6, 6 + P))), params)
         self._sync_device_state()
+
+    # the two-pool (keys, values) family's pools by their old names
+    @property
+    def _kpool(self):
+        return self._pools[0]
+
+    @property
+    def _vpool(self):
+        return self._pools[1]
 
     def _sync_device_state(self) -> None:
         """Re-upload the decode carry from the host mirrors
@@ -547,14 +559,36 @@ class PagedLMEngine:
             elif self.pool.is_shared(page):
                 new = self.pool.alloc(1)[0]  # pairs-with: release (slot exit)
                 try:
-                    self._kpool, self._vpool = self._copy_page(
-                        self._kpool, self._vpool, new, page)
+                    self._pools = self._copy_page(new, page, *self._pools)
                 except BaseException:
                     self.pool.release([new])  # copy failed: page never owned
                     raise
                 self.pool.release([page])  # drop OUR ref; sibling keeps its page
                 self._bt[slot, b] = new
                 self.pool.note_cow()
+
+    def _note_counts(self, call: str, counts) -> dict:
+        """Add what the family's expert layers counted in one call of a
+        program (``family.counters``, in order) to ``layer_counts``;
+        returns that call's counts, for the span of the pull that brought
+        them. The scheduler sums both programs' into its metrics."""
+        got = dict(zip(self.family.counters, map(int, counts)))
+        total = self.layer_counts[call]
+        for k, v in got.items():
+            total[k] += v
+        return got
+
+    def _pull_chunk_counts(self) -> dict:
+        """The counts of the chunks dispatched since the last pull, summed
+        (their programs have finished: a later program's answer is here)."""
+        if not self._chunk_counts:
+            return {}
+        pending, self._chunk_counts = self._chunk_counts, []
+        total = dict.fromkeys(self.layer_counts["chunk"], 0)
+        for counts in self._jax.device_get(pending):
+            for k, v in self._note_counts("chunk", counts).items():
+                total[k] += v
+        return total
 
     def projected_page_bytes(self, tokens: int, steps: int) -> int:
         """Worst-case pool bytes a request needs (no sharing assumed) —
@@ -567,10 +601,10 @@ class PagedLMEngine:
         if tokens.ndim != 1 or tokens.size == 0:
             raise ValueError(
                 f"prompt must be non-empty 1-D tokens, got {tokens.shape}")
-        if tokens.size + steps > self.cfg.max_seq:
+        if tokens.size + steps > self.max_seq:
             raise ValueError(
                 f"prompt ({tokens.size}) + steps ({steps}) exceeds "
-                f"max_seq {self.cfg.max_seq}")
+                f"max_seq {self.max_seq}")
 
     def admit_start(self, slot: int, tokens: np.ndarray, steps: int) -> None:
         """Queue a prompt for chunked prefill (``prefill_tick`` drives
@@ -617,10 +651,15 @@ class PagedLMEngine:
             padded = np.zeros((self.chunk,), np.int32)
             padded[:n_valid] = tokens[start:start + n_valid]
         with obs_context.span("engine.chunk.dispatch", **attrs) as dispatch:
-            logits, self._kpool, self._vpool = self._prefill_chunk(
+            logits, *rest = self._prefill_chunk(
                 jnp.asarray(padded), jnp.asarray(start, jnp.int32),
                 jnp.asarray(n_valid, jnp.int32), self._bt[slot],
-                self._kpool, self._vpool)
+                *self._pools)
+            if self.family.counters:
+                # pulled with the next answer that is pulled anyway (this
+                # prompt's last chunk, or the next step's tokens)
+                self._chunk_counts.append(rest.pop(0))
+            self._pools = tuple(rest)
         self.host_s += prepare.dur_s + dispatch.dur_s
         lane = self._lane.setdefault(slot, [dispatch.start_s, 0])
         lane[1] += 1
@@ -631,6 +670,7 @@ class PagedLMEngine:
         del self._pending[slot]
         with obs_context.span("engine.chunk.pull", **attrs) as pull:
             first = int(np.argmax(np.asarray(logits[n_valid - 1])))
+            pull.attrs.update(self._pull_chunk_counts())
         self.pull_s += pull.dur_s
         self._tok[slot, 0] = first
         self._pos[slot] = tokens.size
@@ -670,19 +710,24 @@ class PagedLMEngine:
         live = len(slots)
         with obs_context.span("engine.step.prepare", live=live) as prepare:
             for s in slots:
-                if self._pos[s] < self.cfg.max_seq:
+                if self._pos[s] < self.max_seq:
                     self._ensure_writable(int(s), int(self._pos[s]),
                                           int(self._pos[s]) + 1)
         with obs_context.span("engine.step.dispatch", live=live) as dispatch:
-            (tok_dev, self._tok_dev, self._pos_dev, self._kpool,
-             self._vpool) = self._step(
+            tok_dev, self._tok_dev, self._pos_dev, *pools = self._step(
                 self._tok_dev, self._pos_dev, self._mask_dev,
-                self._bt, self._kpool, self._vpool)
+                self._bt, *self._pools)
+            self._pools = tuple(pools)
         with obs_context.span("engine.step.pull", live=live) as pull:
             # nnlint: disable=NNL101 — one (slots,) pull per decode step:
             # the scheduler needs host ints to append/retire (documented
             # contract), matching the dense engine's ledger entry
             tok = self._jax.device_get(tok_dev)
+            if self.family.counters:
+                # an expert family's counts came home behind the tokens
+                tok, counts = tok[:self.slots], tok[self.slots:]
+                pull.attrs.update(self._note_counts("step", counts))
+                self._pull_chunk_counts()
         self.host_s += prepare.dur_s + dispatch.dur_s
         self.pull_s += pull.dur_s
         self._pos = self._pos + self._mask.astype(np.int32)
@@ -697,10 +742,11 @@ class PagedLMEngine:
         for s in np.flatnonzero(self._mask):
             lo = int(self._pos[s])
             self._ensure_writable(int(s), lo,
-                                  min(lo + K, self.cfg.max_seq))
-        logits, self._kpool, self._vpool = self._verify(
+                                  min(lo + K, self.max_seq))
+        logits, *pools = self._verify(
             np.ascontiguousarray(draft, np.int32), self._pos_dev,
-            self._mask_dev, self._bt, self._kpool, self._vpool)
+            self._mask_dev, self._bt, *self._pools)
+        self._pools = tuple(pools)
         # nnlint: disable=NNL101 — one (slots, K, V) pull per speculative
         # round (K tokens' worth), replacing K per-token pulls
         return self._jax.device_get(logits)
@@ -718,14 +764,13 @@ class PagedLMEngine:
         for s in np.flatnonzero(self._mask):
             lo = int(self._pos[s])
             self._ensure_writable(int(s), lo,
-                                  min(lo + K, self.cfg.max_seq))
+                                  min(lo + K, self.max_seq))
         # np array passed straight to the jit call: the committed-call
         # conversion is ~10x cheaper than a standalone jnp.asarray
-        (packed, self._tok_dev, self._pos_dev,
-         self._kpool, self._vpool) = self._verify_commit(
+        packed, self._tok_dev, self._pos_dev, *pools = self._verify_commit(
             np.ascontiguousarray(draft, np.int32), self._pos_dev,
-            self._tok_dev, self._mask_dev, self._bt,
-            self._kpool, self._vpool)
+            self._tok_dev, self._mask_dev, self._bt, *self._pools)
+        self._pools = tuple(pools)
         # nnlint: disable=NNL101 — ONE (slots, K+1) int pull per
         # speculative round (the emitted burst), replacing the (slots,
         # K, V) logits pull of the unfused path
@@ -781,13 +826,11 @@ class PagedLMEngine:
         if not self._mask[slot]:
             raise ServingError(f"slot {slot} not active")
         used = self._bt[slot] != 0
-        kblob, vblob = self._gather_pages(
-            self._kpool, self._vpool, self._bt[slot])
+        blobs = self._gather_pages(self._bt[slot], *self._pools)
         # nnlint: disable=NNL101 — preemption IS the host transfer: the
         # victim's pages move to host RAM so the pool can be re-used;
         # restore uploads the same bytes
-        blob = {"k": self._jax.device_get(kblob),
-                "v": self._jax.device_get(vblob),
+        blob = {"pages": tuple(self._jax.device_get(b) for b in blobs),
                 "used": used.copy(), "tok": int(self._tok[slot, 0]),
                 "pos": int(self._pos[slot])}
         self.pool.release([int(p) for p in self._bt[slot] if p])  # pairs-with: alloc/ref (admit path)
@@ -809,9 +852,9 @@ class PagedLMEngine:
         row[used] = fresh
         self._bt[slot] = row
         dest = self._jnp.asarray(row)
-        self._kpool, self._vpool = self._scatter_pages(
-            self._kpool, self._vpool, dest,
-            self._jnp.asarray(blob["k"]), self._jnp.asarray(blob["v"]))
+        self._pools = self._scatter_pages(
+            dest, tuple(self._jnp.asarray(b) for b in blob["pages"]),
+            *self._pools)
         self._tok[slot, 0] = blob["tok"]
         self._pos[slot] = blob["pos"]
         self._mask[slot] = True
@@ -836,7 +879,9 @@ class PagedLMEngine:
                 "pages_total": s["pages_total"],
                 "pages_used": s["pages_used"],
                 "pages_shared": s["pages_shared"],
-                "page_bytes": self.page_bytes}
+                "page_bytes": self.page_bytes,
+                "line_widths": list(self.line_widths),
+                "token_bytes": self.token_bytes}
 
     def close(self) -> None:
         for slot in range(self.slots):
@@ -851,7 +896,9 @@ def from_entry(entry, slots: int = 4, mesh=None, paged: bool = False,
     dtype-cast per the entry's serve knobs; ``mesh`` reserved for
     sharded slot state — single-device only today). ``paged=True``
     builds the block-table :class:`PagedLMEngine` (``paged_kw``:
-    page_size/pages/chunk/share_prefixes)."""
+    page_size/pages/chunk/share_prefixes/max_positions), which takes the
+    model family from the type of the entry's configuration
+    (models/families.py); the dense slot engine serves the gpt family."""
     if mesh is not None:
         raise NotImplementedError(
             "continuous decode is single-device today; shard the batch "
@@ -860,4 +907,5 @@ def from_entry(entry, slots: int = 4, mesh=None, paged: bool = False,
     params, _ = entry._shard_params(None)
     if paged:
         return PagedLMEngine(cfg, params, slots=slots, **paged_kw)
+    entry._gpt_only("the dense slot engine (paged=False)")
     return ContinuousLMEngine(cfg, params, slots=slots)

@@ -100,6 +100,12 @@ class ServingMetrics:
         self.host_sched_s = 0.0    # passes less the engine's spans
         self.host_engine_s = 0.0   # prepare + dispatch, both programs
         self.pull_wait_s = 0.0     # the engine's pulls (tokens, logits)
+        # what an expert family's layers counted, both programs, summed
+        # over calls and expert layers (PagedLMEngine.layer_counts)
+        self.moe_experts_touched = 0   # experts that received a token
+        self.moe_expert_slots = 0      # experts held x expert layers x calls
+        self.moe_assignments = 0       # (token, expert) pairs served
+        self.moe_max_load = 0          # largest load of one expert, summed
         # device channel: batch execution time (dispatch+block, the
         # reference-comparable number); reservoirs: per-request tails
         self.device = InvokeStats()
@@ -179,6 +185,16 @@ class ServingMetrics:
             self.host_engine_s += host_engine_s
             self.pull_wait_s += pull_wait_s
 
+    def record_layer_counts(self, counts: dict) -> None:
+        """What the engine's expert layers counted since the last pass
+        (the ``moe_*`` keys of ``PagedLMEngine.layer_counts``, both
+        programs added up)."""
+        with self._lock:
+            self.moe_experts_touched += counts.get("moe_experts_touched", 0)
+            self.moe_expert_slots += counts.get("moe_expert_slots", 0)
+            self.moe_assignments += counts.get("moe_assignments", 0)
+            self.moe_max_load += counts.get("moe_max_load", 0)
+
     def record_early_retire(self) -> None:
         with self._lock:
             self.retired_early += 1
@@ -218,6 +234,10 @@ class ServingMetrics:
                 "host_sched_s": self.host_sched_s,
                 "host_engine_s": self.host_engine_s,
                 "pull_wait_s": self.pull_wait_s,
+                "moe_experts_touched": self.moe_experts_touched,
+                "moe_expert_slots": self.moe_expert_slots,
+                "moe_assignments": self.moe_assignments,
+                "moe_max_load": self.moe_max_load,
             }
         out["device"] = self.device.snapshot()
         out["queue_wait"] = self.queue_wait.snapshot()

@@ -52,6 +52,18 @@ def _tensors_nbytes(tensors) -> int:
     return sum(int(getattr(t, "nbytes", 0) or 0) for t in tensors)
 
 
+def _layer_counts(engine) -> dict:
+    """The running sums an expert family's engine keeps of what its
+    layers counted (``PagedLMEngine.layer_counts``), its two programs added
+    up; empty for an engine or a family that counts nothing."""
+    by_call = getattr(engine, "layer_counts", None) or {}
+    total: dict = {}
+    for counts in by_call.values():
+        for k, v in counts.items():
+            total[k] = total.get(k, 0) + v
+    return total
+
+
 def _block_ready(outputs) -> None:
     try:
         import jax
@@ -849,6 +861,7 @@ class DecodeScheduler:
             # what the engine spent under its own spans, before and after
             host0 = getattr(engine, "host_s", 0.0)
             pull0 = getattr(engine, "pull_s", 0.0)
+            counts0 = _layer_counts(engine)
             self._pass_tokens = 0
             with obs_context.span("serving.pass", live=len(self._active),
                                   prefilling=len(self._prefilling),
@@ -860,6 +873,10 @@ class DecodeScheduler:
             pull_s = getattr(engine, "pull_s", 0.0) - pull0
             metrics.record_pass(step, chunks, sp.dur_s - host_s - pull_s,
                                 host_s, pull_s)
+            if counts0:
+                metrics.record_layer_counts(
+                    {k: v - counts0[k]
+                     for k, v in _layer_counts(engine).items()})
 
     def _pass(self, first: Optional[Request]) -> Tuple[int, bool]:
         """The work of one pass; ``first`` is the request an idle wait

@@ -95,6 +95,17 @@ class TestLegsAtCpuSize:
         assert out["near_ties"] == []  # token-exact on the CPU
         assert out["spec_rounds"] > 0
 
+    def test_latent_serving_leg_counts(self):
+        import chip_smoke
+
+        out = chip_smoke.latent_serving_leg(serve_dtype="float32")
+        assert out["completed"] == out["requests"] == 6
+        assert out["line_widths"] == [128]  # 16 + 8 values in one lane row
+        # step + prefill_chunk: no page is shared, so nothing is copied
+        assert out["compile_count"] == 2
+        assert out["served_gap_max"] <= 1e-4  # float32 on the CPU
+        assert out["moe_experts_touched"] > 0
+
     def test_kernels_leg_interpreted(self):
         import chip_smoke
 

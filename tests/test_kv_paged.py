@@ -490,7 +490,8 @@ class TestPoolLayout:
         want = [pool[:, held] for pool in _pools(eng)]
         blob = eng.preempt(0)
         NB = eng.blocks_per_slot
-        assert blob["k"].shape == blob["v"].shape == \
+        kblob, vblob = blob["pages"]  # one blob per pool of the family
+        assert kblob.shape == vblob.shape == \
             (cfg.layers, NB, 8, cfg.dim), "blob: (layer, block, line, dim)"
         # park another tenant on the freed pages so restore lands elsewhere
         eng.admit(1, rng.integers(0, cfg.vocab, 20).astype(np.int32), 4)
@@ -592,6 +593,75 @@ class TestPoolInPlaceOnTpu:
         aliased = compiled.memory_analysis().alias_size_in_bytes
         assert aliased >= 2 * pool_count * 2, \
             f"{program} must alias both donated pools to its outputs"
+
+    @pytest.mark.parametrize("program", ["_step", "_prefill_chunk"])
+    def test_latent_pool_goes_in_and_comes_out_in_one_layout(self, v5e_chip,
+                                                             program):
+        # the same rules for the DeepSeek-V3 family's one pool: a line of
+        # 512 latent + 64 rotary values (Kanana-2's widths) at a small
+        # depth, hidden size and expert count, 2049 rows a layer
+        import functools
+
+        import jax
+        import jax.numpy as jnp
+
+        from nnstreamer_tpu.models.deepseek_v3 import (
+            DeepseekV3Config,
+            init_params,
+        )
+
+        cfg = DeepseekV3Config(
+            vocab_size=512, hidden_size=256, num_hidden_layers=2,
+            num_attention_heads=4, intermediate_size=512,
+            moe_intermediate_size=128, n_routed_experts=8,
+            num_experts_per_tok=2, kv_lora_rank=512, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128, max_position_embeddings=128)
+        S, pg, pages, C = 4, 16, 2048, 32
+        eng = PagedLMEngine(cfg, {"embed": jnp.zeros((1, 1), jnp.bfloat16)},
+                            slots=S, page_size=pg, pages=pages, chunk=C)
+        assert len(eng._pools) == 1 and eng.line_widths == (640,)  # 576 padded
+
+        def shape(s, dt):
+            return jax.ShapeDtypeStruct(s, dt, sharding=v5e_chip)
+
+        params = jax.tree_util.tree_map(
+            lambda a: shape(a.shape, jnp.bfloat16),
+            jax.eval_shape(functools.partial(init_params, cfg)))
+        NB = cfg.max_position_embeddings // pg
+        pool = shape(eng._pools[0].shape, jnp.bfloat16)
+        if program == "_step":
+            args = (shape((S, 1), jnp.int32), shape((S,), jnp.int32),
+                    shape((S,), jnp.bool_), shape((S, NB), jnp.int32))
+        else:
+            args = (shape((C,), jnp.int32), shape((), jnp.int32),
+                    shape((), jnp.int32), shape((NB,), jnp.int32))
+        compiled = getattr(eng, program).func.lower(
+            params, *args, pool).compile()
+
+        pool_count = int(np.prod(eng._pools[0].shape))
+        sizes = {pool_count, pool_count // cfg.num_hidden_layers}
+        moved = [f"{op} {name}"
+                 for name, op, counts in _entry_results(compiled.as_text())
+                 if counts & sizes
+                 and (op == "copy" or "slice" in name or "copy" in name)]
+        assert not moved, \
+            f"{program} copies or slices the pool or a layer of it: {moved}"
+        aliased = compiled.memory_analysis().alias_size_in_bytes
+        assert aliased >= pool_count * 2, \
+            f"{program} must alias the donated pool to its output"
+        # a decode step never expands a context's keys and values per head:
+        # nothing of (slots, ctx, heads, head values) elements exists
+        # (weights of these toy sizes have such counts by accident: only
+        # what an operation computes is looked at)
+        ctx = NB * pg
+        expanded = {S * ctx * 4 * d for d in (128, 192, 256, 320)}
+        if program == "_step":
+            made = [f"{op} {name}" for name, op, counts in
+                    _entry_results(compiled.as_text())
+                    if counts & expanded and op in (
+                        "fusion", "dot", "convolution", "custom-call",
+                        "reshape", "transpose", "broadcast", "copy")]
+            assert not made, f"_step expands keys or values per head: {made}"
 
 
 # ---------------------------------------------------------------------------
