@@ -261,29 +261,44 @@ class DeepseekV3Family:
                     (jnp.concatenate([c, kr, line_pad], axis=-1),))
 
     def attend(self, blk, q, ctxs, visible, mode):
-        """``q (B, Q, H, line)`` over ``ctxs[0] (B, ctx, line)``: scores on
-        the whole line (latent and rotary parts in one contraction), the
-        weighted sum of whole lines, of which the latent part is the
-        output; then ``W_uv`` per head and ``W_o``."""
+        """One slot's chunk: ``q (1, C, H, line)`` over ``ctxs[0] (1, ctx,
+        line)``, ``visible (C, ctx)``: scores on the whole line (latent and
+        rotary parts in one contraction), the weighted sum of whole lines,
+        then :meth:`_project_out`."""
         import jax
         import jax.numpy as jnp
 
-        cfg = self.cfg
         (ctx,) = ctxs
-        if mode == "step":
-            visible = visible[:, None]        # (S, 1, ctx)
-        elif mode == "chunk":
-            visible = visible[None]           # (1, C, ctx)
-        exact = jax.lax.Precision.HIGHEST    # as the GPT step: f32 queries
+        exact = jax.lax.Precision.HIGHEST    # f32 queries over a bf16 pool
         with jax.named_scope("mla"):
             att = (jnp.einsum("bqhl,bcl->bqhc", q, ctx, precision=exact)
-                   / math.sqrt(cfg.qk_head_dim))
-            att = jnp.where(visible[:, :, None, :], att, -1e30)
+                   * self.attention_scale)
+            att = jnp.where(visible[None, :, None, :], att, -1e30)
             att = jax.nn.softmax(att, axis=-1)
             o = jnp.einsum("bqhc,bcl->bqhl", att, ctx, precision=exact)
-            o = jnp.einsum("bqhl,hlv->bqhv", o[..., :cfg.kv_lora_rank],
-                           blk["wuv"])
-            return o.reshape(*q.shape[:2], -1) @ blk["wo"]
+            return self._project_out(blk, o)
+
+    @property
+    def attention_scale(self) -> float:
+        return self.cfg.qk_head_dim ** -0.5
+
+    def step_queries(self, q):
+        return q[:, 0]  # (S, H, line): the absorbed queries as they are
+
+    def step_output(self, blk, o):
+        import jax
+
+        with jax.named_scope("mla"):
+            return self._project_out(blk, o[:, None])
+
+    def _project_out(self, blk, o):
+        """Weighted sums of whole lines ``(B, Q, H, line)``: the latent part
+        is the output; ``W_uv`` per head, then ``W_o``."""
+        import jax.numpy as jnp
+
+        o = jnp.einsum("bqhl,hlv->bqhv", o[..., :self.cfg.kv_lora_rank],
+                       blk["wuv"])
+        return o.reshape(*o.shape[:2], -1) @ blk["wo"]
 
     def ffn(self, blk, x, live):
         cfg = self.cfg

@@ -1,8 +1,8 @@
 """Model families as the paged serving engine sees them (ROADMAP D1).
 
 ``serving.lm_engine.PagedLMEngine`` owns slots, block tables, the page
-pool and the three programs' skeleton (write the new lines, gather a
-slot's lines, attend, feed forward, head). What a *layer* is and what it
+pool and the three programs' skeleton (write the new lines, attend over a
+slot's lines, feed forward, head). What a *layer* is and what it
 keeps per token comes from one object, the family, chosen by the type of
 the entry's configuration (:func:`family_of`); no flag names a model.
 
@@ -20,9 +20,16 @@ A family says:
   query side and the lines to write, one per pool, each ``(B, Q, width)``.
 * ``attend(blk, q, ctxs, visible, mode)`` — the queries over the gathered
   lines ``ctxs`` (one ``(B, ctx, width)`` per pool), output projection
-  applied: what the residual adds. ``mode`` is the program: ``"step"``
-  (one query a slot, ``visible (S, ctx)``), ``"chunk"`` (one slot's chunk,
-  ``visible (C, ctx)``) or ``"verify"`` (``visible (S, K, ctx)``).
+  applied: what the residual adds. ``mode`` is the program: ``"chunk"``
+  (one slot's chunk, ``visible (C, ctx)``) or ``"verify"``
+  (``visible (S, K, ctx)``).
+* ``step_queries(q)``, ``attention_scale``, ``step_output(blk, o)`` — the
+  decode step gathers nothing: every family's step is ``H`` queries a slot
+  over one shared line a token, so the engine hands
+  ``ops.paged_attention.paged_line_attention`` the step's queries over
+  whole lines ``(S, H, width)`` and the score scale, and the family
+  finishes the ``(S, H, width)`` float32 result (its own part of the line,
+  output projection applied).
 * ``ffn(blk, x, live)`` — norm and feed-forward of ``x (B, Q, D)`` →
   ``(y, counts)``; ``live (B, Q)`` marks the rows that are real, and
   ``counts`` is ``None`` or the int32 vector ``counters`` names.
@@ -82,33 +89,37 @@ class GPTFamily:
     def attend(self, blk, q, ctxs, visible, mode):
         return getattr(self, f"_attend_{mode}")(q, *ctxs, visible) @ blk["wo"]
 
-    def _attend_step(self, q, ck, cv, visible):
-        import jax
+    @property
+    def attention_scale(self) -> float:
+        return self.cfg.head_dim ** -0.5
+
+    def _own(self):
         import jax.numpy as jnp
 
+        # line element j belongs to head j // head_dim
         cfg = self.cfg
-        H, Dh, S = cfg.heads, cfg.head_dim, q.shape[0]
-        heads = jnp.arange(H)
-        # line element j belongs to head j // Dh
-        own = jnp.arange(H * Dh)[:, None] // Dh == heads[None, :]
-        exact = jax.lax.Precision.HIGHEST
-        # one query per slot against 2048 lines of 16 slots: the
-        # contexts are read where the take left them, whole lines
-        # against a block-diagonal q (column h holds head h's
-        # query and zeros), instead of being re-tiled by head
-        # first: 1.29 ms a layer against 6.09 on a v5e. The zeros
-        # add nothing, and HIGHEST keeps the float32 query and
-        # weights float32 on the MXU, so the scores and outputs
-        # are the per-head float32 ones (3.6e-7 apart on the chip)
-        qbd = jnp.where(own, q[:, 0, :, None], 0.0)  # (S, H*Dh, H)
-        att = (jnp.einsum("scj,sjh->shc", ck, qbd, precision=exact)
-               / jnp.sqrt(cfg.head_dim))
-        att = jnp.where(visible[:, None, :], att, -1e30)
-        att = jax.nn.softmax(att, axis=-1)           # (S, H, ctx)
-        o = jnp.einsum("shc,scj->shj", att, cv, precision=exact)
-        # row h of o is head h's weights over every head's values:
-        # its own block is the attention output
-        return o.reshape(S, H, H, Dh)[:, heads, heads].reshape(S, 1, cfg.dim)
+        return (jnp.arange(cfg.heads)[:, None]
+                == jnp.arange(cfg.heads * cfg.head_dim)[None, :]
+                // cfg.head_dim)
+
+    def step_queries(self, q):
+        import jax.numpy as jnp
+
+        # whole lines against a block-diagonal query (row h holds head h's
+        # query and zeros) instead of lines re-tiled by head: 1.29 ms a
+        # layer against 6.09 on a v5e for the gathered form (PR 25), and
+        # the shape the latent family's step has by construction. The
+        # zeros add nothing; the scores are the per-head float32 ones
+        return jnp.where(self._own()[None], q[:, 0, None, :], 0.0)
+
+    def step_output(self, blk, o):
+        import jax.numpy as jnp
+
+        # row h of o is head h's weights over every head's values: its
+        # own block is the attention output
+        S = o.shape[0]
+        o = jnp.where(self._own()[None], o, 0.0).sum(axis=1)
+        return o.reshape(S, 1, self.cfg.dim) @ blk["wo"]
 
     def _attend_chunk(self, q, ck, cv, visible):
         import jax

@@ -421,7 +421,13 @@ def _collect_serving(reg: Registry) -> None:
              "(token, expert) assignments served"),
             ("moe_max_load", "moe_max_load",
              "largest number of tokens one expert received, summed over "
-             "expert layers and program calls"))}
+             "expert layers and program calls"),
+            ("attn_pages_read", "attn_pages_read",
+             "pages the live slots held, summed over decode steps: what "
+             "the steps' attention read"),
+            ("attn_pages_padded", "attn_pages_padded",
+             "slots times the blocks a slot may hold, summed over decode "
+             "steps: what attention over padded positions would read"))}
     # snapshot mirrors: repopulated from live schedulers each scrape, so
     # a garbage-collected scheduler's series disappears with it
     for inst in (subm, comp, fail, shedf, shedd, shedm, shedo, batches,

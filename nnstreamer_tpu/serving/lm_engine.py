@@ -236,11 +236,15 @@ class PagedLMEngine:
       branches in the scatter). A slot's logical position ``p`` lives at
       ``(li*(pages+1) + block_table[p // page], p % page)``. No program
       slices a layer out of the pool: a write is a scatter on the two
-      leading axes, a context read one ``take`` of the slot's rows.
+      leading axes, a chunk's context read one ``take`` of the slot's rows,
+      a step's attention a walk over the rows themselves.
     * **serving limit** — ``max_seq``, the positions a slot may hold: the
       family's ``max_positions`` (the ``gpt`` family's position table, the
       latent family's ``max_position_embeddings``) or ``max_positions=``
-      below it. A step attends over that many padded positions.
+      below it. A prefill chunk and a verify round attend over that many
+      padded positions; a decode step reads the pages each live slot holds
+      and no more (``ops/paged_attention.py``; ``attn_pages`` and the
+      ``engine.step.prepare`` span count them against the padding).
     * **chunked prefill** — ``admit_start`` queues the prompt and
       ``prefill_tick`` ingests ONE fixed-size chunk per call, so a long
       prompt interleaves with running decode instead of stalling the
@@ -255,10 +259,13 @@ class PagedLMEngine:
       frees them; ``restore`` re-allocates and uploads byte-exact, so
       memory pressure never drops a request.
 
-    Parity contract: masked scores sit at -1e30 → exact-zero softmax
-    weight, and the gathered context length equals ``max_seq``, so the
-    paged step is token-exact against the dense engine (asserted in
-    test_kv_paged.py).
+    Parity contract: a position a slot does not see has exact-zero softmax
+    weight (masked at -1e30 in the gathered forms, never read by the
+    step's kernel), so on the CPU, where the step's attention runs in its
+    plain form over ``max_seq`` gathered positions, the paged step is
+    token-exact against the dense engine (asserted in test_kv_paged.py).
+    On a TPU the kernel's online softmax sums in another order: agreement
+    to float32 rounding, tokens under ``chip_smoke.near_tie``.
     """
 
     def __init__(self, cfg, params, slots: int = 4, page_size: int = 16,
@@ -273,6 +280,10 @@ class PagedLMEngine:
         import jax.numpy as jnp
 
         from ..models.families import family_of
+        from ..ops.paged_attention import (
+            gathered_lines,
+            paged_line_attention,
+        )
         from .kv_pool import KVPagePool
 
         fam = family_of(cfg)
@@ -322,7 +333,7 @@ class PagedLMEngine:
                             for w in self.line_widths)
         P = len(self._pools)
         NB = self.blocks_per_slot
-        ctx = NB * page_size  # == max_seq: dense-identical contraction
+        ctx = NB * page_size  # == max_seq: what a chunk or a verify gathers
 
         # host mirrors (authoritative; device copies re-synced on change)
         self._bt = np.zeros((slots, NB), np.int32)
@@ -339,6 +350,9 @@ class PagedLMEngine:
         self.layer_counts = {call: dict.fromkeys(fam.counters, 0)
                              for call in ("step", "chunk")}
         self._chunk_counts: list = []
+        # running sums over decode steps: the pages the live slots held
+        # (what a step's attention reads) and slots x blocks_per_slot
+        self.attn_pages = {"attn_pages_read": 0, "attn_pages_padded": 0}
 
         self.cache_bytes = int(sum(p.nbytes for p in self._pools))
         self.param_bytes = obs_memory.tree_nbytes(params)
@@ -352,37 +366,34 @@ class PagedLMEngine:
             # a scatter on the two leading axes, one whole line per token
             return pool.at[li * R + dest, offs].set(rows.astype(pool.dtype))
 
-        def _read_ctx(pool, li, bt):
-            # bt (S, NB) -> (S, ctx, width): logical position p of slot s is
-            # line (s, p). One take of the slot's rows straight from the
-            # pool, the layer's offset added to the block table on the
-            # device. Block tables hold page ids the pool handed out, so
-            # "clip" never clips; the default mode would mask the gathered
-            # copy against out-of-range ids, one more pass over it. Merging
-            # (NB, pg) moves nothing. Splitting a line into (H, Dh) does on
-            # a TPU (the copy is re-tiled with Dh padded to a full lane
-            # row), so only the programs whose context is one slot's do it
-            g = jnp.take(pool, li * R + bt, axis=0, mode="clip")
-            return g.reshape(bt.shape[0], ctx, pool.shape[-1])
-
-        def _layers(p, x, pos, live, mode, dest, offs, bt, visible, pools,
-                    unbatch):
+        def _layers(p, x, pos, live, dest, offs, pools, unbatch, attend):
             # the skeleton every program shares: per layer, write the new
-            # lines, gather the slots' lines, attend, feed forward.
-            # ``unbatch`` strips the axis a program's lines do not have
+            # lines, attend over the slots' lines (``attend(li, blk, q,
+            # pools)``: what the residual adds), feed forward. ``unbatch``
+            # strips the axis a program's lines do not have
             counts = jnp.zeros((NC,), jnp.int32) if NC else None
             for li, blk in enumerate(fam.blocks(p)):
                 q, lines = fam.project(blk, x, pos)
                 with jax.named_scope(fam.attention_scope):
                     pools = tuple(_write(pool, li, dest, offs, unbatch(line))
                                   for pool, line in zip(pools, lines))
-                    ctxs = tuple(_read_ctx(pool, li, bt) for pool in pools)
-                x = x + fam.attend(blk, q, ctxs, visible, mode)
+                x = x + attend(li, blk, q, pools)
                 y, c = fam.ffn(blk, x, live)
                 x = x + y
                 if c is not None:
                     counts = counts + c
             return x, pools, counts
+
+        def _gathered(mode, bt, visible):
+            # attention over a gathered copy of the block table's whole
+            # ``max_seq`` positions: the programs whose context is one
+            # slot's (a chunk) or that score several queries a slot (verify)
+            def attend(li, blk, q, pools):
+                with jax.named_scope(fam.attention_scope):
+                    ctxs = tuple(gathered_lines(pool, li * R + bt)
+                                 for pool in pools)
+                return fam.attend(blk, q, ctxs, visible, mode)
+            return attend
 
         def _step(p, token, pos, mask, bt, *pools):
             self.compile_count += 1  # trace-time only: one step program
@@ -393,11 +404,23 @@ class PagedLMEngine:
             dest = jnp.where(mask & (pos < max_seq),
                              bt[jnp.arange(S), bidx], 0)
             offs = pos % pg
-            positions = jnp.arange(ctx)
-            visible = (positions[None, :] <= pos[:, None])  # (S, ctx)
+            # what a slot sees: its positions up to the one just written,
+            # nothing for a slot that is not live
+            lengths = jnp.where(mask, jnp.minimum(pos + 1, max_seq), 0)
+
+            def attend(li, blk, q, pools):
+                # the step's one attention form (ops/paged_attention.py):
+                # the family's queries over whole lines, read from the
+                # pool's rows where they lie, as far as each slot's length
+                with jax.named_scope(fam.attention_scope):
+                    o = paged_line_attention(
+                        fam.step_queries(q), pools[0], pools[-1],
+                        li * R + bt, lengths, fam.attention_scale)
+                return fam.step_output(blk, o)
+
             x, pools, counts = _layers(
-                p, x, lp[:, None], mask[:, None], "step", dest, offs, bt,
-                visible, pools, lambda line: line[:, 0])
+                p, x, lp[:, None], mask[:, None], dest, offs, pools,
+                lambda line: line[:, 0], attend)
             with jax.named_scope("head"):
                 logits = fam.head(p, x[:, 0])
             out = jnp.argmax(logits, -1).astype(jnp.int32)
@@ -425,8 +448,8 @@ class PagedLMEngine:
             positions = jnp.arange(ctx)
             visible = (positions[None, :] <= q_pos[:, None])  # (C, ctx)
             x, pools, counts = _layers(
-                p, x, lp[None], valid[None], "chunk", dest, offs, bt[None],
-                visible, pools, lambda line: line[0])
+                p, x, lp[None], valid[None], dest, offs, pools,
+                lambda line: line[0], _gathered("chunk", bt[None], visible))
             with jax.named_scope("head"):
                 logits = fam.head(p, x[0])  # (C, V)
             if NC:
@@ -481,8 +504,9 @@ class PagedLMEngine:
             positions = jnp.arange(ctx)
             visible = (positions[None, None, :] <= q_pos[:, :, None])
             x, pools, _ = _layers(
-                p, x, lp, jnp.broadcast_to(mask[:, None], (S, K)), "verify",
-                dest, offs, bt, visible, pools, lambda line: line)
+                p, x, lp, jnp.broadcast_to(mask[:, None], (S, K)), dest,
+                offs, pools, lambda line: line,
+                _gathered("verify", bt, visible))
             logits = fam.head(p, x)  # (S, K, V)
             return (logits, *pools)
 
@@ -713,6 +737,14 @@ class PagedLMEngine:
                 if self._pos[s] < self.max_seq:
                     self._ensure_writable(int(s), int(self._pos[s]),
                                           int(self._pos[s]) + 1)
+            # how far the step's attention follows what is visible: the
+            # pages the live slots hold against every slot's whole table
+            seen = np.minimum(self._pos[slots] + 1, self.max_seq)
+            read = int((-(-seen // self.page_size)).sum())
+            padded = self.slots * self.blocks_per_slot
+            prepare.attrs.update(pages_read=read, pages_padded=padded)
+            self.attn_pages["attn_pages_read"] += read
+            self.attn_pages["attn_pages_padded"] += padded
         with obs_context.span("engine.step.dispatch", live=live) as dispatch:
             tok_dev, self._tok_dev, self._pos_dev, *pools = self._step(
                 self._tok_dev, self._pos_dev, self._mask_dev,

@@ -106,6 +106,10 @@ class ServingMetrics:
         self.moe_expert_slots = 0      # experts held x expert layers x calls
         self.moe_assignments = 0       # (token, expert) pairs served
         self.moe_max_load = 0          # largest load of one expert, summed
+        # how far a decode step's attention follows what is visible
+        # (PagedLMEngine.attn_pages), summed over steps
+        self.attn_pages_read = 0       # pages the live slots held
+        self.attn_pages_padded = 0     # slots x blocks a slot may hold
         # device channel: batch execution time (dispatch+block, the
         # reference-comparable number); reservoirs: per-request tails
         self.device = InvokeStats()
@@ -186,10 +190,12 @@ class ServingMetrics:
             self.pull_wait_s += pull_wait_s
 
     def record_layer_counts(self, counts: dict) -> None:
-        """What the engine's expert layers counted since the last pass
+        """What the engine counted since the last pass: its expert layers
         (the ``moe_*`` keys of ``PagedLMEngine.layer_counts``, both
-        programs added up)."""
+        programs added up) and its steps' attention (``attn_pages``)."""
         with self._lock:
+            self.attn_pages_read += counts.get("attn_pages_read", 0)
+            self.attn_pages_padded += counts.get("attn_pages_padded", 0)
             self.moe_experts_touched += counts.get("moe_experts_touched", 0)
             self.moe_expert_slots += counts.get("moe_expert_slots", 0)
             self.moe_assignments += counts.get("moe_assignments", 0)
@@ -238,6 +244,8 @@ class ServingMetrics:
                 "moe_expert_slots": self.moe_expert_slots,
                 "moe_assignments": self.moe_assignments,
                 "moe_max_load": self.moe_max_load,
+                "attn_pages_read": self.attn_pages_read,
+                "attn_pages_padded": self.attn_pages_padded,
             }
         out["device"] = self.device.snapshot()
         out["queue_wait"] = self.queue_wait.snapshot()

@@ -53,11 +53,12 @@ def _tensors_nbytes(tensors) -> int:
 
 
 def _layer_counts(engine) -> dict:
-    """The running sums an expert family's engine keeps of what its
+    """The running sums a paged engine keeps: what an expert family's
     layers counted (``PagedLMEngine.layer_counts``), its two programs added
-    up; empty for an engine or a family that counts nothing."""
+    up, and the pages its steps' attention read (``attn_pages``); empty for
+    an engine that counts nothing."""
     by_call = getattr(engine, "layer_counts", None) or {}
-    total: dict = {}
+    total = dict(getattr(engine, "attn_pages", None) or {})
     for counts in by_call.values():
         for k, v in counts.items():
             total[k] = total.get(k, 0) + v

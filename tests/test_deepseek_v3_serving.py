@@ -171,8 +171,15 @@ def test_absorbed_attention_equals_expanded_on_one_layer():
         want = ref.attention(x, blk, sz)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=LOGIT_TOL, rtol=0)
-    # the step's view of the same thing: the last position alone
-    got1 = fam.attend(blk, q[:, -1:], (line,), visible[-1:], "step")[0, 0]
+    # the step's view of the same thing: the last position alone, by the
+    # step's own path (its queries over whole lines, the op's plain form
+    # over a pool of one page of S lines, the family's finish)
+    from nnstreamer_tpu.ops.paged_attention import plain_line_attention
+
+    o = plain_line_attention(
+        fam.step_queries(q[:, -1:]), line, line, jnp.zeros((1, 1), jnp.int32),
+        jnp.asarray([S], jnp.int32), fam.attention_scale)
+    got1 = fam.step_output(blk, o)[0, 0]
     np.testing.assert_allclose(np.asarray(got1), np.asarray(want[-1]),
                                atol=LOGIT_TOL, rtol=0)
 

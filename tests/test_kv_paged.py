@@ -15,10 +15,11 @@ The properties the paged data plane exists for, each asserted directly:
   alternating, real drafts), so speculation can change latency only;
 * compile discipline — the chunk size is the only compiled prefill
   shape, so compile_count is flat across prompt lengths;
-* pool in place — compiled for a described TPU v5e, the step and the
-  prefill chunk take the donated pool in and hand it back in one
-  layout: no copy and no slice of a pool's or a layer's size, both
-  pools aliased input to output; and on any backend a write routed to
+* pool in place — compiled for a described TPU v5e, the step (with its
+  attention kernel in) and the prefill chunk take the donated pool in and
+  hand it back in one layout: no copy and no slice of a pool's or a
+  layer's size, both pools aliased input to output, and nothing of a
+  gathered context's size left in the step; and on any backend a write routed to
   the null page, a COW copy and a preempt/restore touch only the rows
   they name, in every layer;
 * page lifecycle — every scheduler exit path (retire, close with
@@ -33,6 +34,7 @@ import numpy as np
 import pytest
 
 from nnstreamer_tpu.analysis import sanitizer
+from nnstreamer_tpu.ops import paged_attention
 from nnstreamer_tpu.serving import (
     DecodeScheduler,
     PagedLMEngine,
@@ -541,10 +543,22 @@ def _entry_results(hlo_text):
             yield m.group(1), m.group(3), counts
 
 
+def _no_gathered_context(compiled, count):
+    """A step reads the pool's rows where they lie: the kernel is in the
+    program, and nothing of a gathered context's ``(slots, max_seq,
+    width)`` elements is computed."""
+    hlo = compiled.as_text()
+    assert "paged_line_attention" in hlo and "tpu_custom_call" in hlo, \
+        "the step's attention is not the kernel"
+    made = [f"{op} {name}" for name, op, counts in _entry_results(hlo)
+            if count in counts and op != "parameter"]
+    assert not made, f"_step still gathers a padded context: {made}"
+
+
 class TestPoolInPlaceOnTpu:
     @pytest.mark.parametrize("program", ["_step", "_prefill_chunk"])
     def test_pool_goes_in_and_comes_out_in_one_layout(self, v5e_chip,
-                                                      program):
+                                                      program, monkeypatch):
         import functools
 
         import jax
@@ -562,6 +576,10 @@ class TestPoolInPlaceOnTpu:
         cfg = TransformerConfig(vocab=512, dim=256, heads=4, layers=2,
                                 mlp_mult=4, max_seq=128)
         S, pg, pages, C = 4, 16, 2048, 32
+        # the step as a TPU runs it, with the kernel in (here the op would
+        # take its plain form: this process's backend is the CPU)
+        monkeypatch.setattr(paged_attention, "paged_line_attention",
+                            paged_attention.kernel_line_attention)
         eng = PagedLMEngine(cfg, {"embed": jnp.zeros((1, 1), jnp.bfloat16)},
                             slots=S, page_size=pg, pages=pages, chunk=C)
 
@@ -593,10 +611,13 @@ class TestPoolInPlaceOnTpu:
         aliased = compiled.memory_analysis().alias_size_in_bytes
         assert aliased >= 2 * pool_count * 2, \
             f"{program} must alias both donated pools to its outputs"
+        if program == "_step":
+            _no_gathered_context(compiled, S * cfg.max_seq * cfg.dim)
 
     @pytest.mark.parametrize("program", ["_step", "_prefill_chunk"])
     def test_latent_pool_goes_in_and_comes_out_in_one_layout(self, v5e_chip,
-                                                             program):
+                                                             program,
+                                                             monkeypatch):
         # the same rules for the DeepSeek-V3 family's one pool: a line of
         # 512 latent + 64 rotary values (Kanana-2's widths) at a small
         # depth, hidden size and expert count, 2049 rows a layer
@@ -617,6 +638,8 @@ class TestPoolInPlaceOnTpu:
             num_experts_per_tok=2, kv_lora_rank=512, qk_nope_head_dim=128,
             qk_rope_head_dim=64, v_head_dim=128, max_position_embeddings=128)
         S, pg, pages, C = 4, 16, 2048, 32
+        monkeypatch.setattr(paged_attention, "paged_line_attention",
+                            paged_attention.kernel_line_attention)
         eng = PagedLMEngine(cfg, {"embed": jnp.zeros((1, 1), jnp.bfloat16)},
                             slots=S, page_size=pg, pages=pages, chunk=C)
         assert len(eng._pools) == 1 and eng.line_widths == (640,)  # 576 padded
@@ -662,6 +685,7 @@ class TestPoolInPlaceOnTpu:
                         "fusion", "dot", "convolution", "custom-call",
                         "reshape", "transpose", "broadcast", "copy")]
             assert not made, f"_step expands keys or values per head: {made}"
+            _no_gathered_context(compiled, S * ctx * 640)
 
 
 # ---------------------------------------------------------------------------

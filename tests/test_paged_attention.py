@@ -1,0 +1,200 @@
+"""The decode step's attention over the pool's rows (ops/paged_attention.py).
+
+* the Pallas kernel, interpreted on the CPU, against the op's plain form
+  (gather, mask, softmax): two pools of one width and one pool as both,
+  lengths at every edge of a page and of a block, a shuffled block table;
+* an engine step through the kernel emits the tokens the plain form's step
+  emits, for both model families;
+* what ``GPTFamily._attend_step`` used to be held to: the family's step
+  path (block-diagonal queries over whole lines, the plain form, each
+  head's own block) is per-head attention;
+* the counters that say how far the step follows what is visible, on the
+  ``engine.step.prepare`` span and summed in the scheduler's metrics.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from nnstreamer_tpu.ops import paged_attention as pa
+from nnstreamer_tpu.serving import DecodeScheduler, PagedLMEngine
+
+PG, NB, PAGES, LAYERS = 8, 6, 40, 2
+MAX_SEQ = PG * NB
+LENGTHS = {
+    "empty": [0, 0, 0],
+    "one": [1, 1, 1],
+    "page_less_one": [PG - 1, 2 * PG - 1, 0],
+    "page": [PG, 2 * PG, 3 * PG],
+    "page_plus_one": [PG + 1, 2 * PG + 1, 1],
+    "all_of_max_seq": [MAX_SEQ, MAX_SEQ, MAX_SEQ],
+    "mixed": [0, MAX_SEQ, 2 * PG + 3],
+}
+
+
+def _pool(rng, width):
+    return jnp.asarray(rng.standard_normal((LAYERS * (PAGES + 1), PG, width)),
+                       jnp.bfloat16)
+
+
+@pytest.mark.parametrize("lengths", LENGTHS, ids=list(LENGTHS))
+@pytest.mark.parametrize("pools", ["keys_and_values", "one_pool_as_both"])
+def test_kernel_matches_the_plain_form(pools, lengths):
+    rng = np.random.default_rng(28)
+    S, H, W = 3, 4, 48
+    kpool = _pool(rng, W)
+    vpool = kpool if pools == "one_pool_as_both" else _pool(rng, W)
+    # a shuffled table: no slot's pages are neighbours, and layer 1's rows
+    bt = np.stack([rng.permutation(PAGES)[:NB] + 1 for _ in range(S)])
+    rows = jnp.asarray((PAGES + 1) + bt, jnp.int32)
+    q = jnp.asarray(rng.standard_normal((S, H, W)), jnp.float32)
+    seen = jnp.asarray(LENGTHS[lengths], jnp.int32)
+
+    want = pa.plain_line_attention(q, kpool, vpool, rows, seen, 0.25)
+    # two pages a block: three blocks a full slot, tails of both parities
+    got = pa.kernel_line_attention(q, kpool, vpool, rows, seen, 0.25,
+                                   pages_per_block=2, interpret=True)
+    assert got.shape == (S, H, W) and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6, rtol=0)
+    empty = np.asarray(seen) == 0
+    assert not np.asarray(got)[empty].any(), "an empty slot reads nothing"
+    # the block size the kernel derives for itself (here: the whole table)
+    derived = pa.kernel_line_attention(q, kpool, vpool, rows, seen, 0.25,
+                                       interpret=True)
+    np.testing.assert_allclose(np.asarray(derived), np.asarray(want),
+                               atol=2e-6, rtol=0)
+
+
+def _gpt():
+    from nnstreamer_tpu.models.lm_serving import tiny
+    from nnstreamer_tpu.models.transformer import init_params
+
+    return tiny.cfg, init_params(tiny.cfg, seed=0)
+
+
+def _latent():
+    from nnstreamer_tpu.models.deepseek_v3 import (
+        DeepseekV3Config,
+        init_params,
+    )
+
+    cfg = DeepseekV3Config(
+        vocab_size=96, hidden_size=32, num_hidden_layers=2,
+        num_attention_heads=4, intermediate_size=64, moe_intermediate_size=16,
+        n_routed_experts=8, num_experts_per_tok=2, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        max_position_embeddings=64)
+    return cfg, init_params(cfg, seed=0)
+
+
+def _serve(cfg, params):
+    """Two prompts of unlike lengths decoded side by side, slot 1 of 3 left
+    empty: every token both slots emit."""
+    eng = PagedLMEngine(cfg, params, slots=3, page_size=4, chunk=8, pages=24)
+    rng = np.random.default_rng(5)
+    out = {0: [eng.admit(0, rng.integers(1, 60, 21).astype(np.int32), 9)],
+           2: [eng.admit(2, rng.integers(1, 60, 3).astype(np.int32), 9)]}
+    for _ in range(8):
+        tok = eng.step()
+        for s in out:
+            out[s].append(int(tok[s]))
+    eng.close()
+    return out
+
+
+@pytest.mark.parametrize("family", [_gpt, _latent], ids=["gpt", "latent"])
+def test_a_step_through_the_kernel_emits_the_plain_forms_tokens(family,
+                                                                monkeypatch):
+    cfg, params = family()
+    want = _serve(cfg, params)
+    calls = []
+
+    def through_the_kernel(*args):
+        calls.append(args)
+        return pa.kernel_line_attention(*args, pages_per_block=2,
+                                        interpret=True)
+
+    # steered here, in the test: the engine takes the op as it stands in
+    # the module when the engine is built
+    monkeypatch.setattr(pa, "paged_line_attention", through_the_kernel)
+    got = _serve(cfg, params)
+    assert len(calls) == 2, "one call a layer, traced once"
+    assert got == want
+
+
+def test_gpt_step_path_is_per_head_attention():
+    from nnstreamer_tpu.models.families import GPTFamily
+
+    cfg, params = _gpt()
+    fam = GPTFamily(cfg)
+    blk = params["blocks"][0]
+    H, Dh = cfg.heads, cfg.head_dim
+    rng = np.random.default_rng(7)
+    S, seen = 3, np.asarray([5, 16, 0], np.int32)
+    q = jnp.asarray(rng.standard_normal((S, 1, cfg.dim)), jnp.float32)
+    kpool = jnp.asarray(rng.standard_normal((4, 8, cfg.dim)), jnp.float32)
+    vpool = jnp.asarray(rng.standard_normal((4, 8, cfg.dim)), jnp.float32)
+    rows = jnp.asarray([[1, 3], [2, 0], [0, 0]], jnp.int32)
+
+    queries = fam.step_queries(q)
+    assert queries.shape == (S, H, cfg.dim)
+    o = pa.plain_line_attention(queries, kpool, vpool, rows,
+                                jnp.asarray(seen), fam.attention_scale)
+    got = np.asarray(fam.step_output(blk, o))
+
+    for s in range(S):
+        n = int(seen[s])
+        if not n:
+            assert not got[s].any()
+            continue
+        k = np.asarray(kpool)[np.asarray(rows[s])].reshape(-1, H, Dh)[:n]
+        v = np.asarray(vpool)[np.asarray(rows[s])].reshape(-1, H, Dh)[:n]
+        att = np.einsum("hd,chd->hc", np.asarray(q[s, 0]).reshape(H, Dh),
+                        k) / np.sqrt(Dh)
+        att = np.exp(att - att.max(-1, keepdims=True))
+        att /= att.sum(-1, keepdims=True)
+        want = np.einsum("hc,chd->hd", att, v).reshape(cfg.dim) \
+            @ np.asarray(blk["wo"], np.float32)
+        np.testing.assert_allclose(got[s, 0], want, atol=2e-5, rtol=0)
+
+
+def test_pages_per_block_follows_the_lines_bytes(monkeypatch):
+    seen = {}
+
+    def call(*args, pages_per_block, **kw):
+        seen["pages"] = pages_per_block
+
+    monkeypatch.setattr(pa, "_call", call)
+    rows, q = jnp.zeros((16, 128), jnp.int32), None
+    for width, pages in ((2048, 8), (640, 32), (256, 64)):
+        pool = jax.ShapeDtypeStruct((10, 16, width), jnp.bfloat16)
+        pa.kernel_line_attention(q, pool, pool, rows, None, 1.0)
+        assert seen["pages"] == pages, width
+
+
+def test_step_counts_the_pages_it_reads_against_the_padding():
+    from nnstreamer_tpu.obs import context as obs_context
+    from nnstreamer_tpu.obs import metrics as obs_metrics
+
+    cfg, params = _gpt()
+    eng = PagedLMEngine(cfg, params, slots=3, page_size=4, chunk=8, pages=24)
+    sched = DecodeScheduler(eng)
+    try:
+        prompt = np.arange(1, 11, dtype=np.int32)  # 10 tokens: 3 pages
+        sched.submit(prompt, steps=4).result(timeout=300)
+        text = obs_metrics.render()
+    finally:
+        sched.close()
+    steps = [s for s in obs_context.finished_spans()
+             if s.name == "engine.step.prepare"][-3:]
+    # the first token comes from the prompt's last chunk; three steps see
+    # 11, 12 and 13 positions of one live slot of three
+    assert [s.attrs["pages_read"] for s in steps] == [3, 3, 4]
+    assert {s.attrs["pages_padded"] for s in steps} == {3 * (64 // 4)}
+    snap = sched.metrics_snapshot()
+    assert snap["attn_pages_read"] == eng.attn_pages["attn_pages_read"] == 10
+    assert snap["attn_pages_padded"] == 3 * 48
+    assert "nns_serving_attn_pages_read_total" in text
+    assert "nns_serving_attn_pages_padded_total" in text

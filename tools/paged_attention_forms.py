@@ -1,0 +1,170 @@
+"""Stand-alone measurement behind the decode step's attention (PR 28).
+
+One layer's step attention at the benchmark cells' shapes, over pools of
+the cells' size filled from a seed, in each form tried:
+
+* ``gather``  — ``ops.paged_attention.plain_line_attention``: take every
+  slot's whole block table, mask, softmax (the step's form until PR 28);
+* ``blocked`` — a ``fori_loop`` in XLA over blocks of pages up to the
+  batch's longest context, online softmax (dynamic trip count);
+* ``jaxlib``  — ``jax.experimental.pallas.ops.tpu.paged_attention`` with
+  ``kv_heads = 1``, ``head_dim`` the line and ``pool[None]``;
+* ``kernel``  — ``ops.paged_attention.kernel_line_attention`` at several
+  pages a block (``None``: the number it derives from the line's bytes).
+
+Shapes: ``opt`` 16 slots × 128 pages of ``(16, 2048)``, keys and values in
+two pools of 24 layers × 1025 rows; ``kanana`` 32 slots × 192 pages of
+``(16, 640)``, one pool of 8 layers × 6145 rows. Lengths as the cells' are:
+one slot of 300 (chat), sixteen of 300–830 (saturated), thirty-two of
+400–3072 (kanana), and every slot full (the guard). Each form runs every
+layer of the pool once a call (the rows differ by layer as in the engine),
+so the time printed is per layer with the pool's lines cold in HBM.
+
+Prints one JSON line per (shape, lengths, form): ms a layer and the largest
+difference from ``gather``'s output (float32 at ``HIGHEST``).
+
+    chiprun -- python tools/paged_attention_forms.py [form prefix ...]
+"""
+import functools
+import json
+import os
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from nnstreamer_tpu.ops import paged_attention as pa  # noqa: E402
+
+EXACT = jax.lax.Precision.HIGHEST
+PG = 16
+SHAPES = {
+    # slots, heads (queries a slot), line, blocks a slot, layers, pages, pools
+    "opt": dict(S=16, H=32, W=2048, NB=128, L=24, pages=1024, pools=2),
+    "kanana": dict(S=32, H=32, W=640, NB=192, L=8, pages=6144, pools=1),
+}
+
+
+def lengths_of(shape, name, rng):
+    S, ctx = shape["S"], shape["NB"] * PG
+    out = np.zeros((S,), np.int32)
+    if name == "chat":
+        out[3] = 300
+    elif name == "saturated":
+        out[:] = rng.integers(300, 831, S)
+    elif name == "kanana":
+        out[:] = rng.integers(400, ctx + 1, S) * 0.66  # mean 37% of ctx
+    elif name == "full":
+        out[:] = ctx
+    return out
+
+
+def blocked(q, kpool, vpool, rows, lengths, scale, PB=8):
+    S, NB = rows.shape
+    T = PB * PG
+    H = q.shape[1]
+
+    def body(i, carry):
+        m, l, acc = carry
+        r = jax.lax.dynamic_slice(rows, (0, i * PB), (S, PB))
+        k = jnp.take(kpool, r, axis=0, mode="clip").reshape(S, T, -1)
+        v = k if vpool is kpool else jnp.take(
+            vpool, r, axis=0, mode="clip").reshape(S, T, -1)
+        sc = jnp.einsum("shj,scj->shc", q, k, precision=EXACT) * scale
+        at = i * T + jnp.arange(T)
+        sc = jnp.where(at[None, None] < lengths[:, None, None], sc, -1e30)
+        m_new = jnp.maximum(m, sc.max(-1))
+        p = jnp.exp(sc - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "shc,scj->shj", p, v, precision=EXACT)
+        return m_new, alpha * l + p.sum(-1), acc
+
+    init = (jnp.full((S, H), -1e30), jnp.zeros((S, H)),
+            jnp.zeros((S, H, vpool.shape[-1])))
+    blocks = (lengths.max() + T - 1) // T
+    _, l, acc = jax.lax.fori_loop(0, blocks, body, init)
+    return jnp.where((lengths > 0)[:, None, None],
+                     acc / jnp.maximum(l, 1e-30)[..., None], 0.0)
+
+
+def jaxlib(q, kpool, vpool, rows, lengths, scale, PB=8):
+    from jax.experimental.pallas.ops.tpu.paged_attention import (
+        paged_attention,
+    )
+
+    return paged_attention(q * scale, kpool[None], vpool[None], lengths,
+                           rows, pages_per_compute_block=PB)
+
+
+FORMS = {
+    "gather": pa.plain_line_attention,
+    "blocked": blocked,
+    "jaxlib": jaxlib,
+    **{f"kernel_pb{pb}": functools.partial(pa.kernel_line_attention,
+                                           pages_per_block=pb)
+       for pb in (None, 4, 8, 16, 32)},
+}
+
+
+def main():
+    only = sys.argv[1:]
+    rng = np.random.default_rng(28)
+    for shape_name, shape in SHAPES.items():
+        S, H, W, NB, L = (shape[k] for k in ("S", "H", "W", "NB", "L"))
+        R = shape["pages"] + 1
+        keys = jax.random.split(jax.random.PRNGKey(28), 3)
+        pools = tuple(jax.random.normal(k, (L * R, PG, W), jnp.bfloat16)
+                      for k in keys[:shape["pools"]])
+        kpool, vpool = pools[0], pools[-1]
+        q = jax.random.normal(keys[2], (S, H, W), jnp.float32)
+        bt = np.stack([rng.permutation(shape["pages"])[:NB] + 1
+                       for _ in range(S)]).astype(np.int32)
+        scale = 0.125
+        mixes = (("chat", "saturated", "full") if shape_name == "opt"
+                 else ("kanana", "full"))
+        for mix in mixes:
+            lengths = lengths_of(shape, mix, rng)
+            ref = None
+            for form, fn in FORMS.items():
+                if only and form != "gather" and not any(
+                        form.startswith(o) for o in only):
+                    continue  # named forms only, beside their oracle
+                if form == "kernel_pb32" and shape_name == "opt":
+                    continue  # 2 MB a buffer, four buffers: nothing to learn
+
+                def layers(q, bt, lengths, kpool, vpool, fn=fn):
+                    out = []
+                    for li in range(L):
+                        out.append(fn(q, kpool, vpool, li * R + bt, lengths,
+                                      scale))
+                    return out[0], sum(o.sum() for o in out)
+
+                row = {"shape": shape_name, "lengths": mix, "form": form,
+                       "tokens": int(lengths.sum())}
+                try:
+                    run = jax.jit(layers)
+                    args = (q, bt, lengths, kpool, vpool)
+                    first, _ = jax.block_until_ready(run(*args))
+                    reps = 5
+                    t0 = time.perf_counter()
+                    for _ in range(reps):
+                        out = run(*args)
+                    jax.block_until_ready(out)
+                    row["ms_per_layer"] = round(
+                        1e3 * (time.perf_counter() - t0) / reps / L, 4)
+                    if form == "gather":
+                        ref = first
+                    row["max_diff"] = float(jnp.abs(first - ref).max())
+                    row["ref_absmax"] = float(jnp.abs(ref).max())
+                except Exception as e:  # a form the compiler refuses
+                    row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+                print(json.dumps(row), flush=True)
+        del pools, kpool, vpool
+
+
+if __name__ == "__main__":
+    main()
