@@ -9,7 +9,7 @@ out by the repo's own means. One process, no child that needs the chip.
            image_labeling frames-in=64 → tensor_sink) at batch 64, 224×224,
            plus the same model behind a tensor_transform so that a fused
            segment runs;
-  serving  lm_serving.base.make_continuous(slots=8, paged=True) behind a
+  serving  lm_serving.base.make_continuous(slots=8) behind a
            DecodeScheduler, twelve seeded requests (5..1500 prompt tokens,
            a shared prefix, a page-aligned copy-on-write), then two
            requests through the speculative engine (draft="ngram");
@@ -335,8 +335,7 @@ def serving_leg(entry=None, slots: int = 8, steps: int = 32,
     check(len(prompts) > slots, "more requests than slots, so slots churn")
     out = {"requests": len(prompts), "slots": slots, "steps": steps}
 
-    engine = entry.make_continuous(slots=slots, paged=True,
-                                   page_size=page_size)
+    engine = entry.make_continuous(slots=slots, page_size=page_size)
     params = engine.params
     # warm the two programs every request runs, so that their compile
     # stays out of the run
@@ -390,7 +389,7 @@ def serving_leg(entry=None, slots: int = 8, steps: int = 32,
             ties.append({"request": i, **res})
 
     # speculative decode: _verify_commit on the chip, two requests
-    spec = entry.make_continuous(slots=2, paged=True, draft="ngram",
+    spec = entry.make_continuous(slots=2, draft="ngram",
                                  page_size=page_size)
     spec_prompts = [np.tile(prompts[1], 8), prompts[0]]
     streams, snap, spec_s = _serve(spec, spec_prompts, steps)
@@ -445,8 +444,8 @@ def latent_serving_leg(serve_dtype: str = "bfloat16", slots: int = 4,
             return params, False
 
     engine = Seeded(cfg, serve_dtype=serve_dtype).make_continuous(
-        paged=True, slots=slots, **{k: v for k, v in config["engine"].items()
-                                    if k != "slots"})
+        slots=slots, **{k: v for k, v in config["engine"].items()
+                        if k != "slots"})
     check(engine.family.name == "deepseek_v3" and len(engine._pools) == 1,
           "latent: the engine took another family or geometry")
     rng = np.random.default_rng(seed)

@@ -116,7 +116,7 @@ class _LMServingEntry:
             raise NotImplementedError(
                 f"lm_serving: {what} serves the gpt family only; the "
                 f"{self._family.name} family is served by "
-                "make_continuous(paged=True)")
+                "make_continuous()")
 
     def _build(self, mesh=None):
         from .decoding import make_generate
@@ -304,37 +304,40 @@ class _LMServingEntry:
 
         return stream
 
-    def make_continuous(self, slots: int = 4, mesh=None,
-                        paged: bool = False, draft=None,
-                        spec_k: int = 4, **paged_kw):
+    def make_continuous(self, slots: int = 4, paged: bool = True,
+                        draft=None, spec_k: int = 4, **engine_kw):
         """Continuous-batching decode state for the serving layer: a
-        fixed-``slots`` engine where sequences join/retire independently
-        between decode steps (``serving.DecodeScheduler`` drives it).
-        Params honor the entry's serve knobs (serve_dtype, cache_len).
+        fixed-``slots`` :class:`~...serving.PagedLMEngine` where sequences
+        join/retire independently between decode steps
+        (``serving.DecodeScheduler`` drives it; ``engine_kw``: page_size /
+        pages / chunk / share_prefixes / max_positions, docs/serving.md).
+        Params honor the entry's serve knobs (serve_dtype, cache_len); the
+        model family comes from the type of the entry's configuration
+        (models/families.py).
 
-        ``paged=True`` builds the block-table
-        :class:`~...serving.PagedLMEngine` (``paged_kw``: page_size /
-        pages / chunk / share_prefixes — see docs/serving.md §paged KV).
-        ``draft`` additionally wraps it in
-        :class:`~...serving.SpeculativeLMEngine`: pass a draft object
-        (``NgramDraft()``), a draft ``_LMServingEntry`` (becomes a
-        ``ModelDraft`` over its own params), or the string ``"ngram"``;
-        ``spec_k`` is the draft burst length verified per target call."""
-        from ..serving.lm_engine import from_entry
+        ``draft`` wraps it in :class:`~...serving.SpeculativeLMEngine`:
+        pass a draft object (``NgramDraft()``), a draft
+        ``_LMServingEntry`` (becomes a ``ModelDraft`` over its own
+        params), or the string ``"ngram"``; ``spec_k`` is the draft burst
+        length verified per target call.
+
+        ``paged`` is inert: True is its only value (ROADMAP D12)."""
+        if not paged:
+            raise ValueError(
+                "make_continuous(paged=False): the dense slot engine was "
+                "removed; the paged engine is the only one")
+        from ..serving.lm_engine import PagedLMEngine
 
         fam = self._family
         if draft is not None and not fam.serves_verify:
             raise NotImplementedError(
                 f"lm_serving: speculative verification (_verify) does not "
                 f"serve the {fam.name} family yet; build it without draft=")
-        eng = from_entry(self, slots=slots, mesh=mesh, paged=paged,
-                         **paged_kw)
+        params, _ = self._shard_params(None)
+        eng = PagedLMEngine(self._cfg_serve, params, slots=slots,
+                            **engine_kw)
         if draft is None:
             return eng
-        if not paged:
-            raise ValueError(
-                "speculative decode rides the paged engine "
-                "(verify() needs block tables); pass paged=True")
         from ..serving.speculative import (
             ModelDraft,
             NgramDraft,

@@ -35,8 +35,8 @@ Two forms, chosen in one place (:func:`paged_line_attention`) by
 * :func:`plain_line_attention` — gather every slot's ``NB`` pages, mask,
   softmax: the oracle the kernel is pinned to (``tests/
   test_paged_attention.py``) and what runs where a TPU kernel would only be
-  interpreted, so the CPU suites keep their token-exact parity with the
-  dense engine.
+  interpreted, so the CPU suites keep their token-exact parity with
+  ``models.decoding.make_generate``.
 """
 from __future__ import annotations
 

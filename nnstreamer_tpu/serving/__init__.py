@@ -11,9 +11,11 @@ Public surface:
 * :class:`Scheduler` / :class:`DecodeScheduler` — the two loops;
 * :class:`RequestQueue`, :class:`BatchFormer`, :class:`Request` — the
   building blocks, composable separately;
-* :class:`ContinuousLMEngine` / :class:`PagedLMEngine` — slot-based LM
-  decode state (dense per-slot caches vs block-table paged KV pool with
-  COW prefix sharing, chunked prefill, and preempt/restore);
+* :class:`DecodeEngine` — what an engine owes the ``DecodeScheduler``
+  that drives it, written once (where a fake or a proxy is substituted);
+* :class:`PagedLMEngine` — the slot-based LM decode engine: a block-table
+  paged KV pool with COW prefix sharing, chunked prefill, and
+  preempt/restore;
 * :class:`KVPagePool` — the refcounted page allocator + prefix registry;
 * :class:`SpeculativeLMEngine` (+ :class:`NgramDraft`/:class:`ModelDraft`)
   — draft-verify decoding riding the same join/retire loop;
@@ -32,7 +34,8 @@ from typing import Callable, Dict, Tuple
 
 from .batcher import Batch, BatchFormer  # noqa: F401
 from .kv_pool import KVPagePool, PagePoolExhausted  # noqa: F401
-from .lm_engine import ContinuousLMEngine, PagedLMEngine  # noqa: F401
+from .engine import DecodeEngine  # noqa: F401
+from .lm_engine import PagedLMEngine  # noqa: F401
 from .metrics import ServingMetrics, metrics_snapshot  # noqa: F401
 from .speculative import (  # noqa: F401
     ModelDraft,

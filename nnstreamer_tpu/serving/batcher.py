@@ -200,8 +200,8 @@ class BatchFormer:
         drained) additionally flushes cells sitting exactly ON a bucket
         boundary: padding cost is zero and no co-batchable traffic is
         waiting, so holding them out the max-wait timer buys occupancy
-        nothing — it only defers the batch (measured 9× throughput at
-        offered-load 1 in tools/bench_serving.py). Under dense traffic
+        nothing — it only defers the batch (9× throughput at offered-load
+        1 in a CPU bench since deleted). Under dense traffic
         the boundary flush lingers ``idle_linger_s`` past the newest
         arrival first: concurrent submitters trickle in one at a time,
         and an instant flush would split their burst into fragment

@@ -1,12 +1,12 @@
 """Paged KV-cache allocator: refcounted page pool + prefix registry (L6).
 
-The dense ``ContinuousLMEngine`` gives every slot a full ``max_seq`` KV
-cache, so concurrent-stream count is bounded by worst-case sequence
-length × slots whatever the traffic actually looks like. The paged
-engine (``lm_engine.PagedLMEngine``) instead draws fixed-size **pages**
-(``page_size`` positions each) from the pool owned here and addresses
-them through per-slot **block tables** — a slot's resident bytes follow
-its ACTUAL sequence length, and identical prompt prefixes dedupe across
+A slot with a full ``max_seq`` KV cache of its own bounds the number of
+concurrent streams by worst-case sequence length × slots whatever the
+traffic actually looks like. The engine (``lm_engine.PagedLMEngine``)
+draws fixed-size **pages** (``page_size`` positions each) from the pool
+owned here and addresses them through per-slot **block tables** — a
+slot's resident bytes follow its ACTUAL sequence length, and identical
+prompt prefixes dedupe across
 streams by sharing pages (Hermes' memory-over-kernels framing, arxiv
 2409.04249; pages are planner-visible resources per the multi-TPU
 profiled-segmentation stance, arxiv 2503.01025).

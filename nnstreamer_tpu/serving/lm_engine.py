@@ -1,32 +1,28 @@
-"""Slot-based continuous-decode engine over the LM decoding primitives
-(L6 serving ← models/decoding.py).
+"""The paged continuous-decode engine (L6 serving ← models/families.py).
 
 The batched-generation paths in ``models/lm_serving.py`` decode a FIXED
 batch: everyone prefills together, everyone steps together, the batch
 drains before the next one forms. Continuous batching needs per-slot
-independence — each sequence has its own position and lifetime — which
-this engine gets by **vmapping** :func:`models.decoding.decode_step` over
-a leading slot axis: one compiled program steps every slot, each against
-its own KV cache and position, exactly the math of S independent
-batch-1 decoders but issued as ONE device call per token.
+independence: each sequence has its own position and lifetime. Here one
+compiled program steps every slot, each against its own block table into
+a shared page pool and its own position: the math of S independent
+batch-1 decoders, issued as ONE device call per token.
 
-Join protocol (driven by ``DecodeScheduler``):
+Join protocol (``engine.DecodeEngine``, driven by ``DecodeScheduler``):
 
-* ``admit(slot, prompt, steps)`` — prefill the prompt in isolation
-  (batch-1 cache), then scatter the fresh cache into the slot axis of
-  the batched state (one jitted ``.at[slot].set`` per join). Prefill
-  compiles once per distinct prompt length — if that recompile churn
-  matters for your traffic, use :class:`PagedLMEngine` below: its
-  chunked prefill makes the chunk size the ONLY compiled prefill shape,
-  so compile_count stays flat across arbitrary prompt lengths.
-* ``step()`` — one vmapped decode step over ALL slots. Inactive slots
-  compute garbage at position 0 (static shapes are the point); the
-  scheduler ignores their outputs and ``admit`` overwrites their state.
-* ``release(slot)`` — host bookkeeping only; device state is dead until
-  the next admit overwrites it.
+* ``admit_start(slot, prompt, steps)`` queues the prompt and
+  ``prefill_tick()`` ingests one fixed-size chunk of one pending prompt a
+  call: the chunk is the ONLY compiled prefill shape, so ``compile_count``
+  stays flat across prompt lengths;
+* ``step()``: one decode step over ALL slots. Inactive slots write to a
+  null page and read nothing (static shapes are the point); the scheduler
+  ignores their outputs;
+* ``release(slot)`` returns the slot's pages to the pool.
 
 Greedy (argmax) decoding only — sampling policy belongs to the caller's
-model entry; the scheduler contract is deterministic token streams.
+model entry; the scheduler contract is deterministic token streams. The
+tests' reference is ``models.decoding.make_generate`` (tests/
+test_kv_paged.py ``_dense_baseline``).
 
 Spans (``obs.context.span``, always on, docs/observability.md): each call
 of a program is split where the host does three different things,
@@ -44,180 +40,18 @@ import numpy as np
 
 from ..obs import context as obs_context
 from ..obs import memory as obs_memory
+from .engine import DecodeEngine
 from .request import ServingError
 
 _engine_ids = itertools.count()
 
 
-class ContinuousLMEngine:
-    """Fixed-slot continuous decoder for a transformer config + params
-    (build via ``lm_serving._LMServingEntry.make_continuous``)."""
-
-    def __init__(self, cfg, params, slots: int = 4):
-        if slots < 1:
-            raise ValueError(f"slots={slots} must be >= 1")
-        import functools
-
-        import jax
-        import jax.numpy as jnp
-
-        from ..models.decoding import decode_step, init_cache, prefill
-
-        self.cfg = cfg
-        self.params = params
-        self.slots = slots
-        self.compile_count = 0
-        self.host_s = self.pull_s = 0.0  # under the step's spans, summed
-        self._jnp = jnp
-
-        cache_dtype = params["embed"].dtype
-        proto = init_cache(cfg, 1, dtype=cache_dtype)
-        # batched state: every cache leaf gains a leading slot axis
-        self._cache = jax.tree_util.tree_map(
-            lambda a: jnp.zeros((slots, *a.shape), a.dtype), proto)
-        # host mirrors: authoritative for admit/release bookkeeping and
-        # the scheduler's append/retire reads
-        self._tok = np.zeros((slots, 1), np.int32)
-        self._pos = np.zeros((slots,), np.int32)
-        self._mask = np.zeros((slots,), bool)
-        # memory accounting (obs/memory.py): the batched slot cache is
-        # the serving plane's dominant resident buffer — its footprint
-        # is static (fixed slots × max_seq), so one measurement at build
-        # time is the truth for the engine's whole lifetime
-        self.cache_bytes = obs_memory.tree_nbytes(self._cache)
-        self.param_bytes = obs_memory.tree_nbytes(params)
-        self._mem_name = f"lm_engine#{next(_engine_ids)}"
-        obs_memory.track_serving(self)
-
-        def _prefill(p, tokens):
-            self.compile_count += 1  # trace-time only: once per prompt len
-            cache = init_cache(cfg, 1, dtype=cache_dtype)
-            logits, cache, pos = prefill(cfg, p, tokens, cache)
-            return (jnp.argmax(logits, -1).astype(jnp.int32), cache,
-                    pos.astype(jnp.int32))
-
-        self._prefill = jax.jit(_prefill)
-
-        def _one_step(p, token, pos, cache):
-            logits, cache = decode_step(cfg, p, token, pos, cache)
-            return jnp.argmax(logits, -1).astype(jnp.int32), cache
-
-        def _step(p, token, pos, mask, cache):
-            self.compile_count += 1  # trace-time only: one step program
-            out, cache = jax.vmap(_one_step, in_axes=(None, 0, 0, 0))(
-                p, token, pos, cache)
-            # advance the carry state ON DEVICE: inactive slots keep
-            # their token/position, active slots take the new token and
-            # step forward — the host used to do this per token, paying
-            # two H2D uploads per decode step (NNL402's finding)
-            token = jnp.where(mask[:, None], out, token)
-            pos = pos + mask.astype(jnp.int32)
-            return out, token, pos, cache
-
-        # donate the whole device carry — token, position, AND the
-        # batched cache (each step rewrites them in place; without
-        # donation every token holds two full slot-caches in device
-        # memory). The mask is NOT donated: it is reused unchanged
-        # across steps and only re-uploaded at admit/release.
-        self._step = functools.partial(
-            jax.jit(_step, donate_argnums=(1, 2, 4)), params)
-
-        def _insert(state, new, slot):
-            self.compile_count += 1
-            return jax.tree_util.tree_map(
-                lambda s, n: s.at[slot].set(n), state, new)
-
-        self._insert = jax.jit(_insert, donate_argnums=(0,))
-        self._jax = jax
-        # device carry state (tok/pos/mask): resident across decode
-        # steps, re-synced from the host mirrors only at admit/release
-        # — per-request, not per-token
-        self._sync_device_state()
-
-    def _sync_device_state(self) -> None:
-        """Re-upload the decode carry state (token/position/mask) from
-        the host mirrors. Called at build, admit, and release — the join
-        protocol's slot edits — never per token: steady-state decode
-        carries these arrays device-resident and donated."""
-        jnp = self._jnp
-        self._tok_dev = jnp.asarray(self._tok)
-        self._pos_dev = jnp.asarray(self._pos)
-        self._mask_dev = jnp.asarray(self._mask)
-
-    # -- scheduler contract --------------------------------------------------
-    def validate(self, tokens: np.ndarray, steps: int) -> None:
-        if tokens.ndim != 1 or tokens.size == 0:
-            raise ValueError(
-                f"prompt must be non-empty 1-D tokens, got {tokens.shape}")
-        if tokens.size + steps > self.cfg.max_seq:
-            raise ValueError(
-                f"prompt ({tokens.size}) + steps ({steps}) exceeds "
-                f"max_seq {self.cfg.max_seq}")
-
-    def admit(self, slot: int, tokens: np.ndarray, steps: int) -> int:
-        if self._mask[slot]:
-            raise ServingError(f"slot {slot} already active")
-        tokens = np.asarray(tokens, np.int32)
-        self.validate(tokens, steps)
-        first, cache1, pos = self._prefill(self.params, tokens[None, :])
-        self._cache = self._insert(self._cache, cache1, slot)
-        self._tok[slot, 0] = int(first[0])
-        self._pos[slot] = int(pos)
-        self._mask[slot] = True
-        self._sync_device_state()
-        return int(first[0])
-
-    def step(self) -> np.ndarray:
-        """One decode step over every slot; returns (slots,) int32 (only
-        active-slot entries are meaningful)."""
-        live = self.active_slots
-        # the carry is device-resident, so nothing is prepared: the span
-        # keeps the tree the shape of the paged engine's
-        with obs_context.span("engine.step.prepare", live=live) as prepare:
-            pass
-        with obs_context.span("engine.step.dispatch", live=live) as dispatch:
-            tok_dev, self._tok_dev, self._pos_dev, self._cache = self._step(
-                self._tok_dev, self._pos_dev, self._mask_dev, self._cache)
-        with obs_context.span("engine.step.pull", live=live) as pull:
-            # nnlint: disable=NNL101 — one (slots,) pull per decode step:
-            # the scheduler needs host ints to append/retire (documented
-            # contract); explicit device_get, so it stays legal under the
-            # NNS_XFERCHECK disallow scopes and lands in the byte ledger
-            tok = self._jax.device_get(tok_dev)[:, 0]
-        self.host_s += prepare.dur_s + dispatch.dur_s
-        self.pull_s += pull.dur_s
-        self._pos = self._pos + self._mask.astype(np.int32)
-        self._tok[self._mask, 0] = tok[self._mask]
-        return tok
-
-    def release(self, slot: int) -> None:
-        self._mask[slot] = False
-        self._tok[slot, 0] = 0
-        self._pos[slot] = 0
-        self._sync_device_state()
-
-    # -- introspection --------------------------------------------------------
-    @property
-    def active_slots(self) -> int:
-        return int(self._mask.sum())
-
-    def memory_bytes(self) -> dict:
-        """Serving-plane byte source (obs/memory.py ``track_serving``
-        contract): the slot KV cache + params this engine keeps
-        device-resident, and how many slots are live in it."""
-        return {"name": self._mem_name, "kind": "kv_cache",
-                "bytes": self.cache_bytes,
-                "param_bytes": self.param_bytes,
-                "slots": self.slots, "active_slots": self.active_slots}
-
-
-class PagedLMEngine:
+class PagedLMEngine(DecodeEngine):
     """Block-table paged continuous decoder (the ROADMAP item 4 engine).
 
-    Where :class:`ContinuousLMEngine` gives every slot a dense
-    ``max_seq`` cache, this engine draws fixed-size pages from a
-    :class:`~.kv_pool.KVPagePool` and addresses them through per-slot
-    block tables, gathered/scattered inside the jitted programs:
+    No slot owns a ``max_seq`` cache: the engine draws fixed-size pages
+    from a :class:`~.kv_pool.KVPagePool` and addresses them through
+    per-slot block tables, gathered/scattered inside the jitted programs:
 
     * **model family** — what a layer is and what it keeps per token comes
       from ``models/families.py`` (``family_of(cfg)``, by the
@@ -263,14 +97,15 @@ class PagedLMEngine:
     weight (masked at -1e30 in the gathered forms, never read by the
     step's kernel), so on the CPU, where the step's attention runs in its
     plain form over ``max_seq`` gathered positions, the paged step is
-    token-exact against the dense engine (asserted in test_kv_paged.py).
+    token-exact against ``models.decoding.make_generate`` (asserted in
+    test_kv_paged.py).
     On a TPU the kernel's online softmax sums in another order: agreement
     to float32 rounding, tokens under ``chip_smoke.near_tie``.
     """
 
     def __init__(self, cfg, params, slots: int = 4, page_size: int = 16,
                  pages: Optional[int] = None, chunk: int = 32,
-                 share_prefixes: bool = True, pool_name: Optional[str] = None,
+                 share_prefixes: bool = True,
                  max_positions: Optional[int] = None):
         if slots < 1:
             raise ValueError(f"slots={slots} must be >= 1")
@@ -315,7 +150,7 @@ class PagedLMEngine:
 
         if pages is None:
             pages = slots * self.blocks_per_slot  # dense-equivalent pool
-        self._mem_name = pool_name or f"lm_engine#{next(_engine_ids)}"
+        self._mem_name = f"lm_engine#{next(_engine_ids)}"
 
         # the pool's geometry, from the family and from nowhere else: one
         # device array per kind of line a token keeps in a layer
@@ -536,9 +371,6 @@ class PagedLMEngine:
             return (out, tok, pos, *pools)
 
         if fam.serves_verify:
-            self._verify = functools.partial(
-                jax.jit(_verify, donate_argnums=tuple(range(5, 5 + P))),
-                params)
             self._verify_commit = functools.partial(
                 jax.jit(_verify_commit,
                         donate_argnums=(2, 3, *range(6, 6 + P))), params)
@@ -595,7 +427,7 @@ class PagedLMEngine:
         """Add what the family's expert layers counted in one call of a
         program (``family.counters``, in order) to ``layer_counts``;
         returns that call's counts, for the span of the pull that brought
-        them. The scheduler sums both programs' into its metrics."""
+        them (``counters`` sums both programs')."""
         got = dict(zip(self.family.counters, map(int, counts)))
         total = self.layer_counts[call]
         for k, v in got.items():
@@ -612,6 +444,17 @@ class PagedLMEngine:
         for counts in self._jax.device_get(pending):
             for k, v in self._note_counts("chunk", counts).items():
                 total[k] += v
+        return total
+
+    def counters(self) -> dict:
+        """The running sums the scheduler's metrics take per pass: the
+        pages the steps' attention read (``attn_pages``) and what an
+        expert family's layers counted (``layer_counts``), its two
+        programs added up."""
+        total = dict(self.attn_pages)
+        for counts in self.layer_counts.values():
+            for k, v in counts.items():
+                total[k] = total.get(k, 0) + v
         return total
 
     def projected_page_bytes(self, tokens: int, steps: int) -> int:
@@ -715,17 +558,6 @@ class PagedLMEngine:
         self._sync_device_state()
         return [(slot, first)]
 
-    def admit(self, slot: int, tokens: np.ndarray, steps: int) -> int:
-        """Blocking admit (contract-compatible with the dense engine):
-        runs the chunked prefill to completion before returning."""
-        self.admit_start(slot, tokens, steps)
-        while slot in self._pending:
-            done = self.prefill_tick()
-            for s, first in done:
-                if s == slot:
-                    return first
-        raise ServingError(f"slot {slot} prefill did not complete")
-
     def step(self) -> np.ndarray:
         """One paged decode step over every slot; may raise
         PagePoolExhausted when an active slot crosses into a page the
@@ -753,7 +585,8 @@ class PagedLMEngine:
         with obs_context.span("engine.step.pull", live=live) as pull:
             # nnlint: disable=NNL101 — one (slots,) pull per decode step:
             # the scheduler needs host ints to append/retire (documented
-            # contract), matching the dense engine's ledger entry
+            # contract); explicit device_get, so it stays legal under the
+            # NNS_XFERCHECK disallow scopes and lands in the byte ledger
             tok = self._jax.device_get(tok_dev)
             if self.family.counters:
                 # an expert family's counts came home behind the tokens
@@ -766,32 +599,15 @@ class PagedLMEngine:
         self._tok[self._mask, 0] = tok[self._mask]
         return tok
 
-    def verify(self, draft: np.ndarray) -> np.ndarray:
-        """Score ``draft`` (slots, K) token blocks in one call → logits
-        (slots, K, vocab). Column 0 must be each slot's carry token;
-        columns 1.. are proposals. Used by SpeculativeLMEngine."""
-        K = draft.shape[1]
-        for s in np.flatnonzero(self._mask):
-            lo = int(self._pos[s])
-            self._ensure_writable(int(s), lo,
-                                  min(lo + K, self.max_seq))
-        logits, *pools = self._verify(
-            np.ascontiguousarray(draft, np.int32), self._pos_dev,
-            self._mask_dev, self._bt, *self._pools)
-        self._pools = tuple(pools)
-        # nnlint: disable=NNL101 — one (slots, K, V) pull per speculative
-        # round (K tokens' worth), replacing K per-token pulls
-        return self._jax.device_get(logits)
-
     def verify_commit(self, draft: np.ndarray):
         """Fused speculative round: verify ``draft`` (slots, K) AND
         resolve greedy acceptance + carry advance on device in ONE call.
         Returns ``(pred, n_emit)`` — slot ``s`` emitted
         ``pred[s, :n_emit[s]]`` (accepted drafts equal the target argmax
-        by definition; the last entry is the correction). The carry
-        stays device-resident: no logits download, no ``commit`` /
-        ``sync_carry`` re-upload — the per-round host traffic that
-        dominated the unfused path."""
+        by definition; the last entry is the correction). Column 0 of
+        ``draft`` must be each slot's carry token, columns 1.. the
+        proposals. The carry stays device-resident: no logits come home
+        and nothing is uploaded but ``draft``."""
         K = draft.shape[1]
         for s in np.flatnonzero(self._mask):
             lo = int(self._pos[s])
@@ -804,8 +620,7 @@ class PagedLMEngine:
             self._tok_dev, self._mask_dev, self._bt, *self._pools)
         self._pools = tuple(pools)
         # nnlint: disable=NNL101 — ONE (slots, K+1) int pull per
-        # speculative round (the emitted burst), replacing the (slots,
-        # K, V) logits pull of the unfused path
+        # speculative round (the emitted burst)
         packed = self._jax.device_get(packed)
         n_emit, pred = packed[:, 0], packed[:, 1:]
         for s in np.flatnonzero(n_emit):
@@ -813,30 +628,6 @@ class PagedLMEngine:
             self._pos[s] += n
             self._tok[s, 0] = int(pred[s, n - 1])
         return pred, n_emit
-
-    def commit(self, slot: int, tokens: "list[int]",
-               sync: bool = True) -> None:
-        """Advance a slot past ``tokens`` accepted by speculative
-        verification: K/V for them is already in the pool (written by
-        ``verify``); only the host carry moves. The LAST entry is the
-        new carry token (its K/V is NOT yet written). ``sync=False``
-        defers the device upload — the caller batches many slots'
-        commits into ONE :meth:`sync_carry` per round (per-slot uploads
-        would cost more than the verify call they follow)."""
-        if not tokens:
-            return
-        # verify wrote K/V for [carry, accepted...]: len(tokens)
-        # positions are now cache-valid, the new carry's K/V is not
-        self._pos[slot] = int(self._pos[slot]) + len(tokens)
-        self._tok[slot, 0] = int(tokens[-1])
-        if sync:
-            self.sync_carry()
-
-    def sync_carry(self) -> None:
-        """Upload the host carry mirrors (token + position) in one
-        round-trip; pairs with ``commit(..., sync=False)`` batches."""
-        self._tok_dev = self._jnp.asarray(self._tok)
-        self._pos_dev = self._jnp.asarray(self._pos)
 
     def release(self, slot: int) -> None:
         with obs_context.span("engine.release", slot=slot):
@@ -920,24 +711,3 @@ class PagedLMEngine:
             if self._mask[slot] or self._bt[slot].any():
                 self.release(slot)
         self.pool.close()
-
-
-def from_entry(entry, slots: int = 4, mesh=None, paged: bool = False,
-               **paged_kw):
-    """Build an engine from an ``lm_serving`` entry (params initialized /
-    dtype-cast per the entry's serve knobs; ``mesh`` reserved for
-    sharded slot state — single-device only today). ``paged=True``
-    builds the block-table :class:`PagedLMEngine` (``paged_kw``:
-    page_size/pages/chunk/share_prefixes/max_positions), which takes the
-    model family from the type of the entry's configuration
-    (models/families.py); the dense slot engine serves the gpt family."""
-    if mesh is not None:
-        raise NotImplementedError(
-            "continuous decode is single-device today; shard the batch "
-            "with the whole-sequence lm_serving paths instead")
-    cfg = entry._cfg_serve
-    params, _ = entry._shard_params(None)
-    if paged:
-        return PagedLMEngine(cfg, params, slots=slots, **paged_kw)
-    entry._gpt_only("the dense slot engine (paged=False)")
-    return ContinuousLMEngine(cfg, params, slots=slots)

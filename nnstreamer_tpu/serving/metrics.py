@@ -190,9 +190,9 @@ class ServingMetrics:
             self.pull_wait_s += pull_wait_s
 
     def record_layer_counts(self, counts: dict) -> None:
-        """What the engine counted since the last pass: its expert layers
-        (the ``moe_*`` keys of ``PagedLMEngine.layer_counts``, both
-        programs added up) and its steps' attention (``attn_pages``)."""
+        """What the engine counted since the last pass (the growth of
+        ``DecodeEngine.counters()``): its expert layers (``moe_*``, both
+        programs added up) and its steps' attention (``attn_pages_*``)."""
         with self._lock:
             self.attn_pages_read += counts.get("attn_pages_read", 0)
             self.attn_pages_padded += counts.get("attn_pages_padded", 0)

@@ -6,8 +6,8 @@ Two loops over the same admission/queue/bucketing machinery:
   ``tensor_filter``-style callable): requests coalesce into shape-bucketed
   padded batches (``batcher.py``), one jitted call serves many clients.
 * :class:`DecodeScheduler` — iterative LM decode against a slot-based
-  engine (``lm_engine.py``): new requests JOIN the running batch between
-  decode steps (prefill into a free slot), finished sequences RETIRE
+  engine (``engine.DecodeEngine``): new requests JOIN the running batch
+  between decode steps (prefill into a free slot), finished sequences RETIRE
   early and free their slot — the Hermes/Orca-style continuous batching
   loop (arxiv 2409.04249).
 
@@ -50,19 +50,6 @@ _REQUEST_PHASES = ("request.queue", "request.lane", "request.prefill",
 
 def _tensors_nbytes(tensors) -> int:
     return sum(int(getattr(t, "nbytes", 0) or 0) for t in tensors)
-
-
-def _layer_counts(engine) -> dict:
-    """The running sums a paged engine keeps: what an expert family's
-    layers counted (``PagedLMEngine.layer_counts``), its two programs added
-    up, and the pages its steps' attention read (``attn_pages``); empty for
-    an engine that counts nothing."""
-    by_call = getattr(engine, "layer_counts", None) or {}
-    total = dict(getattr(engine, "attn_pages", None) or {})
-    for counts in by_call.values():
-        for k, v in counts.items():
-            total[k] = total.get(k, 0) + v
-    return total
 
 
 def _block_ready(outputs) -> None:
@@ -398,38 +385,15 @@ class DecodeScheduler:
     finish (max steps or ``eos_id``), freeing the slot for the next
     queued request — no drain barrier between batches.
 
-    The engine contract (``lm_engine.ContinuousLMEngine`` implements it):
-
-    * ``slots`` — fixed batch capacity;
-    * ``admit(slot, tokens, steps) -> int`` — prefill; returns the first
-      generated token;
-    * ``step() -> np.ndarray (slots,)`` — one decode step over every
-      slot (inactive slots compute garbage; the loop ignores them);
-    * ``release(slot)`` — slot freed (optional);
-    * ``compile_count`` — optional compile hook.
-
-    Optional extensions the paged/speculative engines provide
-    (``lm_engine.PagedLMEngine`` / ``speculative.SpeculativeLMEngine``):
-
-    * ``admit_start``/``prefill_tick`` — chunked prefill: admit queues
-      the prompt, the loop ingests ONE bounded chunk per pass, so a
-      long prompt interleaves with running decode instead of stalling
-      the batch;
-    * ``step_tokens() -> list[list[int]]`` — burst decode (speculative
-      rounds emit 1..K tokens per slot per pass);
-    * ``preempt(slot) -> blob``/``restore(slot, blob)`` — deadline-aware
-      memory pressure: on ``PagePoolExhausted`` the loop evicts the
-      victim with the MOST deadline slack to host and requeues it;
-      readmission restores byte-exact — the request is never dropped;
-    * ``projected_page_bytes(tokens, steps)`` — the AdmissionGuard
-      reserves page-pool bytes instead of dense tensor bytes;
-    * ``prefill_stamp(slot) -> (first_chunk_t, chunks)`` — when the
-      slot's first prompt chunk was dispatched and how many it took
-      (``Request.metrics``, the ``request.lane`` span);
-    * ``host_s``/``pull_s`` — running sums of the time under the
-      engine's prepare and dispatch spans, and under its pull spans
-      (``ServingMetrics.host_engine_s``/``pull_wait_s``; an engine
-      without them counts as scheduler time).
+    What the engine owes this loop is written once, as a class:
+    :class:`~.engine.DecodeEngine` (``lm_engine.PagedLMEngine`` and
+    ``speculative.SpeculativeLMEngine`` derive from it; a test's fake and
+    a measuring proxy are substituted there). A prompt is admitted with
+    ``admit_start`` and ingested one bounded ``prefill_tick`` a pass, so a
+    long prompt interleaves with running decode instead of stalling the
+    batch; on ``PagePoolExhausted`` the loop evicts the victim with the
+    MOST deadline slack to host (``preempt``) and requeues it, and
+    readmission restores byte-exact: the request is never dropped.
 
     Page-release invariant: EVERY request exit path — normal retire,
     deadline shed (queued or mid-decode), batch failure, close — goes
@@ -454,8 +418,7 @@ class DecodeScheduler:
         self._active: Dict[int, Request] = {}
         self._prefilling: Dict[int, Request] = {}  # chunked-prefill slots
         self._free: List[int] = list(range(engine.slots))[::-1]
-        self._has_chunked = getattr(engine, "prefill_tick", None) is not None
-        self._step_tokens = getattr(engine, "step_tokens", None)
+        self._step_tokens = engine.step_tokens  # None: one token a slot
         self._pass_tokens = 0  # tokens emitted in the pass that is running
         self._running = threading.Event()
         self._closed = False
@@ -494,9 +457,7 @@ class DecodeScheduler:
         for req in self.queue.drain():
             req.fail(err)
             self._record_done(req, failed=True)
-        close = getattr(self.engine, "close", None)
-        if close is not None:
-            close()  # paged engine: drop the prefix registry's page refs
+        self.engine.close()  # paged engine: drop the prefix registry's refs
 
     # -- submission ---------------------------------------------------------
     def submit(self, tokens, steps: int, priority: int = 0,
@@ -515,9 +476,8 @@ class DecodeScheduler:
         if tokens.ndim != 1:
             raise ValueError(
                 f"decode prompt must be 1-D tokens, got shape {tokens.shape}")
-        validate = getattr(self.engine, "validate", None)
-        if validate is not None:
-            validate(tokens, steps)  # fail fast (e.g. prompt+steps > max_seq)
+        # fail fast (e.g. prompt+steps > max_seq)
+        self.engine.validate(tokens, steps)
         deadline = (time.monotonic() + deadline_s
                     if deadline_s is not None else None)
         req = Request((tokens,), priority=priority, deadline=deadline,
@@ -582,14 +542,12 @@ class DecodeScheduler:
         """Paged engines reserve PAGES (what the request will actually
         pin in the pool), not dense tensor bytes — the AdmissionGuard
         gate matches the resource that can actually run out."""
-        projected = getattr(self.engine, "projected_page_bytes", None)
-        if projected is not None and req.steps:
-            return projected(int(req.tensors[0].size), int(req.steps))
-        return _tensors_nbytes(req.tensors)
+        return self.engine.projected_page_bytes(int(req.tensors[0].size),
+                                                int(req.steps))
 
     @property
     def compile_count(self) -> int:
-        return getattr(self.engine, "compile_count", 0)
+        return self.engine.compile_count
 
     def metrics_snapshot(self) -> dict:
         snap = self.metrics.snapshot()
@@ -598,12 +556,11 @@ class DecodeScheduler:
         snap["active_slots"] = len(self._active)
         snap["slots"] = self.engine.slots
         snap["compile_count"] = self.compile_count
-        pool = getattr(self.engine, "pool", None)
+        pool = self.engine.pool
         if pool is not None:
             snap["kv_pool"] = pool.stats()
-        rate = getattr(self.engine, "acceptance_rate", None)
-        if rate is not None:
-            snap["spec_acceptance_rate"] = rate()
+        if self._step_tokens is not None:  # a burst engine counts its rounds
+            snap["spec_acceptance_rate"] = self.engine.acceptance_rate()
             snap["spec_rounds"] = self.engine.spec_rounds
             snap["spec_proposed"] = self.engine.spec_proposed
             snap["spec_accepted"] = self.engine.spec_accepted
@@ -611,10 +568,9 @@ class DecodeScheduler:
 
     # -- loop ---------------------------------------------------------------
     def _admit_one(self, req: Request) -> bool:
-        """Place a request into a free slot: restore a preempted one,
-        queue a chunked prefill, or run the blocking admit. Returns
-        False when the pool cannot take it YET (request requeued; stop
-        admitting this pass)."""
+        """Place a request into a free slot: restore a preempted one, or
+        queue its prompt for the prefill lane. Returns False when the pool
+        cannot take it YET (request requeued; stop admitting this pass)."""
         from .kv_pool import PagePoolExhausted
 
         slot = self._free.pop()
@@ -645,45 +601,24 @@ class DecodeScheduler:
                               {"scheduler": self.name, "request": req.id,
                                "slot": slot})
             return True
-        if getattr(self.engine, "admit_start", None) is not None:
-            try:
-                self.engine.admit_start(slot, req.tensors[0], req.steps)
-            except PagePoolExhausted:
-                self._free.append(slot)
-                if not self._preempt_victim():
-                    self._fail_mem(req)
-                else:
-                    self._requeue(req)
-                return False
-            except Exception as e:  # noqa: BLE001 - engine rejected prompt
-                self._free.append(slot)
-                req.fail(e if isinstance(e, ServingError)
-                         else ServingError(f"decode admit failed: {e}"))
-                self._record_done(req, failed=True)
-                return True
-            req.metrics["slot"] = slot
-            req.metrics["_prefill_t0"] = t0
-            self._prefilling[slot] = req
-            return True
         try:
-            first = int(self.engine.admit(slot, req.tensors[0], req.steps))
-        except Exception as e:  # noqa: BLE001 - engine rejected this prompt
+            self.engine.admit_start(slot, req.tensors[0], req.steps)
+        except PagePoolExhausted:
+            self._free.append(slot)
+            if not self._preempt_victim():
+                self._fail_mem(req)
+            else:
+                self._requeue(req)
+            return False
+        except Exception as e:  # noqa: BLE001 - engine rejected prompt
             self._free.append(slot)
             req.fail(e if isinstance(e, ServingError)
                      else ServingError(f"decode admit failed: {e}"))
             self._record_done(req, failed=True)
             return True
-        now = time.monotonic()
         req.metrics["slot"] = slot
-        req.metrics["ttft_s"] = now - req.metrics["enqueue_time"]
-        req.metrics["prefill_s"] = now - t0
-        # a blocking admit has no prefill lane to wait in
-        req.metrics["first_chunk_t"] = t0
-        self._emit(req, first, now)
-        if self._finished(req, first):
-            self._retire(slot, req, early=False)
-        else:
-            self._active[slot] = req
+        req.metrics["_prefill_t0"] = t0
+        self._prefilling[slot] = req
         return True
 
     def _requeue(self, req: Request) -> None:
@@ -716,18 +651,19 @@ class DecodeScheduler:
         cannot preempt or fewer than ``min_active`` streams are running
         (evicting the only runner to feed itself is a livelock, not
         progress — the caller sheds typed instead)."""
-        preempt = getattr(self.engine, "preempt", None)
-        if preempt is None or len(self._active) < min_active:
+        if len(self._active) < min_active:
             return False
         slot = max(self._active,
                    key=lambda s: (self._active[s].deadline is None,
                                   self._active[s].deadline or 0.0))
         req = self._active.pop(slot)
         try:
-            blob = preempt(slot)
+            blob = self.engine.preempt(slot)
         except Exception:  # noqa: BLE001 - engine state is authoritative
             logger.exception("serving %s: preempt of slot %d failed",
                              self.name, slot)
+            blob = None
+        if blob is None:  # failed, or an engine that cannot preempt
             self._active[slot] = req
             return False
         self._free.append(slot)
@@ -747,9 +683,7 @@ class DecodeScheduler:
 
     def _retire(self, slot: int, req: Request, early: bool) -> None:
         self._active.pop(slot, None)
-        release = getattr(self.engine, "release", None)
-        if release is not None:
-            release(slot)
+        self.engine.release(slot)
         self._free.append(slot)
         if early:
             self.metrics.record_early_retire()
@@ -760,10 +694,9 @@ class DecodeScheduler:
         self._record_done(req)
 
     def _prefill_tick(self) -> bool:
-        """Ingest ONE prompt chunk (chunked-prefill engines): long
-        prompts advance one bounded chunk per loop pass, interleaved
-        with decode steps, instead of stalling the whole batch. True
-        when a chunk ran."""
+        """Ingest ONE prompt chunk: long prompts advance one bounded
+        chunk per loop pass, interleaved with decode steps, instead of
+        stalling the whole batch. True when a chunk ran."""
         from .kv_pool import PagePoolExhausted
 
         # bounded retry IN THIS PASS: preempting a victim only helps if
@@ -799,7 +732,6 @@ class DecodeScheduler:
         else:
             return False  # every retry preempted a victim; none ran
         now = time.monotonic()
-        stamp = getattr(self.engine, "prefill_stamp", None)
         for slot, first in done:
             req = self._prefilling.pop(slot, None)
             if req is None:
@@ -807,11 +739,11 @@ class DecodeScheduler:
             req.metrics["ttft_s"] = now - req.metrics["enqueue_time"]
             req.metrics["prefill_s"] = now - req.metrics.pop(
                 "_prefill_t0", now)
+            stamp = self.engine.prefill_stamp(slot)
             if stamp is not None:
                 # the engine owns the lane: when this prompt's first chunk
                 # was dispatched, and how many it took
-                (req.metrics["first_chunk_t"],
-                 req.metrics["chunks"]) = stamp(slot)
+                req.metrics["first_chunk_t"], req.metrics["chunks"] = stamp
             self._emit(req, int(first), now)
             if self._finished(req, int(first)):
                 self._retire(slot, req, early=False)
@@ -860,9 +792,8 @@ class DecodeScheduler:
                 if first is None:
                     continue
             # what the engine spent under its own spans, before and after
-            host0 = getattr(engine, "host_s", 0.0)
-            pull0 = getattr(engine, "pull_s", 0.0)
-            counts0 = _layer_counts(engine)
+            host0, pull0 = engine.host_s, engine.pull_s
+            counts0 = engine.counters()
             self._pass_tokens = 0
             with obs_context.span("serving.pass", live=len(self._active),
                                   prefilling=len(self._prefilling),
@@ -870,14 +801,14 @@ class DecodeScheduler:
                 chunks, step = self._pass(first)
                 sp.attrs.update(chunks=chunks, steps=int(step),
                                 tokens=self._pass_tokens)
-            host_s = getattr(engine, "host_s", 0.0) - host0
-            pull_s = getattr(engine, "pull_s", 0.0) - pull0
+            host_s = engine.host_s - host0
+            pull_s = engine.pull_s - pull0
             metrics.record_pass(step, chunks, sp.dur_s - host_s - pull_s,
                                 host_s, pull_s)
             if counts0:
                 metrics.record_layer_counts(
                     {k: v - counts0[k]
-                     for k, v in _layer_counts(engine).items()})
+                     for k, v in engine.counters().items()})
 
     def _pass(self, first: Optional[Request]) -> Tuple[int, bool]:
         """The work of one pass; ``first`` is the request an idle wait
@@ -895,7 +826,7 @@ class DecodeScheduler:
                 admitted, req = admitted + 1, None
             admit.attrs["admitted"] = admitted
         chunks = 0
-        if self._has_chunked and self._prefilling:
+        if self._prefilling:
             chunks = int(self._prefill_tick())
         if self._active:
             self._shed_expired_active()
@@ -979,7 +910,5 @@ class DecodeScheduler:
 
     def _retire_slot_only(self, slot: int) -> None:
         self._active.pop(slot, None)
-        release = getattr(self.engine, "release", None)
-        if release is not None:
-            release(slot)
+        self.engine.release(slot)
         self._free.append(slot)

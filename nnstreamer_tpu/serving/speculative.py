@@ -3,7 +3,7 @@
 A decode step is dispatch-bound: one device call yields ONE token per
 slot however small the model. Speculative decoding buys back the
 dispatch by letting a cheap **draft** propose K-1 tokens and the
-**target** score all K positions in ONE ``verify`` call; greedy
+**target** score all K positions in ONE ``verify_commit`` call; greedy
 acceptance keeps the longest prefix of proposals the target agrees
 with, plus the target's own correction token. Because acceptance is
 exact-match against the target's argmax, the emitted stream is
@@ -15,13 +15,13 @@ Round protocol (carry state: ``tok`` = last emitted token, K/V for it
 not yet written; cache valid for positions < ``pos``):
 
 1. draft proposes ``d1..d_{K-1}`` continuing the slot's history;
-2. target ``verify`` scores ``[tok, d1..d_{K-1}]`` at positions
+2. target ``verify_commit`` scores ``[tok, d1..d_{K-1}]`` at positions
    ``pos..pos+K-1`` in one call (writing their K/V);
 3. ``j`` = longest prefix with ``argmax(L_{i-1}) == d_i``; emit
    ``d1..dj`` + the correction ``argmax(L_j)`` — 1..K tokens;
-4. ``commit`` advances ``pos`` by ``j+1``; rejected positions hold
-   garbage K/V that the ``<= pos`` visibility mask hides until decode
-   overwrites them.
+4. the same call advances ``pos`` by ``j+1`` on the device; rejected
+   positions hold garbage K/V that the ``<= pos`` visibility mask hides
+   until decode overwrites them.
 
 Drafts: :class:`NgramDraft` (prompt-lookup self-speculation — zero
 device cost, the honest CPU-bench winner since CPU decode is
@@ -40,6 +40,7 @@ from typing import List
 import numpy as np
 
 from ..obs import metrics as obs_metrics
+from .engine import DecodeEngine
 from .lm_engine import PagedLMEngine
 
 _engines: "weakref.WeakSet" = weakref.WeakSet()
@@ -179,13 +180,11 @@ class ModelDraft:
         self.admit(slot, hist[:-1], int(hist[-1]))
 
 
-class SpeculativeLMEngine:
+class SpeculativeLMEngine(DecodeEngine):
     """Scheduler-facing wrapper pairing a :class:`PagedLMEngine` target
-    with a draft. Implements the engine contract plus ``step_tokens()``
-    — the multi-token-per-pass path ``DecodeScheduler`` prefers when
-    present. ``step()`` stays available and speculative, returning only
-    each slot's first emitted token (contract shim for callers that
-    cannot consume bursts)."""
+    with a draft: a burst engine (``DecodeEngine.step_tokens``), so the
+    scheduler routes 1..k tokens a slot a pass and never calls ``step``.
+    The prefill lane, the pool and the spans' sums are the target's."""
 
     def __init__(self, target: PagedLMEngine, draft, k: int = 4):
         if k < 2:
@@ -222,6 +221,17 @@ class SpeculativeLMEngine:
     def pool(self):
         return self.target.pool
 
+    @property
+    def host_s(self) -> float:
+        return self.target.host_s
+
+    @property
+    def pull_s(self) -> float:
+        return self.target.pull_s
+
+    def prefill_stamp(self, slot: int):
+        return self.target.prefill_stamp(slot)
+
     def validate(self, tokens, steps) -> None:
         self.target.validate(tokens, steps)
 
@@ -246,13 +256,6 @@ class SpeculativeLMEngine:
             self.draft.admit(slot, self._hist[slot][:-1], int(first))
         return done
 
-    def admit(self, slot: int, tokens, steps: int) -> int:
-        self.admit_start(slot, tokens, steps)
-        while True:
-            for s, first in self.prefill_tick():
-                if s == slot:
-                    return first
-
     def release(self, slot: int) -> None:
         self.target.release(slot)
         self.draft.release(slot)
@@ -274,7 +277,7 @@ class SpeculativeLMEngine:
     def step_tokens(self) -> List[List[int]]:
         """One draft-verify round over every slot → per-slot emitted
         token bursts (1..k tokens active, [] inactive). May raise
-        PagePoolExhausted exactly like ``step()``."""
+        PagePoolExhausted exactly like a target's ``step()``."""
         t = self.target
         active = np.flatnonzero(t._mask)
         out: List[List[int]] = [[] for _ in range(t.slots)]
@@ -305,18 +308,6 @@ class SpeculativeLMEngine:
             self._hist[s].extend(emitted)
             out[s] = emitted
         return out
-
-    def step(self) -> np.ndarray:
-        """Single-token contract shim: run a speculative round but emit
-        only the first token per slot (the rest of the burst is
-        discarded host-side — the cache stays consistent because commit
-        already advanced past the full acceptance)."""
-        burst = self.step_tokens()
-        tok = np.zeros((self.slots,), np.int32)
-        for s, toks in enumerate(burst):
-            if toks:
-                tok[s] = toks[0]
-        return tok
 
     def acceptance_rate(self) -> float:
         if not self.spec_proposed:

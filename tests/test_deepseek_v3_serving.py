@@ -80,7 +80,7 @@ def _reference_logits(key, sz, prompt, served, width=48):
 def test_chunked_prefill_then_decode_matches_the_reference_forward():
     cfg, sz, key, params = _model()
     eng = _entry(cfg, params).make_continuous(
-        paged=True, slots=3, page_size=4, chunk=8, pages=48)
+        slots=3, page_size=4, chunk=8, pages=48)
     assert isinstance(eng, PagedLMEngine) and eng.family.name == "deepseek_v3"
     # keep every chunk's logits as the program returned them
     chunk_logits, real = [], eng._prefill_chunk
@@ -394,7 +394,7 @@ def test_the_serving_limit_is_the_engines_not_a_tables():
 def test_speculative_verify_refuses_the_family_by_name():
     cfg, sz, key, params = _model()
     with pytest.raises(NotImplementedError, match="deepseek_v3"):
-        _entry(cfg, params).make_continuous(paged=True, draft="ngram")
+        _entry(cfg, params).make_continuous(draft="ngram")
     with pytest.raises(NotImplementedError, match="deepseek_v3"):
         _entry(cfg, params).make()
     with pytest.raises(NotImplementedError):
@@ -407,7 +407,7 @@ def test_the_schedulers_metrics_sum_what_the_expert_layers_counted():
 
     cfg, sz, key, params = _model()
     eng = _entry(cfg, params).make_continuous(
-        paged=True, slots=2, page_size=4, chunk=8, pages=32)
+        slots=2, page_size=4, chunk=8, pages=32)
     sched = DecodeScheduler(eng, name="dsv3-metrics")
     try:
         prompt = np.arange(1, 12, dtype=np.int32)

@@ -3,8 +3,8 @@
 The properties the paged data plane exists for, each asserted directly:
 
 * parity — the block-table gather/scatter programs are token-exact
-  against the dense engine AND against batch-1 unbatched decode, so
-  paging is purely a memory-layout change;
+  against batch-1 unbatched decode (``models.decoding.make_generate``),
+  so paging is purely a memory-layout change;
 * copy-on-write prefix sharing — a registered prefix is mapped, not
   recomputed, and a sharer's writes never corrupt the other stream;
 * preemption — evict-to-host then restore is byte-exact (the request
@@ -122,8 +122,8 @@ class TestPagedParity:
 
     def test_compile_count_flat_across_prompt_lengths(self):
         # the chunk size is the ONLY compiled prefill shape: arbitrary
-        # prompt lengths reuse the same executables (the dense engine
-        # compiles once per distinct length — the NNL008 churn)
+        # prompt lengths reuse the same executables (a prefill over the
+        # whole prompt compiles once per distinct length: the NNL008 churn)
         cfg, params = _tiny()
         rng = np.random.default_rng(13)
         eng = PagedLMEngine(cfg, params, slots=1, page_size=8, pages=8,
@@ -357,7 +357,7 @@ class TestSpeculativeParity:
         from nnstreamer_tpu.models.lm_serving import tiny, tiny_draft
 
         eng = tiny.make_continuous(
-            slots=2, paged=True, draft=tiny_draft, spec_k=4,
+            slots=2, draft=tiny_draft, spec_k=4,
             page_size=8, pages=16, chunk=16, share_prefixes=False)
         cfg, params = eng.cfg, eng.target.params
         rng = np.random.default_rng(37)

@@ -24,6 +24,7 @@ from nnstreamer_tpu.serving import (
     AdmissionError,
     BatchFormer,
     DeadlineExceededError,
+    DecodeEngine,
     DecodeScheduler,
     QueueFullError,
     Request,
@@ -322,21 +323,23 @@ class TestScheduler:
 # ---------------------------------------------------------------------------
 # DecodeScheduler (continuous LM decode) — toy engine for policy
 # ---------------------------------------------------------------------------
-class ToyEngine:
+class ToyEngine(DecodeEngine):
     """Deterministic counter engine: next token = last + 1 (mod 97).
     Slot-independent by construction, so scheduler-policy failures
     (corrupted joins, leaked slots) show up as wrong token streams."""
 
     def __init__(self, slots=2):
         self.slots = slots
-        self.compile_count = 0
         self._tok = np.zeros(slots, np.int32)
-        self.admits = []
+        self._pending = []
 
-    def admit(self, slot, tokens, steps):
-        self.admits.append(slot)
-        self._tok[slot] = (int(tokens[-1]) + 1) % 97
-        return int(self._tok[slot])
+    def admit_start(self, slot, tokens, steps):
+        self._pending.append((slot, (int(tokens[-1]) + 1) % 97))
+
+    def prefill_tick(self):
+        slot, first = self._pending.pop(0)
+        self._tok[slot] = first
+        return [(slot, first)]
 
     def step(self):
         self._tok = (self._tok + 1) % 97
@@ -400,66 +403,6 @@ class TestDecodeScheduler:
                 sched.submit(np.array([1], np.int32), steps=0)
         finally:
             sched.close()
-
-
-# ---------------------------------------------------------------------------
-# ContinuousLMEngine — real transformer parity vs unbatched decode
-# ---------------------------------------------------------------------------
-class TestContinuousLMEngine:
-    def _reference(self, engine, prompt, steps):
-        """Batch-1 greedy decode straight through models/decoding.py —
-        what each slot of the vmapped engine must reproduce exactly."""
-        import jax.numpy as jnp
-
-        from nnstreamer_tpu.models.decoding import (
-            decode_step,
-            init_cache,
-            prefill,
-        )
-
-        cfg, params = engine.cfg, engine.params
-        cache = init_cache(cfg, 1, dtype=params["embed"].dtype)
-        logits, cache, pos = prefill(cfg, params, prompt[None], cache)
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        out = [int(tok[0])]
-        pos = jnp.asarray(pos, jnp.int32)
-        for _ in range(steps - 1):
-            logits, cache = decode_step(cfg, params, tok[:, None][:, :, 0]
-                                        if tok.ndim > 1 else tok[:, None],
-                                        pos, cache)
-            tok = jnp.argmax(logits, -1).astype(jnp.int32)
-            if tok.ndim > 1:
-                tok = tok[:, 0]
-            out.append(int(tok[0]))
-            pos = pos + 1
-        return out
-
-    def test_vmapped_slots_match_unbatched_decode(self):
-        from nnstreamer_tpu.models.lm_serving import tiny
-
-        engine = tiny.make_continuous(slots=2)
-        sched = DecodeScheduler(engine, name="t-lm")
-        try:
-            rng = np.random.default_rng(3)
-            p1 = rng.integers(0, 64, 5).astype(np.int32)
-            p2 = rng.integers(0, 64, 3).astype(np.int32)
-            # p2 joins while p1 decodes; p2 retires first — slot traffic
-            # must not perturb either stream
-            r1 = sched.submit(p1, steps=6)
-            r2 = sched.submit(p2, steps=3)
-            got1 = r1.result(120)[0].tolist()
-            got2 = r2.result(120)[0].tolist()
-            assert got1 == self._reference(engine, p1, 6)
-            assert got2 == self._reference(engine, p2, 3)
-        finally:
-            sched.close()
-
-    def test_validate_rejects_overlong(self):
-        from nnstreamer_tpu.models.lm_serving import tiny
-
-        engine = tiny.make_continuous(slots=1)
-        with pytest.raises(ValueError):
-            engine.validate(np.zeros(60, np.int32), steps=10)  # > max_seq 64
 
 
 # ---------------------------------------------------------------------------

@@ -16,7 +16,6 @@ import pytest
 from nnstreamer_tpu.obs import context as obs_ctx
 from nnstreamer_tpu.obs import flight as obs_flight
 from nnstreamer_tpu.serving import DecodeScheduler, PagedLMEngine
-from nnstreamer_tpu.serving.lm_engine import ContinuousLMEngine
 
 PASS_PHASES = {"sched.admit", "engine.chunk.prepare", "engine.chunk.dispatch",
                "engine.chunk.pull", "engine.step.prepare",
@@ -30,13 +29,11 @@ SPAN_BUDGET_S = 5e-6   # ISSUE 24: one span() with no profiler session
 SPANS_PER_PASS = 12
 
 
-def _tiny_engine(paged=True, **kw):
+def _tiny_engine(**kw):
     from nnstreamer_tpu.models.lm_serving import tiny
     from nnstreamer_tpu.models.transformer import init_params
 
     params = init_params(tiny.cfg, seed=0)
-    if not paged:
-        return ContinuousLMEngine(tiny.cfg, params, slots=4)
     return PagedLMEngine(tiny.cfg, params, slots=4, page_size=8, chunk=8, **kw)
 
 
@@ -330,20 +327,7 @@ def test_a_failed_request_gets_the_phases_it_reached():
     assert tree[0].status == "error" and tree[0].attrs["tokens"] == 0
 
 
-# -- the dense engine, and a proxy between the two ---------------------------------------
-
-def test_the_dense_engine_gets_the_three_step_spans_and_no_lane():
-    reqs, snap, spans = _serve(_tiny_engine(paged=False), "dense")
-    names = [s.name for s in spans if s.name.startswith("engine.")]
-    assert set(names) == {"engine.step.prepare", "engine.step.dispatch",
-                          "engine.step.pull"}
-    assert names.count("engine.step.dispatch") == snap["decode_steps"]
-    assert snap["passes_with_chunk"] == snap["prefill_chunks"] == 0
-    assert snap["host_engine_s"] > 0 and snap["pull_wait_s"] > 0
-    lanes = [s for s in spans if s.name == "request.lane"]
-    assert len(lanes) == len(reqs) and all(s.dur_s == 0.0 for s in lanes)
-    assert all(len(r.metrics["token_t"]) == len(r.tokens) for r in reqs)
-
+# -- a proxy between the scheduler and the engine -----------------------------------------
 
 class _Proxy:
     """The benchmark's ``EngineProxy`` in outline: the scheduler's calls by
