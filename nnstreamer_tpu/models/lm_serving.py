@@ -311,6 +311,10 @@ class _LMServingEntry:
         join/retire independently between decode steps
         (``serving.DecodeScheduler`` drives it; ``engine_kw``: page_size /
         pages / chunk / share_prefixes / max_positions, docs/serving.md).
+        ``chunk`` is the least a prefill launch ingests: the engine widens
+        it to the chip's ridge where it knows the chip (256 tokens on a
+        v5e in bfloat16, ``serving.lm_engine.prefill_width``) and takes it
+        as given on the CPU; ``engine.chunk`` is the width in use.
         Params honor the entry's serve knobs (serve_dtype, cache_len); the
         model family comes from the type of the entry's configuration
         (models/families.py).

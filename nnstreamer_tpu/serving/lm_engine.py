@@ -13,7 +13,8 @@ Join protocol (``engine.DecodeEngine``, driven by ``DecodeScheduler``):
 * ``admit_start(slot, prompt, steps)`` queues the prompt and
   ``prefill_tick()`` ingests one fixed-size chunk of one pending prompt a
   call: the chunk is the ONLY compiled prefill shape, so ``compile_count``
-  stays flat across prompt lengths;
+  stays flat across prompt lengths. Its width is the engine's to choose
+  (``prefill_width``): as wide as the chip's ridge, where the chip is known;
 * ``step()``: one decode step over ALL slots. Inactive slots write to a
   null page and read nothing (static shapes are the point); the scheduler
   ignores their outputs;
@@ -34,16 +35,38 @@ sums of the first two and of the third.
 from __future__ import annotations
 
 import itertools
+import math
 from typing import Optional
 
 import numpy as np
 
 from ..obs import context as obs_context
 from ..obs import memory as obs_memory
+from ..utils import flops
 from .engine import DecodeEngine
 from .request import ServingError
 
 _engine_ids = itertools.count()
+
+
+def prefill_width(chunk: int, max_seq: int, page_size: int,
+                  weight_bytes: int, device=None) -> int:
+    """The one compiled width of a prefill launch, in tokens.
+
+    A launch reads every weight once whatever its width, so under the
+    chip's ridge its time is the reading and more rows are free: ``rows =
+    peak FLOP/s / HBM bytes/s x weight_bytes / 2`` is where a dense product
+    (2 FLOPs a weight a row) stops being bound by it. The width is ``rows``
+    rounded up to a power of two, then to whole pages, never under ``chunk``
+    and at most ``max_seq`` (v5e, bfloat16: 240.5 -> 256). Where the chip
+    is unknown (``utils.flops.ridge_flops_per_byte`` is None: the CPU)
+    ``chunk`` stands as given; a TPU missing from the tables raises."""
+    ridge = flops.ridge_flops_per_byte(device)
+    width = chunk
+    if ridge is not None:
+        rows = 1 << (math.ceil(ridge * weight_bytes / 2) - 1).bit_length()
+        width = max(chunk, -(-rows // page_size) * page_size)
+    return min(width, max_seq)
 
 
 class PagedLMEngine(DecodeEngine):
@@ -84,7 +107,11 @@ class PagedLMEngine(DecodeEngine):
       prompt interleaves with running decode instead of stalling the
       batch, and the chunk size is the only compiled prefill shape
       (``compile_count`` is flat across prompt lengths — the NNL008
-      churn fix).
+      churn fix). ``chunk=`` means "ingest at least this many tokens a
+      launch": the width in use, ``engine.chunk``, is ``prefill_width``'s,
+      as wide as the chip's ridge where the chip is known (a narrower
+      launch reads every weight for fewer tokens) and ``chunk`` itself on
+      the CPU.
     * **COW prefix sharing** — identical prompt prefixes resolve to the
       same pages via the pool's registry; ``_ensure_writable`` copies a
       shared page before any write lands in it, so divergence never
@@ -141,7 +168,9 @@ class PagedLMEngine(DecodeEngine):
         self.slots = slots
         self.page_size = page_size
         self.blocks_per_slot = max_seq // page_size
-        self.chunk = min(chunk, max_seq)
+        self.chunk = prefill_width(
+            chunk, max_seq, page_size,
+            jnp.dtype(params["embed"].dtype).itemsize)
         self.share_prefixes = share_prefixes
         self.compile_count = 0
         self.host_s = self.pull_s = 0.0  # under the spans below, summed
@@ -513,7 +542,8 @@ class PagedLMEngine(DecodeEngine):
         tokens, start = st["tokens"], st["next"]
         n_valid = min(self.chunk, tokens.size - start)
         attrs = {"slot": slot, "start": start, "n_valid": n_valid}
-        with obs_context.span("engine.chunk.prepare", **attrs) as prepare:
+        with obs_context.span("engine.chunk.prepare", width=self.chunk,
+                              **attrs) as prepare:
             self._ensure_writable(slot, start, start + n_valid)
             padded = np.zeros((self.chunk,), np.int32)
             padded[:n_valid] = tokens[start:start + n_valid]

@@ -34,10 +34,21 @@ _PEAK_BF16: Tuple[Tuple[str, float], ...] = (
 )
 
 
-def peak_flops_per_chip(device=None) -> Optional[float]:
-    """Peak dense bf16 FLOP/s for ``device`` (default: jax.devices()[0]).
-    None on CPU; a TPU whose ``device_kind`` is not in the table raises —
-    an unknown chip is an error, not a default peak."""
+# HBM bytes/s per chip, keyed as ``_PEAK_BF16`` is (the same spec sheets).
+_HBM_BYTES_PER_S: Tuple[Tuple[str, float], ...] = (
+    ("v6e", 1640e9), ("v6 lite", 1640e9), ("trillium", 1640e9),
+    ("v5p", 2765e9),
+    ("v5e", 819e9), ("v5 lite", 819e9), ("v5litepod", 819e9),
+    ("v4", 1200e9),
+    ("v3", 900e9),
+    ("v2", 700e9),
+)
+
+
+def _chip_spec(table, what: str, device=None) -> Optional[float]:
+    """``device``'s row of a per-generation table (default device:
+    jax.devices()[0]). None on CPU; a TPU whose ``device_kind`` is not in
+    the table raises — an unknown chip is an error, not a default."""
     if device is None:
         import jax
 
@@ -46,16 +57,37 @@ def peak_flops_per_chip(device=None) -> Optional[float]:
     if platform == "cpu":
         return None
     kind = str(getattr(device, "device_kind", "")).lower()
-    for key, peak in _PEAK_BF16:
+    for key, value in table:
         if key in kind:
-            return peak
+            return value
     from .hw_accel import is_tpu_platform
 
     if is_tpu_platform(platform):
         raise ValueError(
-            f"no peak FLOP/s on file for TPU device_kind {kind!r} "
-            f"(known: {[k for k, _ in _PEAK_BF16]})")
+            f"no {what} on file for TPU device_kind {kind!r} "
+            f"(known: {[k for k, _ in table]})")
     return None
+
+
+def peak_flops_per_chip(device=None) -> Optional[float]:
+    """Peak dense bf16 FLOP/s for ``device`` (default: jax.devices()[0]).
+    None on CPU; a TPU whose ``device_kind`` is not in the table raises —
+    an unknown chip is an error, not a default peak."""
+    return _chip_spec(_PEAK_BF16, "peak FLOP/s", device)
+
+
+def hbm_bytes_per_s_per_chip(device=None) -> Optional[float]:
+    """HBM bytes/s for ``device``, under ``peak_flops_per_chip``'s rules."""
+    return _chip_spec(_HBM_BYTES_PER_S, "HBM bytes/s", device)
+
+
+def ridge_flops_per_byte(device=None) -> Optional[float]:
+    """The chip's ridge: the bf16 FLOPs a byte read from HBM at which a
+    kernel stops being bound by its reads (v5e: 240.5). None where the
+    chip is unknown to both tables (CPU)."""
+    peak = peak_flops_per_chip(device)
+    hbm = hbm_bytes_per_s_per_chip(device)
+    return peak / hbm if peak and hbm else None
 
 
 def compiled_flops(fn, *example_args, static_argnums=()) -> Optional[float]:

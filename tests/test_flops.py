@@ -11,9 +11,11 @@ import pytest
 from nnstreamer_tpu.utils.flops import (
     compiled_flops,
     count_params,
+    hbm_bytes_per_s_per_chip,
     mfu,
     peak_flops_per_chip,
     perf_record,
+    ridge_flops_per_byte,
     transformer_flops,
 )
 
@@ -55,6 +57,17 @@ def test_unknown_tpu_kind_is_an_error(monkeypatch):
         peak_flops_per_chip(_FakeDev("tpu", "unknown-kind"))
     # other accelerators have no table at all: unknown, not an error
     assert peak_flops_per_chip(_FakeDev("gpu", "some gpu")) is None
+
+
+def test_the_ridge_is_peak_over_bandwidth():
+    # the HBM table is keyed as the peak table is, under the same rules
+    v5e = _FakeDev("tpu", "TPU v5 lite")
+    assert hbm_bytes_per_s_per_chip(v5e) == 819e9
+    assert ridge_flops_per_byte(v5e) == pytest.approx(240.5, abs=0.1)
+    assert hbm_bytes_per_s_per_chip(_FakeDev("cpu", "cpu")) is None
+    assert ridge_flops_per_byte(_FakeDev("cpu", "cpu")) is None
+    with pytest.raises(ValueError, match="HBM"):
+        hbm_bytes_per_s_per_chip(_FakeDev("tpu", "unknown-kind"))
 
 
 def test_mfu_and_record():

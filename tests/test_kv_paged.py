@@ -556,9 +556,15 @@ def _no_gathered_context(compiled, count):
 
 
 class TestPoolInPlaceOnTpu:
-    @pytest.mark.parametrize("program", ["_step", "_prefill_chunk"])
+    # a prefill launch at the small presets' width and at the width the
+    # engine derives on a v5e (256 rows over a context of 512)
+    PROGRAMS = [("_step", 32, 128), ("_prefill_chunk", 32, 128),
+                ("_prefill_chunk", 256, 512)]
+
+    @pytest.mark.parametrize("program, C, max_seq", PROGRAMS)
     def test_pool_goes_in_and_comes_out_in_one_layout(self, v5e_chip,
-                                                      program, monkeypatch):
+                                                      program, C, max_seq,
+                                                      monkeypatch):
         import functools
 
         import jax
@@ -574,8 +580,8 @@ class TestPoolInPlaceOnTpu:
         # element count by accident, and at 17 MB a layer the pool is past
         # what the compiler would prefetch whole into faster memory
         cfg = TransformerConfig(vocab=512, dim=256, heads=4, layers=2,
-                                mlp_mult=4, max_seq=128)
-        S, pg, pages, C = 4, 16, 2048, 32
+                                mlp_mult=4, max_seq=max_seq)
+        S, pg, pages = 4, 16, 2048
         # the step as a TPU runs it, with the kernel in (here the op would
         # take its plain form: this process's backend is the CPU)
         monkeypatch.setattr(paged_attention, "paged_line_attention",
@@ -614,9 +620,10 @@ class TestPoolInPlaceOnTpu:
         if program == "_step":
             _no_gathered_context(compiled, S * cfg.max_seq * cfg.dim)
 
-    @pytest.mark.parametrize("program", ["_step", "_prefill_chunk"])
+    @pytest.mark.parametrize("program, C, max_seq", PROGRAMS)
     def test_latent_pool_goes_in_and_comes_out_in_one_layout(self, v5e_chip,
-                                                             program,
+                                                             program, C,
+                                                             max_seq,
                                                              monkeypatch):
         # the same rules for the DeepSeek-V3 family's one pool: a line of
         # 512 latent + 64 rotary values (Kanana-2's widths) at a small
@@ -636,8 +643,9 @@ class TestPoolInPlaceOnTpu:
             num_attention_heads=4, intermediate_size=512,
             moe_intermediate_size=128, n_routed_experts=8,
             num_experts_per_tok=2, kv_lora_rank=512, qk_nope_head_dim=128,
-            qk_rope_head_dim=64, v_head_dim=128, max_position_embeddings=128)
-        S, pg, pages, C = 4, 16, 2048, 32
+            qk_rope_head_dim=64, v_head_dim=128,
+            max_position_embeddings=max_seq)
+        S, pg, pages = 4, 16, 2048
         monkeypatch.setattr(paged_attention, "paged_line_attention",
                             paged_attention.kernel_line_attention)
         eng = PagedLMEngine(cfg, {"embed": jnp.zeros((1, 1), jnp.bfloat16)},
