@@ -18,6 +18,11 @@ out by the repo's own means. One process, no child that needs the chip.
            one pool line a token, dropless experts) through the same engine
            and scheduler at the benchmark configuration's rehearsal sizes,
            served tokens against the plain reference under the near-tie rule;
+  window_serving
+           the third model family (Mellum-shaped: grouped-query lines,
+           window layers that give their pages back beside full layers that
+           keep them, dropless softmax top-k experts) the same way, at its
+           benchmark configuration's rehearsal sizes;
   kernels  both Pallas kernels, Mosaic-lowered, at base geometry.
 
 It refuses to run anywhere but on a TPU, prints no result there, and exits
@@ -488,6 +493,89 @@ def latent_serving_leg(serve_dtype: str = "bfloat16", slots: int = 4,
     return out
 
 
+def window_serving_leg(serve_dtype: str = "bfloat16", slots: int = 4,
+                       steps: int = 30, lengths=(37, 7, 20, 9, 45, 14),
+                       seed: int = 31) -> dict:
+    """The third model family through the same engine and scheduler: the
+    Mellum-shaped block (grouped-query attention, three window layers to
+    one full layer, dropless softmax top-k experts) at the benchmark
+    configuration's rehearsal sizes, seeded weights, against the plain
+    reference (``benchmark/references/mellum_lm.py``, float32 at
+    ``highest``), teacher-forced on what was served. The contexts cross the
+    window inside prefill and while decoding, so pages of the window kind
+    are given back on the served path; none of either kind is left."""
+    import numpy as np
+
+    import jax.numpy as jnp
+
+    from benchmark.lib import harness
+    from benchmark.lib.weights import seed_key
+    from benchmark.references import mellum_lm as reference
+    from nnstreamer_tpu.models.lm_serving import _LMServingEntry
+    from nnstreamer_tpu.models.mellum import MellumConfig
+
+    _, config = harness.find_cell(harness.load_benchmark(),
+                                  "mellum2_longctx_decode")
+    config = {**config, **config["rehearsal"]}
+    cfg = MellumConfig.from_published(config)
+    sizes, key = reference.sizes(config), seed_key(seed)
+    params = reference.program_params(key, sizes, jnp.dtype(serve_dtype))
+
+    class Seeded(_LMServingEntry):
+        def _shard_params(self, mesh):
+            return params, False
+
+    engine = Seeded(cfg, serve_dtype=serve_dtype).make_continuous(
+        slots=slots, **{k: v for k, v in config["engine"].items()
+                        if k != "slots"})
+    check(engine.family.name == "mellum"
+          and engine.kinds == ("full", "window") and len(engine._pools) == 4,
+          "window: the engine took another family or geometry")
+    rng = np.random.default_rng(seed)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in lengths]
+    streams, snap, took = _serve(engine, prompts, steps)
+    out = {"requests": len(prompts), "steady_s": round(took, 2),
+           "completed": snap["completed"],
+           "compile_count": snap["compile_count"],
+           "window_pages_released": engine.window_pages_released,
+           "moe_assignments": snap["moe_assignments"],
+           "moe_experts_touched": snap["moe_experts_touched"]}
+    check(snap["completed"] == len(prompts), f"window: {snap['completed']} "
+                                             f"of {len(prompts)} completed")
+    # one step and one chunk program, whatever the lengths
+    check(snap["compile_count"] == 2,
+          f"window: compile_count {snap['compile_count']}, expected 2")
+    check(all(p.used_pages == 0 for p in engine.pools_by_kind.values()),
+          "window: pages held after close")
+    check(engine.window_pages_released > 0,
+          "window: no page was given back behind the window")
+    # every prompt token and every decoded row, top-k experts in every
+    # layer: nothing dropped
+    rows = sum(len(p) + steps - 1 for p in prompts)
+    per_row = cfg.num_experts_per_tok * cfg.num_hidden_layers
+    check(snap["moe_assignments"] == rows * per_row,
+          f"window: {snap['moe_assignments']} assignments for {rows} rows")
+    width = cfg.max_position_embeddings
+    tokens = np.zeros((len(prompts), width), np.int32)
+    at = np.zeros((len(prompts), steps), np.int32)
+    for i, (p, toks) in enumerate(zip(prompts, streams)):
+        check(len(toks) == steps, f"window[{i}]: {len(toks)} tokens")
+        tokens[i, :len(p)] = p
+        tokens[i, len(p):len(p) + steps - 1] = toks[:-1]
+        at[i] = len(p) - 1 + np.arange(steps)
+    exact = reference.logits_for(key, sizes, tokens, at)["none"]
+    gaps = [exact[i].max(-1) - np.take_along_axis(
+        exact[i], np.asarray(toks)[:, None], 1)[:, 0]
+        for i, toks in enumerate(streams)]
+    out["served_gap_max"] = float(max(g.max() for g in gaps))
+    check(out["served_gap_max"] <= LM_NEAR_TIE_GAP,
+          f"window: a served token lies {out['served_gap_max']:.5f} under "
+          f"the reference's best (tolerance {LM_NEAR_TIE_GAP}): not a near "
+          "tie, a wrong program")
+    return out
+
+
 # -- kernels ------------------------------------------------------------------
 
 def kernels_leg(B: int = 8, H: int = 16, T: int = 2048, D: int = 64,
@@ -597,7 +685,8 @@ def main() -> int:
     }
     clock = CompileClock()
     legs = {"kernels": kernels_leg, "stream": stream_leg,
-            "serving": serving_leg, "latent_serving": latent_serving_leg}
+            "serving": serving_leg, "latent_serving": latent_serving_leg,
+            "window_serving": window_serving_leg}
     for name, leg in legs.items():
         t0, before = time.monotonic(), clock.read()
         try:

@@ -201,7 +201,8 @@ class DeepseekV3Family:
     (``models/families.py`` has the contract)."""
 
     name = "deepseek_v3"
-    attention_scope = "mla"
+    attention_scopes = {"full": "mla"}
+    window = None          # every layer sees the whole context
     counters = moe_dropless.COUNTERS
     serves_verify = False  # speculative verification: not in this family yet
 
@@ -210,6 +211,7 @@ class DeepseekV3Family:
         self.vocab = cfg.vocab_size
         self.layers = cfg.num_hidden_layers
         self.max_positions = cfg.max_position_embeddings
+        self.layer_kinds = ("full",) * self.layers
         moe_layers = sum(not cfg.is_dense(li) for li in range(self.layers))
         # expert slots of one call: held experts times expert layers
         self.expert_slots = moe_layers * cfg.held[1]
@@ -235,30 +237,27 @@ class DeepseekV3Family:
     def blocks(self, p):
         return p["blocks"]
 
-    def project(self, blk, x, pos):
+    def project(self, blk, x, pos, kind="full"):
         """``x (B, Q, D)`` at ``pos (B, Q)`` → the absorbed queries
         ``(B, Q, H, line)`` and the one line to write ``(B, Q, line)``."""
-        import jax
         import jax.numpy as jnp
 
         cfg = self.cfg
         H, N = cfg.num_attention_heads, cfg.qk_nope_head_dim
-        with jax.named_scope("mla"):
-            h = rms_norm(x, blk["ln1"], cfg.rms_norm_eps)
-            q = (h @ blk["wq"]).reshape(*x.shape[:2], H, cfg.qk_head_dim)
-            q_rope = rotate_pairs(q[..., N:], pos[..., None], cfg.rope_theta)
-            q_lat = jnp.einsum("bqhn,hln->bqhl", q[..., :N], blk["wuk"])
-            kva = h @ blk["wkva"]
-            c = rms_norm(kva[..., :cfg.kv_lora_rank], blk["kv_norm"],
-                         cfg.rms_norm_eps)
-            kr = rotate_pairs(kva[..., cfg.kv_lora_rank:], pos,
-                              cfg.rope_theta)
-            # both sides zero-padded to the stored line: zeros add nothing
-            pad = cfg.line_stored - cfg.line_width
-            q_pad = jnp.zeros((*q_lat.shape[:-1], pad), q_lat.dtype)
-            line_pad = jnp.zeros((*c.shape[:-1], pad), c.dtype)
-            return (jnp.concatenate([q_lat, q_rope, q_pad], axis=-1),
-                    (jnp.concatenate([c, kr, line_pad], axis=-1),))
+        h = rms_norm(x, blk["ln1"], cfg.rms_norm_eps)
+        q = (h @ blk["wq"]).reshape(*x.shape[:2], H, cfg.qk_head_dim)
+        q_rope = rotate_pairs(q[..., N:], pos[..., None], cfg.rope_theta)
+        q_lat = jnp.einsum("bqhn,hln->bqhl", q[..., :N], blk["wuk"])
+        kva = h @ blk["wkva"]
+        c = rms_norm(kva[..., :cfg.kv_lora_rank], blk["kv_norm"],
+                     cfg.rms_norm_eps)
+        kr = rotate_pairs(kva[..., cfg.kv_lora_rank:], pos, cfg.rope_theta)
+        # both sides zero-padded to the stored line: zeros add nothing
+        pad = cfg.line_stored - cfg.line_width
+        q_pad = jnp.zeros((*q_lat.shape[:-1], pad), q_lat.dtype)
+        line_pad = jnp.zeros((*c.shape[:-1], pad), c.dtype)
+        return (jnp.concatenate([q_lat, q_rope, q_pad], axis=-1),
+                (jnp.concatenate([c, kr, line_pad], axis=-1),))
 
     def attend(self, blk, q, ctxs, visible, mode):
         """One slot's chunk: ``q (1, C, H, line)`` over ``ctxs[0] (1, ctx,
@@ -270,13 +269,12 @@ class DeepseekV3Family:
 
         (ctx,) = ctxs
         exact = jax.lax.Precision.HIGHEST    # f32 queries over a bf16 pool
-        with jax.named_scope("mla"):
-            att = (jnp.einsum("bqhl,bcl->bqhc", q, ctx, precision=exact)
-                   * self.attention_scale)
-            att = jnp.where(visible[None, :, None, :], att, -1e30)
-            att = jax.nn.softmax(att, axis=-1)
-            o = jnp.einsum("bqhc,bcl->bqhl", att, ctx, precision=exact)
-            return self._project_out(blk, o)
+        att = (jnp.einsum("bqhl,bcl->bqhc", q, ctx, precision=exact)
+               * self.attention_scale)
+        att = jnp.where(visible[None, :, None, :], att, -1e30)
+        att = jax.nn.softmax(att, axis=-1)
+        o = jnp.einsum("bqhc,bcl->bqhl", att, ctx, precision=exact)
+        return self._project_out(blk, o)
 
     @property
     def attention_scale(self) -> float:
@@ -286,10 +284,7 @@ class DeepseekV3Family:
         return q[:, 0]  # (S, H, line): the absorbed queries as they are
 
     def step_output(self, blk, o):
-        import jax
-
-        with jax.named_scope("mla"):
-            return self._project_out(blk, o[:, None])
+        return self._project_out(blk, o[:, None])
 
     def _project_out(self, blk, o):
         """Weighted sums of whole lines ``(B, Q, H, line)``: the latent part
@@ -314,7 +309,8 @@ class DeepseekV3Family:
         flat = h.reshape(B * Q, D)
         experts, weights = moe_dropless.route(
             blk["router"], blk["router_bias"], flat, cfg.num_experts_per_tok,
-            cfg.routed_scaling_factor, cfg.norm_topk_prob)
+            cfg.routed_scaling_factor, cfg.norm_topk_prob,
+            scoring=cfg.scoring_func)
         e, s = blk["experts"], blk["shared"]
         y, counts = moe_dropless.experts_ffn(
             e["w_gate"], e["w_up"], e["w_down"], flat, experts, weights,

@@ -1,23 +1,30 @@
 """Model families as the paged serving engine sees them (ROADMAP D1).
 
 ``serving.lm_engine.PagedLMEngine`` owns slots, block tables, the page
-pool and the three programs' skeleton (write the new lines, attend over a
+pools and the programs' skeleton (write the new lines, attend over a
 slot's lines, feed forward, head). What a *layer* is and what it
 keeps per token comes from one object, the family, chosen by the type of
 the entry's configuration (:func:`family_of`); no flag names a model.
 
 A family says:
 
-* ``attention_scope`` — the ``jax.named_scope`` the engine puts around the
-  cache's write and gather, so that they carry attention's region.
+* ``layer_kinds`` — per layer ``"full"`` (a query sees every earlier
+  position) or ``"window"`` (the last ``window`` positions only), and
+  ``window``, or ``None`` where no layer has one. The engine keeps a block
+  table, a page allocator and pool arrays per kind present, and gives a
+  window layer's pages back behind the window. ``gpt`` and ``deepseek_v3``
+  say "all full"; ``mellum`` (``models/mellum.py``) has both.
+* ``attention_scopes`` — per layer kind, the ``jax.named_scope`` the engine
+  puts around a layer's projection, cache write, read and attention.
 * ``cache_lines`` — one width per pool: the values a token keeps in a
   layer. The GPT block keeps keys and values, two pools of
   ``heads * head_dim``; the latent-attention block keeps one line that
   every head reads as keys and as values (``models/deepseek_v3.py``).
 * ``embed(p, toks, pos)`` — tokens at positions → float32 activations.
 * ``blocks(p)`` — the per-layer parameter groups, in order.
-* ``project(blk, x, pos)`` — from a layer's input ``x (B, Q, D)``: the
-  query side and the lines to write, one per pool, each ``(B, Q, width)``.
+* ``project(blk, x, pos, kind)`` — from the input ``x (B, Q, D)`` of a
+  layer of ``kind``: the query side and the lines to write, one per pool,
+  each ``(B, Q, width)``.
 * ``attend(blk, q, ctxs, visible, mode)`` — the queries over the gathered
   lines ``ctxs`` (one ``(B, ctx, width)`` per pool), output projection
   applied: what the residual adds. ``mode`` is the program: ``"chunk"``
@@ -46,7 +53,8 @@ class GPTFamily:
     values, a ReLU MLP (or the trainer's switch layer), a tied head."""
 
     name = "gpt"
-    attention_scope = "attention"  # named scope of the cache's write, read
+    attention_scopes = {"full": "attention"}
+    window = None          # every layer sees the whole context
     counters = ()          # nothing an expert layer would count
     serves_verify = True   # speculative verification (``_verify``)
 
@@ -56,6 +64,7 @@ class GPTFamily:
         self.layers = cfg.layers
         # the position table is a weight: the serving limit cannot pass it
         self.max_positions = cfg.max_seq
+        self.layer_kinds = ("full",) * cfg.layers
 
     @property
     def cache_lines(self) -> tuple:
@@ -80,7 +89,7 @@ class GPTFamily:
     def blocks(self, p):
         return p["blocks"]
 
-    def project(self, blk, x, pos):
+    def project(self, blk, x, pos, kind="full"):
         import jax.numpy as jnp
 
         q, k, v = jnp.split(_rmsnorm(x, blk["ln1"]) @ blk["wqkv"], 3, axis=-1)
@@ -181,6 +190,11 @@ def family_of(cfg):
 
     if isinstance(cfg, DeepseekV3Config):
         return DeepseekV3Family(cfg)
+    from .mellum import MellumConfig, MellumFamily
+
+    if isinstance(cfg, MellumConfig):
+        return MellumFamily(cfg)
     raise TypeError(
         f"no model family serves a configuration of type "
-        f"{type(cfg).__name__} (have TransformerConfig, DeepseekV3Config)")
+        f"{type(cfg).__name__} (have TransformerConfig, DeepseekV3Config, "
+        f"MellumConfig)")

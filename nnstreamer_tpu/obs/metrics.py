@@ -427,7 +427,16 @@ def _collect_serving(reg: Registry) -> None:
              "the steps' attention read"),
             ("attn_pages_padded", "attn_pages_padded",
              "slots times the blocks a slot may hold, summed over decode "
-             "steps: what attention over padded positions would read"))}
+             "steps: what attention over padded positions would read"),
+            ("attn_pages_read_full", "attn_pages_read_full",
+             "pages a full layer's attention read, summed over decode "
+             "steps (an engine whose family has layers of two kinds)"),
+            ("attn_pages_read_window", "attn_pages_read_window",
+             "pages a window layer's attention read, from the window's "
+             "first page on, summed over decode steps"),
+            ("window_pages_released", "window_pages_released",
+             "pages of window layers given back behind the window while "
+             "their slot was live"))}
     # snapshot mirrors: repopulated from live schedulers each scrape, so
     # a garbage-collected scheduler's series disappears with it
     for inst in (subm, comp, fail, shedf, shedd, shedm, shedo, batches,

@@ -11,7 +11,10 @@ latent family by construction). :func:`paged_line_attention` is that op:
 * ``rows (S, NB)`` int32 — the pool row of every block of every slot
   (``li * R + block_table``, made on the device);
 * ``lengths (S,)`` int32 — the positions a slot sees, 0 for an empty slot;
-* ``scale`` — what the scores are multiplied by.
+* ``scale`` — what the scores are multiplied by;
+* ``starts (S,)`` int32 — the first position a slot sees (a layer that
+  looks back a window only); ``None`` is 0 for every slot. Blocks wholly
+  below it are not read: their table entries may name any row.
 
 It returns ``(S, H, Wv)`` float32: softmax(q · lines) · lines, zeros for an
 empty slot. Float32 queries, scores, softmax and weighted sum over a
@@ -25,8 +28,9 @@ Two forms, chosen in one place (:func:`paged_line_attention`) by
   several pages at a time is fetched by asynchronous copies into
   double-buffered VMEM, the next block (of this slot or of the next live
   one) on its way while this one is contracted. Online softmax; a slot of
-  length 0 does nothing, nothing past a slot's last block is read, the last
-  block masks its tail. The float32 operand of each product (the queries,
+  length 0 does nothing, nothing before the block of a slot's first visible
+  position or past its last block is read, the first block masks its head
+  and the last its tail. The float32 operand of each product (the queries,
   the softmax's weights) is split into three bfloat16 terms stacked along
   the rows, so one pass of the pool's bfloat16 lines through the matrix
   unit gives the float32 product exactly (the lines are bfloat16 already:
@@ -58,13 +62,15 @@ BLOCK_BYTES = 768 * 1024
 _MASKED = -1e30
 
 
-def paged_line_attention(q, kpool, vpool, rows, lengths, scale):
+def paged_line_attention(q, kpool, vpool, rows, lengths, scale, starts=None):
     """The step's attention (module docstring), in the form this platform
     runs: Mosaic on a TPU, the plain form where the kernel would be
     interpreted."""
     if hw_accel.pallas_interpret(jax.default_backend()):
-        return plain_line_attention(q, kpool, vpool, rows, lengths, scale)
-    return kernel_line_attention(q, kpool, vpool, rows, lengths, scale)
+        return plain_line_attention(q, kpool, vpool, rows, lengths, scale,
+                                    starts)
+    return kernel_line_attention(q, kpool, vpool, rows, lengths, scale,
+                                 starts)
 
 
 def gathered_lines(pool, rows):
@@ -79,13 +85,15 @@ def gathered_lines(pool, rows):
     return lines.reshape(rows.shape[0], -1, pool.shape[-1])
 
 
-def plain_line_attention(q, kpool, vpool, rows, lengths, scale):
+def plain_line_attention(q, kpool, vpool, rows, lengths, scale, starts=None):
     """Gather, mask, softmax: every slot's whole block table."""
     exact = jax.lax.Precision.HIGHEST
     ck = gathered_lines(kpool, rows)
     cv = ck if vpool is kpool else gathered_lines(vpool, rows)
     att = jnp.einsum("shj,scj->shc", q, ck, precision=exact) * scale
     visible = jnp.arange(ck.shape[1])[None, :] < lengths[:, None]
+    if starts is not None:
+        visible &= jnp.arange(ck.shape[1])[None, :] >= starts[:, None]
     att = jax.nn.softmax(jnp.where(visible[:, None, :], att, _MASKED), axis=-1)
     out = jnp.einsum("shc,scj->shj", att, cv, precision=exact)
     return jnp.where((lengths > 0)[:, None, None], out, 0.0)
@@ -112,7 +120,10 @@ def _kernel(rows_ref, meta_ref, q_ref, *refs, S, NB, PB, H, scale, shared):
     s = pl.program_id(0)
     length = meta_ref[s]
     next_live = meta_ref[2 * S + s]
+    start = meta_ref[3 * S + s]
     blocks = (length + T - 1) // T
+    lo = start // T  # the block of the first visible position
+    next_lo = meta_ref[3 * S + jnp.minimum(next_live, S - 1)] // T
 
     @pl.when(s == 0)
     def _():
@@ -158,18 +169,19 @@ def _kernel(rows_ref, meta_ref, q_ref, *refs, S, NB, PB, H, scale, shared):
         def body(i, _):
             # one place starts copies and one waits for them: block i + 1
             # of this slot, or the next live slot's first, is on its way
-            # while block i is contracted. i is -1 once a call, for the
-            # slot nobody fetched ahead for: that pass only starts block 0
+            # while block i is contracted. i is lo - 1 once a call, for the
+            # slot nobody fetched ahead for: that pass only starts block lo
             more = i + 1 < blocks
 
             @pl.when(more | (next_live < S))
             def _():
                 copies("start", jnp.where(more, s, next_live),
-                       jnp.where(more, i + 1, 0), (first + i + 1) % 2)
+                       jnp.where(more, i + 1, next_lo),
+                       (first + i + 1 - lo) % 2)
 
-            @pl.when(i >= 0)
+            @pl.when(i >= lo)
             def _():
-                contract(i, (first + i) % 2)
+                contract(i, (first + i - lo) % 2)
 
             return 0
 
@@ -181,7 +193,7 @@ def _kernel(rows_ref, meta_ref, q_ref, *refs, S, NB, PB, H, scale, shared):
                 preferred_element_type=jnp.float32)          # (3H, T)
             sc = sc3[:H] + sc3[H:2 * H] + sc3[2 * H:]
             at = i * T + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-            sc = jnp.where(at < length, sc, _MASKED)
+            sc = jnp.where((at >= start) & (at < length), sc, _MASKED)
             m_prev = m_ref[...]
             m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
             # every block holds a visible position, so m_new is a score and
@@ -198,15 +210,15 @@ def _kernel(rows_ref, meta_ref, q_ref, *refs, S, NB, PB, H, scale, shared):
             o_ref[...] = (alpha * o_ref[...]
                           + o3[:H] + o3[H:2 * H] + o3[2 * H:])
 
-        jax.lax.fori_loop(fetched - 1, blocks, body, 0)
-        state[0] = (first + blocks) % 2
+        jax.lax.fori_loop(lo + fetched - 1, blocks, body, 0)
+        state[0] = (first + blocks - lo) % 2
         o_ref[...] = o_ref[...] / l_ref[...]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("scale", "pages_per_block", "interpret"))
-def _call(q, kpool, vpool, rows, lengths, *, scale, pages_per_block,
-          interpret):
+def _call(q, kpool, vpool, rows, lengths, starts, *, scale,
+          pages_per_block, interpret):
     shared = vpool is None
     S, H0, Wk = q.shape
     NB = rows.shape[1]
@@ -218,6 +230,9 @@ def _call(q, kpool, vpool, rows, lengths, *, scale, pages_per_block,
     if H != H0:
         q = jnp.pad(q, ((0, 0), (0, H - H0), (0, 0)))
     lengths = jnp.clip(lengths, 0, NB * pg)
+    # a live slot sees a position: every block it visits holds one
+    starts = (jnp.zeros_like(lengths) if starts is None
+              else jnp.clip(starts, 0, jnp.maximum(lengths - 1, 0)))
     live = lengths > 0
     idx = jnp.arange(S, dtype=jnp.int32)
     # an empty slot's program touches nothing: its query and output blocks
@@ -227,7 +242,8 @@ def _call(q, kpool, vpool, rows, lengths, *, scale, pages_per_block,
     next_live = jnp.concatenate([
         jax.lax.cummin(jnp.where(live, idx, S), reverse=True)[1:],
         jnp.full((1,), S, jnp.int32)])
-    meta = jnp.concatenate([lengths, stay, next_live]).astype(jnp.int32)
+    meta = jnp.concatenate([lengths, stay, next_live,
+                            starts]).astype(jnp.int32)
 
     def block(width):
         return pl.BlockSpec((None, H, width),
@@ -262,8 +278,8 @@ def _call(q, kpool, vpool, rows, lengths, *, scale, pages_per_block,
     return jnp.where(live[:, None, None], out[:, :H0], 0.0)
 
 
-def kernel_line_attention(q, kpool, vpool, rows, lengths, scale, *,
-                          pages_per_block=None, interpret=False):
+def kernel_line_attention(q, kpool, vpool, rows, lengths, scale, starts=None,
+                          *, pages_per_block=None, interpret=False):
     """The Pallas kernel (module docstring). ``pages_per_block`` is derived
     from the line's bytes unless a test or a stand-alone timing names it;
     ``interpret`` runs the kernel through the Pallas interpreter (tests on
@@ -273,5 +289,5 @@ def kernel_line_attention(q, kpool, vpool, rows, lengths, scale, *,
         fit = max(1, BLOCK_BYTES // page_bytes)
         pages_per_block = min(rows.shape[1], 1 << (fit.bit_length() - 1))
     return _call(q, kpool, None if vpool is kpool else vpool, rows, lengths,
-                 scale=float(scale), pages_per_block=int(pages_per_block),
+                 starts, scale=float(scale), pages_per_block=int(pages_per_block),
                  interpret=interpret)

@@ -1,4 +1,5 @@
-"""A dropless mixture-of-experts layer for serving (DeepSeek-V3 routing).
+"""A dropless mixture-of-experts layer for serving (DeepSeek-V3's routing
+and the softmax top-k of ``norm_topk_prob`` models).
 
 ``parallel/moe.py`` is the trainer's switch layer: top-1, a capacity per
 expert, tokens over it dropped. A served model may drop nothing, so this
@@ -10,8 +11,10 @@ Routing, as DeepSeek-V3 publishes it (``scoring_func`` sigmoid,
 ``topk_method`` noaux_tc with one group): scores ``s = sigmoid(W_g h)``
 over all experts in float32; the ``top_k`` are the largest of ``s + b``
 where ``b`` is a selection bias used for the choice only; the weights are
-the chosen ``s`` renormalised and scaled. The router runs in float32 at
-``Precision.HIGHEST``: on a TPU the default precision of a float32 product
+the chosen ``s`` renormalised and scaled. With ``scoring`` ``"softmax"``
+(a model family whose configuration has no ``scoring_func`` and no bias)
+``s = softmax(W_g h)`` and the ``top_k`` are the largest of ``s`` itself. The
+router runs in float32 at ``Precision.HIGHEST``: on a TPU the default precision of a float32 product
 is one bfloat16 pass, which picks other experts at near ties, and an expert
 swapped is not a rounding error.
 
@@ -27,18 +30,27 @@ COUNTERS = ("moe_experts_touched", "moe_assignments", "moe_max_load",
 
 
 def route(router_w, bias, h, top_k: int, scale: float,
-          norm_topk_prob: bool = True):
+          norm_topk_prob: bool = True, scoring: str = "sigmoid"):
     """``h (T, D)`` → ``(experts (T, k) int32, weights (T, k) float32)``.
-    ``router_w (D, E)``, ``bias (E,)``: the bias decides the choice and
-    never enters the weights."""
+    ``router_w (D, E)``; ``scoring`` is the family's configuration's:
+    ``"sigmoid"`` with ``bias (E,)``, which decides the choice and never
+    enters the weights, or ``"softmax"`` with none."""
     import jax
     import jax.numpy as jnp
 
+    if (scoring, bias is None) not in (("sigmoid", False), ("softmax", True)):
+        raise ValueError(f"route: scoring {scoring!r} with"
+                         f"{'out' if bias is None else ''} a selection bias")
     with jax.named_scope("moe.route"):
         logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        scores = jax.nn.sigmoid(logits)
-        _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        if scoring == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+            _, experts = jax.lax.top_k(scores, top_k)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32),
+                                       top_k)
         chosen = jnp.take_along_axis(scores, experts, axis=-1)
         if norm_topk_prob:
             chosen = chosen / (chosen.sum(-1, keepdims=True) + 1e-20)

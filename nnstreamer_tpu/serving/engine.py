@@ -22,6 +22,8 @@ class DecodeEngine:
     host_s = pull_s = 0.0      # running sums under the engine's prepare +
     #                            dispatch spans, and under its pull spans
     pool = None                # a ``KVPagePool``, where pages are kept
+    pools_by_kind: dict = {}   # layer kind -> its ``KVPagePool``; ``pool``
+    #                            is the first of them (below)
     # a burst engine (1..K tokens a slot a pass) defines ``step_tokens() ->
     # list[list[int]]``, which the scheduler then calls in place of ``step``,
     # with ``acceptance_rate()`` and ``spec_rounds|proposed|accepted``

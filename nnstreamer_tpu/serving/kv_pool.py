@@ -35,7 +35,9 @@ Leakcheck contract: every page incref pairs with exactly one decref
 or scheduler exit path that drops a block table without releasing its
 pages fails the test ledger. Gauges
 ``nns_serving_kv_{pages_total,pages_used,pages_shared,prefix_hits_total,
-preemptions_total}`` render from the collector below on every scrape.
+preemptions_total}`` render from the collector below on every scrape, one
+series per pool: an engine whose family has layers of two kinds keeps one
+pool for each (``kind``; the window kind's is named ``<engine>.window``).
 """
 from __future__ import annotations
 
@@ -70,7 +72,8 @@ class KVPagePool:
 
     def __init__(self, pages: int, page_size: int,
                  name: str = "kv_pool", prefix_capacity: int = 32,
-                 line_widths: Tuple[int, ...] = (), token_bytes: int = 0):
+                 line_widths: Tuple[int, ...] = (), token_bytes: int = 0,
+                 kind: str = "full"):
         if pages < 1:
             raise ValueError(f"pages={pages} must be >= 1")
         if page_size < 1 or (page_size & (page_size - 1)):
@@ -79,8 +82,13 @@ class KVPagePool:
         self.pages = pages
         self.page_size = page_size
         self.name = name
+        # the kind of layer whose pages these are: an engine keeps one
+        # pool per kind ("full": a slot holds every page until it leaves;
+        # "window": it gives back the pages behind the window as it goes)
+        self.kind = kind
         # what a token keeps on the device, from the engine's model family:
-        # the width of its line in each pool, and its bytes over all layers
+        # the width of its line in each pool, and its bytes over the layers
+        # of this kind
         # (0 when the owner did not say: the allocator itself counts pages)
         self.line_widths = tuple(line_widths)
         self.token_bytes = int(token_bytes)
@@ -266,6 +274,7 @@ class KVPagePool:
             used = len(self._ref)
             return {
                 "name": self.name,
+                "kind": self.kind,
                 "pages_total": self.pages,
                 "pages_used": used,
                 "pages_free": len(self._free),
@@ -294,9 +303,11 @@ class KVPagePool:
 # -- metrics collector (scrape-time, weakset pattern of obs/metrics.py) ------
 
 _G_TOTAL = obs_metrics.gauge(
-    "nns_serving_kv_pages_total", "KV page-pool capacity", ("pool",))
+    "nns_serving_kv_pages_total", "KV page-pool capacity",
+    ("pool", "kind"))
 _G_USED = obs_metrics.gauge(
-    "nns_serving_kv_pages_used", "KV pages currently referenced", ("pool",))
+    "nns_serving_kv_pages_used", "KV pages currently referenced",
+    ("pool", "kind"))
 _G_SHARED = obs_metrics.gauge(
     "nns_serving_kv_pages_shared",
     "KV pages referenced by more than one block table (prefix sharing)",
@@ -322,8 +333,8 @@ def _collect_kv(_registry) -> None:
             s = pool.stats()
         except Exception:  # noqa: BLE001 - pool mid-close
             continue
-        _G_TOTAL.set(s["pages_total"], pool=s["name"])
-        _G_USED.set(s["pages_used"], pool=s["name"])
+        _G_TOTAL.set(s["pages_total"], pool=s["name"], kind=s["kind"])
+        _G_USED.set(s["pages_used"], pool=s["name"], kind=s["kind"])
         _G_SHARED.set(s["pages_shared"], pool=s["name"])
         _G_PREFIX_HITS.set(s["prefix_hits_total"], pool=s["name"])
         _G_PREEMPT.set(s["preemptions_total"], pool=s["name"])

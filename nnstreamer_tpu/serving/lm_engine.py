@@ -83,25 +83,43 @@ class PagedLMEngine(DecodeEngine):
       the head, and the pool's geometry (``cache_lines``: one width per
       pool). The GPT block keeps keys and values (two pools); the
       DeepSeek-V3 block keeps one latent line that every head reads.
-    * **pool layout** — per kind of line ``(layers * (pages+1), page,
-      width)`` device arrays: one row per page of one layer, one contiguous
-      ``width`` line per token, so row-major order IS the order
-      every program touches it and the donated pool goes in and comes
-      out of each program in the array's own layout (no relayout copy).
-      Layer ``li`` owns rows ``li*(pages+1) ..``; its row 0 is that
-      layer's null page, the sink inactive/pad writes route to (no
-      branches in the scatter). A slot's logical position ``p`` lives at
-      ``(li*(pages+1) + block_table[p // page], p % page)``. No program
-      slices a layer out of the pool: a write is a scatter on the two
-      leading axes, a chunk's context read one ``take`` of the slot's rows,
-      a step's attention a walk over the rows themselves.
+    * **layer kinds** — a family says of each layer whether it is
+      ``"full"`` (a query sees every earlier position) or ``"window"`` (the
+      last ``family.window`` only). Each kind present has its own block
+      table (``_bts[kind]``, ``(slots, blocks)``), its own page allocator
+      (``pools_by_kind[kind]``, a ``KVPagePool``) and its own pool arrays,
+      whose rows belong to the layers of that kind alone: a page id names
+      one row in every layer of its kind and nothing in the other's. A
+      live slot holds every page of its context in the full kind and, in
+      the window kind, only those some future query can still see (and the
+      launch in flight during prefill: ``held_blocks["window"]`` at most);
+      the others go back to the window kind's allocator as it advances
+      (``_release_behind``, before every chunk and step). A family whose
+      layers are all full (``gpt``, ``deepseek_v3``) has one table, one
+      allocator and the programs it had before kinds existed.
+    * **pool layout** — per kind of layer and kind of line ``(layers of
+      the kind * (pages+1), page, width)`` device arrays: one row per page
+      of one layer, one contiguous ``width`` line per token, so row-major
+      order IS the order every program touches it and the donated pool
+      goes in and comes out of each program in the array's own layout (no
+      relayout copy). The ``i``-th layer of a kind owns rows
+      ``i*(pages+1) ..``; its row 0 is that layer's null page, the sink
+      inactive/pad writes route to (no branches in the scatter). A slot's
+      logical position ``p`` lives at ``(i*(pages+1) + block_table[p //
+      page], p % page)``. No program slices a layer out of the pool: a
+      write is a scatter on the two leading axes, a chunk's context read
+      one ``take`` of the slot's rows, a step's attention a walk over the
+      rows themselves.
     * **serving limit** — ``max_seq``, the positions a slot may hold: the
       family's ``max_positions`` (the ``gpt`` family's position table, the
-      latent family's ``max_position_embeddings``) or ``max_positions=``
-      below it. A prefill chunk and a verify round attend over that many
-      padded positions; a decode step reads the pages each live slot holds
-      and no more (``ops/paged_attention.py``; ``attn_pages`` and the
-      ``engine.step.prepare`` span count them against the padding).
+      rotary families' ``max_position_embeddings``) or ``max_positions=``
+      below it. A full layer's prefill chunk (and a verify round) attends
+      over that many padded positions, a window layer's over the window's
+      pages and the chunk's own; a decode step reads the pages each live
+      slot holds, in a window layer from the window's first page on, and
+      no more (``ops/paged_attention.py``; ``attn_pages`` and the
+      ``engine.step.prepare`` span count them, by kind, against the
+      padding).
     * **chunked prefill** — ``admit_start`` queues the prompt and
       ``prefill_tick`` ingests ONE fixed-size chunk per call, so a long
       prompt interleaves with running decode instead of stalling the
@@ -115,10 +133,13 @@ class PagedLMEngine(DecodeEngine):
     * **COW prefix sharing** — identical prompt prefixes resolve to the
       same pages via the pool's registry; ``_ensure_writable`` copies a
       shared page before any write lands in it, so divergence never
-      perturbs the sibling stream.
-    * **preempt/restore** — ``preempt`` pulls a slot's pages to host and
-      frees them; ``restore`` re-allocates and uploads byte-exact, so
-      memory pressure never drops a request.
+      perturbs the sibling stream. Refused (``NotImplementedError``) for
+      a family with window layers: a registered prefix would have to keep
+      the pages of both kinds that a hit needs.
+    * **preempt/restore** — ``preempt`` pulls the pages a slot holds of
+      every kind to host and frees them; ``restore`` re-allocates (every
+      kind or none) and uploads byte-exact, so memory pressure never drops
+      a request.
 
     Parity contract: a position a slot does not see has exact-zero softmax
     weight (masked at -1e30 in the gathered forms, never read by the
@@ -131,7 +152,7 @@ class PagedLMEngine(DecodeEngine):
     """
 
     def __init__(self, cfg, params, slots: int = 4, page_size: int = 16,
-                 pages: Optional[int] = None, chunk: int = 32,
+                 pages=None, chunk: int = 32,
                  share_prefixes: bool = True,
                  max_positions: Optional[int] = None):
         if slots < 1:
@@ -161,8 +182,18 @@ class PagedLMEngine(DecodeEngine):
         if max_seq % page_size:
             raise ValueError(
                 f"max_seq {max_seq} must divide by page_size {page_size}")
+        # the kinds of layer this family has, "full" first: each has its
+        # own block table, page allocator and pool arrays
+        kinds = tuple(k for k in ("full", "window") if k in fam.layer_kinds)
+        if share_prefixes and "window" in kinds:
+            raise NotImplementedError(
+                f"lm_engine: prefix sharing does not serve the {fam.name} "
+                f"family yet (a hit would need the pages of both kinds of "
+                f"layer that a registered prefix keeps); build it with "
+                f"share_prefixes=False")
         self.cfg = cfg
         self.family = fam
+        self.kinds = kinds
         self.max_seq = max_seq
         self.params = params
         self.slots = slots
@@ -177,30 +208,66 @@ class PagedLMEngine(DecodeEngine):
         self._jnp = jnp
         self._jax = jax
 
-        if pages is None:
-            pages = slots * self.blocks_per_slot  # dense-equivalent pool
+        NB = self.blocks_per_slot
+        pg = page_size
+        C = self.chunk
+        window = fam.window
+        # the blocks a slot may hold at once: everything in a full layer; in
+        # a window layer what some future query still sees plus the launch
+        # in flight, ceil((window + width) / page) + 1 at most
+        self.held_blocks = {
+            kind: NB if kind == "full"
+            else min(NB, -(-(window + C) // pg) + 1) for kind in kinds}
+        if pages is None:  # every slot's most at once
+            pages = {kind: slots * self.held_blocks[kind] for kind in kinds}
+        elif not isinstance(pages, dict):
+            if len(kinds) > 1:
+                raise ValueError(
+                    f"pages={pages}: the {fam.name} family has layers of "
+                    f"kinds {kinds}; give the pages of each, "
+                    f"pages={{kind: count}}")
+            pages = {kinds[0]: pages}
+        if set(pages) != set(kinds):
+            raise ValueError(f"pages {sorted(pages)} for kinds {kinds}")
         self._mem_name = f"lm_engine#{next(_engine_ids)}"
 
-        # the pool's geometry, from the family and from nowhere else: one
-        # device array per kind of line a token keeps in a layer
+        # the pool's geometry, from the family and from nowhere else: per
+        # kind of layer, one device array per kind of line a token keeps
         cache_dtype = params["embed"].dtype
-        L = fam.layers
-        R = pages + 1  # rows of one layer: its null page 0, then the pages
+        item = jnp.dtype(cache_dtype).itemsize
         self.line_widths = tuple(int(w) for w in fam.cache_lines)
-        self.token_bytes = (L * sum(self.line_widths)
-                            * jnp.dtype(cache_dtype).itemsize)
-        self.pool = KVPagePool(pages, page_size, name=self._mem_name,
-                               line_widths=self.line_widths,
-                               token_bytes=self.token_bytes)
+        P = len(self.line_widths)
+        K = len(kinds)
+        # layer li is the index[li]-th layer of its kind
+        layers_of = dict.fromkeys(kinds, 0)
+        index = []
+        for kind in fam.layer_kinds:
+            index.append(layers_of[kind])
+            layers_of[kind] += 1
+        self.kind_layers = layers_of
+        # rows of one layer: its null page 0, then the kind's pages
+        R = {kind: pages[kind] + 1 for kind in kinds}
+        self.token_bytes = fam.layers * sum(self.line_widths) * item
+        self.pools_by_kind = {
+            kind: KVPagePool(
+                pages[kind], page_size, kind=kind,
+                name=self._mem_name + ("" if kind == kinds[0]
+                                       else f".{kind}"),
+                line_widths=self.line_widths,
+                token_bytes=layers_of[kind] * sum(self.line_widths) * item)
+            for kind in kinds}
+        self.pool = self.pools_by_kind[kinds[0]]
         self.page_bytes = self.pool.page_bytes
-        self._pools = tuple(jnp.zeros((L * R, page_size, w), cache_dtype)
-                            for w in self.line_widths)
-        P = len(self._pools)
-        NB = self.blocks_per_slot
-        ctx = NB * page_size  # == max_seq: what a chunk or a verify gathers
+        # flat, kind by kind: kind k's arrays are [k * P, (k + 1) * P)
+        self._pools = tuple(
+            jnp.zeros((layers_of[kind] * R[kind], page_size, w), cache_dtype)
+            for kind in kinds for w in self.line_widths)
+        ctx = NB * page_size  # == max_seq: what a full layer's chunk gathers
 
         # host mirrors (authoritative; device copies re-synced on change)
-        self._bt = np.zeros((slots, NB), np.int32)
+        self._bts = {kind: np.zeros((slots, NB), np.int32) for kind in kinds}
+        # first block a slot still holds in a window layer
+        self._held_from = np.zeros((slots,), np.int64)
         self._tok = np.zeros((slots, 1), np.int32)
         self._pos = np.zeros((slots,), np.int32)
         self._mask = np.zeros((slots,), bool)
@@ -215,75 +282,94 @@ class PagedLMEngine(DecodeEngine):
                              for call in ("step", "chunk")}
         self._chunk_counts: list = []
         # running sums over decode steps: the pages the live slots held
-        # (what a step's attention reads) and slots x blocks_per_slot
-        self.attn_pages = {"attn_pages_read": 0, "attn_pages_padded": 0}
+        # (what a step's attention reads in a layer, the mean over layers
+        # where kinds differ) and slots x blocks_per_slot; by kind beside
+        # them, and the pages given back behind the window so far
+        self.attn_pages = {"attn_pages_read": 0, "attn_pages_padded": 0,
+                           **{f"attn_pages_read_{kind}": 0 for kind in kinds
+                              if len(kinds) > 1}}
+        self.window_pages_released = 0
 
         self.cache_bytes = int(sum(p.nbytes for p in self._pools))
         self.param_bytes = obs_memory.tree_nbytes(params)
         obs_memory.track_serving(self)
 
-        pg = page_size
         NC = len(fam.counters)
 
-        def _write(pool, li, dest, offs, rows):
-            # rows (..., width) -> position offs of page dest of layer li:
-            # a scatter on the two leading axes, one whole line per token
-            return pool.at[li * R + dest, offs].set(rows.astype(pool.dtype))
+        def _write(pool, row0, dest, offs, rows):
+            # rows (..., width) -> position offs of page dest of the layer
+            # whose rows start at row0: a scatter on the two leading axes,
+            # one whole line per token
+            return pool.at[row0 + dest, offs].set(rows.astype(pool.dtype))
 
-        def _layers(p, x, pos, live, dest, offs, pools, unbatch, attend):
+        def _layers(p, x, pos, live, dests, offs, pools, unbatch, attend):
             # the skeleton every program shares: per layer, write the new
-            # lines, attend over the slots' lines (``attend(li, blk, q,
-            # pools)``: what the residual adds), feed forward. ``unbatch``
-            # strips the axis a program's lines do not have
+            # lines into its kind's arrays (``dests``: the page of each
+            # row, by kind), attend over the slots' lines (``attend(kind,
+            # row0, blk, q, pools of the kind)``: what the residual adds),
+            # feed forward. ``unbatch`` strips the axis a program's lines
+            # do not have
             counts = jnp.zeros((NC,), jnp.int32) if NC else None
             for li, blk in enumerate(fam.blocks(p)):
-                q, lines = fam.project(blk, x, pos)
-                with jax.named_scope(fam.attention_scope):
-                    pools = tuple(_write(pool, li, dest, offs, unbatch(line))
-                                  for pool, line in zip(pools, lines))
-                x = x + attend(li, blk, q, pools)
+                kind = fam.layer_kinds[li]
+                k = kinds.index(kind)
+                row0 = index[li] * R[kind]
+                with jax.named_scope(fam.attention_scopes[kind]):
+                    q, lines = fam.project(blk, x, pos, kind)
+                    mine = tuple(
+                        _write(pool, row0, dests[k], offs, unbatch(line))
+                        for pool, line in zip(pools[k * P:(k + 1) * P],
+                                              lines))
+                    pools = pools[:k * P] + mine + pools[(k + 1) * P:]
+                    x = x + attend(kind, row0, blk, q, mine)
                 y, c = fam.ffn(blk, x, live)
                 x = x + y
                 if c is not None:
                     counts = counts + c
             return x, pools, counts
 
-        def _gathered(mode, bt, visible):
-            # attention over a gathered copy of the block table's whole
-            # ``max_seq`` positions: the programs whose context is one
+        def _gathered(mode, tables, visible):
+            # attention over a gathered copy of a block table (``tables``
+            # and ``visible`` by kind): the programs whose context is one
             # slot's (a chunk) or that score several queries a slot (verify)
-            def attend(li, blk, q, pools):
-                with jax.named_scope(fam.attention_scope):
-                    ctxs = tuple(gathered_lines(pool, li * R + bt)
-                                 for pool in pools)
-                return fam.attend(blk, q, ctxs, visible, mode)
+            def attend(kind, row0, blk, q, pools):
+                ctxs = tuple(gathered_lines(pool, row0 + tables[kind])
+                             for pool in pools)
+                return fam.attend(blk, q, ctxs, visible[kind], mode)
             return attend
 
-        def _step(p, token, pos, mask, bt, *pools):
+        def _step(p, token, pos, mask, *rest):
             self.compile_count += 1  # trace-time only: one step program
+            bts, pools = rest[:K], rest[K:]
             S = token.shape[0]
             lp = jnp.clip(pos, 0, max_seq - 1)
             x = fam.embed(p, token[:, 0], lp)[:, None, :]  # (S,1,D)
             bidx = jnp.clip(pos // pg, 0, NB - 1)
-            dest = jnp.where(mask & (pos < max_seq),
-                             bt[jnp.arange(S), bidx], 0)
+            dests = tuple(jnp.where(mask & (pos < max_seq),
+                                    bt[jnp.arange(S), bidx], 0)
+                          for bt in bts)
             offs = pos % pg
             # what a slot sees: its positions up to the one just written,
-            # nothing for a slot that is not live
+            # nothing for a slot that is not live; in a window layer from
+            # the window's first position on
             lengths = jnp.where(mask, jnp.minimum(pos + 1, max_seq), 0)
+            starts = {"full": None}
+            if window is not None:
+                starts["window"] = jnp.maximum(lengths - window, 0)
 
-            def attend(li, blk, q, pools):
+            def attend(kind, row0, blk, q, pools):
                 # the step's one attention form (ops/paged_attention.py):
                 # the family's queries over whole lines, read from the
-                # pool's rows where they lie, as far as each slot's length
-                with jax.named_scope(fam.attention_scope):
-                    o = paged_line_attention(
-                        fam.step_queries(q), pools[0], pools[-1],
-                        li * R + bt, lengths, fam.attention_scale)
+                # pool's rows where they lie, from each slot's first
+                # visible position as far as its length
+                o = paged_line_attention(
+                    fam.step_queries(q), pools[0], pools[-1],
+                    row0 + bts[kinds.index(kind)], lengths,
+                    fam.attention_scale, starts[kind])
                 return fam.step_output(blk, o)
 
             x, pools, counts = _layers(
-                p, x, lp[:, None], mask[:, None], dest, offs, pools,
+                p, x, lp[:, None], mask[:, None], dests, offs, pools,
                 lambda line: line[:, 0], attend)
             with jax.named_scope("head"):
                 logits = fam.head(p, x[:, 0])
@@ -295,25 +381,39 @@ class PagedLMEngine(DecodeEngine):
             return (out, token, pos, *pools)
 
         self._step = functools.partial(
-            jax.jit(_step, donate_argnums=(1, 2, *range(5, 5 + P))), params)
+            jax.jit(_step, donate_argnums=(
+                1, 2, *range(4 + K, 4 + K + K * P))), params)
 
-        C = self.chunk
+        # the blocks of a window layer that a chunk's queries can see
+        NW = self.held_blocks.get("window", 0)
 
-        def _prefill_chunk(p, toks, start, n_valid, bt, *pools):
+        def _prefill_chunk(p, toks, start, n_valid, *rest):
             # toks (C,) padded; ingest positions start..start+n_valid-1 of
             # ONE slot. C is static — the only compiled prefill shape.
             self.compile_count += 1  # trace-time only: once per engine
+            bts, pools = rest[:K], rest[K:]
             q_pos = start + jnp.arange(C)
             valid = jnp.arange(C) < n_valid
             lp = jnp.clip(q_pos, 0, max_seq - 1)
-            dest = jnp.where(valid, bt[lp // pg], 0)
+            dests = tuple(jnp.where(valid, bt[lp // pg], 0) for bt in bts)
             offs = lp % pg
             x = fam.embed(p, toks, lp)[None]        # (1, C, D)
-            positions = jnp.arange(ctx)
-            visible = (positions[None, :] <= q_pos[:, None])  # (C, ctx)
+            tables, visible = {}, {}
+            for kind, bt in zip(kinds, bts):
+                if kind == "full":  # the whole table, max_seq positions
+                    positions = jnp.arange(ctx)
+                    tables[kind] = bt[None]
+                    visible[kind] = positions[None, :] <= q_pos[:, None]
+                else:  # the window's pages and the chunk's own
+                    first = jnp.clip((start - window + 1) // pg, 0, NB - NW)
+                    positions = first * pg + jnp.arange(NW * pg)
+                    tables[kind] = jax.lax.dynamic_slice(
+                        bt, (first,), (NW,))[None]
+                    back = q_pos[:, None] - positions[None, :]
+                    visible[kind] = (back >= 0) & (back < window)
             x, pools, counts = _layers(
-                p, x, lp[None], valid[None], dest, offs, pools,
-                lambda line: line[0], _gathered("chunk", bt[None], visible))
+                p, x, lp[None], valid[None], dests, offs, pools,
+                lambda line: line[0], _gathered("chunk", tables, visible))
             with jax.named_scope("head"):
                 logits = fam.head(p, x[0])  # (C, V)
             if NC:
@@ -321,33 +421,36 @@ class PagedLMEngine(DecodeEngine):
             return (logits, *pools)
 
         self._prefill_chunk = functools.partial(
-            jax.jit(_prefill_chunk, donate_argnums=tuple(range(5, 5 + P))),
-            params)
+            jax.jit(_prefill_chunk, donate_argnums=tuple(
+                range(4 + K, 4 + K + K * P))), params)
 
-        layer_rows = jnp.arange(L) * R  # row of every layer's null page
+        # page movers, one set per kind of layer (compiled when first used)
+        def movers(kind):
+            layer_rows = jnp.arange(layers_of[kind]) * R[kind]  # null pages
 
-        def _copy_page(dst, src, *pools):
-            self.compile_count += 1  # trace-time only: the COW primitive
-            return tuple(pool.at[layer_rows + dst].set(pool[layer_rows + src])
-                         for pool in pools)
+            def _copy_page(dst, src, *pools):
+                self.compile_count += 1  # trace-time only: the COW primitive
+                return tuple(
+                    pool.at[layer_rows + dst].set(pool[layer_rows + src])
+                    for pool in pools)
 
-        self._copy_page = jax.jit(_copy_page,
-                                  donate_argnums=tuple(range(2, 2 + P)))
+            def _gather_pages(pages_row, *pools):
+                # (n,) page ids -> (layers, n, pg, width) blobs (preempt)
+                rows = layer_rows[:, None] + pages_row[None, :]
+                return tuple(pool[rows] for pool in pools)
 
-        def _gather_pages(pages_row, *pools):
-            # (NB,) page ids -> (L, NB, pg, width) blobs (preempt read)
-            rows = layer_rows[:, None] + pages_row[None, :]
-            return tuple(pool[rows] for pool in pools)
+            def _scatter_pages(dest_row, blobs, *pools):
+                rows = layer_rows[:, None] + dest_row[None, :]
+                return tuple(pool.at[rows].set(blob.astype(pool.dtype))
+                             for pool, blob in zip(pools, blobs))
 
-        self._gather_pages = jax.jit(_gather_pages)
+            donated = tuple(range(2, 2 + P))
+            return {"copy": jax.jit(_copy_page, donate_argnums=donated),
+                    "gather": jax.jit(_gather_pages),
+                    "scatter": jax.jit(_scatter_pages,
+                                       donate_argnums=donated)}
 
-        def _scatter_pages(dest_row, blobs, *pools):
-            rows = layer_rows[:, None] + dest_row[None, :]
-            return tuple(pool.at[rows].set(blob.astype(pool.dtype))
-                         for pool, blob in zip(pools, blobs))
-
-        self._scatter_pages = jax.jit(_scatter_pages,
-                                      donate_argnums=tuple(range(2, 2 + P)))
+        self._movers = {kind: movers(kind) for kind in kinds}
 
         def _verify(p, toks, pos, mask, bt, *pools):
             # speculative verification: score K tokens per slot in ONE
@@ -368,9 +471,9 @@ class PagedLMEngine(DecodeEngine):
             positions = jnp.arange(ctx)
             visible = (positions[None, None, :] <= q_pos[:, :, None])
             x, pools, _ = _layers(
-                p, x, lp, jnp.broadcast_to(mask[:, None], (S, K)), dest,
+                p, x, lp, jnp.broadcast_to(mask[:, None], (S, K)), (dest,),
                 offs, pools, lambda line: line,
-                _gathered("verify", bt, visible))
+                _gathered("verify", {"full": bt}, {"full": visible}))
             logits = fam.head(p, x)  # (S, K, V)
             return (logits, *pools)
 
@@ -399,11 +502,36 @@ class PagedLMEngine(DecodeEngine):
             out = jnp.concatenate([n_emit[:, None], pred], axis=1)
             return (out, tok, pos, *pools)
 
-        if fam.serves_verify:
+        if fam.serves_verify:  # families whose layers are all of one kind
             self._verify_commit = functools.partial(
                 jax.jit(_verify_commit,
                         donate_argnums=(2, 3, *range(6, 6 + P))), params)
         self._sync_device_state()
+
+    @property
+    def _bt(self):
+        """The block table of the first kind of layer (the only one, for a
+        family whose layers are all alike)."""
+        return self._bts[self.kinds[0]]
+
+    def _tables(self, slot=None) -> tuple:
+        """The block tables by kind, as the programs take them. A window
+        layer's table rides as a copy: its entries go back to 0 as pages
+        are given back, and on the CPU a program still in flight (a chunk
+        is not waited for) may read the host's array in place."""
+        tables = ((kind, bt if slot is None else bt[slot])
+                  for kind, bt in self._bts.items())
+        return tuple(t.copy() if kind == "window" else t
+                     for kind, t in tables)
+
+    def _kind_pools(self, kind: str) -> tuple:
+        k, P = self.kinds.index(kind), len(self.line_widths)
+        return self._pools[k * P:(k + 1) * P]
+
+    def _set_kind_pools(self, kind: str, pools) -> None:
+        k, P = self.kinds.index(kind), len(self.line_widths)
+        self._pools = (*self._pools[:k * P], *pools,
+                       *self._pools[(k + 1) * P:])
 
     # the two-pool (keys, values) family's pools by their old names
     @property
@@ -429,28 +557,50 @@ class PagedLMEngine(DecodeEngine):
     # -- page bookkeeping -----------------------------------------------------
     def _ensure_writable(self, slot: int, lo: int, hi: int) -> None:
         """Make blocks covering logical positions [lo, hi) exclusively
-        owned by ``slot``: allocate missing pages, COW-copy shared ones.
-        Raises PagePoolExhausted (caller sheds or preempts)."""
+        owned by ``slot`` in every kind of layer: allocate missing pages,
+        COW-copy shared ones. Raises PagePoolExhausted (caller sheds or
+        preempts)."""
         if hi <= lo:
             return
-        for b in range(lo // self.page_size,
-                       (hi - 1) // self.page_size + 1):
-            page = int(self._bt[slot, b])
-            if page == 0:
-                # ownership lands in the block table atomically with the
-                # alloc: release(slot) walks _bt on every exit path
-                # nnlint: disable=NNL302
-                self._bt[slot, b] = self.pool.alloc(1)[0]  # pairs-with: release (slot exit)
-            elif self.pool.is_shared(page):
-                new = self.pool.alloc(1)[0]  # pairs-with: release (slot exit)
-                try:
-                    self._pools = self._copy_page(new, page, *self._pools)
-                except BaseException:
-                    self.pool.release([new])  # copy failed: page never owned
-                    raise
-                self.pool.release([page])  # drop OUR ref; sibling keeps its page
-                self._bt[slot, b] = new
-                self.pool.note_cow()
+        blocks = range(lo // self.page_size, (hi - 1) // self.page_size + 1)
+        for kind, pool in self.pools_by_kind.items():
+            bt = self._bts[kind]
+            for b in blocks:
+                page = int(bt[slot, b])
+                if page == 0:
+                    # ownership lands in the block table atomically with
+                    # the alloc: release(slot) walks the tables on every
+                    # exit path
+                    # nnlint: disable=NNL302
+                    bt[slot, b] = pool.alloc(1)[0]  # pairs-with: release (slot exit)
+                elif pool.is_shared(page):
+                    new = pool.alloc(1)[0]  # pairs-with: release (slot exit)
+                    try:
+                        self._set_kind_pools(kind, self._movers[kind]["copy"](
+                            new, page, *self._kind_pools(kind)))
+                    except BaseException:
+                        pool.release([new])  # copy failed: page never owned
+                        raise
+                    pool.release([page])  # drop OUR ref; sibling keeps its page
+                    bt[slot, b] = new
+                    pool.note_cow()
+
+    def _release_behind(self, slot: int, pos: int) -> None:
+        """Give back the pages of ``slot``'s window layers that no query at
+        ``pos`` or later can see: the blocks wholly below ``pos - window +
+        1``. The full layers keep everything."""
+        if "window" not in self.kinds:
+            return
+        keep = max(pos - self.family.window + 1, 0) // self.page_size
+        first = int(self._held_from[slot])
+        if keep <= first:
+            return
+        row = self._bts["window"][slot]
+        gone = [int(p) for p in row[first:keep] if p]
+        self.pools_by_kind["window"].release(gone)  # pairs-with: alloc (_ensure_writable)
+        row[first:keep] = 0
+        self._held_from[slot] = keep
+        self.window_pages_released += len(gone)
 
     def _note_counts(self, call: str, counts) -> dict:
         """Add what the family's expert layers counted in one call of a
@@ -481,6 +631,8 @@ class PagedLMEngine(DecodeEngine):
         expert family's layers counted (``layer_counts``), its two
         programs added up."""
         total = dict(self.attn_pages)
+        if "window" in self.kinds:
+            total["window_pages_released"] = self.window_pages_released
         for counts in self.layer_counts.values():
             for k, v in counts.items():
                 total[k] = total.get(k, 0) + v
@@ -488,9 +640,12 @@ class PagedLMEngine(DecodeEngine):
 
     def projected_page_bytes(self, tokens: int, steps: int) -> int:
         """Worst-case pool bytes a request needs (no sharing assumed) —
-        the AdmissionGuard reservation unit (pages, not dense slots)."""
+        the AdmissionGuard reservation unit (pages, not dense slots): by
+        kind of layer, its pages at their bytes; a window layer holds
+        ``held_blocks["window"]`` at most however long the request."""
         n = -(-(tokens + steps) // self.page_size)
-        return n * self.page_bytes
+        return sum(min(n, self.held_blocks[kind]) * pool.page_bytes
+                   for kind, pool in self.pools_by_kind.items())
 
     # -- scheduler contract ---------------------------------------------------
     def validate(self, tokens: np.ndarray, steps: int) -> None:
@@ -544,13 +699,14 @@ class PagedLMEngine(DecodeEngine):
         attrs = {"slot": slot, "start": start, "n_valid": n_valid}
         with obs_context.span("engine.chunk.prepare", width=self.chunk,
                               **attrs) as prepare:
+            self._release_behind(slot, start)
             self._ensure_writable(slot, start, start + n_valid)
             padded = np.zeros((self.chunk,), np.int32)
             padded[:n_valid] = tokens[start:start + n_valid]
         with obs_context.span("engine.chunk.dispatch", **attrs) as dispatch:
             logits, *rest = self._prefill_chunk(
                 jnp.asarray(padded), jnp.asarray(start, jnp.int32),
-                jnp.asarray(n_valid, jnp.int32), self._bt[slot],
+                jnp.asarray(n_valid, jnp.int32), *self._tables(slot),
                 *self._pools)
             if self.family.counters:
                 # pulled with the next answer that is pulled anyway (this
@@ -597,20 +753,37 @@ class PagedLMEngine(DecodeEngine):
         with obs_context.span("engine.step.prepare", live=live) as prepare:
             for s in slots:
                 if self._pos[s] < self.max_seq:
+                    self._release_behind(int(s), int(self._pos[s]))
                     self._ensure_writable(int(s), int(self._pos[s]),
                                           int(self._pos[s]) + 1)
             # how far the step's attention follows what is visible: the
             # pages the live slots hold against every slot's whole table
+            # (by kind of layer where kinds differ: a window layer reads
+            # from the window's first page on)
             seen = np.minimum(self._pos[slots] + 1, self.max_seq)
-            read = int((-(-seen // self.page_size)).sum())
+            by_kind = {"full": int((-(-seen // self.page_size)).sum())}
+            if "window" in self.kinds:
+                skipped = np.maximum(seen - self.family.window, 0)
+                by_kind["window"] = by_kind["full"] - int(
+                    (skipped // self.page_size).sum())
+            read = round(sum(by_kind[kind] * n for kind, n
+                             in self.kind_layers.items())
+                         / self.family.layers)
             padded = self.slots * self.blocks_per_slot
             prepare.attrs.update(pages_read=read, pages_padded=padded)
             self.attn_pages["attn_pages_read"] += read
             self.attn_pages["attn_pages_padded"] += padded
+            if len(self.kinds) > 1:
+                for kind in self.kinds:
+                    prepare.attrs[f"pages_read_{kind}"] = by_kind[kind]
+                    self.attn_pages[f"attn_pages_read_{kind}"] += \
+                        by_kind[kind]
+                prepare.attrs["window_pages_released"] = \
+                    self.window_pages_released
         with obs_context.span("engine.step.dispatch", live=live) as dispatch:
             tok_dev, self._tok_dev, self._pos_dev, *pools = self._step(
                 self._tok_dev, self._pos_dev, self._mask_dev,
-                self._bt, *self._pools)
+                *self._tables(), *self._pools)
             self._pools = tuple(pools)
         with obs_context.span("engine.step.pull", live=live) as pull:
             # nnlint: disable=NNL101 — one (slots,) pull per decode step:
@@ -659,55 +832,86 @@ class PagedLMEngine(DecodeEngine):
             self._tok[s, 0] = int(pred[s, n - 1])
         return pred, n_emit
 
+    def _drop_pages(self, slot: int) -> None:
+        """Return every page ``slot`` holds, of every kind of layer."""
+        for kind, pool in self.pools_by_kind.items():
+            bt = self._bts[kind]
+            pool.release([int(p) for p in bt[slot] if p])  # pairs-with: alloc/ref (admit path)
+            bt[slot] = 0
+        self._held_from[slot] = 0
+
     def release(self, slot: int) -> None:
         with obs_context.span("engine.release", slot=slot):
             self._pending.pop(slot, None)
             self._lane.pop(slot, None)
-            self.pool.release([int(p) for p in self._bt[slot] if p])  # pairs-with: alloc/ref (admit path)
-            self._bt[slot] = 0
+            self._drop_pages(slot)
             self._mask[slot] = False
             self._tok[slot, 0] = 0
             self._pos[slot] = 0
             self._sync_device_state()
 
     # -- preemption -----------------------------------------------------------
+    def _held_span(self, kind: str, slot: int) -> slice:
+        """The blocks of ``slot``'s table that may hold a page: all of a
+        full layer's, ``held_blocks`` from the first one held of a window
+        layer's."""
+        n = self.held_blocks[kind]
+        first = 0 if kind == "full" else min(int(self._held_from[slot]),
+                                             self.blocks_per_slot - n)
+        return slice(first, first + n)
+
     def preempt(self, slot: int) -> dict:
-        """Evict a slot to host: pull its pages, free them, deactivate.
-        The returned blob restores the request byte-exact later —
-        deadline-aware memory pressure never DROPS work (contract with
-        the scheduler + obs/memory watermark events)."""
+        """Evict a slot to host: pull the pages it holds of every kind of
+        layer, free them, deactivate. The returned blob restores the
+        request byte-exact later — deadline-aware memory pressure never
+        DROPS work (contract with the scheduler + obs/memory watermark
+        events)."""
         if not self._mask[slot]:
             raise ServingError(f"slot {slot} not active")
-        used = self._bt[slot] != 0
-        blobs = self._gather_pages(self._bt[slot], *self._pools)
-        # nnlint: disable=NNL101 — preemption IS the host transfer: the
-        # victim's pages move to host RAM so the pool can be re-used;
-        # restore uploads the same bytes
-        blob = {"pages": tuple(self._jax.device_get(b) for b in blobs),
-                "used": used.copy(), "tok": int(self._tok[slot, 0]),
-                "pos": int(self._pos[slot])}
-        self.pool.release([int(p) for p in self._bt[slot] if p])  # pairs-with: alloc/ref (admit path)
-        self._bt[slot] = 0
+        blob = {"pages": (), "used": {}, "held_from": int(
+                    self._held_from[slot]),
+                "tok": int(self._tok[slot, 0]), "pos": int(self._pos[slot])}
+        for kind in self.kinds:
+            row = self._bts[kind][slot, self._held_span(kind, slot)]
+            blobs = self._movers[kind]["gather"](row,
+                                                 *self._kind_pools(kind))
+            # nnlint: disable=NNL101 — preemption IS the host transfer: the
+            # victim's pages move to host RAM so the pool can be re-used;
+            # restore uploads the same bytes
+            blob["pages"] += tuple(self._jax.device_get(b) for b in blobs)
+            blob["used"][kind] = row != 0
+        self._drop_pages(slot)
         self._mask[slot] = False
         self._sync_device_state()
         self.pool.note_preemption()
         return blob
 
     def restore(self, slot: int, blob: dict) -> None:
-        """Re-admit a preempted request: fresh pages, byte-exact upload,
-        decode resumes mid-sequence. Raises PagePoolExhausted if the
-        pool still cannot hold it (scheduler keeps it queued)."""
+        """Re-admit a preempted request: fresh pages of every kind, byte-
+        exact upload, decode resumes mid-sequence. Raises PagePoolExhausted
+        if a pool still cannot hold it (scheduler keeps it queued)."""
         if self._mask[slot]:
             raise ServingError(f"slot {slot} already active")
-        used = blob["used"]
-        fresh = self.pool.alloc(int(used.sum()))  # pairs-with: release (slot exit)
-        row = np.zeros_like(self._bt[slot])
-        row[used] = fresh
-        self._bt[slot] = row
-        dest = self._jnp.asarray(row)
-        self._pools = self._scatter_pages(
-            dest, tuple(self._jnp.asarray(b) for b in blob["pages"]),
-            *self._pools)
+        fresh = {}
+        try:
+            for kind, pool in self.pools_by_kind.items():
+                fresh[kind] = pool.alloc(int(blob["used"][kind].sum()))  # pairs-with: release (slot exit)
+        except BaseException:
+            for kind, got in fresh.items():  # all kinds or none
+                self.pools_by_kind[kind].release(got)
+            raise
+        self._held_from[slot] = blob["held_from"]
+        P = len(self.line_widths)
+        for k, kind in enumerate(self.kinds):
+            row = np.zeros((self.held_blocks[kind],), np.int32)
+            row[blob["used"][kind]] = fresh[kind]
+            self._bts[kind][slot] = 0
+            self._bts[kind][slot, self._held_span(kind, slot)] = row
+            self._set_kind_pools(kind, self._movers[kind]["scatter"](
+                self._jnp.asarray(row),
+                tuple(self._jnp.asarray(b)
+                      for b in blob["pages"][k * P:(k + 1) * P]),
+                *self._kind_pools(kind)))
         self._tok[slot, 0] = blob["tok"]
         self._pos[slot] = blob["pos"]
         self._mask[slot] = True
@@ -734,10 +938,18 @@ class PagedLMEngine(DecodeEngine):
                 "pages_shared": s["pages_shared"],
                 "page_bytes": self.page_bytes,
                 "line_widths": list(self.line_widths),
-                "token_bytes": self.token_bytes}
+                "token_bytes": self.token_bytes,
+                "kinds": {kind: {"layers": self.kind_layers[kind],
+                                 "pages_total": pool.pages,
+                                 "pages_used": pool.used_pages,
+                                 "page_bytes": pool.page_bytes,
+                                 "bytes": (pool.pages + 1) * pool.page_bytes}
+                          for kind, pool in self.pools_by_kind.items()}}
 
     def close(self) -> None:
         for slot in range(self.slots):
-            if self._mask[slot] or self._bt[slot].any():
+            if self._mask[slot] or any(bt[slot].any()
+                                       for bt in self._bts.values()):
                 self.release(slot)
-        self.pool.close()
+        for pool in self.pools_by_kind.values():
+            pool.close()

@@ -110,6 +110,12 @@ class ServingMetrics:
         # (PagedLMEngine.attn_pages), summed over steps
         self.attn_pages_read = 0       # pages the live slots held
         self.attn_pages_padded = 0     # slots x blocks a slot may hold
+        # by kind of layer, where an engine's family has two kinds: what a
+        # full layer's and a window layer's attention read, and the pages
+        # given back behind the window
+        self.attn_pages_read_full = 0
+        self.attn_pages_read_window = 0
+        self.window_pages_released = 0
         # device channel: batch execution time (dispatch+block, the
         # reference-comparable number); reservoirs: per-request tails
         self.device = InvokeStats()
@@ -196,6 +202,12 @@ class ServingMetrics:
         with self._lock:
             self.attn_pages_read += counts.get("attn_pages_read", 0)
             self.attn_pages_padded += counts.get("attn_pages_padded", 0)
+            self.attn_pages_read_full += counts.get(
+                "attn_pages_read_full", 0)
+            self.attn_pages_read_window += counts.get(
+                "attn_pages_read_window", 0)
+            self.window_pages_released += counts.get(
+                "window_pages_released", 0)
             self.moe_experts_touched += counts.get("moe_experts_touched", 0)
             self.moe_expert_slots += counts.get("moe_expert_slots", 0)
             self.moe_assignments += counts.get("moe_assignments", 0)
@@ -246,6 +258,9 @@ class ServingMetrics:
                 "moe_max_load": self.moe_max_load,
                 "attn_pages_read": self.attn_pages_read,
                 "attn_pages_padded": self.attn_pages_padded,
+                "attn_pages_read_full": self.attn_pages_read_full,
+                "attn_pages_read_window": self.attn_pages_read_window,
+                "window_pages_released": self.window_pages_released,
             }
         out["device"] = self.device.snapshot()
         out["queue_wait"] = self.queue_wait.snapshot()

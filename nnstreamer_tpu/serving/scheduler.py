@@ -559,6 +559,8 @@ class DecodeScheduler:
         pool = self.engine.pool
         if pool is not None:
             snap["kv_pool"] = pool.stats()
+            snap["kv_pools"] = {kind: p.stats() for kind, p
+                                in self.engine.pools_by_kind.items()}
         if self._step_tokens is not None:  # a burst engine counts its rounds
             snap["spec_acceptance_rate"] = self.engine.acceptance_rate()
             snap["spec_rounds"] = self.engine.spec_rounds

@@ -106,6 +106,17 @@ class TestLegsAtCpuSize:
         assert out["served_gap_max"] <= 1e-4  # float32 on the CPU
         assert out["moe_experts_touched"] > 0
 
+    def test_window_serving_leg_counts(self):
+        import chip_smoke
+
+        out = chip_smoke.window_serving_leg(serve_dtype="float32")
+        assert out["completed"] == out["requests"] == 6
+        # step + prefill_chunk: no page is shared, so nothing is copied
+        assert out["compile_count"] == 2
+        assert out["window_pages_released"] > 0
+        assert out["served_gap_max"] <= 1e-4  # float32 on the CPU
+        assert out["moe_experts_touched"] > 0
+
     def test_kernels_leg_interpreted(self):
         import chip_smoke
 

@@ -695,6 +695,83 @@ class TestPoolInPlaceOnTpu:
             assert not made, f"_step expands keys or values per head: {made}"
             _no_gathered_context(compiled, S * ctx * 640)
 
+    @pytest.mark.parametrize("program, C, max_seq", PROGRAMS)
+    def test_pools_by_layer_kind_go_in_and_come_out_in_one_layout(
+            self, v5e_chip, program, C, max_seq, monkeypatch):
+        # the same rules for a family with two kinds of layer (Mellum2's
+        # line of 4 x 128 keys and as many values, window 1024, at a small
+        # depth, hidden size and expert count): four pool arrays, the full
+        # kind's of 2049 rows a layer and the window kind's of 513, each
+        # aliased to its output, none copied or sliced by layer, and the
+        # step's kernel taking both tables
+        import functools
+
+        import jax
+        import jax.numpy as jnp
+
+        from nnstreamer_tpu.models.mellum import MellumConfig, init_params
+
+        max_seq = max_seq * 8  # past the window: 1024 or 4096 positions
+        cfg = MellumConfig(
+            vocab_size=512, hidden_size=256, num_hidden_layers=4,
+            num_attention_heads=32, num_key_value_heads=4, head_dim=128,
+            moe_intermediate_size=128, num_experts=8, num_experts_per_tok=2,
+            sliding_window=1024, max_position_embeddings=max_seq,
+            rope_parameters={
+                "full_attention": {
+                    "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+                    "original_max_position_embeddings": 8192,
+                    "beta_fast": 32, "beta_slow": 1,
+                    "attention_factor": 1.2772588722239782},
+                "sliding_attention": {"rope_type": "default",
+                                      "rope_theta": 500000}})
+        S, pg = 4, 16
+        monkeypatch.setattr(paged_attention, "paged_line_attention",
+                            paged_attention.kernel_line_attention)
+        eng = PagedLMEngine(cfg, {"embed": jnp.zeros((1, 1), jnp.bfloat16)},
+                            slots=S, page_size=pg, chunk=C,
+                            pages={"full": 2048, "window": 512},
+                            share_prefixes=False)
+        assert eng.kinds == ("full", "window") and len(eng._pools) == 4
+        assert eng.line_widths == (512, 512)  # four whole lane rows
+        assert [p.shape[0] for p in eng._pools] == [2049, 2049,
+                                                    3 * 513, 3 * 513]
+
+        def shape(s, dt):
+            return jax.ShapeDtypeStruct(s, dt, sharding=v5e_chip)
+
+        params = jax.tree_util.tree_map(
+            lambda a: shape(a.shape, jnp.bfloat16),
+            jax.eval_shape(functools.partial(init_params, cfg)))
+        NB = max_seq // pg
+        pools = [shape(p.shape, jnp.bfloat16) for p in eng._pools]
+        if program == "_step":
+            args = (shape((S, 1), jnp.int32), shape((S,), jnp.int32),
+                    shape((S,), jnp.bool_), shape((S, NB), jnp.int32),
+                    shape((S, NB), jnp.int32))
+        else:
+            args = (shape((C,), jnp.int32), shape((), jnp.int32),
+                    shape((), jnp.int32), shape((NB,), jnp.int32),
+                    shape((NB,), jnp.int32))
+        compiled = getattr(eng, program).func.lower(
+            params, *args, *pools).compile()
+
+        counts = {int(np.prod(p.shape)) for p in eng._pools}
+        sizes = counts | {int(np.prod(eng._pools[0].shape)),
+                          int(np.prod(eng._pools[2].shape)) // 3}
+        moved = [f"{op} {name}"
+                 for name, op, seen in _entry_results(compiled.as_text())
+                 if seen & sizes
+                 and (op == "copy" or "slice" in name or "copy" in name)]
+        assert not moved, \
+            f"{program} copies or slices a pool or a layer of it: {moved}"
+        aliased = compiled.memory_analysis().alias_size_in_bytes
+        assert aliased >= sum(int(np.prod(p.shape)) for p in eng._pools) * 2, \
+            f"{program} must alias all four donated pools to its outputs"
+        if program == "_step":
+            assert compiled.as_text().count("paged_line_attention") >= 4
+            _no_gathered_context(compiled, S * max_seq * 512)
+
 
 # ---------------------------------------------------------------------------
 # page lifecycle — refcounts reach zero on EVERY scheduler exit path

@@ -3,6 +3,10 @@
 * the Pallas kernel, interpreted on the CPU, against the op's plain form
   (gather, mask, softmax): two pools of one width and one pool as both,
   lengths at every edge of a page and of a block, a shuffled block table;
+* the same with a first visible position a slot (a window layer's step):
+  starts at every edge of a page and of a block, table entries below the
+  start naming the null page; a start of 0 is the result without one, bit
+  for bit in the plain form;
 * an engine step through the kernel emits the tokens the plain form's step
   emits, for both model families;
 * what ``GPTFamily._attend_step`` used to be held to: the family's step
@@ -65,6 +69,80 @@ def test_kernel_matches_the_plain_form(pools, lengths):
                                        interpret=True)
     np.testing.assert_allclose(np.asarray(derived), np.asarray(want),
                                atol=2e-6, rtol=0)
+
+
+# (lengths, starts): the head of the first block masked, blocks below it
+# not read, at every edge of a page (8) and of a block of two pages (16)
+WINDOWS = {
+    "start_0": ([MAX_SEQ, 2 * PG + 3, 5], [0, 0, 0]),
+    "inside_first_page": ([MAX_SEQ, 2 * PG + 3, 5], [3, 1, 4]),
+    "at_a_page": ([MAX_SEQ, 3 * PG, 2 * PG], [PG, 2 * PG, PG]),
+    "at_a_block": ([MAX_SEQ, 4 * PG + 1, 2 * PG + 1],
+                   [2 * PG, 4 * PG, 2 * PG]),
+    "block_less_one": ([MAX_SEQ, 4 * PG, 0], [2 * PG - 1, 4 * PG - 1, 0]),
+    "last_position_only": ([MAX_SEQ, 2 * PG + 3, 1],
+                           [MAX_SEQ - 1, 2 * PG + 2, 0]),
+    "a_window_of_ten": ([MAX_SEQ, 2 * PG + 3, 7],
+                        [MAX_SEQ - 10, 2 * PG + 3 - 10, 0]),
+    "an_empty_slot_between": ([MAX_SEQ, 0, 3 * PG + 2],
+                              [MAX_SEQ - 10, 0, 2 * PG + 2]),
+}
+
+
+@pytest.mark.parametrize("case", WINDOWS, ids=list(WINDOWS))
+@pytest.mark.parametrize("pools", ["keys_and_values", "one_pool_as_both"])
+def test_kernel_matches_the_plain_form_from_a_first_visible_position(
+        pools, case):
+    rng = np.random.default_rng(31)
+    S, H, W = 3, 4, 48
+    kpool = _pool(rng, W)
+    vpool = kpool if pools == "one_pool_as_both" else _pool(rng, W)
+    lengths, starts = (np.asarray(x) for x in WINDOWS[case])
+    bt = np.stack([rng.permutation(PAGES)[:NB] + 1 for _ in range(S)])
+    # the pages behind the window were given back: their entries are 0
+    bt[np.arange(NB)[None, :] < (starts // PG)[:, None]] = 0
+    rows = jnp.asarray((PAGES + 1) + bt, jnp.int32)
+    q = jnp.asarray(rng.standard_normal((S, H, W)), jnp.float32)
+    seen, first = jnp.asarray(lengths, jnp.int32), jnp.asarray(starts,
+                                                               jnp.int32)
+
+    want = pa.plain_line_attention(q, kpool, vpool, rows, seen, 0.25, first)
+    # the oracle's own meaning, spelled out for one slot: softmax over
+    # positions start..length-1 and no others
+    s0 = 0
+    k = np.asarray(pa.gathered_lines(kpool, rows), np.float32)[s0]
+    v = np.asarray(pa.gathered_lines(vpool, rows), np.float32)[s0]
+    lo, hi = int(starts[s0]), int(lengths[s0])
+    sc = (np.asarray(q)[s0] @ k[lo:hi].T) * 0.25
+    w = np.exp(sc - sc.max(-1, keepdims=True))
+    np.testing.assert_allclose(
+        np.asarray(want)[s0], (w / w.sum(-1, keepdims=True)) @ v[lo:hi],
+        atol=2e-5, rtol=0)
+    for blocks in (2, None):  # two pages a block; the derived block size
+        got = pa.kernel_line_attention(q, kpool, vpool, rows, seen, 0.25,
+                                       first, pages_per_block=blocks,
+                                       interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-6, rtol=0)
+        assert not np.asarray(got)[lengths == 0].any()
+
+
+def test_a_start_of_zero_is_the_plain_form_without_one_bit_for_bit():
+    rng = np.random.default_rng(32)
+    S, H, W = 3, 4, 48
+    kpool, vpool = _pool(rng, W), _pool(rng, W)
+    bt = np.stack([rng.permutation(PAGES)[:NB] + 1 for _ in range(S)])
+    rows = jnp.asarray(bt, jnp.int32)
+    q = jnp.asarray(rng.standard_normal((S, H, W)), jnp.float32)
+    seen = jnp.asarray([MAX_SEQ, 2 * PG + 3, 0], jnp.int32)
+    without = pa.plain_line_attention(q, kpool, vpool, rows, seen, 0.25)
+    zeros = pa.plain_line_attention(q, kpool, vpool, rows, seen, 0.25,
+                                    jnp.zeros((S,), jnp.int32))
+    np.testing.assert_array_equal(np.asarray(zeros), np.asarray(without))
+    kernel = [np.asarray(pa.kernel_line_attention(
+        q, kpool, vpool, rows, seen, 0.25, first, pages_per_block=2,
+        interpret=True)) for first in (None, jnp.zeros((S,), jnp.int32))]
+    np.testing.assert_array_equal(kernel[0], kernel[1])
 
 
 def _gpt():
