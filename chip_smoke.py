@@ -22,7 +22,9 @@ out by the repo's own means. One process, no child that needs the chip.
            the third model family (Mellum-shaped: grouped-query lines,
            window layers that give their pages back beside full layers that
            keep them, dropless softmax top-k experts) the same way, at its
-           benchmark configuration's rehearsal sizes;
+           benchmark configuration's rehearsal sizes with hidden and expert
+           widths of 128: whole lanes, so the programs hold the experts'
+           kernel (both legs' report says the form, ``experts_form``);
   kernels  both Pallas kernels, Mosaic-lowered, at base geometry.
 
 It refuses to run anywhere but on a TPU, prints no result there, and exits
@@ -418,6 +420,27 @@ def serving_leg(entry=None, slots: int = 8, steps: int = 32,
     return out
 
 
+#: the window leg's widths over its configuration's rehearsal sizes: whole
+#: lanes, so that on a TPU its programs hold the experts' kernel
+#: (``ops/moe_grouped.py``) as the benchmark's do, not the grouped product
+#: a width of 32 falls back to. The latent leg keeps its rehearsal widths
+#: and so the grouped product: at 128 its seed has a near tie in the router
+#: (sigmoid weights scaled by 2.448 over two of eight experts: an expert
+#: swapped moves a logit by far more than a rounding does), and on the chip
+#: a served token lay 0.0894462 under the reference's best through the
+#: kernel and through the grouped product alike, 0.0 on two other seeds
+#: (PR 32)
+EXPERT_WIDTHS = {"hidden_size": 128, "moe_intermediate_size": 128}
+
+
+def _experts_form(slots: int, cfg, serve_dtype: str) -> str:
+    """The form the leg's decode step holds its expert layers in."""
+    from nnstreamer_tpu.ops import moe_grouped
+
+    return moe_grouped.form(slots, cfg.hidden_size,
+                            cfg.moe_intermediate_size, serve_dtype)
+
+
 def latent_serving_leg(serve_dtype: str = "bfloat16", slots: int = 4,
                        steps: int = 12, lengths=(37, 5, 20, 9, 30, 14),
                        seed: int = 27) -> dict:
@@ -462,7 +485,8 @@ def latent_serving_leg(serve_dtype: str = "bfloat16", slots: int = 4,
            "compile_count": snap["compile_count"],
            "line_widths": snap["kv_pool"]["line_widths"],
            "moe_assignments": snap["moe_assignments"],
-           "moe_experts_touched": snap["moe_experts_touched"]}
+           "moe_experts_touched": snap["moe_experts_touched"],
+           "experts_form": _experts_form(slots, cfg, serve_dtype)}
     check(snap["completed"] == len(prompts), f"latent: {snap['completed']} "
                                              f"of {len(prompts)} completed")
     check(engine.pool.used_pages == 0, "latent: pages held after close")
@@ -506,6 +530,7 @@ def window_serving_leg(serve_dtype: str = "bfloat16", slots: int = 4,
     are given back on the served path; none of either kind is left."""
     import numpy as np
 
+    import jax
     import jax.numpy as jnp
 
     from benchmark.lib import harness
@@ -516,7 +541,7 @@ def window_serving_leg(serve_dtype: str = "bfloat16", slots: int = 4,
 
     _, config = harness.find_cell(harness.load_benchmark(),
                                   "mellum2_longctx_decode")
-    config = {**config, **config["rehearsal"]}
+    config = {**config, **config["rehearsal"], **EXPERT_WIDTHS}
     cfg = MellumConfig.from_published(config)
     sizes, key = reference.sizes(config), seed_key(seed)
     params = reference.program_params(key, sizes, jnp.dtype(serve_dtype))
@@ -540,12 +565,16 @@ def window_serving_leg(serve_dtype: str = "bfloat16", slots: int = 4,
            "compile_count": snap["compile_count"],
            "window_pages_released": engine.window_pages_released,
            "moe_assignments": snap["moe_assignments"],
-           "moe_experts_touched": snap["moe_experts_touched"]}
+           "moe_experts_touched": snap["moe_experts_touched"],
+           "experts_form": _experts_form(slots, cfg, serve_dtype)}
     check(snap["completed"] == len(prompts), f"window: {snap['completed']} "
                                              f"of {len(prompts)} completed")
     # one step and one chunk program, whatever the lengths
     check(snap["compile_count"] == 2,
           f"window: compile_count {snap['compile_count']}, expected 2")
+    check(out["experts_form"] == "kernel" or jax.default_backend() != "tpu",
+          f"window: the step's expert layers take the form "
+          f"{out['experts_form']!r} on a TPU")
     check(all(p.used_pages == 0 for p in engine.pools_by_kind.values()),
           "window: pages held after close")
     check(engine.window_pages_released > 0,

@@ -3,9 +3,18 @@ and the softmax top-k of ``norm_topk_prob`` models).
 
 ``parallel/moe.py`` is the trainer's switch layer: top-1, a capacity per
 expert, tokens over it dropped. A served model may drop nothing, so this
-layer has no capacity: every token's ``top_k`` assignments are sorted by
-expert and the experts' three matrices are applied as grouped products
-over exactly the rows each expert received (``jax.lax.ragged_dot``).
+layer has no capacity: every one of a token's ``top_k`` assignments is
+served, whatever the routing. ``experts_ffn`` says which assignments are
+served here and counts them; the products are ``ops/moe_grouped.py``'s, in
+one of two forms chosen there by the platform and the call's static shapes
+and by nothing else. On a TPU a launch of at most 256 rows whose widths
+are whole lanes (a decode step's 32 rows, a prefill launch's 256) runs a
+Pallas kernel that streams each reached expert's three matrices from HBM
+once and offers it every row under the row's router weight, zero where
+not assigned; a wider launch, other widths and the CPU run the
+assignments sorted by expert through three grouped products over exactly
+the rows each expert received (``jax.lax.ragged_dot``), which is also the
+oracle the kernel is pinned to.
 
 Routing, as DeepSeek-V3 publishes it (``scoring_func`` sigmoid,
 ``topk_method`` noaux_tc with one group): scores ``s = sigmoid(W_g h)``
@@ -80,35 +89,20 @@ def experts_ffn(w_gate, w_up, w_down, h, experts, weights, live=None,
     import jax
     import jax.numpy as jnp
 
+    from ..ops import moe_grouped
+
     with jax.named_scope("moe.experts"):
-        T, k = experts.shape
         held = w_gate.shape[0]
         local = experts - first_expert
         here = (local >= 0) & (local < held)
         if live is not None:
             here = here & live[:, None]
-        # assignments not served here sort behind every held expert's,
-        # past the last group: ragged_dot leaves their rows zero
-        flat = jnp.where(here, local, held).reshape(T * k)
-        order = jnp.argsort(flat, stable=True)
-        sizes = jnp.zeros((held + 1,), jnp.int32).at[flat].add(1)[:held]
-        rows = h[order // k]                               # (T*k, D)
-
-        def product(x, w):
-            # activations take the weights' type for the grouped product
-            # (on the MXU a default-precision float32 product rounds them
-            # to bfloat16 anyway); sums are kept in float32. The precision
-            # is said outright: under a raised default the TPU's grouped
-            # kernel refuses bfloat16 operands ("Bad lhs type")
-            return jax.lax.ragged_dot(
-                x.astype(w.dtype), w, sizes,
-                precision=jax.lax.Precision.DEFAULT,
-                preferred_element_type=jnp.float32)
-
-        out = gated_mlp(rows, w_gate, w_up, w_down, product)  # (T*k, D)
-        back = jnp.argsort(order)                           # undo the sort
-        out = out[back].reshape(T, k, -1)
-        y = jnp.where(here[..., None], out * weights[..., None], 0.0).sum(1)
+        # an assignment not served here names the expert past the last held
+        flat = jnp.where(here, local, held)
+        sizes = jnp.zeros((held + 1,), jnp.int32).at[flat.reshape(-1)].add(
+            1)[:held]
+        y = moe_grouped.grouped_experts(h, w_gate, w_up, w_down, flat,
+                                        weights, sizes)
         counts = jnp.stack([(sizes > 0).sum(), sizes.sum(), sizes.max(),
                             jnp.int32(held)])
         return y, counts.astype(jnp.int32)

@@ -135,6 +135,44 @@ def test_chunked_prefill_then_decode_matches_the_reference_forward():
         sum(p.size for p in prompts) * 2 * 2
 
 
+def test_served_through_the_experts_kernel_matches_the_reference(monkeypatch):
+    """Both programs with the expert layers in the form a TPU runs
+    (``ops/moe_grouped.py``'s kernel, interpreted here), at widths that
+    tile by 128 lanes: the served tokens are the reference's."""
+    import functools
+
+    from nnstreamer_tpu.ops import moe_grouped
+
+    rows, real = [], moe_grouped.kernel_grouped_experts
+
+    def kernel(h, *args, **kw):
+        rows.append(h.shape[0])
+        return real(h, *args, **kw)
+
+    monkeypatch.setattr(moe_grouped, "kernel_grouped_experts", kernel)
+    monkeypatch.setattr(moe_grouped, "grouped_experts", functools.partial(
+        moe_grouped.tpu_grouped_experts, interpret=True))
+    cfg, sz, key, params = _model(hidden_size=128, moe_intermediate_size=128)
+    eng = _entry(cfg, params).make_continuous(
+        slots=3, page_size=4, chunk=8, pages=48)
+    sched = DecodeScheduler(eng, name="dsv3-kernel")
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 96, n).astype(np.int32) for n in (19, 7, 12)]
+    try:
+        reqs = [sched.submit(p, steps=8) for p in prompts]
+        outs = [np.asarray(r.result(timeout=300)[0]) for r in reqs]
+    finally:
+        sched.close()
+    # traced once a program and expert layer: a step of 3 rows, a launch of 8
+    assert sorted(rows) == [3, 3, 8, 8]
+    for prompt, served in zip(prompts, outs):
+        exact = _reference_logits(key, sz, prompt, served)
+        gap = exact.max(-1) - np.take_along_axis(
+            exact, served[:, None], 1)[:, 0]
+        assert gap.max() <= GAP_TOL, "a served token is not the reference's"
+    assert eng.layer_counts["step"]["moe_assignments"] == 3 * 7 * 2 * 2
+
+
 def test_a_bfloat16_cache_would_fail():
     # the same weights (exact in bfloat16) served with bfloat16 cache lines
     # and products: the chunk's logits leave the reference by far more than

@@ -773,6 +773,101 @@ class TestPoolInPlaceOnTpu:
             _no_gathered_context(compiled, S * max_seq * 512)
 
 
+class TestExpertsStreamOnTpu:
+    """What the compiled programs of the two expert families hold on a
+    TPU (PR 32): the record of where ``ops/moe_grouped.py``'s kernel
+    engages. The benchmark configurations' widths, experts and slots at
+    two layers (four: one period of Mellum's) and a short serving limit,
+    compiled for a described v5e with both kernels in (this process's
+    backend is the CPU, where either op would take its plain form)."""
+
+    # family, program, rows of the launch, the form its expert layers hold
+    PROGRAMS = [
+        ("mellum2_12b_a2.5b_l12", "_step", 32, "kernel"),
+        ("mellum2_12b_a2.5b_l12", "_prefill_chunk", 256, "kernel"),
+        ("mellum2_12b_a2.5b_l12", "_prefill_chunk", 512, "ragged-dot"),
+        ("kanana2_30b_a3b_l8", "_step", 32, "kernel"),
+        ("kanana2_30b_a3b_l8", "_prefill_chunk", 256, "kernel"),
+    ]
+
+    @pytest.mark.parametrize("name, program, rows, form", PROGRAMS)
+    def test_the_expert_layers_stream_the_stacks_as_they_lie(
+            self, v5e_chip, name, program, rows, form, monkeypatch):
+        import functools
+        import json
+        import os
+
+        import jax
+        import jax.numpy as jnp
+
+        from nnstreamer_tpu.models import deepseek_v3, mellum
+        from nnstreamer_tpu.ops import moe_grouped
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "benchmark", "configs",
+                               f"{name}.json")) as f:
+            conf = json.load(f)
+        conf.update(max_position_embeddings=2048)
+        if name.startswith("mellum"):  # one period: three window, one full
+            conf.update(num_hidden_layers=4)
+            cfg = mellum.MellumConfig.from_published(conf)
+            init, pages = mellum.init_params, {"full": 256, "window": 256}
+            E, D, F, layers = (cfg.num_experts, cfg.hidden_size,
+                               cfg.moe_intermediate_size, 4)
+        else:  # layer 0 is the dense one
+            conf.update(num_hidden_layers=2)
+            cfg = deepseek_v3.DeepseekV3Config.from_published(conf)
+            init, pages = deepseek_v3.init_params, 256
+            E, D, F, layers = (cfg.n_routed_experts, cfg.hidden_size,
+                               cfg.moe_intermediate_size, 1)
+        monkeypatch.setattr(paged_attention, "paged_line_attention",
+                            paged_attention.kernel_line_attention)
+        monkeypatch.setattr(moe_grouped, "grouped_experts",
+                            moe_grouped.tpu_grouped_experts)
+        S, pg = conf["engine"]["slots"], conf["engine"]["page_size"]
+        eng = PagedLMEngine(cfg, {"embed": jnp.zeros((1, 1), jnp.bfloat16)},
+                            slots=S, page_size=pg, chunk=rows, pages=pages,
+                            share_prefixes=False)
+        assert S == 32 and eng.chunk == rows
+
+        def shape(s, dt):
+            return jax.ShapeDtypeStruct(s, dt, sharding=v5e_chip)
+
+        params = jax.tree_util.tree_map(
+            lambda a: shape(a.shape, jnp.bfloat16),
+            jax.eval_shape(functools.partial(init, cfg)))
+        NB, K = eng.blocks_per_slot, len(eng.kinds)
+        pools = [shape(p.shape, jnp.bfloat16) for p in eng._pools]
+        if program == "_step":
+            args = (shape((S, 1), jnp.int32), shape((S,), jnp.int32),
+                    shape((S,), jnp.bool_),
+                    *[shape((S, NB), jnp.int32)] * K)
+        else:
+            args = (shape((rows,), jnp.int32), shape((), jnp.int32),
+                    shape((), jnp.int32), *[shape((NB,), jnp.int32)] * K)
+        compiled = getattr(eng, program).func.lower(
+            params, *args, *pools).compile()
+        text = compiled.as_text()
+
+        kernels = [line for line in text.splitlines()
+                   if "custom-call(" in line and "grouped_experts" in line]
+        if form == "kernel":
+            assert len(kernels) == layers and "ragged-dot" not in text
+            for line in kernels:  # the drivers charge device time by this
+                assert re.search(r'op_name="[^"]*/moe\.experts/[^"]*'
+                                 r'grouped_experts', line), line
+        else:
+            assert not kernels and text.count("ragged-dot") >= 3 * layers
+        # no copy, re-layout or conversion of an expert stack: nothing the
+        # program makes has a stack's element count, and its temporaries
+        # are smaller than one stack
+        made = [f"{op} {name_}"
+                for name_, op, counts in _entry_results(text)
+                if E * D * F in counts and op != "parameter"]
+        assert not made, f"{program} makes a copy of an expert stack: {made}"
+        assert compiled.memory_analysis().temp_size_in_bytes < E * D * F * 2
+
+
 # ---------------------------------------------------------------------------
 # page lifecycle — refcounts reach zero on EVERY scheduler exit path
 # ---------------------------------------------------------------------------

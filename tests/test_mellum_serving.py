@@ -229,6 +229,45 @@ def test_chunked_prefill_then_decode_matches_the_reference_forward():
         sum(p.size for p in prompts) * 2 * 4
 
 
+def test_served_through_the_experts_kernel_matches_the_reference(monkeypatch):
+    """Both programs with the expert layers in the form a TPU runs
+    (``ops/moe_grouped.py``'s kernel, interpreted here), at widths that
+    tile by 128 lanes: the served tokens are the reference's, past the
+    window too."""
+    import functools
+
+    from nnstreamer_tpu.ops import moe_grouped
+
+    rows, real = [], moe_grouped.kernel_grouped_experts
+
+    def kernel(h, *args, **kw):
+        rows.append(h.shape[0])
+        return real(h, *args, **kw)
+
+    monkeypatch.setattr(moe_grouped, "kernel_grouped_experts", kernel)
+    monkeypatch.setattr(moe_grouped, "grouped_experts", functools.partial(
+        moe_grouped.tpu_grouped_experts, interpret=True))
+    cfg, sz, key, params = _model(hidden_size=128, moe_intermediate_size=128)
+    eng = _entry(cfg, params).make_continuous(**ENGINE)
+    sched = DecodeScheduler(eng, name="mellum-kernel")
+    rng = np.random.default_rng(1)
+    lengths = [(21, 20), (7, 12), (30, 9)]
+    prompts = [rng.integers(0, 96, n).astype(np.int32) for n, _ in lengths]
+    try:
+        reqs = [sched.submit(p, steps=s)
+                for p, (_, s) in zip(prompts, lengths)]
+        outs = [np.asarray(r.result(timeout=300)[0]) for r in reqs]
+    finally:
+        sched.close()
+    # traced once a program and layer: a step of 3 rows, a launch of 8
+    assert sorted(rows) == [3] * 4 + [8] * 4
+    for prompt, served in zip(prompts, outs):
+        assert _gaps(key, sz, prompt, served).max() <= GAP_TOL, \
+            "a served token is not the reference's"
+    assert eng.layer_counts["chunk"]["moe_assignments"] == \
+        sum(p.size for p in prompts) * 2 * 4
+
+
 def test_decode_steps_logits_match_the_reference_past_the_window():
     """The step program's own logits (not only its argmax) at positions
     one to six windows deep, past YaRN's original length."""
