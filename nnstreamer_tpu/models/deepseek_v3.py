@@ -204,6 +204,7 @@ class DeepseekV3Family:
     attention_scopes = {"full": "mla"}
     window = None          # every layer sees the whole context
     counters = moe_dropless.COUNTERS
+    state_lines = ()       # no layer keeps a state a sequence
     serves_verify = False  # speculative verification: not in this family yet
 
     def __init__(self, cfg: DeepseekV3Config):

@@ -9,11 +9,38 @@ the entry's configuration (:func:`family_of`); no flag names a model.
 A family says:
 
 * ``layer_kinds`` — per layer ``"full"`` (a query sees every earlier
-  position) or ``"window"`` (the last ``window`` positions only), and
+  position), ``"window"`` (the last ``window`` positions only) or
+  ``"state"`` (no attention: a mixer that keeps a state a sequence), and
   ``window``, or ``None`` where no layer has one. The engine keeps a block
-  table, a page allocator and pool arrays per kind present, and gives a
-  window layer's pages back behind the window. ``gpt`` and ``deepseek_v3``
-  say "all full"; ``mellum`` (``models/mellum.py``) has both.
+  table, a page allocator and pool arrays per attention kind present, and
+  gives a window layer's pages back behind the window. ``gpt`` and
+  ``deepseek_v3`` say "all full"; ``mellum`` (``models/mellum.py``) has
+  full and window layers; ``jamba`` (``models/jamba.py``) full and state
+  layers.
+* ``state_lines`` — what a *slot* keeps in every state layer, whatever the
+  sequence's length: one ``(shape, dtype)`` per array, a dtype of ``None``
+  the cache's (``jamba``: the conv's last inputs ``(3 * 5120,)``, flat, and
+  the scan state ``(16, 5120)`` float32, channels last); ``()`` for a
+  family with no state layer. The engine keeps no pages for the kind: a
+  device array ``(state layers, slots, *shape)`` per entry, donated through
+  its programs beside the pools. Two functions, under the
+  ``jax.named_scope``s ``ssm.in``, ``ssm.conv``, ``ssm.x``, ``ssm.scan``,
+  ``ssm.out``:
+
+  - ``mix_step(blk, x, states, layer, live) -> y, states'`` — one token a
+    slot: ``x (S, D)``; ``states`` are the engine's whole arrays, because a
+    step's update of 128 states is worth doing where they lie (a slice
+    handed out and stored back is two more passes over it): the family
+    advances rows ``[layer, s]`` for the slots in ``live (S,)`` and stores
+    for every other slot what it read, bit for bit;
+  - ``mix_chunk(blk, x, n_valid, state) -> y, state'`` — one slot's
+    launch: ``x (C, D)`` whose first ``n_valid`` rows are real, ``state``
+    that slot's rows (the engine has zeroed them where the launch starts a
+    sequence, and stores what comes back); a padded row moves no part of
+    the state.
+
+  Both return what the residual adds. ``project``, ``attend``,
+  ``step_queries`` and ``step_output`` below are an attention layer's.
 * ``attention_scopes`` — per layer kind, the ``jax.named_scope`` the engine
   puts around a layer's projection, cache write, read and attention.
 * ``cache_lines`` — one width per pool: the values a token keeps in a
@@ -47,6 +74,78 @@ from __future__ import annotations
 from .transformer import TransformerConfig, _rmsnorm
 
 
+class GroupedQueryLines:
+    """What the families whose attention layers keep keys and values of
+    ``num_key_value_heads`` heads side by side share (``mellum``,
+    ``jamba``): two lines of ``kv_heads * head_dim`` a token, the step's
+    block-diagonal query over whole lines, and a chunk's attention with its
+    lines split by key head. ``self.cfg`` has ``num_attention_heads``,
+    ``num_key_value_heads``, ``head_dim`` and ``line_width``; the family's
+    ``project`` makes the queries ``(B, Q, H, head_dim)`` (and rotates
+    them, if it has positions)."""
+
+    @property
+    def cache_lines(self) -> tuple:
+        return (self.cfg.line_width, self.cfg.line_width)
+
+    @property
+    def attention_scale(self) -> float:
+        return self.cfg.head_dim ** -0.5
+
+    def _own(self):
+        import jax.numpy as jnp
+
+        # line element j belongs to key head j // head_dim, which query
+        # heads n with n // group == that head read
+        cfg = self.cfg
+        group = cfg.num_attention_heads // cfg.num_key_value_heads
+        return (jnp.arange(cfg.num_attention_heads)[:, None] // group
+                == jnp.arange(cfg.line_width)[None, :] // cfg.head_dim)
+
+    def step_queries(self, q):
+        import jax.numpy as jnp
+
+        # whole lines against a block-diagonal query: row n holds head n's
+        # query in the block of its key head and zeros elsewhere (the
+        # gpt family's form, with ``group`` rows a block)
+        tiled = jnp.tile(q[:, 0], (1, 1, self.cfg.num_key_value_heads))
+        return jnp.where(self._own()[None], tiled, 0.0)
+
+    def step_output(self, blk, o):
+        import jax.numpy as jnp
+
+        # row n of o is head n's weights over every key head's values: the
+        # block of its own key head is the attention output
+        cfg = self.cfg
+        S = o.shape[0]
+        o = jnp.where(self._own()[None], o, 0.0).reshape(
+            S, cfg.num_attention_heads, cfg.num_key_value_heads,
+            cfg.head_dim).sum(axis=2)
+        return o.reshape(S, 1, -1) @ blk["wo"]
+
+    def attend(self, blk, q, ctxs, visible, mode):
+        """One slot's chunk: ``q (1, C, H, head_dim)`` over the gathered
+        lines ``ctxs`` ``(1, ctx, kv_heads * head_dim)`` each, ``visible
+        (C, ctx)``. The context is one slot's, so its lines are split by
+        key head here (and only here)."""
+        import jax
+        import jax.numpy as jnp
+
+        cfg = self.cfg
+        KV, Dh = cfg.num_key_value_heads, cfg.head_dim
+        ck, cv = ctxs
+        C, ctx = q.shape[1], ck.shape[1]
+        exact = jax.lax.Precision.HIGHEST    # f32 queries over a bf16 pool
+        qg = q[0].reshape(C, KV, -1, Dh)
+        att = jnp.einsum("qkgd,ckd->kgqc", qg, ck[0].reshape(ctx, KV, Dh),
+                         precision=exact) * self.attention_scale
+        att = jax.nn.softmax(jnp.where(visible[None, None], att, -1e30),
+                             axis=-1)
+        o = jnp.einsum("kgqc,ckd->qkgd", att, cv[0].reshape(ctx, KV, Dh),
+                       precision=exact)
+        return o.reshape(1, C, -1) @ blk["wo"]
+
+
 class GPTFamily:
     """The repo's GPT-2-shaped block (``models/transformer.py``): learned
     positions, one fused ``wqkv``, full multi-head attention over keys and
@@ -56,6 +155,7 @@ class GPTFamily:
     attention_scopes = {"full": "attention"}
     window = None          # every layer sees the whole context
     counters = ()          # nothing an expert layer would count
+    state_lines = ()       # no layer keeps a state a sequence
     serves_verify = True   # speculative verification (``_verify``)
 
     def __init__(self, cfg: TransformerConfig):
@@ -194,7 +294,11 @@ def family_of(cfg):
 
     if isinstance(cfg, MellumConfig):
         return MellumFamily(cfg)
+    from .jamba import JambaConfig, JambaFamily
+
+    if isinstance(cfg, JambaConfig):
+        return JambaFamily(cfg)
     raise TypeError(
         f"no model family serves a configuration of type "
         f"{type(cfg).__name__} (have TransformerConfig, DeepseekV3Config, "
-        f"MellumConfig)")
+        f"MellumConfig, JambaConfig)")

@@ -333,6 +333,13 @@ class _LMServingEntry:
         from ..serving.lm_engine import PagedLMEngine
 
         fam = self._family
+        if draft is not None and fam.state_lines:
+            raise NotImplementedError(
+                f"lm_serving: speculative verification (_verify) does not "
+                f"serve the {fam.name} family yet (a rejected draft would "
+                f"have to roll its state layers' state back to the accepted "
+                f"token, and no snapshot of it is kept); build it without "
+                f"draft=")
         if draft is not None and not fam.serves_verify:
             raise NotImplementedError(
                 f"lm_serving: speculative verification (_verify) does not "

@@ -33,6 +33,7 @@ from typing import Any, Dict, Optional, Tuple
 
 from ..parallel import moe_dropless
 from .deepseek_v3 import rms_norm
+from .families import GroupedQueryLines
 
 _KINDS = {"full_attention": "full", "sliding_attention": "window"}
 
@@ -190,13 +191,14 @@ def init_params(cfg: MellumConfig, seed: int = 0) -> Dict[str, Any]:
             "head": dense(D, cfg.vocab_size)}
 
 
-class MellumFamily:
+class MellumFamily(GroupedQueryLines):
     """The block above as the paged engine takes it
     (``models/families.py`` has the contract)."""
 
     name = "mellum"
     attention_scopes = {"full": "attn.full", "window": "attn.window"}
     counters = moe_dropless.COUNTERS
+    state_lines = ()       # no layer keeps a state a sequence
     serves_verify = False  # window layers under speculative verify: not yet
 
     def __init__(self, cfg: MellumConfig):
@@ -210,10 +212,6 @@ class MellumFamily:
         self.expert_slots = self.layers * cfg.held[1]
         self._rope = {kind: rope_frequencies(cfg.head_dim, cfg.rope(t))
                       for t, kind in _KINDS.items()}
-
-    @property
-    def cache_lines(self) -> tuple:
-        return (self.cfg.line_width, self.cfg.line_width)
 
     def init_params(self, seed: int):
         return init_params(self.cfg, seed=seed)
@@ -246,63 +244,6 @@ class MellumFamily:
         q = rotate_half(q, pos[..., None], freq, factor)
         k = rotate_half(k, pos[..., None], freq, factor)
         return q, (k.reshape(*x.shape[:2], KV * Dh), h @ blk["wv"])
-
-    @property
-    def attention_scale(self) -> float:
-        return self.cfg.head_dim ** -0.5
-
-    def _own(self):
-        import jax.numpy as jnp
-
-        # line element j belongs to key head j // head_dim, which query
-        # heads n with n // group == that head read
-        cfg = self.cfg
-        group = cfg.num_attention_heads // cfg.num_key_value_heads
-        return (jnp.arange(cfg.num_attention_heads)[:, None] // group
-                == jnp.arange(cfg.line_width)[None, :] // cfg.head_dim)
-
-    def step_queries(self, q):
-        import jax.numpy as jnp
-
-        # whole lines against a block-diagonal query: row n holds head n's
-        # query in the block of its key head and zeros elsewhere (the
-        # gpt family's form, with ``group`` rows a block)
-        tiled = jnp.tile(q[:, 0], (1, 1, self.cfg.num_key_value_heads))
-        return jnp.where(self._own()[None], tiled, 0.0)
-
-    def step_output(self, blk, o):
-        import jax.numpy as jnp
-
-        # row n of o is head n's weights over every key head's values: the
-        # block of its own key head is the attention output
-        cfg = self.cfg
-        S = o.shape[0]
-        o = jnp.where(self._own()[None], o, 0.0).reshape(
-            S, cfg.num_attention_heads, cfg.num_key_value_heads,
-            cfg.head_dim).sum(axis=2)
-        return o.reshape(S, 1, -1) @ blk["wo"]
-
-    def attend(self, blk, q, ctxs, visible, mode):
-        """One slot's chunk: ``q (1, C, H, head_dim)`` over the gathered
-        lines ``ctxs`` ``(1, ctx, kv_heads * head_dim)`` each, ``visible
-        (C, ctx)``. The context is one slot's, so its lines are split by
-        key head here (and only here)."""
-        import jax
-        import jax.numpy as jnp
-
-        cfg = self.cfg
-        KV, Dh = cfg.num_key_value_heads, cfg.head_dim
-        ck, cv = ctxs
-        C, ctx = q.shape[1], ck.shape[1]
-        exact = jax.lax.Precision.HIGHEST    # f32 queries over a bf16 pool
-        qg = q[0].reshape(C, KV, -1, Dh)
-        att = jnp.einsum("qkgd,ckd->kgqc", qg, ck[0].reshape(ctx, KV, Dh),
-                         precision=exact) * self.attention_scale
-        att = jax.nn.softmax(jnp.where(visible[None, None], att, -1e30),
-                             axis=-1)
-        o = jnp.einsum("kgqc,ckd->qkgd", att, cv[0].reshape(ctx, KV, Dh),
-                       precision=exact)
-        return o.reshape(1, C, -1) @ blk["wo"]
 
     def ffn(self, blk, x, live):
         cfg = self.cfg

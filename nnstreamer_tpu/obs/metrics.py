@@ -436,11 +436,26 @@ def _collect_serving(reg: Registry) -> None:
              "first page on, summed over decode steps"),
             ("window_pages_released", "window_pages_released",
              "pages of window layers given back behind the window while "
-             "their slot was live"))}
+             "their slot was live"),
+            ("state_slots_live", "state_slots_live",
+             "slots whose recurrent state a decode step advanced, summed "
+             "over decode steps (an engine whose family has state layers)"),
+            ("state_slots", "state_slots",
+             "slots whose recurrent state a decode step read and wrote "
+             "(every slot), summed over decode steps"))}
+    state_bytes = reg.gauge(
+        "nns_serving_state_bytes",
+        "bytes of the state layers' cache: a fixed cost a slot, resident "
+        "whether the slot is live or not", ("scheduler",))
+    state_live = reg.gauge(
+        "nns_serving_state_slots_live",
+        "slots whose recurrent state belongs to a live sequence",
+        ("scheduler",))
     # snapshot mirrors: repopulated from live schedulers each scrape, so
     # a garbage-collected scheduler's series disappears with it
     for inst in (subm, comp, fail, shedf, shedd, shedm, shedo, batches,
-                 depth, occ, wait, p99, *per_pass.values()):
+                 depth, occ, wait, p99, state_bytes, state_live,
+                 *per_pass.values()):
         inst.clear()
     for name, sched in serving_metrics.iter_schedulers():
         try:
@@ -457,6 +472,9 @@ def _collect_serving(reg: Registry) -> None:
         batches.set_total(snap.get("batches", 0), scheduler=name)
         for key, inst in per_pass.items():
             inst.set_total(snap.get(key, 0), scheduler=name)
+        if "state" in snap:
+            state_bytes.set(snap["state"]["bytes"], scheduler=name)
+            state_live.set(snap["state"]["slots_live"], scheduler=name)
         depth.set(snap.get("queue_depth", 0), scheduler=name)
         occ.set(snap.get("batch_occupancy", 0.0), scheduler=name)
         wait.set(snap.get("estimated_wait_ms", 0.0) / 1e3, scheduler=name)

@@ -61,7 +61,11 @@ class DecodeEngine:
 
     def preempt(self, slot: int) -> Optional[dict]:
         """Evict a live slot to the host and return what ``restore``
-        needs; ``None`` where the engine cannot."""
+        needs; ``None`` where the engine cannot. The blob is the engine's
+        own: the paged engine's holds the slot's pages of every kind of
+        attention layer and, for a family with state layers, the slot's
+        state in each (``"state"``: one host array per kind of state, all
+        state layers' rows), every kind or none."""
         return None
 
     def restore(self, slot: int, blob: dict) -> None:
@@ -72,6 +76,13 @@ class DecodeEngine:
         """Bytes a request of ``tokens`` + ``steps`` pins at most: what
         the scheduler's memory guard reserves for it."""
         return 0
+
+    def state_stats(self) -> Optional[dict]:
+        """The cache that is a fixed cost a slot and not a cost a token
+        (a recurrent state beside the pages): ``layers``, ``slots``,
+        ``slots_live``, ``slot_bytes``, ``bytes``; ``None`` where the
+        engine keeps none."""
+        return None
 
     def counters(self) -> dict:
         """Running sums the engine keeps (``ServingMetrics``

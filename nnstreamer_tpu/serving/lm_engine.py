@@ -97,6 +97,26 @@ class PagedLMEngine(DecodeEngine):
       (``_release_behind``, before every chunk and step). A family whose
       layers are all full (``gpt``, ``deepseek_v3``) has one table, one
       allocator and the programs it had before kinds existed.
+    * **state layers** — a family may say ``"state"`` of a layer: a mixer
+      that keeps a state a *sequence* and no line a token
+      (``family.state_lines``: shapes and dtypes). Such layers have no
+      table, no allocator and no pages: per entry of ``state_lines`` one
+      device array ``(state layers, slots, *shape)`` (``_states``), donated
+      through ``_step`` and ``_prefill_chunk`` beside the pools. A launch
+      reads its slot's rows, or zeros where it starts a sequence (``start
+      == 0``: whatever the slot held before is gone), and writes them
+      back, so a prompt's last launch hands the step its state; a padded
+      row of a launch moves nothing (the family's ``mix_chunk``: its step
+      size is zero and the conv's next inputs are taken before row
+      ``n_valid``); the step advances the slots in ``mask`` and stores for
+      every other slot what it read, bit for bit (``mix_step``).
+      ``release`` leaves the rows to the next admission's reset;
+      ``preempt`` returns them beside the pages and ``restore`` puts both
+      back. A fixed cost a slot, not a cost a token: ``cache_bytes``,
+      ``memory_bytes()["state"]`` and ``state_stats()`` count it,
+      ``projected_page_bytes`` charges no request for it. Prefix sharing
+      is refused for such a family: a hit would need the state as it was
+      at the prefix's last token.
     * **pool layout** — per kind of layer and kind of line ``(layers of
       the kind * (pages+1), page, width)`` device arrays: one row per page
       of one layer, one contiguous ``width`` line per token, so row-major
@@ -191,6 +211,12 @@ class PagedLMEngine(DecodeEngine):
                 f"family yet (a hit would need the pages of both kinds of "
                 f"layer that a registered prefix keeps); build it with "
                 f"share_prefixes=False")
+        if share_prefixes and fam.state_lines:
+            raise NotImplementedError(
+                f"lm_engine: prefix sharing does not serve the {fam.name} "
+                f"family yet (a hit would need its state layers' state as "
+                f"it was at the prefix's last token, and no snapshot of it "
+                f"is kept); build it with share_prefixes=False")
         self.cfg = cfg
         self.family = fam
         self.kinds = kinds
@@ -239,15 +265,18 @@ class PagedLMEngine(DecodeEngine):
         P = len(self.line_widths)
         K = len(kinds)
         # layer li is the index[li]-th layer of its kind
-        layers_of = dict.fromkeys(kinds, 0)
+        layers_of = dict.fromkeys((*kinds, "state"), 0)
         index = []
         for kind in fam.layer_kinds:
             index.append(layers_of[kind])
             layers_of[kind] += 1
+        # a state layer keeps no pages: its count stands beside the kinds'
+        self.state_layers = layers_of.pop("state")
         self.kind_layers = layers_of
         # rows of one layer: its null page 0, then the kind's pages
         R = {kind: pages[kind] + 1 for kind in kinds}
-        self.token_bytes = fam.layers * sum(self.line_widths) * item
+        self.token_bytes = (sum(layers_of.values())
+                            * sum(self.line_widths) * item)
         self.pools_by_kind = {
             kind: KVPagePool(
                 pages[kind], page_size, kind=kind,
@@ -262,6 +291,16 @@ class PagedLMEngine(DecodeEngine):
         self._pools = tuple(
             jnp.zeros((layers_of[kind] * R[kind], page_size, w), cache_dtype)
             for kind in kinds for w in self.line_widths)
+        # what a slot keeps in the state layers, whatever its length: one
+        # array (state layers, slots, *shape) per entry of the family's
+        # ``state_lines``, the i-th state layer's rows at [i]
+        self._states = tuple(
+            jnp.zeros((self.state_layers, slots, *shape),
+                      cache_dtype if dtype is None else dtype)
+            for shape, dtype in fam.state_lines if self.state_layers)
+        NS = len(self._states)
+        self.state_slot_bytes = int(sum(
+            s.nbytes for s in self._states) // slots) if NS else 0
         ctx = NB * page_size  # == max_seq: what a full layer's chunk gathers
 
         # host mirrors (authoritative; device copies re-synced on change)
@@ -289,8 +328,13 @@ class PagedLMEngine(DecodeEngine):
                            **{f"attn_pages_read_{kind}": 0 for kind in kinds
                               if len(kinds) > 1}}
         self.window_pages_released = 0
+        # running sums over decode steps: the slots whose state a step
+        # advanced, and every slot's (what it read and wrote)
+        self.state_slots = ({"state_slots_live": 0, "state_slots": 0}
+                            if NS else {})
 
-        self.cache_bytes = int(sum(p.nbytes for p in self._pools))
+        self.cache_bytes = int(sum(
+            a.nbytes for a in (*self._pools, *self._states)))
         self.param_bytes = obs_memory.tree_nbytes(params)
         obs_memory.track_serving(self)
 
@@ -302,31 +346,39 @@ class PagedLMEngine(DecodeEngine):
             # one whole line per token
             return pool.at[row0 + dest, offs].set(rows.astype(pool.dtype))
 
-        def _layers(p, x, pos, live, dests, offs, pools, unbatch, attend):
-            # the skeleton every program shares: per layer, write the new
-            # lines into its kind's arrays (``dests``: the page of each
-            # row, by kind), attend over the slots' lines (``attend(kind,
-            # row0, blk, q, pools of the kind)``: what the residual adds),
-            # feed forward. ``unbatch`` strips the axis a program's lines
-            # do not have
+        def _layers(p, x, pos, live, dests, offs, pools, unbatch, attend,
+                    states=(), mix=None):
+            # the skeleton every program shares: per layer, by its kind.
+            # An attention layer: write the new lines into its kind's
+            # arrays (``dests``: the page of each row, by kind), attend
+            # over the slots' lines (``attend(kind, row0, blk, q, pools of
+            # the kind)``: what the residual adds). A state layer:
+            # ``mix(i, blk, x, states)`` reads the rows' state of the i-th
+            # state layer, calls the family and writes it back. Then feed
+            # forward. ``unbatch`` strips the axis a program's lines do
+            # not have
             counts = jnp.zeros((NC,), jnp.int32) if NC else None
             for li, blk in enumerate(fam.blocks(p)):
                 kind = fam.layer_kinds[li]
-                k = kinds.index(kind)
-                row0 = index[li] * R[kind]
-                with jax.named_scope(fam.attention_scopes[kind]):
-                    q, lines = fam.project(blk, x, pos, kind)
-                    mine = tuple(
-                        _write(pool, row0, dests[k], offs, unbatch(line))
-                        for pool, line in zip(pools[k * P:(k + 1) * P],
-                                              lines))
-                    pools = pools[:k * P] + mine + pools[(k + 1) * P:]
-                    x = x + attend(kind, row0, blk, q, mine)
+                if kind == "state":
+                    y, states = mix(index[li], blk, x, states)
+                    x = x + y
+                else:
+                    k = kinds.index(kind)
+                    row0 = index[li] * R[kind]
+                    with jax.named_scope(fam.attention_scopes[kind]):
+                        q, lines = fam.project(blk, x, pos, kind)
+                        mine = tuple(
+                            _write(pool, row0, dests[k], offs, unbatch(line))
+                            for pool, line in zip(pools[k * P:(k + 1) * P],
+                                                  lines))
+                        pools = pools[:k * P] + mine + pools[(k + 1) * P:]
+                        x = x + attend(kind, row0, blk, q, mine)
                 y, c = fam.ffn(blk, x, live)
                 x = x + y
                 if c is not None:
                     counts = counts + c
-            return x, pools, counts
+            return x, pools, counts, states
 
         def _gathered(mode, tables, visible):
             # attention over a gathered copy of a block table (``tables``
@@ -340,7 +392,7 @@ class PagedLMEngine(DecodeEngine):
 
         def _step(p, token, pos, mask, *rest):
             self.compile_count += 1  # trace-time only: one step program
-            bts, pools = rest[:K], rest[K:]
+            bts, pools, states = rest[:K], rest[K:K + K * P], rest[K + K * P:]
             S = token.shape[0]
             lp = jnp.clip(pos, 0, max_seq - 1)
             x = fam.embed(p, token[:, 0], lp)[:, None, :]  # (S,1,D)
@@ -368,9 +420,18 @@ class PagedLMEngine(DecodeEngine):
                     fam.attention_scale, starts[kind])
                 return fam.step_output(blk, o)
 
-            x, pools, counts = _layers(
+            def mix(i, blk, x, states):
+                # one token of every slot through the i-th state layer:
+                # the family advances the rows [i, slots in mask] of the
+                # arrays where they lie and stores, for a slot outside
+                # ``mask``, what it read (a select in the pass that reads
+                # and writes the live slots' state: bit for bit)
+                y, states = fam.mix_step(blk, x[:, 0], states, i, mask)
+                return y[:, None], states
+
+            x, pools, counts, states = _layers(
                 p, x, lp[:, None], mask[:, None], dests, offs, pools,
-                lambda line: line[:, 0], attend)
+                lambda line: line[:, 0], attend, states, mix)
             with jax.named_scope("head"):
                 logits = fam.head(p, x[:, 0])
             out = jnp.argmax(logits, -1).astype(jnp.int32)
@@ -378,11 +439,11 @@ class PagedLMEngine(DecodeEngine):
             pos = pos + mask.astype(jnp.int32)
             if NC:  # the counts ride home behind the tokens: one transfer
                 out = jnp.concatenate([out, counts])
-            return (out, token, pos, *pools)
+            return (out, token, pos, *pools, *states)
 
         self._step = functools.partial(
             jax.jit(_step, donate_argnums=(
-                1, 2, *range(4 + K, 4 + K + K * P))), params)
+                1, 2, *range(4 + K, 4 + K + K * P + NS))), params)
 
         # the blocks of a window layer that a chunk's queries can see
         NW = self.held_blocks.get("window", 0)
@@ -391,7 +452,10 @@ class PagedLMEngine(DecodeEngine):
             # toks (C,) padded; ingest positions start..start+n_valid-1 of
             # ONE slot. C is static — the only compiled prefill shape.
             self.compile_count += 1  # trace-time only: once per engine
-            bts, pools = rest[:K], rest[K:]
+            bts, pools = rest[:K], rest[K:K + K * P]
+            # a family with state layers: the slot, then its state arrays
+            slot, states = (rest[K + K * P], rest[K + K * P + 1:]) if NS \
+                else (None, ())
             q_pos = start + jnp.arange(C)
             valid = jnp.arange(C) < n_valid
             lp = jnp.clip(q_pos, 0, max_seq - 1)
@@ -411,18 +475,33 @@ class PagedLMEngine(DecodeEngine):
                         bt, (first,), (NW,))[None]
                     back = q_pos[:, None] - positions[None, :]
                     visible[kind] = (back >= 0) & (back < window)
-            x, pools, counts = _layers(
+
+            def mix(i, blk, x, states):
+                # one slot's launch through the i-th state layer: from
+                # zero where the launch starts a sequence, whatever the
+                # slot held; the family leaves the state at the last real
+                # row (rows past n_valid move neither part of it)
+                old = tuple(jnp.where(start == 0, jnp.zeros_like(s[i, slot]),
+                                      s[i, slot]) for s in states)
+                y, new = fam.mix_chunk(blk, x[0], n_valid, old)
+                states = tuple(s.at[i, slot].set(n.astype(s.dtype))
+                               for s, n in zip(states, new))
+                return y[None], states
+
+            x, pools, counts, states = _layers(
                 p, x, lp[None], valid[None], dests, offs, pools,
-                lambda line: line[0], _gathered("chunk", tables, visible))
+                lambda line: line[0], _gathered("chunk", tables, visible),
+                states, mix)
             with jax.named_scope("head"):
                 logits = fam.head(p, x[0])  # (C, V)
             if NC:
-                return (logits, counts, *pools)
-            return (logits, *pools)
+                return (logits, counts, *pools, *states)
+            return (logits, *pools, *states)
 
         self._prefill_chunk = functools.partial(
-            jax.jit(_prefill_chunk, donate_argnums=tuple(
-                range(4 + K, 4 + K + K * P))), params)
+            jax.jit(_prefill_chunk, donate_argnums=(
+                *range(4 + K, 4 + K + K * P),
+                *range(5 + K + K * P, 5 + K + K * P + NS))), params)
 
         # page movers, one set per kind of layer (compiled when first used)
         def movers(kind):
@@ -452,6 +531,16 @@ class PagedLMEngine(DecodeEngine):
 
         self._movers = {kind: movers(kind) for kind in kinds}
 
+        # a slot's state to the host and back (preempt / restore)
+        def _put_state(slot, blobs, *states):
+            return tuple(s.at[:, slot].set(b.astype(s.dtype))
+                         for s, b in zip(states, blobs))
+
+        self._get_state = jax.jit(
+            lambda slot, *states: tuple(s[:, slot] for s in states))
+        self._put_state = jax.jit(
+            _put_state, donate_argnums=tuple(range(2, 2 + NS)))
+
         def _verify(p, toks, pos, mask, bt, *pools):
             # speculative verification: score K tokens per slot in ONE
             # call — toks (S, K) = [carry, draft...], positions
@@ -470,7 +559,7 @@ class PagedLMEngine(DecodeEngine):
             x = fam.embed(p, toks, lp)
             positions = jnp.arange(ctx)
             visible = (positions[None, None, :] <= q_pos[:, :, None])
-            x, pools, _ = _layers(
+            x, pools, _, _ = _layers(
                 p, x, lp, jnp.broadcast_to(mask[:, None], (S, K)), (dest,),
                 offs, pools, lambda line: line,
                 _gathered("verify", {"full": bt}, {"full": visible}))
@@ -541,6 +630,12 @@ class PagedLMEngine(DecodeEngine):
     @property
     def _vpool(self):
         return self._pools[1]
+
+    def _keep(self, arrays) -> None:
+        """What a program gave back for what it was donated: the pools,
+        then the state layers' arrays."""
+        P = len(self._pools)
+        self._pools, self._states = tuple(arrays[:P]), tuple(arrays[P:])
 
     def _sync_device_state(self) -> None:
         """Re-upload the decode carry from the host mirrors
@@ -633,6 +728,7 @@ class PagedLMEngine(DecodeEngine):
         total = dict(self.attn_pages)
         if "window" in self.kinds:
             total["window_pages_released"] = self.window_pages_released
+        total.update(self.state_slots)
         for counts in self.layer_counts.values():
             for k, v in counts.items():
                 total[k] = total.get(k, 0) + v
@@ -642,7 +738,9 @@ class PagedLMEngine(DecodeEngine):
         """Worst-case pool bytes a request needs (no sharing assumed) —
         the AdmissionGuard reservation unit (pages, not dense slots): by
         kind of layer, its pages at their bytes; a window layer holds
-        ``held_blocks["window"]`` at most however long the request."""
+        ``held_blocks["window"]`` at most however long the request. A
+        state layer's state is a fixed cost a slot, resident whether the
+        slot is live or not: no request is charged for it."""
         n = -(-(tokens + steps) // self.page_size)
         return sum(min(n, self.held_blocks[kind]) * pool.page_bytes
                    for kind, pool in self.pools_by_kind.items())
@@ -703,16 +801,20 @@ class PagedLMEngine(DecodeEngine):
             self._ensure_writable(slot, start, start + n_valid)
             padded = np.zeros((self.chunk,), np.int32)
             padded[:n_valid] = tokens[start:start + n_valid]
+            state_args = ()
+            if self._states:  # the launch that starts a sequence zeroes it
+                prepare.attrs["state_reset"] = int(start == 0)
+                state_args = (jnp.asarray(slot, jnp.int32), *self._states)
         with obs_context.span("engine.chunk.dispatch", **attrs) as dispatch:
             logits, *rest = self._prefill_chunk(
                 jnp.asarray(padded), jnp.asarray(start, jnp.int32),
                 jnp.asarray(n_valid, jnp.int32), *self._tables(slot),
-                *self._pools)
+                *self._pools, *state_args)
             if self.family.counters:
                 # pulled with the next answer that is pulled anyway (this
                 # prompt's last chunk, or the next step's tokens)
                 self._chunk_counts.append(rest.pop(0))
-            self._pools = tuple(rest)
+            self._keep(rest)
         self.host_s += prepare.dur_s + dispatch.dur_s
         lane = self._lane.setdefault(slot, [dispatch.start_s, 0])
         lane[1] += 1
@@ -768,7 +870,7 @@ class PagedLMEngine(DecodeEngine):
                     (skipped // self.page_size).sum())
             read = round(sum(by_kind[kind] * n for kind, n
                              in self.kind_layers.items())
-                         / self.family.layers)
+                         / sum(self.kind_layers.values()))
             padded = self.slots * self.blocks_per_slot
             prepare.attrs.update(pages_read=read, pages_padded=padded)
             self.attn_pages["attn_pages_read"] += read
@@ -780,11 +882,18 @@ class PagedLMEngine(DecodeEngine):
                         by_kind[kind]
                 prepare.attrs["window_pages_released"] = \
                     self.window_pages_released
+            if self._states:
+                # the step reads and writes every slot's state and
+                # advances the live ones'
+                prepare.attrs.update(state_slots_live=live,
+                                     state_slots=self.slots)
+                self.state_slots["state_slots_live"] += live
+                self.state_slots["state_slots"] += self.slots
         with obs_context.span("engine.step.dispatch", live=live) as dispatch:
-            tok_dev, self._tok_dev, self._pos_dev, *pools = self._step(
+            tok_dev, self._tok_dev, self._pos_dev, *rest = self._step(
                 self._tok_dev, self._pos_dev, self._mask_dev,
-                *self._tables(), *self._pools)
-            self._pools = tuple(pools)
+                *self._tables(), *self._pools, *self._states)
+            self._keep(rest)
         with obs_context.span("engine.step.pull", live=live) as pull:
             # nnlint: disable=NNL101 — one (slots,) pull per decode step:
             # the scheduler needs host ints to append/retire (documented
@@ -841,6 +950,8 @@ class PagedLMEngine(DecodeEngine):
         self._held_from[slot] = 0
 
     def release(self, slot: int) -> None:
+        # a state layer's rows stay as they are: the launch that starts the
+        # slot's next sequence zeroes them
         with obs_context.span("engine.release", slot=slot):
             self._pending.pop(slot, None)
             self._lane.pop(slot, None)
@@ -868,21 +979,30 @@ class PagedLMEngine(DecodeEngine):
         events)."""
         if not self._mask[slot]:
             raise ServingError(f"slot {slot} not active")
-        blob = {"pages": (), "used": {}, "held_from": int(
+        blob = {"pages": (), "used": {}, "state": (), "held_from": int(
                     self._held_from[slot]),
                 "tok": int(self._tok[slot, 0]), "pos": int(self._pos[slot])}
-        for kind in self.kinds:
-            row = self._bts[kind][slot, self._held_span(kind, slot)]
-            blobs = self._movers[kind]["gather"](row,
-                                                 *self._kind_pools(kind))
-            # nnlint: disable=NNL101 — preemption IS the host transfer: the
-            # victim's pages move to host RAM so the pool can be re-used;
-            # restore uploads the same bytes
-            blob["pages"] += tuple(self._jax.device_get(b) for b in blobs)
-            blob["used"][kind] = row != 0
-        self._drop_pages(slot)
-        self._mask[slot] = False
-        self._sync_device_state()
+        with obs_context.span("engine.preempt", slot=slot) as sp:
+            for kind in self.kinds:
+                row = self._bts[kind][slot, self._held_span(kind, slot)]
+                blobs = self._movers[kind]["gather"](
+                    row, *self._kind_pools(kind))
+                # nnlint: disable=NNL101 — preemption IS the host transfer:
+                # the victim's pages move to host RAM so the pool can be
+                # re-used; restore uploads the same bytes
+                blob["pages"] += tuple(self._jax.device_get(b)
+                                       for b in blobs)
+                blob["used"][kind] = row != 0
+            if self._states:
+                # the state goes with the pages: another sequence's first
+                # launch in this slot zeroes the rows
+                # nnlint: disable=NNL101 — as the pages above
+                blob["state"] = tuple(self._jax.device_get(
+                    self._get_state(slot, *self._states)))
+                sp.attrs["state_bytes"] = self.state_slot_bytes
+            self._drop_pages(slot)
+            self._mask[slot] = False
+            self._sync_device_state()
         self.pool.note_preemption()
         return blob
 
@@ -902,20 +1022,27 @@ class PagedLMEngine(DecodeEngine):
             raise
         self._held_from[slot] = blob["held_from"]
         P = len(self.line_widths)
-        for k, kind in enumerate(self.kinds):
-            row = np.zeros((self.held_blocks[kind],), np.int32)
-            row[blob["used"][kind]] = fresh[kind]
-            self._bts[kind][slot] = 0
-            self._bts[kind][slot, self._held_span(kind, slot)] = row
-            self._set_kind_pools(kind, self._movers[kind]["scatter"](
-                self._jnp.asarray(row),
-                tuple(self._jnp.asarray(b)
-                      for b in blob["pages"][k * P:(k + 1) * P]),
-                *self._kind_pools(kind)))
-        self._tok[slot, 0] = blob["tok"]
-        self._pos[slot] = blob["pos"]
-        self._mask[slot] = True
-        self._sync_device_state()
+        with obs_context.span("engine.restore", slot=slot) as sp:
+            for k, kind in enumerate(self.kinds):
+                row = np.zeros((self.held_blocks[kind],), np.int32)
+                row[blob["used"][kind]] = fresh[kind]
+                self._bts[kind][slot] = 0
+                self._bts[kind][slot, self._held_span(kind, slot)] = row
+                self._set_kind_pools(kind, self._movers[kind]["scatter"](
+                    self._jnp.asarray(row),
+                    tuple(self._jnp.asarray(b)
+                          for b in blob["pages"][k * P:(k + 1) * P]),
+                    *self._kind_pools(kind)))
+            if self._states:
+                self._states = self._put_state(
+                    self._jnp.asarray(slot, self._jnp.int32),
+                    tuple(self._jnp.asarray(b) for b in blob["state"]),
+                    *self._states)
+                sp.attrs["state_bytes"] = self.state_slot_bytes
+            self._tok[slot, 0] = blob["tok"]
+            self._pos[slot] = blob["pos"]
+            self._mask[slot] = True
+            self._sync_device_state()
         self.pool.note_restore()
 
     # -- introspection --------------------------------------------------------
@@ -923,13 +1050,29 @@ class PagedLMEngine(DecodeEngine):
     def active_slots(self) -> int:
         return int(self._mask.sum())
 
+    def state_stats(self) -> Optional[dict]:
+        """The state layers' cache, a kind of its own beside the pools'
+        ``stats()``: a fixed cost a slot, not a cost a token. ``None`` for
+        a family with no state layer."""
+        if not self._states:
+            return None
+        return {"layers": self.state_layers, "slots": self.slots,
+                "slots_live": self.active_slots,
+                "slot_bytes": self.state_slot_bytes,
+                "bytes": self.state_slot_bytes * self.slots,
+                "shapes": [list(s.shape[2:]) for s in self._states]}
+
     def memory_bytes(self) -> dict:
         """Serving-plane byte source (obs/memory.py ``track_serving``):
-        the page pool is the engine's resident buffer; page occupancy
-        rides along so obs top can render utilization, not just
-        capacity."""
+        the page pools and the state layers' arrays are the engine's
+        resident buffers (``bytes``); page occupancy rides along so obs
+        top can render utilization, not just capacity. ``state`` is the
+        part of ``bytes`` that the slots' state takes (:meth:`state_stats`
+        ; absent for a family with none)."""
         s = self.pool.stats()
-        return {"name": self._mem_name, "kind": "kv_pool",
+        state = self.state_stats()
+        return {**({} if state is None else {"state": state}),
+                "name": self._mem_name, "kind": "kv_pool",
                 "bytes": self.cache_bytes,
                 "param_bytes": self.param_bytes,
                 "slots": self.slots, "active_slots": self.active_slots,

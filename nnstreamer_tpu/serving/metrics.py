@@ -116,6 +116,11 @@ class ServingMetrics:
         self.attn_pages_read_full = 0
         self.attn_pages_read_window = 0
         self.window_pages_released = 0
+        # the state layers' cache (PagedLMEngine.state_slots), summed over
+        # decode steps: the slots whose state a step advanced, and every
+        # slot's (what the step read and wrote)
+        self.state_slots_live = 0
+        self.state_slots = 0
         # device channel: batch execution time (dispatch+block, the
         # reference-comparable number); reservoirs: per-request tails
         self.device = InvokeStats()
@@ -198,7 +203,8 @@ class ServingMetrics:
     def record_layer_counts(self, counts: dict) -> None:
         """What the engine counted since the last pass (the growth of
         ``DecodeEngine.counters()``): its expert layers (``moe_*``, both
-        programs added up) and its steps' attention (``attn_pages_*``)."""
+        programs added up), its steps' attention (``attn_pages_*``) and
+        its state layers' cache (``state_slots*``)."""
         with self._lock:
             self.attn_pages_read += counts.get("attn_pages_read", 0)
             self.attn_pages_padded += counts.get("attn_pages_padded", 0)
@@ -208,6 +214,8 @@ class ServingMetrics:
                 "attn_pages_read_window", 0)
             self.window_pages_released += counts.get(
                 "window_pages_released", 0)
+            self.state_slots_live += counts.get("state_slots_live", 0)
+            self.state_slots += counts.get("state_slots", 0)
             self.moe_experts_touched += counts.get("moe_experts_touched", 0)
             self.moe_expert_slots += counts.get("moe_expert_slots", 0)
             self.moe_assignments += counts.get("moe_assignments", 0)
@@ -261,6 +269,8 @@ class ServingMetrics:
                 "attn_pages_read_full": self.attn_pages_read_full,
                 "attn_pages_read_window": self.attn_pages_read_window,
                 "window_pages_released": self.window_pages_released,
+                "state_slots_live": self.state_slots_live,
+                "state_slots": self.state_slots,
             }
         out["device"] = self.device.snapshot()
         out["queue_wait"] = self.queue_wait.snapshot()

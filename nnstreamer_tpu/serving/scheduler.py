@@ -561,6 +561,9 @@ class DecodeScheduler:
             snap["kv_pool"] = pool.stats()
             snap["kv_pools"] = {kind: p.stats() for kind, p
                                 in self.engine.pools_by_kind.items()}
+        state = self.engine.state_stats()
+        if state is not None:  # a cache a slot, beside the pages
+            snap["state"] = state
         if self._step_tokens is not None:  # a burst engine counts its rounds
             snap["spec_acceptance_rate"] = self.engine.acceptance_rate()
             snap["spec_rounds"] = self.engine.spec_rounds

@@ -117,6 +117,19 @@ class TestLegsAtCpuSize:
         assert out["served_gap_max"] <= 1e-4  # float32 on the CPU
         assert out["moe_experts_touched"] > 0
 
+    def test_state_serving_leg_counts(self):
+        import chip_smoke
+
+        out = chip_smoke.state_serving_leg(serve_dtype="float32")
+        assert out["completed"] == out["requests"] == 10  # over 8 slots
+        # step + prefill_chunk: the state's movers are not counted programs
+        assert out["compile_count"] == 2
+        assert out["served_gap_max"] <= 1e-4  # float32 on the CPU
+        assert out["restored_gap_max"] <= 1e-4
+        # six state layers of (3 x 512) + (16 x 512) float32 values a slot
+        assert out["state_bytes"] == 8 * 6 * (1536 + 8192) * 4
+        assert out["state_slots_live"] > 0
+
     def test_kernels_leg_interpreted(self):
         import chip_smoke
 

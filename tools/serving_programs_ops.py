@@ -1,0 +1,144 @@
+"""Do the accepted families' programs still compile to what they compiled to?
+
+For every configuration ``BENCHMARK.json`` lists whose driver kind this
+script knows (``lm_serving``, ``lm_serving_moe_mla``,
+``lm_serving_moe_window``): ``_step`` and ``_prefill_chunk`` of the paged
+engine at the configuration's sizes and engine geometry, a launch of 256
+rows, in the forms a TPU runs (the step's attention kernel, the experts'
+kernel), compiled for a described v5e with no chip attached. Writes one
+file a program, ``<outdir>/<config>.<program>.ops``, one line an
+instruction of the optimized module in order: opcode, result type and
+shape (names, numbers, layouts, metadata and the kernels' serialized
+bodies left out: they hold source lines), and prints instructions, argument,
+aliased and temporary bytes a program.
+
+A PR that touches ``serving/lm_engine.py`` or a family runs it on an
+unpacked parent and on its own tree and compares the files:
+
+    git archive <parent> | tar -x -C /root/scratch/parent
+    JAX_PLATFORMS=cpu python3 tools/serving_programs_ops.py /root/scratch/parent /root/scratch/ops_parent
+    JAX_PLATFORMS=cpu python3 tools/serving_programs_ops.py . /root/scratch/ops_change
+    diff -r /root/scratch/ops_parent /root/scratch/ops_change
+
+About two minutes a tree. A compile that passes is not a chip run.
+"""
+from __future__ import annotations
+
+import json
+import os
+import re
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+_INSTRUCTION = re.compile(
+    r"(?:ROOT )?%?[\w.\-]+ = (\(?[a-z0-9]+\[[0-9,]*\][^ ]*(?:, [^ ]+\))?) "
+    r"([\w\-]+)\(")
+
+
+def _model(config: dict):
+    kind = config["kind"]
+    if kind == "lm_serving":
+        from nnstreamer_tpu.models.transformer import TransformerConfig
+
+        return TransformerConfig(
+            vocab=config["vocab_size"], dim=config["hidden_size"],
+            heads=config["num_attention_heads"],
+            layers=config["num_hidden_layers"],
+            mlp_mult=config["ffn_dim"] // config["hidden_size"],
+            max_seq=config["max_position_embeddings"])
+    if kind == "lm_serving_moe_mla":
+        from nnstreamer_tpu.models.deepseek_v3 import DeepseekV3Config
+
+        return DeepseekV3Config.from_published(config)
+    if kind == "lm_serving_moe_window":
+        from nnstreamer_tpu.models.mellum import MellumConfig
+
+        return MellumConfig.from_published(config)
+    return None
+
+
+def main(root: str, out: str) -> int:
+    root = os.path.abspath(root)
+    sys.path.insert(0, root)
+    os.makedirs(out, exist_ok=True)
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark.lib import harness
+    from nnstreamer_tpu.ops import moe_grouped, paged_attention
+    from nnstreamer_tpu.serving import lm_engine
+
+    if not os.path.abspath(lm_engine.__file__).startswith(root):
+        raise SystemExit(f"imported {lm_engine.__file__}, not {root}'s")
+    # the forms a TPU runs: chosen by the backend, which is the CPU here
+    paged_attention.paged_line_attention = \
+        paged_attention.kernel_line_attention
+    moe_grouped.grouped_experts = moe_grouped.tpu_grouped_experts
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(tuple(s), dt, sharding=chip)
+
+    width, i32 = 256, jnp.int32
+    for entry in harness.load_benchmark()["configs"]:
+        with open(os.path.join(root, entry["file"])) as fh:
+            config = json.load(fh)
+        mcfg = _model(config)
+        if mcfg is None:
+            continue
+        reference = harness.reference_for(config)
+        sz = reference.sizes(config)
+        geo = dict(config["engine"], chunk=width)
+        pages = geo.pop("pages")
+        by_kind = pages if isinstance(pages, dict) else None
+        # the programs close over the sizes only: a two-page pool, one slot
+        probe = lm_engine.PagedLMEngine(
+            mcfg, {"embed": jnp.zeros((1, 1), jnp.bfloat16)},
+            **{**geo, "slots": 1,
+               "pages": dict.fromkeys(by_kind, 2) if by_kind else 2})
+        by_kind = by_kind or {probe.kinds[0]: pages}
+        params = jax.tree_util.tree_map(
+            lambda a: shape(a.shape, a.dtype),
+            jax.eval_shape(lambda k: reference.program_params(
+                k, sz, jnp.bfloat16), jax.random.key(0)))
+        S, NB, K = geo["slots"], probe.blocks_per_slot, len(probe.kinds)
+        pools = [shape((probe.kind_layers[k] * (by_kind[k] + 1),
+                        geo["page_size"], w), jnp.bfloat16)
+                 for k in probe.kinds for w in probe.line_widths]
+        programs = {
+            "_step": (shape((S, 1), i32), shape((S,), i32),
+                      shape((S,), jnp.bool_), *[shape((S, NB), i32)] * K),
+            "_prefill_chunk": (shape((width,), i32), shape((), i32),
+                               shape((), i32), *[shape((NB,), i32)] * K)}
+        for name, args in programs.items():
+            compiled = getattr(probe, name).func.lower(
+                params, *args, *pools).compile()
+            lines = []
+            for line in compiled.as_text().splitlines():
+                found = _INSTRUCTION.match(line.strip())
+                if found:
+                    result = re.sub(r"[{][^}]*[}]", "", found.group(1))
+                    lines.append(f"{found.group(2)} {result}")
+            with open(os.path.join(out, f"{entry['name']}.{name}.ops"),
+                      "w") as fh:
+                fh.write("\n".join(lines) + "\n")
+            m = compiled.memory_analysis()
+            print(json.dumps({
+                "config": entry["name"], "program": name,
+                "instructions": len(lines),
+                "argument_bytes": m.argument_size_in_bytes,
+                "alias_bytes": m.alias_size_in_bytes,
+                "temp_bytes": m.temp_size_in_bytes}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    if len(sys.argv) != 3:
+        raise SystemExit(__doc__)
+    sys.exit(main(sys.argv[1], sys.argv[2]))
