@@ -314,6 +314,13 @@ def _prompts(vocab: int, lengths, page_size: int, seed: int = 21):
     return prompts
 
 
+def _step_now(engine):
+    """One decode step of a hand-driven engine and its own tokens: the
+    engine keeps a step in flight, so step, then collect."""
+    engine.step()
+    return engine.collect()
+
+
 def _serve(engine, prompts, steps: int):
     """Submit every prompt at once to a DecodeScheduler over ``engine``;
     returns (token streams, metrics snapshot, seconds)."""
@@ -355,7 +362,7 @@ def serving_leg(entry=None, slots: int = 8, steps: int = 32,
     # stays out of the run
     t0 = time.monotonic()
     engine.admit(0, prompts[1], 2)
-    engine.step()
+    _step_now(engine)
     engine.release(0)
     out["warmup_s"] = round(time.monotonic() - t0, 2)
 
@@ -675,15 +682,15 @@ def state_serving_leg(serve_dtype: str = "bfloat16", slots: int = 8,
     first, other = prompts[0], prompts[1]
     moved = [engine.admit(0, first, steps)]
     for _ in range(9):
-        moved.append(int(engine.step()[0]))
+        moved.append(int(_step_now(engine)[0]))
     blob = engine.preempt(0)
     engine.admit(0, other, 8)
     for _ in range(5):
-        engine.step()
+        _step_now(engine)
     engine.release(0)
     engine.restore(0, blob)
     for _ in range(steps - 10):
-        moved.append(int(engine.step()[0]))
+        moved.append(int(_step_now(engine)[0]))
     engine.release(0)
     check(engine.pool.used_pages == 0, "state: pages held after the "
                                        "restored sequence left")

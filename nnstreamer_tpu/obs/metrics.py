@@ -442,7 +442,16 @@ def _collect_serving(reg: Registry) -> None:
              "over decode steps (an engine whose family has state layers)"),
             ("state_slots", "state_slots",
              "slots whose recurrent state a decode step read and wrote "
-             "(every slot), summed over decode steps"))}
+             "(every slot), summed over decode steps"),
+            ("steps_ahead", "steps_ahead",
+             "decode steps dispatched while the step before's tokens were "
+             "still on the device"),
+            ("steps_collected_early", "steps_collected_early",
+             "decode steps whose tokens a preempt, restore, verify round "
+             "or close brought home before the next step was dispatched"),
+            ("surplus_steps", "surplus_steps",
+             "slot-steps whose token was dropped: the one step a slot "
+             "runs over an ending the engine could not foresee (EOS)"))}
     state_bytes = reg.gauge(
         "nns_serving_state_bytes",
         "bytes of the state layers' cache: a fixed cost a slot, resident "

@@ -50,8 +50,22 @@ class DecodeEngine:
 
     def step(self) -> np.ndarray:
         """One decode step over every slot → ``(slots,)`` tokens (an idle
-        slot's entry is garbage). May raise ``PagePoolExhausted``."""
+        slot's entry is garbage). May raise ``PagePoolExhausted``. An
+        entry below zero means "no token for this slot this pass": an
+        engine may keep a step in flight and answer with the tokens of the
+        step before, in which a slot that joined since has none (the paged
+        engine does; one that never answers so is served as it was)."""
         raise NotImplementedError
+
+    def collect(self) -> Optional[np.ndarray]:
+        """For a caller that drives an engine by hand: bring home what
+        ``step`` left in flight → ``(slots,)`` tokens no ``step`` has
+        returned yet, below zero where a slot has none; ``None`` from an
+        engine that keeps nothing in flight. "``step()``, then
+        ``collect()``" is each step's own tokens. ``preempt``, ``restore``
+        and ``close`` collect first themselves, and what they bring home
+        the next ``step`` returns."""
+        return None
 
     def release(self, slot: int) -> None:
         """``slot`` is free again: every exit of a request comes here."""
@@ -65,7 +79,9 @@ class DecodeEngine:
         own: the paged engine's holds the slot's pages of every kind of
         attention layer and, for a family with state layers, the slot's
         state in each (``"state"``: one host array per kind of state, all
-        state layers' rows), every kind or none."""
+        state layers' rows), every kind or none; and, where a step was in
+        flight, the slot's token of it (no ``step`` returned it before the
+        slot left: the ``step`` after ``restore`` does)."""
         return None
 
     def restore(self, slot: int, blob: dict) -> None:

@@ -16,8 +16,12 @@ Join protocol (``engine.DecodeEngine``, driven by ``DecodeScheduler``):
   stays flat across prompt lengths. Its width is the engine's to choose
   (``prefill_width``): as wide as the chip's ridge, where the chip is known;
 * ``step()``: one decode step over ALL slots. Inactive slots write to a
-  null page and read nothing (static shapes are the point); the scheduler
-  ignores their outputs;
+  null page and read nothing (static shapes are the point). The engine
+  keeps ONE step in flight: a call dispatches the next step and only then
+  brings home the tokens of the one before, so the device runs while the
+  host routes, retires and admits (``PagedLMEngine.step``); ``collect()``
+  brings home what is in flight for a caller that wants each step's own
+  tokens;
 * ``release(slot)`` returns the slot's pages to the pool.
 
 Greedy (argmax) decoding only — sampling policy belongs to the caller's
@@ -29,8 +33,9 @@ Spans (``obs.context.span``, always on, docs/observability.md): each call
 of a program is split where the host does three different things,
 ``engine.<step|chunk>.prepare`` (page bookkeeping, padding), ``.dispatch``
 (uploads and the jitted call until it returns) and ``.pull`` (the device's
-answer brought to the host). ``host_s`` and ``pull_s`` are the running
-sums of the first two and of the third.
+answer brought to the host; a step's pull is the wait for the step BEFORE
+the one just dispatched). ``host_s`` and ``pull_s`` are the running sums
+of the first two and of the third.
 """
 from __future__ import annotations
 
@@ -67,6 +72,30 @@ def prefill_width(chunk: int, max_seq: int, page_size: int,
         rows = 1 << (math.ceil(ridge * weight_bytes / 2) - 1).bit_length()
         width = max(chunk, -(-rows // page_size) * page_size)
     return min(width, max_seq)
+
+
+class _LowersShort:
+    """A jitted program whose last argument joined it later: ``lower`` also
+    takes the argument list as it was before (``short`` arguments; what the
+    benchmark's drivers lower ``_step`` by to read its operations' scopes,
+    and they are not this tree's to edit) and appends the missing one's
+    shape, so that what they compile is the program that runs."""
+
+    def __init__(self, jitted, short: int, last: tuple):
+        self._jitted, self._short, self._last = jitted, short, last
+
+    def __call__(self, *args):
+        return self._jitted(*args)
+
+    def lower(self, *args):
+        if len(args) == self._short:
+            import jax
+
+            shape, dtype = self._last
+            # on the device its neighbours are described for, if any
+            args += (jax.ShapeDtypeStruct(
+                shape, dtype, sharding=getattr(args[2], "sharding", None)),)
+        return self._jitted.lower(*args)
 
 
 class PagedLMEngine(DecodeEngine):
@@ -303,13 +332,38 @@ class PagedLMEngine(DecodeEngine):
             s.nbytes for s in self._states) // slots) if NS else 0
         ctx = NB * page_size  # == max_seq: what a full layer's chunk gathers
 
-        # host mirrors (authoritative; device copies re-synced on change)
+        # host mirrors. The block tables, ``_pos`` and ``_mask`` are
+        # authoritative and ride into every step as numpy arguments
+        # (copies: a program in flight may read an argument in place);
+        # ``_pos`` advances when a step is dispatched. ``_tok`` is the last
+        # token the host has seen of a slot: one token behind the device's
+        # carry ``_tok_dev`` while a step is in flight, so nothing uploads
+        # it whole. Where the host knows a slot's next input token and the
+        # device does not (a join, a restore, a verify round) it says so in
+        # ``_join`` and the next step merges it on the device
         self._bts = {kind: np.zeros((slots, NB), np.int32) for kind in kinds}
         # first block a slot still holds in a window layer
         self._held_from = np.zeros((slots,), np.int64)
         self._tok = np.zeros((slots, 1), np.int32)
         self._pos = np.zeros((slots,), np.int32)
         self._mask = np.zeros((slots,), bool)
+        self._join = np.full((slots,), -1, np.int32)
+        # decode steps a slot's request may still take (``steps`` less the
+        # prompt's own token, less the steps dispatched): a slot whose last
+        # token is in flight is left out of the next dispatch
+        self._left = np.zeros((slots,), np.int64)
+        self._tok_dev = jnp.asarray(self._tok)  # an upload: no program
+        # the step in flight, ``(its tokens on the device, the slots it
+        # stepped)``, and the tokens a drain brought home early, kept for
+        # the next ``step()`` to return (``-1``: none for this slot)
+        self._flight: Optional[tuple] = None
+        self._kept = np.full((slots,), -1, np.int32)
+        # running sums (``counters``): steps dispatched while another's
+        # tokens were still on the device, drains that ``preempt``,
+        # ``restore``, ``verify_commit`` or ``close`` forced, and slot-steps
+        # whose token was dropped (the one step an EOS ending runs over)
+        self.run_ahead = {"steps_ahead": 0, "steps_collected_early": 0,
+                          "surplus_steps": 0}
         self._pending: "dict[int, dict]" = {}  # slot -> chunked-prefill state
         # slot -> [when its first chunk was dispatched, chunks so far]:
         # outlives _pending, until the slot is released (prefill_stamp)
@@ -392,8 +446,12 @@ class PagedLMEngine(DecodeEngine):
 
         def _step(p, token, pos, mask, *rest):
             self.compile_count += 1  # trace-time only: one step program
-            bts, pools, states = rest[:K], rest[K:K + K * P], rest[K + K * P:]
+            bts, pools = rest[:K], rest[K:K + K * P]
+            states, join = rest[K + K * P:-1], rest[-1]
             S = token.shape[0]
+            # ``token`` is the carry the last step left on the device; where
+            # the host knows better (``join >= 0``) its token goes in
+            token = jnp.where(join[:, None] >= 0, join[:, None], token)
             lp = jnp.clip(pos, 0, max_seq - 1)
             x = fam.embed(p, token[:, 0], lp)[:, None, :]  # (S,1,D)
             bidx = jnp.clip(pos // pg, 0, NB - 1)
@@ -436,14 +494,14 @@ class PagedLMEngine(DecodeEngine):
                 logits = fam.head(p, x[:, 0])
             out = jnp.argmax(logits, -1).astype(jnp.int32)
             token = jnp.where(mask[:, None], out[:, None], token)
-            pos = pos + mask.astype(jnp.int32)
             if NC:  # the counts ride home behind the tokens: one transfer
                 out = jnp.concatenate([out, counts])
-            return (out, token, pos, *pools, *states)
+            return (out, token, *pools, *states)
 
         self._step = functools.partial(
-            jax.jit(_step, donate_argnums=(
-                1, 2, *range(4 + K, 4 + K + K * P + NS))), params)
+            _LowersShort(jax.jit(_step, donate_argnums=(
+                1, *range(4 + K, 4 + K + K * P + NS))),
+                4 + K + K * P + NS, ((slots,), jnp.int32)), params)
 
         # the blocks of a window layer that a chunk's queries can see
         NW = self.held_blocks.get("window", 0)
@@ -566,15 +624,14 @@ class PagedLMEngine(DecodeEngine):
             logits = fam.head(p, x)  # (S, K, V)
             return (logits, *pools)
 
-        def _verify_commit(p, toks, pos, tok, mask, bt, *pools):
+        def _verify_commit(p, toks, pos, mask, bt, *pools):
             # fused speculative round: verify K tokens AND resolve greedy
-            # acceptance + carry advance on device. Greedy acceptance
-            # emits the target's own argmax prefix (accepted drafts match
-            # it by definition, the correction IS it), so the host needs
-            # only (pred, n_emit) — two tiny int pulls, no logits
-            # download, no carry re-upload.
+            # acceptance on device. Greedy acceptance emits the target's
+            # own argmax prefix (accepted drafts match it by definition,
+            # the correction IS it), so the host needs only (pred, n_emit)
+            # — one tiny int pull, no logits download.
             logits, *pools = _verify(p, toks, pos, mask, bt, *pools)
-            S, K = toks.shape
+            K = toks.shape[1]
             pred = jnp.argmax(logits, -1).astype(jnp.int32)   # (S, K)
             budget = max_seq - pos                            # emit ceiling
             # accept proposal i (column i+1) while every earlier one
@@ -583,19 +640,15 @@ class PagedLMEngine(DecodeEngine):
                   & (jnp.arange(K - 1)[None, :] < (budget - 1)[:, None]))
             j = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1), axis=1)
             n_emit = jnp.where(mask & (budget > 0), j + 1, 0)
-            last = pred[jnp.arange(S), jnp.maximum(n_emit - 1, 0)]
-            tok = jnp.where((n_emit > 0)[:, None], last[:, None], tok)
-            pos = pos + n_emit
             # pack [n_emit | pred] into ONE (S, K+1) array: the host does
             # a single tiny pull per round instead of two
             out = jnp.concatenate([n_emit[:, None], pred], axis=1)
-            return (out, tok, pos, *pools)
+            return (out, *pools)
 
         if fam.serves_verify:  # families whose layers are all of one kind
             self._verify_commit = functools.partial(
                 jax.jit(_verify_commit,
-                        donate_argnums=(2, 3, *range(6, 6 + P))), params)
-        self._sync_device_state()
+                        donate_argnums=tuple(range(5, 5 + P))), params)
 
     @property
     def _bt(self):
@@ -604,14 +657,12 @@ class PagedLMEngine(DecodeEngine):
         return self._bts[self.kinds[0]]
 
     def _tables(self, slot=None) -> tuple:
-        """The block tables by kind, as the programs take them. A window
-        layer's table rides as a copy: its entries go back to 0 as pages
-        are given back, and on the CPU a program still in flight (a chunk
-        is not waited for) may read the host's array in place."""
-        tables = ((kind, bt if slot is None else bt[slot])
-                  for kind, bt in self._bts.items())
-        return tuple(t.copy() if kind == "window" else t
-                     for kind, t in tables)
+        """The block tables by kind, as the programs take them: copies.
+        The host edits its tables while the program that took them is
+        still in flight (a step always is, a chunk is not waited for), and
+        on the CPU a program may read a numpy argument in place."""
+        return tuple((bt if slot is None else bt[slot]).copy()
+                     for bt in self._bts.values())
 
     def _kind_pools(self, kind: str) -> tuple:
         k, P = self.kinds.index(kind), len(self.line_widths)
@@ -637,17 +688,30 @@ class PagedLMEngine(DecodeEngine):
         P = len(self._pools)
         self._pools, self._states = tuple(arrays[:P]), tuple(arrays[P:])
 
-    def _sync_device_state(self) -> None:
-        """Re-upload the decode carry from the host mirrors
-        (admit/release/preempt edits only — never per token). Block
-        tables are NOT device-resident: ``self._bt`` rides into every
-        jit call as a numpy arg (the committed-call conversion is ~10x
-        cheaper than maintaining a device mirror that page-boundary
-        crossings would re-upload mid-decode)."""
-        jnp = self._jnp
-        self._tok_dev = jnp.asarray(self._tok)
-        self._pos_dev = jnp.asarray(self._pos)
-        self._mask_dev = jnp.asarray(self._mask)
+    def _hand_over(self, slot: int, token: int, pos: int, left: int) -> None:
+        """``slot`` is live from the next step on, at ``pos`` with ``token``
+        as its input (a prompt's last launch, ``restore``): the host's
+        mirrors take it, and ``_join`` hands the token to the device's
+        carry inside the next ``_step`` call. Nothing is uploaded here:
+        with a step in flight ``_tok`` is one token old for every other
+        slot, and a whole-array upload would roll them back. Block tables
+        are not device-resident either: they ride into every call as
+        numpy arguments (the committed-call conversion is ~10x cheaper
+        than a device mirror that page-boundary crossings would re-upload
+        mid-decode)."""
+        self._tok[slot, 0] = self._join[slot] = token
+        self._pos[slot] = pos
+        self._left[slot] = left
+        self._mask[slot] = True
+
+    def _drain(self) -> None:
+        """Before anything reads or moves a slot's sequence state
+        (``preempt``, ``restore``, ``verify_commit``, ``close``): the step
+        in flight comes home first, and its tokens are kept for the next
+        ``step()`` to return."""
+        if self._flight is not None:
+            self._kept = self.collect()
+            self.run_ahead["steps_collected_early"] += 1
 
     # -- page bookkeeping -----------------------------------------------------
     def _ensure_writable(self, slot: int, lo: int, hi: int) -> None:
@@ -722,13 +786,14 @@ class PagedLMEngine(DecodeEngine):
 
     def counters(self) -> dict:
         """The running sums the scheduler's metrics take per pass: the
-        pages the steps' attention read (``attn_pages``) and what an
-        expert family's layers counted (``layer_counts``), its two
-        programs added up."""
+        pages the steps' attention read (``attn_pages``), how the steps
+        ran ahead (``run_ahead``) and what an expert family's layers
+        counted (``layer_counts``), its two programs added up."""
         total = dict(self.attn_pages)
         if "window" in self.kinds:
             total["window_pages_released"] = self.window_pages_released
         total.update(self.state_slots)
+        total.update(self.run_ahead)
         for counts in self.layer_counts.values():
             for k, v in counts.items():
                 total[k] = total.get(k, 0) + v
@@ -827,9 +892,7 @@ class PagedLMEngine(DecodeEngine):
             first = int(np.argmax(np.asarray(logits[n_valid - 1])))
             pull.attrs.update(self._pull_chunk_counts())
         self.pull_s += pull.dur_s
-        self._tok[slot, 0] = first
-        self._pos[slot] = tokens.size
-        self._mask[slot] = True
+        self._hand_over(slot, first, tokens.size, st["steps"] - 1)
         if self.share_prefixes:
             # register FULL pages only: a later prompt sharing just the
             # prefix (not the tail) still hits, and registered pages are
@@ -843,14 +906,32 @@ class PagedLMEngine(DecodeEngine):
                     tokens,
                     [int(p) for p in self._bt[slot, :nb_full] if p],
                     nb_full * self.page_size)
-        self._sync_device_state()
         return [(slot, first)]
 
     def step(self) -> np.ndarray:
-        """One paged decode step over every slot; may raise
-        PagePoolExhausted when an active slot crosses into a page the
-        pool cannot supply (scheduler preempts a victim and retries)."""
-        slots = np.flatnonzero(self._mask)
+        """One paged decode step over every slot, run one step ahead: this
+        call prepares and dispatches the step of the slots that still have
+        a token to make, and only then brings home (``collect``) the tokens
+        of the step dispatched by the call before, so the device works
+        while the caller routes them. A slot that was not in that step has
+        ``-1`` in the answer (it joined since, or nothing was in flight).
+        A slot whose request has its last token in flight (``steps`` of
+        ``admit_start``) is left out of the dispatch; an ending the engine
+        cannot foresee (EOS) costs one step whose token ``release`` drops.
+
+        May raise PagePoolExhausted when an active slot crosses into a
+        page the pool cannot supply (scheduler preempts a victim and
+        retries): from prepare, before the step in flight is touched."""
+        who = self._mask & (self._left > 0)
+        flight = self._dispatch(who) if who.any() else None
+        tok = self.collect()
+        self._flight = flight
+        return tok
+
+    def _dispatch(self, who: np.ndarray) -> tuple:
+        """Prepare and dispatch one step of the slots in ``who``; returns
+        what ``_flight`` holds of it."""
+        slots = np.flatnonzero(who)
         live = len(slots)
         with obs_context.span("engine.step.prepare", live=live) as prepare:
             for s in slots:
@@ -889,26 +970,49 @@ class PagedLMEngine(DecodeEngine):
                                      state_slots=self.slots)
                 self.state_slots["state_slots_live"] += live
                 self.state_slots["state_slots"] += self.slots
-        with obs_context.span("engine.step.dispatch", live=live) as dispatch:
-            tok_dev, self._tok_dev, self._pos_dev, *rest = self._step(
-                self._tok_dev, self._pos_dev, self._mask_dev,
-                *self._tables(), *self._pools, *self._states)
+        ahead = int(self._flight is not None)
+        with obs_context.span("engine.step.dispatch", live=live,
+                              ahead=ahead) as dispatch:
+            # every host argument a copy: the mirrors move on below and
+            # at the next join or release, while this step may still read
+            tok_dev, self._tok_dev, *rest = self._step(
+                self._tok_dev, self._pos.copy(), who.copy(),
+                *self._tables(), *self._pools, *self._states,
+                self._join.copy())
             self._keep(rest)
-        with obs_context.span("engine.step.pull", live=live) as pull:
-            # nnlint: disable=NNL101 — one (slots,) pull per decode step:
-            # the scheduler needs host ints to append/retire (documented
-            # contract); explicit device_get, so it stays legal under the
-            # NNS_XFERCHECK disallow scopes and lands in the byte ledger
-            tok = self._jax.device_get(tok_dev)
-            if self.family.counters:
-                # an expert family's counts came home behind the tokens
-                tok, counts = tok[:self.slots], tok[self.slots:]
-                pull.attrs.update(self._note_counts("step", counts))
-                self._pull_chunk_counts()
         self.host_s += prepare.dur_s + dispatch.dur_s
+        self.run_ahead["steps_ahead"] += ahead
+        self._pos += who
+        self._left -= who
+        self._join[:] = -1
+        return tok_dev, who
+
+    def collect(self) -> np.ndarray:
+        """The tokens no ``step()`` has returned yet, ``(slots,)`` with
+        ``-1`` where a slot has none: those of the step in flight, waited
+        for here, and those a drain kept. "``step()``, then ``collect()``"
+        is the synchronous step: each step's own tokens, nothing left in
+        flight."""
+        tok, self._kept = self._kept, np.full((self.slots,), -1, np.int32)
+        flight, self._flight = self._flight, None
+        live = 0 if flight is None else int(flight[1].sum())
+        with obs_context.span("engine.step.pull", live=live) as pull:
+            if flight is not None:
+                tok_dev, who = flight
+                # nnlint: disable=NNL101 — one (slots,) pull per decode
+                # step: the scheduler needs host ints to append/retire
+                # (documented contract); explicit device_get, so it stays
+                # legal under the NNS_XFERCHECK disallow scopes and lands
+                # in the byte ledger
+                got = self._jax.device_get(tok_dev)
+                if self.family.counters:
+                    # an expert family's counts came home behind the tokens
+                    got, counts = got[:self.slots], got[self.slots:]
+                    pull.attrs.update(self._note_counts("step", counts))
+                    self._pull_chunk_counts()
+                self._tok[who, 0] = tok[who] = got[who]
+            pull.attrs["no_token"] = int((self._mask & (tok < 0)).sum())
         self.pull_s += pull.dur_s
-        self._pos = self._pos + self._mask.astype(np.int32)
-        self._tok[self._mask, 0] = tok[self._mask]
         return tok
 
     def verify_commit(self, draft: np.ndarray):
@@ -918,18 +1022,20 @@ class PagedLMEngine(DecodeEngine):
         ``pred[s, :n_emit[s]]`` (accepted drafts equal the target argmax
         by definition; the last entry is the correction). Column 0 of
         ``draft`` must be each slot's carry token, columns 1.. the
-        proposals. The carry stays device-resident: no logits come home
-        and nothing is uploaded but ``draft``."""
+        proposals. No logits come home, and nothing is uploaded but
+        ``draft``, the positions and the mask."""
+        self._drain()
         K = draft.shape[1]
         for s in np.flatnonzero(self._mask):
             lo = int(self._pos[s])
             self._ensure_writable(int(s), lo,
                                   min(lo + K, self.max_seq))
-        # np array passed straight to the jit call: the committed-call
-        # conversion is ~10x cheaper than a standalone jnp.asarray
-        packed, self._tok_dev, self._pos_dev, *pools = self._verify_commit(
-            np.ascontiguousarray(draft, np.int32), self._pos_dev,
-            self._tok_dev, self._mask_dev, self._bt, *self._pools)
+        # np arrays passed straight to the jit call: the committed-call
+        # conversion is ~10x cheaper than a standalone jnp.asarray. The
+        # host's mirrors are exact here (nothing is in flight)
+        packed, *pools = self._verify_commit(
+            np.ascontiguousarray(draft, np.int32), self._pos.copy(),
+            self._mask.copy(), self._bt.copy(), *self._pools)
         self._pools = tuple(pools)
         # nnlint: disable=NNL101 — ONE (slots, K+1) int pull per
         # speculative round (the emitted burst)
@@ -938,7 +1044,9 @@ class PagedLMEngine(DecodeEngine):
         for s in np.flatnonzero(n_emit):
             n = int(n_emit[s])
             self._pos[s] += n
-            self._tok[s, 0] = int(pred[s, n - 1])
+            # the host knows the carry and the device does not: a later
+            # ``step()`` takes it from here
+            self._tok[s, 0] = self._join[s] = int(pred[s, n - 1])
         return pred, n_emit
 
     def _drop_pages(self, slot: int) -> None:
@@ -949,17 +1057,34 @@ class PagedLMEngine(DecodeEngine):
             bt[slot] = 0
         self._held_from[slot] = 0
 
+    def _leave(self, slot: int) -> int:
+        """``slot`` is out of every later step; returns the token it is
+        owed (``-1``: none): one that a drain kept and no ``step()`` has
+        returned yet."""
+        self._mask[slot] = False
+        self._join[slot] = -1
+        self._left[slot] = 0
+        owed, self._kept[slot] = int(self._kept[slot]), -1
+        return owed
+
     def release(self, slot: int) -> None:
         # a state layer's rows stay as they are: the launch that starts the
-        # slot's next sequence zeroes them
+        # slot's next sequence zeroes them. A step in flight may still
+        # write the slot's line and advance its state: safe by the device's
+        # order (the pools are donated from program to program, so whatever
+        # writes these pages or rows next runs after it), and its token is
+        # dropped here
         with obs_context.span("engine.release", slot=slot):
             self._pending.pop(slot, None)
             self._lane.pop(slot, None)
             self._drop_pages(slot)
-            self._mask[slot] = False
+            dropped = int(self._leave(slot) >= 0)
+            if self._flight is not None and self._flight[1][slot]:
+                self._flight[1][slot] = False
+                dropped += 1
+            self.run_ahead["surplus_steps"] += dropped
             self._tok[slot, 0] = 0
             self._pos[slot] = 0
-            self._sync_device_state()
 
     # -- preemption -----------------------------------------------------------
     def _held_span(self, kind: str, slot: int) -> slice:
@@ -979,9 +1104,14 @@ class PagedLMEngine(DecodeEngine):
         events)."""
         if not self._mask[slot]:
             raise ServingError(f"slot {slot} not active")
+        self._drain()
+        # ``left``: the steps its request may still take; ``owed``: the
+        # token the drain brought home for it, which ``restore`` keeps for
+        # the next ``step()`` to return
         blob = {"pages": (), "used": {}, "state": (), "held_from": int(
                     self._held_from[slot]),
-                "tok": int(self._tok[slot, 0]), "pos": int(self._pos[slot])}
+                "tok": int(self._tok[slot, 0]), "pos": int(self._pos[slot]),
+                "left": int(self._left[slot])}
         with obs_context.span("engine.preempt", slot=slot) as sp:
             for kind in self.kinds:
                 row = self._bts[kind][slot, self._held_span(kind, slot)]
@@ -1001,8 +1131,7 @@ class PagedLMEngine(DecodeEngine):
                     self._get_state(slot, *self._states)))
                 sp.attrs["state_bytes"] = self.state_slot_bytes
             self._drop_pages(slot)
-            self._mask[slot] = False
-            self._sync_device_state()
+            blob["owed"] = self._leave(slot)
         self.pool.note_preemption()
         return blob
 
@@ -1012,6 +1141,7 @@ class PagedLMEngine(DecodeEngine):
         if a pool still cannot hold it (scheduler keeps it queued)."""
         if self._mask[slot]:
             raise ServingError(f"slot {slot} already active")
+        self._drain()
         fresh = {}
         try:
             for kind, pool in self.pools_by_kind.items():
@@ -1039,10 +1169,8 @@ class PagedLMEngine(DecodeEngine):
                     tuple(self._jnp.asarray(b) for b in blob["state"]),
                     *self._states)
                 sp.attrs["state_bytes"] = self.state_slot_bytes
-            self._tok[slot, 0] = blob["tok"]
-            self._pos[slot] = blob["pos"]
-            self._mask[slot] = True
-            self._sync_device_state()
+            self._hand_over(slot, blob["tok"], blob["pos"], blob["left"])
+            self._kept[slot] = blob["owed"]
         self.pool.note_restore()
 
     # -- introspection --------------------------------------------------------
@@ -1090,6 +1218,7 @@ class PagedLMEngine(DecodeEngine):
                           for kind, pool in self.pools_by_kind.items()}}
 
     def close(self) -> None:
+        self._drain()
         for slot in range(self.slots):
             if self._mask[slot] or any(bt[slot].any()
                                        for bt in self._bts.values()):

@@ -121,6 +121,14 @@ class ServingMetrics:
         # slot's (what the step read and wrote)
         self.state_slots_live = 0
         self.state_slots = 0
+        # how the engine's decode steps ran ahead of their tokens
+        # (PagedLMEngine.run_ahead): steps dispatched while the step
+        # before's tokens were still on the device, steps brought home
+        # early (a preempt, restore, verify round or close), slot-steps
+        # whose token was dropped (the one step an EOS ending runs over)
+        self.steps_ahead = 0
+        self.steps_collected_early = 0
+        self.surplus_steps = 0
         # device channel: batch execution time (dispatch+block, the
         # reference-comparable number); reservoirs: per-request tails
         self.device = InvokeStats()
@@ -203,8 +211,9 @@ class ServingMetrics:
     def record_layer_counts(self, counts: dict) -> None:
         """What the engine counted since the last pass (the growth of
         ``DecodeEngine.counters()``): its expert layers (``moe_*``, both
-        programs added up), its steps' attention (``attn_pages_*``) and
-        its state layers' cache (``state_slots*``)."""
+        programs added up), its steps' attention (``attn_pages_*``), its
+        state layers' cache (``state_slots*``) and how its steps ran ahead
+        (``steps_ahead``, ``steps_collected_early``, ``surplus_steps``)."""
         with self._lock:
             self.attn_pages_read += counts.get("attn_pages_read", 0)
             self.attn_pages_padded += counts.get("attn_pages_padded", 0)
@@ -216,6 +225,10 @@ class ServingMetrics:
                 "window_pages_released", 0)
             self.state_slots_live += counts.get("state_slots_live", 0)
             self.state_slots += counts.get("state_slots", 0)
+            self.steps_ahead += counts.get("steps_ahead", 0)
+            self.steps_collected_early += counts.get(
+                "steps_collected_early", 0)
+            self.surplus_steps += counts.get("surplus_steps", 0)
             self.moe_experts_touched += counts.get("moe_experts_touched", 0)
             self.moe_expert_slots += counts.get("moe_expert_slots", 0)
             self.moe_assignments += counts.get("moe_assignments", 0)
@@ -271,6 +284,9 @@ class ServingMetrics:
                 "window_pages_released": self.window_pages_released,
                 "state_slots_live": self.state_slots_live,
                 "state_slots": self.state_slots,
+                "steps_ahead": self.steps_ahead,
+                "steps_collected_early": self.steps_collected_early,
+                "surplus_steps": self.surplus_steps,
             }
         out["device"] = self.device.snapshot()
         out["queue_wait"] = self.queue_wait.snapshot()

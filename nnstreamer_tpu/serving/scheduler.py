@@ -896,8 +896,16 @@ class DecodeScheduler:
                                             self.engine.slots, device_s)
             retired = 0
             for slot, req in list(self._active.items()):
-                burst = ([int(toks[slot])] if bursts is None
-                         else [int(t) for t in bursts[slot]])
+                if bursts is not None:
+                    burst = [int(t) for t in bursts[slot]]
+                elif toks[slot] < 0:
+                    # no token for this slot this pass: it was not in the
+                    # step whose tokens the engine returned (it joined
+                    # after that step was dispatched); its first comes
+                    # with the next pass
+                    continue
+                else:
+                    burst = [int(toks[slot])]
                 for tok in burst:
                     self._emit(req, tok, now)
                     if self._finished(req, tok):

@@ -18,6 +18,7 @@ import sys
 
 import numpy as np
 import pytest
+from engine_util import step_now
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -369,13 +370,13 @@ def test_a_shared_prefix_is_mapped_and_copied_on_write():
     assert eng.pool.stats()["cow_copies_total"] >= 1, \
         "recomputing the last prompt position writes into a shared page"
     for _ in range(5):
-        tok = eng.step()
+        tok = step_now(eng)
         a.append(int(tok[0]))
         b.append(int(tok[1]))
     assert a == b, "the sharer decodes what the owner decodes"
     # and both are what a fresh engine serves alone
     _, alone = _engine(share_prefixes=False)
-    c = [alone.admit(0, prompt, 6)] + [int(alone.step()[0]) for _ in range(5)]
+    c = [alone.admit(0, prompt, 6)] + [int(step_now(alone)[0]) for _ in range(5)]
     assert a == c
     eng.release(0)
     eng.release(1)
@@ -392,7 +393,7 @@ def test_preempt_and_restore_are_byte_exact_on_one_pool(prompt_len,
     prompt = rng.integers(0, 96, prompt_len).astype(np.int32)
     out = [eng.admit(0, prompt, 10)]
     for _ in range(steps_before):
-        out.append(int(eng.step()[0]))
+        out.append(int(step_now(eng)[0]))
     held = [int(p) for p in eng._bt[0] if p]
     want = _pool_host(eng)[:, held]
     blob = eng.preempt(0)
@@ -405,10 +406,10 @@ def test_preempt_and_restore_are_byte_exact_on_one_pool(prompt_len,
     assert len(fresh) == len(held)
     np.testing.assert_array_equal(_pool_host(eng)[:, fresh], want)
     while len(out) < 10:
-        out.append(int(eng.step()[0]))
+        out.append(int(step_now(eng)[0]))
     _, alone = _engine(share_prefixes=False)
     straight = [alone.admit(0, prompt, 10)]
-    straight += [int(alone.step()[0]) for _ in range(9)]
+    straight += [int(step_now(alone)[0]) for _ in range(9)]
     assert out == straight, "a paused request resumes where it stopped"
     eng.release(0)
     eng.release(1)

@@ -32,6 +32,7 @@ import re
 
 import numpy as np
 import pytest
+from engine_util import step_now
 
 from nnstreamer_tpu.analysis import sanitizer
 from nnstreamer_tpu.ops import paged_attention
@@ -80,7 +81,7 @@ def _decode(engine, slot, prompt, steps):
     advance too — callers collect their own streams)."""
     out = [engine.admit(slot, np.asarray(prompt, np.int32), steps)]
     while len(out) < steps:
-        out.append(int(engine.step()[slot]))
+        out.append(int(step_now(engine)[slot]))
     engine.release(slot)
     return out
 
@@ -160,7 +161,7 @@ class TestPrefixSharing:
         assert eng.pool.stats()["prefix_hits_total"] >= 1
         assert eng.pool.shared_pages >= 2
         while len(out1) < 8:
-            tok = eng.step()
+            tok = step_now(eng)
             out1.append(int(tok[0]))
             out2.append(int(tok[1]))
         assert out1 == _dense_baseline(cfg, params, p1, 8)
@@ -185,7 +186,7 @@ class TestPrefixSharing:
         out2 = [eng.admit(1, prompt, 10)]  # identical prompt: full hit
         assert eng.pool.stats()["prefix_hits_total"] >= 1
         while len(out1) < 10:
-            tok = eng.step()
+            tok = step_now(eng)
             out1.append(int(tok[0]))
             out2.append(int(tok[1]))
         # both streams must equal the baseline: if either slot's decode
@@ -211,7 +212,7 @@ class TestPreemptRestore:
         out1 = [eng.admit(0, p1, 12)]
         out2 = [eng.admit(1, p2, 12)]
         for _ in range(4):
-            tok = eng.step()
+            tok = step_now(eng)
             out1.append(int(tok[0]))
             out2.append(int(tok[1]))
         used_before = eng.pool.used_pages
@@ -219,10 +220,10 @@ class TestPreemptRestore:
         assert eng.pool.used_pages < used_before  # pages actually freed
         # the survivor keeps decoding while slot 0 sits on the host
         for _ in range(3):
-            out2.append(int(eng.step()[1]))
+            out2.append(int(step_now(eng)[1]))
         eng.restore(0, blob)
         while len(out1) < 12:
-            tok = eng.step()
+            tok = step_now(eng)
             out1.append(int(tok[0]))
             if len(out2) < 12:
                 out2.append(int(tok[1]))
@@ -437,7 +438,7 @@ class TestPoolLayout:
         before = _pools(eng)
         pos = int(eng._pos[0])
         page, offs = int(eng._bt[0, pos // 8]), pos % 8
-        eng.step()
+        step_now(eng)
         for b, a in zip(before, _pools(eng)):
             assert np.any(a[:, page, offs] != b[:, page, offs]), \
                 "the live slot's line must have been written"
@@ -466,7 +467,7 @@ class TestPoolLayout:
         for pool in before:
             np.testing.assert_array_equal(
                 pool[:, int(eng._bt[1, 1]), :7], pool[:, shared[1], :7])
-        eng.step()
+        step_now(eng)
         for b, a in zip(before, _pools(eng)):
             np.testing.assert_array_equal(a[:, shared], b[:, shared])
         eng.release(0)
@@ -487,7 +488,7 @@ class TestPoolLayout:
                             chunk=16, share_prefixes=False)
         out = [eng.admit(0, prompt, 10)]
         for _ in range(steps_before):
-            out.append(int(eng.step()[0]))
+            out.append(int(step_now(eng)[0]))
         held = [int(p) for p in eng._bt[0] if p]
         want = [pool[:, held] for pool in _pools(eng)]
         blob = eng.preempt(0)
@@ -503,7 +504,7 @@ class TestPoolLayout:
         for w, pool in zip(want, _pools(eng)):
             np.testing.assert_array_equal(pool[:, fresh], w)
         while len(out) < 10:
-            out.append(int(eng.step()[0]))
+            out.append(int(step_now(eng)[0]))
         assert out == _dense_baseline(cfg, params, prompt, 10)
         eng.release(0)
         eng.release(1)

@@ -22,6 +22,7 @@ import sys
 
 import numpy as np
 import pytest
+from engine_util import step_now
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -278,7 +279,7 @@ def test_decode_steps_logits_match_the_reference_past_the_window():
     prompt = rng.integers(0, 96, 21).astype(np.int32)
     served = [eng.admit(0, prompt, 60)]
     for _ in range(59):
-        served.append(int(eng.step()[0]))
+        served.append(int(step_now(eng)[0]))
     served = np.asarray(served)
     exact = _reference_logits(key, sz, prompt, served)
     assert (exact.argmax(-1) == served).mean() > 0.9
@@ -347,7 +348,7 @@ def test_a_slot_gives_window_pages_back_and_never_holds_more_than_the_bound():
     while not eng.prefill_tick():
         most = max(most, _window_pages(eng, 0))
     for _ in range(39):
-        eng.step()
+        step_now(eng)
         most = max(most, _window_pages(eng, 0))
         # what is held covers what the next query still sees, no more
         pos = int(eng._pos[0])
@@ -392,7 +393,7 @@ def test_preempt_and_restore_are_byte_exact_after_pages_were_given_back(
     prompt = rng.integers(0, 96, prompt_len).astype(np.int32)
     out = [eng.admit(0, prompt, 40)]
     for _ in range(steps_before):
-        out.append(int(eng.step()[0]))
+        out.append(int(step_now(eng)[0]))
     held = {kind: [int(p) for p in eng._bts[kind][0] if p]
             for kind in eng.kinds}
     if prompt_len + steps_before > WINDOW + 4:
@@ -418,11 +419,11 @@ def test_preempt_and_restore_are_byte_exact_after_pages_were_given_back(
         for got, w in zip(lines(kind, fresh), want[kind]):
             np.testing.assert_array_equal(got, w)
     while len(out) < 40:
-        out.append(int(eng.step()[0]))
+        out.append(int(step_now(eng)[0]))
     _, _, _, alone = _engine()
     straight = [alone.admit(0, prompt, 40)]
     while len(straight) < 40:
-        straight.append(int(alone.step()[0]))
+        straight.append(int(step_now(alone)[0]))
     assert out == straight, "the restored stream is the uninterrupted one"
     assert _gaps(key, sz, prompt, out).max() <= GAP_TOL
 
@@ -497,7 +498,7 @@ def test_a_step_counts_the_pages_it_reads_by_kind():
     eng.admit(0, rng.integers(0, 96, 41).astype(np.int32), 8)
     eng.admit(1, rng.integers(0, 96, 6).astype(np.int32), 8)
     before = dict(eng.counters())
-    eng.step()
+    step_now(eng)
     span = [s for s in context.finished_spans()
             if s.name == "engine.step.prepare"][-1]
     # slot 0 sees 42 positions (11 pages; 12 of them in a window layer: the

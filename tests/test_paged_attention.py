@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from engine_util import step_now
 
 from nnstreamer_tpu.ops import paged_attention as pa
 from nnstreamer_tpu.serving import DecodeScheduler, PagedLMEngine
@@ -175,7 +176,7 @@ def _serve(cfg, params):
     out = {0: [eng.admit(0, rng.integers(1, 60, 21).astype(np.int32), 9)],
            2: [eng.admit(2, rng.integers(1, 60, 3).astype(np.int32), 9)]}
     for _ in range(8):
-        tok = eng.step()
+        tok = step_now(eng)
         for s in out:
             out[s].append(int(tok[s]))
     eng.close()

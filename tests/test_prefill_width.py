@@ -9,6 +9,7 @@ of a described chip; the engine test steers its one chip lookup.
 """
 import numpy as np
 import pytest
+from engine_util import step_now
 
 from nnstreamer_tpu.obs import context as obs_context
 from nnstreamer_tpu.serving import PagedLMEngine, lm_engine
@@ -61,7 +62,7 @@ def _serve(engine, prompts, steps):
         toks = [engine.admit(0, p, steps)]
         launches = engine.prefill_stamp(0)[1]
         while len(toks) < steps:
-            toks.append(int(engine.step()[0]))
+            toks.append(int(step_now(engine)[0]))
         engine.release(0)
         out.append((toks, launches))
     return out

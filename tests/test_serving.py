@@ -349,6 +349,34 @@ class ToyEngine(DecodeEngine):
         self._tok[slot] = 0
 
 
+class LaggingToyEngine(ToyEngine):
+    """The toy engine with a step in flight: ``step`` answers with the
+    tokens of the step before, and below zero for a slot that was not in
+    it (``DecodeEngine.step``)."""
+
+    def __init__(self, slots=2):
+        super().__init__(slots)
+        self._live = np.zeros(slots, bool)
+        self._flight = np.full(slots, -1, np.int32)
+        self.unanswered = 0
+
+    def prefill_tick(self):
+        done = super().prefill_tick()
+        self._live[done[0][0]] = True
+        return done
+
+    def step(self):
+        answer, self._flight = self._flight, np.where(
+            self._live, super().step(), -1).astype(np.int32)
+        self.unanswered += int((self._live & (answer < 0)).sum())
+        return answer
+
+    def release(self, slot):
+        super().release(slot)
+        self._live[slot] = False
+        self._flight[slot] = -1
+
+
 def _expected(prompt_last, steps):
     return [(prompt_last + 1 + i) % 97 for i in range(steps)]
 
@@ -365,6 +393,23 @@ class TestDecodeScheduler:
             assert long.result(30)[0].tolist() == _expected(5, 40)
         finally:
             sched.close()
+
+    def test_a_slot_with_no_token_this_pass_emits_nothing_and_loses_none(self):
+        engine = LaggingToyEngine(slots=2)
+        sched = DecodeScheduler(engine, name="t-lagging")
+        try:
+            long = sched.submit(np.array([5], np.int32), steps=40)
+            short = sched.submit(np.array([10], np.int32), steps=3)
+            third = sched.submit(np.array([50], np.int32), steps=7)
+            assert short.result(30)[0].tolist() == _expected(10, 3)
+            assert third.result(30)[0].tolist() == _expected(50, 7)
+            assert long.result(30)[0].tolist() == _expected(5, 40)
+            stamps = long.metrics["token_t"]
+            assert len(stamps) == 40 and stamps == sorted(stamps)
+        finally:
+            sched.close()
+        # each request had a pass with no token: the one it joined in
+        assert engine.unanswered >= 3
 
     def test_retire_frees_slot_for_queued_request(self):
         sched = DecodeScheduler(ToyEngine(slots=1), name="t-slot1")

@@ -165,6 +165,43 @@ def test_the_second_prompt_waits_in_the_lane_for_the_firsts_chunks(served):
     assert lanes[second] >= chunks[2].end_s - chunks[0].start_s
 
 
+# -- one step ahead (ISSUE 35) ---------------------------------------------------------
+
+def test_a_step_is_dispatched_before_the_one_before_comes_home(served):
+    """In every pass the next step's ``dispatch`` ends before the ``pull``
+    starts, and that pull is the wait for the step dispatched a pass
+    earlier: it carries that step's ``live``."""
+    in_flight, first_dispatches, no_token = None, 0, 0
+    for p in _passes(served):
+        kids = {k.name: k for k in served["kids"].get(id(p), ())}
+        pull = kids.get("engine.step.pull")
+        if pull is None:
+            continue
+        assert p.attrs["steps"] == 1
+        assert pull.attrs["live"] == (in_flight.attrs["live"]
+                                      if in_flight is not None else 0)
+        no_token += pull.attrs["no_token"]
+        dispatch = kids.get("engine.step.dispatch")
+        if dispatch is not None:
+            assert kids["engine.step.prepare"].end_s <= dispatch.start_s
+            assert dispatch.end_s <= pull.start_s + 1e-9
+            assert dispatch.attrs["ahead"] == int(in_flight is not None)
+            first_dispatches += in_flight is None
+        in_flight = dispatch
+    snap = served["snap"]
+    dispatched = sum(1 for s in served["spans"]
+                     if s.name == "engine.step.dispatch")
+    # ahead on all but the first dispatch after nothing was in flight
+    assert snap["steps_ahead"] == dispatched - first_dispatches > 0
+    assert snap["steps_collected_early"] == snap["surplus_steps"] == 0
+    # a request has no token in the answer of the pass in which it joined
+    assert no_token == len(REQUESTS)
+    # every token of a step but each request's first: budgets end on time
+    assert sum(s.attrs["live"] for s in served["spans"]
+               if s.name == "engine.step.dispatch") == sum(
+        steps - 1 for _, steps in REQUESTS)
+
+
 # -- the counters at the same boundaries -------------------------------------------
 
 def test_the_counters_agree_with_the_spans(served):
@@ -179,7 +216,10 @@ def test_the_counters_agree_with_the_spans(served):
         s.name for s in _under(p, served["kids"])})
     assert snap["passes"] == len(passes)
     assert snap["passes_with_chunk"] == holding("engine.chunk.dispatch") == 6
-    assert snap["passes_with_step"] == holding("engine.step.dispatch")
+    # a pass with a step brings a step's tokens home; it dispatches the
+    # next one unless every live slot waits for its last token
+    assert snap["passes_with_step"] == holding("engine.step.pull")
+    assert snap["passes_with_step"] > holding("engine.step.dispatch") > 0
     assert snap["passes_with_step"] == snap["decode_steps"]
     assert snap["passes_with_both"] == both
     assert snap["prefill_chunks"] == 6
@@ -380,6 +420,40 @@ def test_the_engine_calls_work_through_a_delegating_proxy(served):
     assert [r.metrics["chunks"] for r in reqs] == [3, 1, 2]
     assert snap["host_engine_s"] > 0 and snap["pull_wait_s"] > 0
     assert snap["prefill_chunks"] == 6
+
+
+def test_the_benchmarks_stamps_give_the_programs_token_gap_to_within_one_in_n():
+    """The benchmark's proxy stamps a token for every slot it holds live at
+    each return of ``step()``. With a step in flight such a stamp falls a
+    pass before the token it stands for and a request has one more of them
+    than tokens; the gaps between stamps are still the pass period. The
+    program's own stamps (``token_t``, written where a token is emitted)
+    stay exact."""
+    from benchmark.drivers.lm_serving import EngineProxy, _record
+
+    prompts, budgets = _prompts(), (12, 9, 15)
+    proxy = EngineProxy(_tiny_engine())
+    records = [_record({"prompt": p, "steps": n})
+               for p, n in zip(prompts, budgets)]
+    sched = DecodeScheduler(proxy, name="stamped")
+    try:
+        for p, rec in zip(prompts, records):
+            proxy.track(p, rec)
+        reqs = [sched.submit(p, steps=n) for p, n in zip(prompts, budgets)]
+        for r in reqs:
+            r.result(timeout=120)
+    finally:
+        sched.close()
+    for req, rec, n in zip(reqs, records, budgets):
+        own, seen = req.metrics["token_t"], rec["token_t"]
+        assert len(own) == n and len(seen) == n + 1
+        # its first token and its last come out in the passes the proxy
+        # saw them in: the same span of time, over n gaps and not n - 1
+        assert seen[0] == pytest.approx(own[0], abs=5e-3)
+        assert seen[-1] == pytest.approx(own[-1], abs=5e-3)
+        mean_own = (own[-1] - own[0]) / (n - 1)
+        mean_seen = (seen[-1] - seen[0]) / n
+        assert abs(mean_seen / mean_own - 1) <= 1 / n + 0.05
 
 
 def test_a_preempted_request_keeps_its_first_stamps():
