@@ -105,44 +105,20 @@ FUSED_STAGE = ("tensor_transform mode=arithmetic "
                "model=nnstreamer_tpu.models.mobilenet_v2:filter_model name=f")
 
 
-class CompileClock:
-    """Seconds jax itself reports for tracing and for XLA compilation (or
-    the load from the persistent cache that takes its place), and the
-    cache's hits and misses — from jax.monitoring, so a warm run shows as
-    what it is whatever else the wall time holds."""
+def compile_clock() -> dict:
+    """Seconds jax itself reports for tracing and lowering and for XLA
+    compilation (or the load from the persistent cache that takes its
+    place), and the cache's hits and misses, so a warm run shows as what
+    it is whatever else the wall time holds: the program's own compile
+    account (``obs.context.compile_account``), which listens from
+    ``enable_compilation_cache`` on."""
+    from nnstreamer_tpu.obs import context as obs_context
 
-    TRACE = ("/jax/core/compile/jaxpr_trace_duration",
-             "/jax/core/compile/jaxpr_to_mlir_module_duration")
-    COMPILE = "/jax/core/compile/backend_compile_duration"
-    HIT = "/jax/compilation_cache/cache_hits"
-    MISS = "/jax/compilation_cache/cache_misses"
-
-    def __init__(self):
-        import collections
-        import threading
-
-        import jax.monitoring
-
-        self._lock = threading.Lock()  # pipelines compile on their threads
-        self._sum = collections.Counter()
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        with self._lock:
-            self._sum[event] += duration
-
-    def _event(self, event, **_):
-        with self._lock:
-            self._sum[event] += 1
-
-    def read(self) -> dict:
-        with self._lock:
-            s = dict(self._sum)
-        return {"trace_s": sum(s.get(e, 0.0) for e in self.TRACE),
-                "compile_s": s.get(self.COMPILE, 0.0),
-                "cache_hits": s.get(self.HIT, 0),
-                "cache_misses": s.get(self.MISS, 0)}
+    totals = obs_context.compile_account()["totals"]
+    return {"trace_s": totals["trace_s"] + totals["lower_s"],
+            "compile_s": totals["compile_s"],
+            "cache_hits": totals["cache_hits"],
+            "cache_misses": totals["cache_misses"]}
 
 
 class CheckFailed(AssertionError):
@@ -824,20 +800,19 @@ def main() -> int:
         "native_available": True,
         "legs": {},
     }
-    clock = CompileClock()
     legs = {"kernels": kernels_leg, "stream": stream_leg,
             "serving": serving_leg, "latent_serving": latent_serving_leg,
             "window_serving": window_serving_leg,
             "state_serving": state_serving_leg}
     for name, leg in legs.items():
-        t0, before = time.monotonic(), clock.read()
+        t0, before = time.monotonic(), compile_clock()
         try:
             res = leg()
             res["passed"] = True
         except Exception as e:  # noqa: BLE001 — recorded, exit code 1 below
             traceback.print_exc()
             res = {"passed": False, "error": f"{type(e).__name__}: {e}"[:500]}
-        after = clock.read()
+        after = compile_clock()
         res.update({k: round(after[k] - before[k], 2) for k in after})
         res["wall_s"] = round(time.monotonic() - t0, 2)
         summary["legs"][name] = res
@@ -845,7 +820,7 @@ def main() -> int:
               f"{'passed' if res['passed'] else 'FAILED'} "
               f"in {res['wall_s']} s", file=sys.stderr)
     summary["ok"] = all(leg["passed"] for leg in summary["legs"].values())
-    summary["compile_s"] = round(clock.read()["compile_s"], 2)
+    summary["compile_s"] = round(compile_clock()["compile_s"], 2)
     summary["wall_s"] = round(time.monotonic() - t_start, 2)
     print(json.dumps({"report": summary}))
     print(json.dumps(result_line(summary)), flush=True)

@@ -344,9 +344,23 @@ class _LMServingEntry:
             raise NotImplementedError(
                 f"lm_serving: speculative verification (_verify) does not "
                 f"serve the {fam.name} family yet; build it without draft=")
-        params, _ = self._shard_params(None)
-        eng = PagedLMEngine(self._cfg_serve, params, slots=slots,
-                            **engine_kw)
+        from ..obs import context as obs_context
+
+        # start-up's spans (docs/observability.md): the weights in the
+        # serving type, then the engine: what its constructor cost the host
+        # (the pools' allocations are dispatched and not waited for) and
+        # what it built
+        with obs_context.span("setup.params") as sp:
+            params, _ = self._shard_params(None)
+        with obs_context.span("setup.engine", slots=slots) as built:
+            eng = PagedLMEngine(self._cfg_serve, params, slots=slots,
+                                **engine_kw)
+            built.attrs.update(
+                page_size=eng.page_size, chunk=eng.chunk,
+                pool_bytes={kind: of["bytes"] for kind, of
+                            in eng.memory_bytes()["kinds"].items()},
+                state_bytes=eng.state_slot_bytes * slots)
+        sp.attrs["bytes"] = eng.param_bytes
         if draft is None:
             return eng
         from ..serving.speculative import (

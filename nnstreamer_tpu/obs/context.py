@@ -30,6 +30,17 @@ whenever a session runs, and lands in the same bounded ring on
 (``tools/microbench_overhead.py`` measures it), no id string, no flight
 event.
 
+The compile account (:func:`compile_account`) is the program's own record
+of what jax spent before a program could run: one ``jax.monitoring``
+listener, registered with the first program span (or by whoever reads the
+account first), hears every trace, lowering and backend-compile duration
+and every persistent-cache hit and miss, charges it to the innermost
+program span open on the thread that paid it, and keeps it in a bounded
+list with running totals. A backend compile that followed a cache hit on
+its thread is a *load*, any other is *fresh*. The spans of start-up
+(``setup.*``, ``program.first_call``) are kept in a list of their own
+(:func:`startup_spans`), which a window's pass spans cannot push out.
+
 Export: :func:`export_chrome_trace` writes chrome://tracing / Perfetto
 JSON (``X`` complete events); trace_id/span_id/parent_span_id/links ride
 each event's ``args`` so tooling (and tests) can reconstruct the tree.
@@ -62,8 +73,13 @@ _span_seq = itertools.count(1)
 # finished spans, bounded (deque append/iteration is thread-safe under
 # the GIL; oldest spans fall off — export is for recent activity, the
 # flight recorder keeps the tail even when tracing is later disabled).
-# Sized for the always-on program spans: about ten a scheduler pass, so
-# some minutes of passes of 60 ms and every request of that time
+# Sized for the always-on program spans: a steady pass leaves six, nine with
+# a prefill launch, one more a retirement and five a finished request. The
+# benchmark's cells leave 2,200–25,100 a run (PERF.md section 6, PR 36); a
+# 48 s window kept full at the fastest full-batch pass measured (7.5 ms,
+# eight spans with a launch every seventh pass) would leave 51,000, and
+# passes of 3.6 ms (one read of a 1.3B model's weights a step) fill the ring
+# in 34 s. Start-up's spans do not depend on it (``_startup``)
 MAX_FINISHED = 65536
 _finished: "collections.deque[Span]" = collections.deque(maxlen=MAX_FINISHED)
 _finished_seq = itertools.count(1)
@@ -250,7 +266,19 @@ def record_span(name: str, kind: str = "span", parent=None,
 ANNOTATION_PREFIX = "nns:"
 _annotation_names: Dict[str, str] = {}   # span name -> prefixed, built once
 _TraceAnnotation = None                  # jax.profiler's, loaded at first use
-_open = threading.local()                # .top: innermost open span, per thread
+# per thread. .top: innermost open span; for the compile account,
+# .cache_hit: a persistent-cache hit was heard since this thread's last
+# backend compile, .heard: its latest durations that none has held yet, and
+# .compiled: (backend compiles, their seconds) this thread has paid
+_open = threading.local()
+
+# spans of start-up, by name: also kept in ``_startup``, out of the ring's
+# reach (an engine leaves two ``setup.*`` and one ``program.first_call`` a
+# program; the bound is for a process that builds engines all day)
+STARTUP_NAMES = ("setup.", "program.first_call")
+MAX_STARTUP = 512
+_startup: "collections.deque[ProgramSpan]" = collections.deque(
+    maxlen=MAX_STARTUP)
 
 
 class ProgramSpan:
@@ -279,6 +307,7 @@ class ProgramSpan:
         global _TraceAnnotation
         if _TraceAnnotation is None:
             from jax.profiler import TraceAnnotation as _TraceAnnotation
+            _listen_to_jax()
         self._prev = getattr(_open, "top", None)
         if self.parent is None:
             self.parent = self._prev
@@ -303,6 +332,8 @@ class ProgramSpan:
         # flight event (a pass's spans would flush that ring's 512 events
         # in seconds)
         _append_finished(self)
+        if self.name.startswith(STARTUP_NAMES):
+            _startup.append(self)
         return False
 
     def record(self, start_s: float, end_s: float) -> "ProgramSpan":
@@ -369,6 +400,201 @@ def span(name: str, parent=None, **attrs) -> ProgramSpan:
     return ProgramSpan(name, parent, attrs)
 
 
+# -- the compile account -----------------------------------------------------
+
+# jax's event -> the attribute it adds to on the span that paid, and the
+# name it has in the account
+_JAX_SECONDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "compile_s",
+}
+_JAX_COUNTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+_COUNTED = frozenset(_JAX_COUNTS.values())
+
+CompileEvent = collections.namedtuple(
+    "CompileEvent", "t event seconds own hit span fun")
+CompileEvent.__doc__ = """One thing jax reported: when it ended
+(``time.monotonic``), which (``trace_s``, ``lower_s``, ``compile_s``,
+``cache_hits``, ``cache_misses``), its seconds as jax gave them (0.0 for the
+two counts) and its ``own`` seconds (less the events that ended inside it on
+its thread: tracing a function holds the tracing of every jitted function it
+calls, and whatever it computes on the way), ``hit`` (a ``compile_s`` that
+was a load from the persistent cache, or the hit itself: True; a fresh
+compile or a miss: False; None for tracing and lowering), the name of the
+program span charged (None: no span was open on that thread) and jax's name
+for the function."""
+# the durations of one thread nest or follow one another; a listener hears
+# an end a few microseconds late, which is all the slack nesting needs
+_NESTING_SLACK_S = 2e-5
+# a thread's latest durations that nothing has held yet: one traced program
+# calls thousands of jitted functions, all of them inside its own tracing
+_HEARD_KEPT = 16384
+
+# a program's start is a tracing event for every jitted function it calls
+# (a thousand and more), then one lowering, one compile and the cache's
+# word; the totals run on when the list has dropped its oldest
+MAX_COMPILE_EVENTS = 32768
+_compile_events: "collections.deque[CompileEvent]" = collections.deque(
+    maxlen=MAX_COMPILE_EVENTS)
+_account_lock = threading.Lock()
+_NO_TOTALS = {"trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
+              "trace_own_s": 0.0, "lower_own_s": 0.0, "load_s": 0.0,
+              "compiles": 0, "loads": 0, "cache_hits": 0,
+              "cache_misses": 0, "unspanned": 0}
+_totals = dict(_NO_TOTALS)                # guarded-by: _account_lock
+_events_heard = 0                         # guarded-by: _account_lock
+_listening = False                        # guarded-by: _account_lock
+
+
+def _listen_to_jax() -> None:
+    """Register the account's two listeners with jax.monitoring, once a
+    process (jax has no way to take one back)."""
+    global _listening
+    with _account_lock:
+        if _listening:
+            return
+        _listening = True
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_heard_seconds)
+    jax.monitoring.register_event_listener(_heard_count)
+
+
+def _heard_seconds(event, duration, **kw) -> None:
+    key = _JAX_SECONDS.get(event)
+    if key is not None:
+        _charge(key, float(duration), kw.get("fun_name"))
+
+
+def _heard_count(event, **_kw) -> None:
+    key = _JAX_COUNTS.get(event)
+    if key is not None:
+        _charge(key, 1, None)
+
+
+def _tally(totals: dict, ev: CompileEvent) -> None:
+    totals[ev.event] += 1 if ev.event in _COUNTED else ev.seconds
+    if ev.event == "compile_s":
+        totals["compiles"] += 1
+        if ev.hit:
+            totals["loads"] += 1
+            totals["load_s"] += ev.seconds
+    elif ev.event == "trace_s":
+        totals["trace_own_s"] += ev.own
+    elif ev.event == "lower_s":
+        totals["lower_own_s"] += ev.own
+    if ev.span is None:
+        totals["unspanned"] += 1
+
+
+def _charge(key: str, amount, fun) -> None:
+    """One event into the account, and onto the innermost program span
+    open on this thread (jax compiles on the thread that called).
+    ``amount``: the seconds of a duration, 1 for a count. A span is
+    charged a duration's own seconds, so that what it carries adds up to
+    no more than it lasted."""
+    global _events_heard
+    now = time.monotonic()
+    top = getattr(_open, "top", None)
+    hit, seconds, own = None, 0.0, 0.0
+    if key == "cache_hits":
+        _open.cache_hit = hit = True
+    elif key == "cache_misses":
+        hit = False
+    else:
+        seconds = own = amount
+        if key == "compile_s":
+            hit = getattr(_open, "cache_hit", False)
+            _open.cache_hit = False
+            paid = getattr(_open, "compiled", (0, 0.0))
+            _open.compiled = (paid[0] + 1, paid[1] + seconds)
+        # what ended inside this duration has reported its seconds already
+        heard = getattr(_open, "heard", None)
+        if heard is None:
+            heard = _open.heard = collections.deque(maxlen=_HEARD_KEPT)
+        began = now - seconds
+        while heard and heard[-1][0] >= began - _NESTING_SLACK_S:
+            own -= heard.pop()[1]
+        amount = own = max(own, 0.0)
+        if seconds:
+            heard.append((began, seconds))
+    ev = CompileEvent(now, key, seconds, own, hit,
+                      None if top is None else top.name, fun)
+    with _account_lock:
+        if top is not None:
+            attrs = top.attrs
+            attrs[key] = attrs.get(key, 0) + amount
+            if key == "compile_s":
+                attrs["compiles"] = attrs.get("compiles", 0) + 1
+        _tally(_totals, ev)
+        _events_heard += 1
+        _compile_events.append(ev)
+
+
+def compile_running() -> Tuple[int, float]:
+    """``(backend compiles, their seconds)`` that the calling thread has
+    paid so far, loads and fresh ones alike (jax compiles on the thread that
+    called the program): what the decode loop reads before and after a pass,
+    so a pass counts its own engine's compiles and no other thread's."""
+    return getattr(_open, "compiled", (0, 0.0))
+
+
+def compile_account(since: Optional[float] = None,
+                    until: Optional[float] = None) -> dict:
+    """What jax reported since the account began to listen (the first
+    program span of the process, ``utils.hw_accel.enable_compilation_cache``
+    or the first call of this function, whichever came first)::
+
+        {"totals": {"trace_s", "lower_s", "compile_s", "trace_own_s",
+                    "lower_own_s", "load_s", "fresh_s", "compiles", "loads",
+                    "fresh", "cache_hits", "cache_misses", "unspanned"},
+         "events": [CompileEvent, ...],      # oldest first, bounded
+         "dropped": 0}
+
+    ``trace_s`` and ``lower_s`` are jax's durations summed as any listener
+    would sum them; ``trace_own_s`` and ``lower_own_s`` count no second
+    twice (:class:`CompileEvent`), so they and ``compile_s`` add up to wall
+    time. ``load_s`` / ``loads`` are the backend compiles that a
+    persistent-cache hit preceded on their thread, ``fresh_s`` / ``fresh``
+    the others; ``unspanned`` counts the events whose thread had no program
+    span open (kept with ``span=None``). With ``since`` or ``until``
+    (``time.monotonic``) both are of the events kept with ``since <= t <
+    until``: "before the window opened", "inside it". ``dropped`` counts
+    the events the bounded list has let go that such sums lack (all older
+    than the oldest kept): 0 without an interval, where the totals are the
+    running ones, and 0 where ``since`` is no older than the oldest kept."""
+    _listen_to_jax()
+    with _account_lock:
+        totals = dict(_totals)
+        events = list(_compile_events)
+        dropped = _events_heard - len(events)
+    if since is None and until is None:
+        dropped = 0
+    else:
+        if since is not None and events and events[0].t <= since:
+            dropped = 0  # the interval begins among the events kept
+        events = [e for e in events
+                  if (since is None or e.t >= since)
+                  and (until is None or e.t < until)]
+        totals = dict(_NO_TOTALS)
+        for ev in events:
+            _tally(totals, ev)
+    totals["fresh_s"] = totals["compile_s"] - totals["load_s"]
+    totals["fresh"] = totals["compiles"] - totals["loads"]
+    return {"totals": totals, "events": events, "dropped": dropped}
+
+
+def startup_spans() -> List[ProgramSpan]:
+    """The ``setup.*`` and ``program.first_call`` spans of this process,
+    oldest first: kept apart from the ring, so they can be read after any
+    number of passes."""
+    return list(_startup)
+
+
 # -- control -----------------------------------------------------------------
 
 def enable_tracing() -> None:
@@ -382,8 +608,10 @@ def disable_tracing() -> None:
 
 
 def reset() -> None:
-    """Drop recorded spans (tests / fresh export windows)."""
+    """Drop recorded spans (tests / fresh export windows). The compile
+    account runs on: its totals are counters."""
     _finished.clear()
+    _startup.clear()
 
 
 def finished_spans() -> List[Span]:

@@ -412,6 +412,12 @@ def _collect_serving(reg: Registry) -> None:
              "host wall under the engine's prepare and dispatch spans"),
             ("pull_wait_s", "pull_wait_seconds",
              "host wall under the engine's pull spans"),
+            ("compiles", "compiles",
+             "backend compiles jax reported on the decode loop's thread "
+             "while a pass ran, loads from the persistent cache among them "
+             "(0 once every shape is warm)"),
+            ("compile_s", "compile_seconds",
+             "seconds of the backend compiles that fell in a pass"),
             ("moe_experts_touched", "moe_experts_touched",
              "experts that received a token, summed over expert layers "
              "and program calls"),

@@ -35,7 +35,12 @@ of a program is split where the host does three different things,
 (uploads and the jitted call until it returns) and ``.pull`` (the device's
 answer brought to the host; a step's pull is the wait for the step BEFORE
 the one just dispatched). ``host_s`` and ``pull_s`` are the running sums
-of the first two and of the third.
+of the first two and of the third. Start-up has its own: the serving entry
+builds the engine under ``setup.engine``
+(``models.lm_serving.make_continuous``), and the first call of each jitted
+program runs under a ``program.first_call`` child of the span that made it,
+where the compile account (``obs.context.compile_account``) writes what jax
+spent tracing, lowering and compiling or loading it.
 """
 from __future__ import annotations
 
@@ -260,6 +265,7 @@ class PagedLMEngine(DecodeEngine):
         self.share_prefixes = share_prefixes
         self.compile_count = 0
         self.host_s = self.pull_s = 0.0  # under the spans below, summed
+        self._ran: set = set()           # the programs called once (_run)
         self._jnp = jnp
         self._jax = jax
 
@@ -650,6 +656,22 @@ class PagedLMEngine(DecodeEngine):
                 jax.jit(_verify_commit,
                         donate_argnums=tuple(range(5, 5 + P))), params)
 
+    def _run(self, name: str, program, *args):
+        """Call a jitted program of this engine. The first call of each
+        runs under a ``program.first_call`` span, a child of whatever span
+        made the call: jax traces, lowers and compiles (or loads) a program
+        when it is first called, and the compile account charges those
+        seconds to the innermost span open. Later calls open nothing."""
+        if name in self._ran:
+            return program(*args)
+        self._ran.add(name)
+        with obs_context.span("program.first_call", program=name):
+            return program(*args)
+
+    def _move(self, kind: str, mover: str, *args):
+        """One of a kind's page movers (``copy``, ``gather``, ``scatter``)."""
+        return self._run(f"{mover}.{kind}", self._movers[kind][mover], *args)
+
     @property
     def _bt(self):
         """The block table of the first kind of layer (the only one, for a
@@ -735,8 +757,9 @@ class PagedLMEngine(DecodeEngine):
                 elif pool.is_shared(page):
                     new = pool.alloc(1)[0]  # pairs-with: release (slot exit)
                     try:
-                        self._set_kind_pools(kind, self._movers[kind]["copy"](
-                            new, page, *self._kind_pools(kind)))
+                        self._set_kind_pools(kind, self._move(
+                            kind, "copy", new, page,
+                            *self._kind_pools(kind)))
                     except BaseException:
                         pool.release([new])  # copy failed: page never owned
                         raise
@@ -871,8 +894,9 @@ class PagedLMEngine(DecodeEngine):
                 prepare.attrs["state_reset"] = int(start == 0)
                 state_args = (jnp.asarray(slot, jnp.int32), *self._states)
         with obs_context.span("engine.chunk.dispatch", **attrs) as dispatch:
-            logits, *rest = self._prefill_chunk(
-                jnp.asarray(padded), jnp.asarray(start, jnp.int32),
+            logits, *rest = self._run(
+                "_prefill_chunk", self._prefill_chunk, jnp.asarray(padded),
+                jnp.asarray(start, jnp.int32),
                 jnp.asarray(n_valid, jnp.int32), *self._tables(slot),
                 *self._pools, *state_args)
             if self.family.counters:
@@ -975,7 +999,8 @@ class PagedLMEngine(DecodeEngine):
                               ahead=ahead) as dispatch:
             # every host argument a copy: the mirrors move on below and
             # at the next join or release, while this step may still read
-            tok_dev, self._tok_dev, *rest = self._step(
+            tok_dev, self._tok_dev, *rest = self._run(
+                "_step", self._step,
                 self._tok_dev, self._pos.copy(), who.copy(),
                 *self._tables(), *self._pools, *self._states,
                 self._join.copy())
@@ -1033,7 +1058,8 @@ class PagedLMEngine(DecodeEngine):
         # np arrays passed straight to the jit call: the committed-call
         # conversion is ~10x cheaper than a standalone jnp.asarray. The
         # host's mirrors are exact here (nothing is in flight)
-        packed, *pools = self._verify_commit(
+        packed, *pools = self._run(
+            "_verify_commit", self._verify_commit,
             np.ascontiguousarray(draft, np.int32), self._pos.copy(),
             self._mask.copy(), self._bt.copy(), *self._pools)
         self._pools = tuple(pools)
@@ -1115,8 +1141,8 @@ class PagedLMEngine(DecodeEngine):
         with obs_context.span("engine.preempt", slot=slot) as sp:
             for kind in self.kinds:
                 row = self._bts[kind][slot, self._held_span(kind, slot)]
-                blobs = self._movers[kind]["gather"](
-                    row, *self._kind_pools(kind))
+                blobs = self._move(kind, "gather", row,
+                                   *self._kind_pools(kind))
                 # nnlint: disable=NNL101 — preemption IS the host transfer:
                 # the victim's pages move to host RAM so the pool can be
                 # re-used; restore uploads the same bytes
@@ -1127,8 +1153,8 @@ class PagedLMEngine(DecodeEngine):
                 # the state goes with the pages: another sequence's first
                 # launch in this slot zeroes the rows
                 # nnlint: disable=NNL101 — as the pages above
-                blob["state"] = tuple(self._jax.device_get(
-                    self._get_state(slot, *self._states)))
+                blob["state"] = tuple(self._jax.device_get(self._run(
+                    "_get_state", self._get_state, slot, *self._states)))
                 sp.attrs["state_bytes"] = self.state_slot_bytes
             self._drop_pages(slot)
             blob["owed"] = self._leave(slot)
@@ -1158,13 +1184,14 @@ class PagedLMEngine(DecodeEngine):
                 row[blob["used"][kind]] = fresh[kind]
                 self._bts[kind][slot] = 0
                 self._bts[kind][slot, self._held_span(kind, slot)] = row
-                self._set_kind_pools(kind, self._movers[kind]["scatter"](
-                    self._jnp.asarray(row),
+                self._set_kind_pools(kind, self._move(
+                    kind, "scatter", self._jnp.asarray(row),
                     tuple(self._jnp.asarray(b)
                           for b in blob["pages"][k * P:(k + 1) * P]),
                     *self._kind_pools(kind)))
             if self._states:
-                self._states = self._put_state(
+                self._states = self._run(
+                    "_put_state", self._put_state,
                     self._jnp.asarray(slot, self._jnp.int32),
                     tuple(self._jnp.asarray(b) for b in blob["state"]),
                     *self._states)

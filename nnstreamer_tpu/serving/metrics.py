@@ -100,6 +100,12 @@ class ServingMetrics:
         self.host_sched_s = 0.0    # passes less the engine's spans
         self.host_engine_s = 0.0   # prepare + dispatch, both programs
         self.pull_wait_s = 0.0     # the engine's pulls (tokens, logits)
+        # backend compiles jax reported while a pass ran (loads from the
+        # persistent cache too) and their seconds: the decode loop's
+        # thread's, by ``obs.context.compile_running``. 0 once every shape
+        # is warm
+        self.compiles = 0
+        self.compile_s = 0.0
         # what an expert family's layers counted, both programs, summed
         # over calls and expert layers (PagedLMEngine.layer_counts)
         self.moe_experts_touched = 0   # experts that received a token
@@ -193,11 +199,13 @@ class ServingMetrics:
         self.device.record_device(device_s)
 
     def record_pass(self, step: bool, chunks: int, host_sched_s: float,
-                    host_engine_s: float, pull_wait_s: float) -> None:
+                    host_engine_s: float, pull_wait_s: float,
+                    compiles: int = 0, compile_s: float = 0.0) -> None:
         """One pass of the decode loop that did work: whether it ran a
-        decode step, how many prefill chunks (today at most one), and its
+        decode step, how many prefill chunks (today at most one), its
         host wall split three ways (the scheduler's own code, the engine's
-        prepare and dispatch, the engine's pulls)."""
+        prepare and dispatch, the engine's pulls), and the backend
+        compiles jax reported while it ran, with their seconds."""
         with self._lock:
             self.passes += 1
             self.passes_with_step += step
@@ -207,6 +215,8 @@ class ServingMetrics:
             self.host_sched_s += host_sched_s
             self.host_engine_s += host_engine_s
             self.pull_wait_s += pull_wait_s
+            self.compiles += compiles
+            self.compile_s += compile_s
 
     def record_layer_counts(self, counts: dict) -> None:
         """What the engine counted since the last pass (the growth of
@@ -273,6 +283,8 @@ class ServingMetrics:
                 "host_sched_s": self.host_sched_s,
                 "host_engine_s": self.host_engine_s,
                 "pull_wait_s": self.pull_wait_s,
+                "compiles": self.compiles,
+                "compile_s": self.compile_s,
                 "moe_experts_touched": self.moe_experts_touched,
                 "moe_expert_slots": self.moe_expert_slots,
                 "moe_assignments": self.moe_assignments,

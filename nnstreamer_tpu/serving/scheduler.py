@@ -784,7 +784,8 @@ class DecodeScheduler:
     def _loop(self) -> None:
         """One pass: JOIN (fill free slots from the queue), one prefill
         chunk, one decode step, ROUTE (append, retire). Each pass that
-        did work is a ``serving.pass`` span with the phases under it; a
+        did work is a ``serving.pass`` span with the phases under it (and
+        ``compiles`` / ``compile_s`` where jax compiled in it); a
         wait on an empty queue with nothing live is ``serving.idle_wait``
         (docs/observability.md has the tree)."""
         engine, metrics = self.engine, self.metrics
@@ -799,6 +800,7 @@ class DecodeScheduler:
             # what the engine spent under its own spans, before and after
             host0, pull0 = engine.host_s, engine.pull_s
             counts0 = engine.counters()
+            compiled0 = obs_context.compile_running()
             self._pass_tokens = 0
             with obs_context.span("serving.pass", live=len(self._active),
                                   prefilling=len(self._prefilling),
@@ -808,8 +810,17 @@ class DecodeScheduler:
                                 tokens=self._pass_tokens)
             host_s = engine.host_s - host0
             pull_s = engine.pull_s - pull0
+            compiled = obs_context.compile_running()
+            compiles = compiled[0] - compiled0[0]
+            compile_s = compiled[1] - compiled0[1]
+            if compiles:
+                # jax compiled or loaded a program on this thread while
+                # the pass ran (a first call, a new shape): the pass carries
+                # the whole count, its own and that of the spans under it,
+                # and the ``program.first_call`` under it says which program
+                sp.attrs.update(compiles=compiles, compile_s=compile_s)
             metrics.record_pass(step, chunks, sp.dur_s - host_s - pull_s,
-                                host_s, pull_s)
+                                host_s, pull_s, compiles, compile_s)
             if counts0:
                 metrics.record_layer_counts(
                     {k: v - counts0[k]

@@ -52,7 +52,14 @@ def enable_compilation_cache() -> str:
     slow ones — a pipeline is many small jitted stages and a restart pays
     for each (on the v5e a warm ``chip_smoke.py`` still spent a quarter of
     the cold run's compile seconds under jax's 1 s threshold, PR 21).
+
+    The program's compile account (``obs.context.compile_account``) begins
+    to listen here, so that it holds every compile of a process whose entry
+    point enabled the cache, those before its first program span too.
     """
+    from ..obs import context as obs_context
+
+    obs_context.compile_account()
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

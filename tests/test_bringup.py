@@ -206,15 +206,17 @@ def test_result_line_has_the_contract_keys_and_no_others():
 
 
 def test_compile_clock_reads_jax_monitoring():
-    """Compile seconds in the summary are jax's own, not wall time."""
+    """Compile seconds in the summary are jax's own, not wall time: the
+    smoke reads the program's compile account."""
     import chip_smoke
 
-    clock = chip_smoke.CompileClock()
+    before = chip_smoke.compile_clock()
     import jax
     import jax.numpy as jnp
 
     jax.jit(lambda x: x * 2 + 1)(jnp.ones((3,))).block_until_ready()
-    read = clock.read()
+    read = chip_smoke.compile_clock()
     assert set(read) == {"trace_s", "compile_s", "cache_hits", "cache_misses"}
-    assert read["compile_s"] > 0 and read["trace_s"] > 0
+    assert read["compile_s"] > before["compile_s"]
+    assert read["trace_s"] > before["trace_s"]
     json.dumps(read)

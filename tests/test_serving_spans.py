@@ -273,8 +273,9 @@ def test_the_ring_stays_bounded():
     for _ in range(obs_ctx.MAX_FINISHED + 10):
         obs_ctx.span("filler").record(0.0, 1.0)
     assert len(obs_ctx.finished_spans()) == obs_ctx.MAX_FINISHED
-    # a whole benchmark run: 160 s of the shortest passes, and its requests
-    assert obs_ctx.MAX_FINISHED >= 160 / 0.064 * SPANS_PER_PASS + 5 * 500
+    # a 48 s window kept full at the fastest full-batch pass (7.5 ms since
+    # PR 35; eight spans a pass with its launches), and its requests
+    assert obs_ctx.MAX_FINISHED >= 48 / 0.0075 * 8 + 5 * 500
     obs_ctx.reset()
 
 
