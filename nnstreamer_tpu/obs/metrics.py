@@ -431,6 +431,9 @@ def _collect_serving(reg: Registry) -> None:
             ("attn_pages_read", "attn_pages_read",
              "pages the live slots held, summed over decode steps: what "
              "the steps' attention read"),
+            ("attn_pages_fetched", "attn_pages_fetched",
+             "pages the steps' attention kernel copied from a pool, by its "
+             "own rule, summed over decode steps"),
             ("attn_pages_padded", "attn_pages_padded",
              "slots times the blocks a slot may hold, summed over decode "
              "steps: what attention over padded positions would read"),

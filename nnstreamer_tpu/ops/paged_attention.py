@@ -13,7 +13,7 @@ latent family by construction). :func:`paged_line_attention` is that op:
 * ``lengths (S,)`` int32 — the positions a slot sees, 0 for an empty slot;
 * ``scale`` — what the scores are multiplied by;
 * ``starts (S,)`` int32 — the first position a slot sees (a layer that
-  looks back a window only); ``None`` is 0 for every slot. Blocks wholly
+  looks back a window only); ``None`` is 0 for every slot. Pages wholly
   below it are not read: their table entries may name any row.
 
 It returns ``(S, H, Wv)`` float32: softmax(q · lines) · lines, zeros for an
@@ -23,19 +23,29 @@ bfloat16 pool: the meaning of ``Precision.HIGHEST`` with nothing lowered.
 Two forms, chosen in one place (:func:`paged_line_attention`) by
 ``utils.hw_accel.pallas_interpret``'s rule:
 
-* :func:`kernel_line_attention` — a Pallas TPU kernel. ``rows`` and
-  ``lengths`` are scalar prefetch, the pools stay in HBM, and a block of
-  several pages at a time is fetched by asynchronous copies into
-  double-buffered VMEM, the next block (of this slot or of the next live
-  one) on its way while this one is contracted. Online softmax; a slot of
-  length 0 does nothing, nothing before the block of a slot's first visible
-  position or past its last block is read, the first block masks its head
-  and the last its tail. The float32 operand of each product (the queries,
-  the softmax's weights) is split into three bfloat16 terms stacked along
-  the rows, so one pass of the pool's bfloat16 lines through the matrix
-  unit gives the float32 product exactly (the lines are bfloat16 already:
-  the three further passes of a float32 × float32 product would multiply
-  zeros).
+* :func:`kernel_line_attention` — a Pallas TPU kernel. ``rows`` and the
+  slots' scalars are scalar prefetch, the pools stay in HBM. A slot's walk
+  starts at the first page that holds a visible position
+  (:func:`visible_pages`: the rule the host counts ``pages_fetched`` by)
+  and goes on in blocks of several pages to the last such page. Only those
+  pages are copied, page by page, into a ring of VMEM buffers; the copies
+  of a block signal one semaphore a pool, so a whole block is waited for
+  once, by a descriptor of the buffer (the sum of their bytes), and a
+  slot's last block page by page. The fetches run on from a live slot's
+  last block to the next live slot's first, two blocks ahead of the block
+  being contracted: each pass of the one loop starts a block's copies,
+  waits for the block at hand, and multiplies it. A slot's last block is
+  multiplied over a quarter of a block's lines when it holds no more
+  pages; what lies in a buffer where no page was copied is zero or an
+  older page, never bits the call did not put there (the values' buffers
+  are zeroed once a call: a weight of 0 times NaN would be NaN). Online
+  softmax; a slot of length 0 does nothing; the first block masks its
+  head and the last its tail. The float32 operand of each product (the
+  queries, the softmax's weights) is split into three bfloat16 terms
+  stacked along the rows, so one pass of the pool's bfloat16 lines through
+  the matrix unit gives the float32 product exactly (the lines are
+  bfloat16 already: the three further passes of a float32 × float32
+  product would multiply zeros).
 * :func:`plain_line_attention` — gather every slot's ``NB`` pages, mask,
   softmax: the oracle the kernel is pinned to (``tests/
   test_paged_attention.py``) and what runs where a TPU kernel would only be
@@ -54,11 +64,17 @@ from jax.experimental.pallas import tpu as pltpu
 from ..utils import hw_accel
 
 #: most bytes of one pool's lines fetched a block. Pages per block is the
-#: largest power of two that fits (8 pages of ``(16, 2048)`` bfloat16, 32 of
-#: ``(16, 640)``): stand-alone on a v5e (``tools/paged_attention_forms.py``,
-#: PR 28) 20 KB pages at 8, 16, 32 a block took 0.34, 0.28, 0.25 ms a layer
-#: and 64 KB pages at 4, 8, 16 took 0.20, 0.13, 0.14
-BLOCK_BYTES = 768 * 1024
+#: largest power of two that fits (16 pages of ``(16, 2048)`` bfloat16, 64 of
+#: ``(16, 640)``, ``(16, 512)`` or ``(64, 128)``). Every block costs one chain
+#: of product, maximum, exponential, product that nothing overlaps, so a
+#: larger block is faster until its buffers (three a pool) crowd VMEM.
+#: Stand-alone on a v5e at the cells' lengths (``tools/
+#: paged_attention_forms.py``, PR 37), ms a layer at 16, 32, 64 pages a
+#: block: kanana 0.241, 0.211, 0.206; a mellum full layer 0.953, 0.776,
+#: 0.694 and a window layer 0.213, 0.180, 0.165; jamba 0.609, 0.557, 0.566;
+#: at 8, 16 pages of 64 KB: opt saturated 0.119, 0.120, every slot full
+#: 0.375, 0.376
+BLOCK_BYTES = 1280 * 1024
 _MASKED = -1e30
 
 
@@ -108,111 +124,171 @@ def _three_terms(x):
     return hi, mid, low
 
 
-def _kernel(rows_ref, meta_ref, q_ref, *refs, S, NB, PB, H, scale, shared):
+def visible_pages(lengths, starts, page):
+    """The kernel's rule, a slot: the first page of its table that holds a
+    visible position and how many pages from there on do (0 for an empty
+    slot). ``lengths`` and ``starts`` are arrays of one shape, numpy's on
+    the host or jax's on the device: the kernel's scalars are this
+    function's output, and so is the engine's ``pages_fetched``."""
+    first = starts // page
+    return first, (-(-lengths // page) - first) * (lengths > 0)
+
+
+def pages_fetched(lengths, starts, page):
+    """Pages one call of the kernel copies from a pool, over all slots
+    (host arrays): every page that holds a visible position, once."""
+    return int(visible_pages(lengths, starts, page)[1].sum())
+
+
+def _kernel(rows_ref, meta_ref, q_ref, *refs, S, NB, PB, sizes, H, scale,
+            shared):
     if shared:
         k_hbm, o_ref, kbuf, sems, q3_ref, p3_ref, m_ref, l_ref, state = refs
         v_hbm, vbuf = k_hbm, kbuf
     else:
         (k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, q3_ref, p3_ref, m_ref, l_ref,
          state) = refs
-    pg = kbuf.shape[2]
-    T = PB * pg
+    pairs = ((k_hbm, kbuf),) if shared else ((k_hbm, kbuf), (v_hbm, vbuf))
+    ring, pg = kbuf.shape[0], kbuf.shape[2]
     s = pl.program_id(0)
     length = meta_ref[s]
-    next_live = meta_ref[2 * S + s]
     start = meta_ref[3 * S + s]
-    blocks = (length + T - 1) // T
-    lo = start // T  # the block of the first visible position
-    next_lo = meta_ref[3 * S + jnp.minimum(next_live, S - 1)] // T
+    first = meta_ref[4 * S + s]   # the first page that holds a visible line
+    count = meta_ref[5 * S + s]   # and how many from there on do
 
     @pl.when(s == 0)
     def _():
         state[0] = 0  # the buffer the next block to contract lies in
-        state[1] = 0  # whether that block's copies have been started
+        state[1] = 0  # the buffer the next block to fetch goes to
+        state[2] = meta_ref[6 * S]  # the slot of the next block to fetch
+        state[3] = 0  # and which of that slot's blocks it is
+        # a block's buffer holds stale lines where no page was copied into
+        # it (the tail of a slot's last block): their weight is 0, and 0
+        # times whatever bits lie there must be 0
+        vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
 
-    def copies(act, slot, blk, buf):
-        # start, or wait for, the copies of one block's pages into buffer
-        # ``buf``. A loop over fours, called from two places, and not PB
-        # copies spelled out at four: the kernel's text is traced and
-        # lowered at every start-up, and 32 pages a block spelled out cost
-        # the latent engine 3.7 s of set-up on a v5e's host (PR 28); not
-        # unrolled at all, the step's attention took an eighth longer
-        def page(j):
-            # a block past the table's end repeats its last page: those
-            # positions are past any length
-            row = rows_ref[slot * NB + jnp.minimum(blk * PB + j, NB - 1)]
-            pairs = ((k_hbm, kbuf),) if shared else ((k_hbm, kbuf),
-                                                     (v_hbm, vbuf))
-            for i, (hbm, vmem) in enumerate(pairs):
-                getattr(pltpu.make_async_copy(
-                    hbm.at[row], vmem.at[buf, j], sems.at[i, buf]), act)()
+    def fetch(*_):
+        # start the copies of the next block in the call's order, the
+        # blocks of every live slot one after the other, and move the
+        # cursor on. Only pages that hold a visible position are copied.
+        # Loops over fours and then ones, traced in two places, and not PB
+        # copies spelled out: the kernel's text is traced and lowered at
+        # every start-up, and 32 pages a block spelled out cost the latent
+        # engine 3.7 s of set-up on a v5e's host (PR 28); not unrolled at
+        # all, the step's attention took an eighth longer
+        slot = state[2]
 
-        def four(g, _):
-            for u in range(group):
-                page(g * group + u)
-            return 0
+        @pl.when(slot < S)
+        def _():
+            blk, buf = state[3], state[1]
+            pages = meta_ref[5 * S + slot]
+            n = jnp.minimum(PB, pages - blk * PB)
+            base = slot * NB + meta_ref[4 * S + slot] + blk * PB
 
-        group = 4 if PB % 4 == 0 else 1
-        jax.lax.fori_loop(0, PB // group, four, 0)
+            def page(j):
+                row = rows_ref[base + j]
+                for i, (hbm, vmem) in enumerate(pairs):
+                    pltpu.make_async_copy(hbm.at[row], vmem.at[buf, j],
+                                          sems.at[i, buf]).start()
 
-    @pl.when(blocks > 0)
+            def several(g, _):
+                for u in range(group):
+                    page(g * group + u)
+                return 0
+
+            def one(j, _):
+                page(j)
+                return 0
+
+            group = 4 if PB % 4 == 0 else 1
+            whole = n // group
+            jax.lax.fori_loop(0, whole, several, 0)
+            jax.lax.fori_loop(whole * group, n, one, 0)
+            last = (blk + 1) * PB >= pages
+            state[3] = jnp.where(last, 0, blk + 1)
+            state[2] = jnp.where(last, meta_ref[2 * S + slot], slot)
+            state[1] = jnp.where(buf + 1 == ring, 0, buf + 1)
+        return 0
+
+    def arrived(buf, n):
+        # a whole block's copies signalled one semaphore a pool: one wait
+        # for the sum of their bytes; a slot's last block page by page
+        @pl.when(n == PB)
+        def _():
+            for i, (_, vmem) in enumerate(pairs):
+                pltpu.make_async_copy(vmem.at[buf], vmem.at[buf],
+                                      sems.at[i, buf]).wait()
+
+        @pl.when(n < PB)
+        def _():
+            def one(j, _):
+                for i, (_, vmem) in enumerate(pairs):
+                    pltpu.make_async_copy(vmem.at[buf, j], vmem.at[buf, j],
+                                          sems.at[i, buf]).wait()
+                return 0
+
+            jax.lax.fori_loop(0, n, one, 0)
+
+    def contract(at0, buf, size):
+        # the products over the first ``size`` pages of buffer ``buf``,
+        # whose first line is position ``at0``
+        T = size * pg
+        k = kbuf[buf, :size].reshape(T, kbuf.shape[-1])
+        sc3 = jax.lax.dot_general(
+            q3_ref[...], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)          # (3H, T)
+        sc = sc3[:H] + sc3[H:2 * H] + sc3[2 * H:]
+        at = at0 + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        sc = jnp.where((at >= start) & (at < length), sc, _MASKED)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
+        # every block holds a visible position, so m_new is a score and
+        # a masked one's weight is exp(-1e30 - m_new) == 0
+        p = jnp.exp(sc - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+        m_ref[...] = m_new
+        for j, term in enumerate(_three_terms(p)):
+            p3_ref[j * H:(j + 1) * H, :T] = term
+        v = vbuf[buf, :size].reshape(T, vbuf.shape[-1])
+        o3 = jnp.dot(p3_ref[:, :T], v,
+                     preferred_element_type=jnp.float32)  # (3H, Wv)
+        o_ref[...] = (alpha * o_ref[...]
+                      + o3[:H] + o3[H:2 * H] + o3[2 * H:])
+
+    @pl.when(count > 0)
     def _():
-        first = state[0]
-        fetched = state[1]  # 0 only for the call's first live slot
-        state[1] = 1
         for i, term in enumerate(_three_terms(q_ref[...] * scale)):
             q3_ref[i * H:(i + 1) * H, :] = term
         m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
 
-        def body(i, _):
-            # one place starts copies and one waits for them: block i + 1
-            # of this slot, or the next live slot's first, is on its way
-            # while block i is contracted. i is lo - 1 once a call, for the
-            # slot nobody fetched ahead for: that pass only starts block lo
-            more = i + 1 < blocks
+        @pl.when(s == meta_ref[6 * S])
+        def _():
+            # the call's first live slot: nothing is on its way yet
+            jax.lax.fori_loop(0, ring - 1, fetch, 0)
 
-            @pl.when(more | (next_live < S))
-            def _():
-                copies("start", jnp.where(more, s, next_live),
-                       jnp.where(more, i + 1, next_lo),
-                       (first + i + 1 - lo) % 2)
-
-            @pl.when(i >= lo)
-            def _():
-                contract(i, (first + i - lo) % 2)
-
+        def body(b, _):
+            # ring - 1 blocks are on their way; one more is started, then
+            # one wait for block b's bytes, then its products
+            fetch()
+            buf = state[0]
+            n = jnp.minimum(PB, count - b * PB)
+            arrived(buf, n)
+            for size, below in zip(sizes, (*sizes[1:], 0)):
+                @pl.when((n > below) & (n <= size))
+                def _(size=size):
+                    contract((first + b * PB) * pg, buf, size)
+            state[0] = jnp.where(buf + 1 == ring, 0, buf + 1)
             return 0
 
-        def contract(i, buf):
-            copies("wait", s, i, buf)
-            k = kbuf[buf].reshape(T, kbuf.shape[-1])
-            sc3 = jax.lax.dot_general(
-                q3_ref[...], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)          # (3H, T)
-            sc = sc3[:H] + sc3[H:2 * H] + sc3[2 * H:]
-            at = i * T + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-            sc = jnp.where((at >= start) & (at < length), sc, _MASKED)
-            m_prev = m_ref[...]
-            m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
-            # every block holds a visible position, so m_new is a score and
-            # a masked one's weight is exp(-1e30 - m_new) == 0
-            p = jnp.exp(sc - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
-            m_ref[...] = m_new
-            for j, term in enumerate(_three_terms(p)):
-                p3_ref[j * H:(j + 1) * H, :] = term
-            v = vbuf[buf].reshape(T, vbuf.shape[-1])
-            o3 = jnp.dot(p3_ref[...], v,
-                         preferred_element_type=jnp.float32)  # (3H, Wv)
-            o_ref[...] = (alpha * o_ref[...]
-                          + o3[:H] + o3[H:2 * H] + o3[2 * H:])
-
-        jax.lax.fori_loop(lo + fetched - 1, blocks, body, 0)
-        state[0] = (first + blocks - lo) % 2
+        jax.lax.fori_loop(0, (count + PB - 1) // PB, body, 0)
         o_ref[...] = o_ref[...] / l_ref[...]
+
+
+#: blocks on their way while one is contracted
+_AHEAD = 2
 
 
 @functools.partial(jax.jit,
@@ -225,6 +301,10 @@ def _call(q, kpool, vpool, rows, lengths, starts, *, scale,
     pg = kpool.shape[1]
     Wv = Wk if shared else vpool.shape[2]
     PB = pages_per_block
+    # a slot's last block is contracted over a quarter of a block's lines
+    # when it holds no more pages than that: 128 positions at the least
+    short = max(PB // 4, -(-128 // pg))
+    sizes = (PB, short) if short < PB else (PB,)
     # rows of the stacked bfloat16 operands start on a tile row (16)
     H = -(-H0 // 16) * 16
     if H != H0:
@@ -237,23 +317,25 @@ def _call(q, kpool, vpool, rows, lengths, starts, *, scale,
     idx = jnp.arange(S, dtype=jnp.int32)
     # an empty slot's program touches nothing: its query and output blocks
     # are the last live slot's (no copy in or out for a block that stays),
-    # and a live slot's last block fetches ahead for the next live one
+    # and the fetches run on from a live slot's last block to the next
+    # live slot's first
     stay = jax.lax.cummax(jnp.where(live, idx, 0))
-    next_live = jnp.concatenate([
-        jax.lax.cummin(jnp.where(live, idx, S), reverse=True)[1:],
-        jnp.full((1,), S, jnp.int32)])
-    meta = jnp.concatenate([lengths, stay, next_live,
-                            starts]).astype(jnp.int32)
+    after = jax.lax.cummin(jnp.where(live, idx, S), reverse=True)
+    next_live = jnp.concatenate([after[1:], jnp.full((1,), S, jnp.int32)])
+    meta = jnp.concatenate([lengths, stay, next_live, starts,
+                            *visible_pages(lengths, starts, pg),
+                            after[:1]]).astype(jnp.int32)
 
     def block(width):
         return pl.BlockSpec((None, H, width),
                             lambda s, rows, meta: (meta[S + s], 0, 0))
 
     pools = (kpool,) if shared else (kpool, vpool)
-    bufs = [pltpu.VMEM((2, PB, pg, p.shape[2]), p.dtype) for p in pools]
+    bufs = [pltpu.VMEM((_AHEAD + 1, PB, pg, p.shape[2]), p.dtype)
+            for p in pools]
     out = pl.pallas_call(
-        functools.partial(_kernel, S=S, NB=NB, PB=PB, H=H, scale=scale,
-                          shared=shared),
+        functools.partial(_kernel, S=S, NB=NB, PB=PB, sizes=sizes, H=H,
+                          scale=scale, shared=shared),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(S,),
@@ -262,12 +344,12 @@ def _call(q, kpool, vpool, rows, lengths, starts, *, scale,
             out_specs=block(Wv),
             scratch_shapes=[
                 *bufs,
-                pltpu.SemaphoreType.DMA((len(pools), 2)),
+                pltpu.SemaphoreType.DMA((len(pools), _AHEAD + 1)),
                 pltpu.VMEM((3 * H, Wk), jnp.bfloat16),        # the queries
                 pltpu.VMEM((3 * H, PB * pg), jnp.bfloat16),   # the weights
                 pltpu.VMEM((H, 1), jnp.float32),              # running max
                 pltpu.VMEM((H, 1), jnp.float32),              # running sum
-                pltpu.SMEM((2,), jnp.int32),
+                pltpu.SMEM((4,), jnp.int32),
             ]),
         out_shape=jax.ShapeDtypeStruct((S, H, Wv), jnp.float32),
         compiler_params=pltpu.CompilerParams(

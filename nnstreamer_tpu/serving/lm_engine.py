@@ -220,6 +220,7 @@ class PagedLMEngine(DecodeEngine):
         from ..ops.paged_attention import (
             gathered_lines,
             paged_line_attention,
+            pages_fetched,
         )
         from .kv_pool import KVPagePool
 
@@ -268,6 +269,7 @@ class PagedLMEngine(DecodeEngine):
         self._ran: set = set()           # the programs called once (_run)
         self._jnp = jnp
         self._jax = jax
+        self._pages_fetched = pages_fetched
 
         NB = self.blocks_per_slot
         pg = page_size
@@ -384,9 +386,11 @@ class PagedLMEngine(DecodeEngine):
         # (what a step's attention reads in a layer, the mean over layers
         # where kinds differ) and slots x blocks_per_slot; by kind beside
         # them, and the pages given back behind the window so far
-        self.attn_pages = {"attn_pages_read": 0, "attn_pages_padded": 0,
-                           **{f"attn_pages_read_{kind}": 0 for kind in kinds
-                              if len(kinds) > 1}}
+        self.attn_pages = {"attn_pages_read": 0, "attn_pages_fetched": 0,
+                           "attn_pages_padded": 0,
+                           **{f"attn_pages_{what}_{kind}": 0
+                              for what in ("read", "fetched")
+                              for kind in kinds if len(kinds) > 1}}
         self.window_pages_released = 0
         # running sums over decode steps: the slots whose state a step
         # advanced, and every slot's (what it read and wrote)
@@ -967,24 +971,33 @@ class PagedLMEngine(DecodeEngine):
             # pages the live slots hold against every slot's whole table
             # (by kind of layer where kinds differ: a window layer reads
             # from the window's first page on)
+            # ``pages_fetched`` beside them is what the step's kernel
+            # copies, by its own rule (ops/paged_attention.py)
             seen = np.minimum(self._pos[slots] + 1, self.max_seq)
+            first = {"full": np.zeros_like(seen)}
             by_kind = {"full": int((-(-seen // self.page_size)).sum())}
             if "window" in self.kinds:
-                skipped = np.maximum(seen - self.family.window, 0)
+                first["window"] = np.maximum(seen - self.family.window, 0)
                 by_kind["window"] = by_kind["full"] - int(
-                    (skipped // self.page_size).sum())
-            read = round(sum(by_kind[kind] * n for kind, n
-                             in self.kind_layers.items())
-                         / sum(self.kind_layers.values()))
+                    (first["window"] // self.page_size).sum())
+            fetched = {kind: self._pages_fetched(seen, first[kind],
+                                                 self.page_size)
+                       for kind in by_kind}
+            layers = sum(self.kind_layers.values())
             padded = self.slots * self.blocks_per_slot
-            prepare.attrs.update(pages_read=read, pages_padded=padded)
-            self.attn_pages["attn_pages_read"] += read
+            prepare.attrs["pages_padded"] = padded
             self.attn_pages["attn_pages_padded"] += padded
+            for what, pages in (("read", by_kind), ("fetched", fetched)):
+                mean = round(sum(pages[kind] * n for kind, n
+                                 in self.kind_layers.items()) / layers)
+                prepare.attrs[f"pages_{what}"] = mean
+                self.attn_pages[f"attn_pages_{what}"] += mean
+                if len(self.kinds) > 1:
+                    for kind in self.kinds:
+                        prepare.attrs[f"pages_{what}_{kind}"] = pages[kind]
+                        self.attn_pages[f"attn_pages_{what}_{kind}"] += \
+                            pages[kind]
             if len(self.kinds) > 1:
-                for kind in self.kinds:
-                    prepare.attrs[f"pages_read_{kind}"] = by_kind[kind]
-                    self.attn_pages[f"attn_pages_read_{kind}"] += \
-                        by_kind[kind]
                 prepare.attrs["window_pages_released"] = \
                     self.window_pages_released
             if self._states:

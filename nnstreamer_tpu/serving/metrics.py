@@ -115,6 +115,7 @@ class ServingMetrics:
         # how far a decode step's attention follows what is visible
         # (PagedLMEngine.attn_pages), summed over steps
         self.attn_pages_read = 0       # pages the live slots held
+        self.attn_pages_fetched = 0    # pages the steps' kernel copied
         self.attn_pages_padded = 0     # slots x blocks a slot may hold
         # by kind of layer, where an engine's family has two kinds: what a
         # full layer's and a window layer's attention read, and the pages
@@ -226,6 +227,7 @@ class ServingMetrics:
         (``steps_ahead``, ``steps_collected_early``, ``surplus_steps``)."""
         with self._lock:
             self.attn_pages_read += counts.get("attn_pages_read", 0)
+            self.attn_pages_fetched += counts.get("attn_pages_fetched", 0)
             self.attn_pages_padded += counts.get("attn_pages_padded", 0)
             self.attn_pages_read_full += counts.get(
                 "attn_pages_read_full", 0)
@@ -290,6 +292,7 @@ class ServingMetrics:
                 "moe_assignments": self.moe_assignments,
                 "moe_max_load": self.moe_max_load,
                 "attn_pages_read": self.attn_pages_read,
+                "attn_pages_fetched": self.attn_pages_fetched,
                 "attn_pages_padded": self.attn_pages_padded,
                 "attn_pages_read_full": self.attn_pages_read_full,
                 "attn_pages_read_window": self.attn_pages_read_window,

@@ -506,12 +506,19 @@ def test_a_step_counts_the_pages_it_reads_by_kind():
     assert span.attrs["pages_read_full"] == 11 + 2
     assert span.attrs["pages_read_window"] == 4 + 2
     assert span.attrs["pages_read"] == round((13 * 1 + 6 * 3) / 4)
+    # the kernel copies the pages that hold a visible position and no
+    # other, in a layer of either kind (ops.paged_attention.pages_fetched)
+    assert span.attrs["pages_fetched_full"] == 13
+    assert span.attrs["pages_fetched_window"] == 6
+    assert span.attrs["pages_fetched"] == span.attrs["pages_read"]
     assert span.attrs["pages_padded"] == 3 * (LIMIT // 4)
     assert span.attrs["window_pages_released"] == eng.window_pages_released
     after = eng.counters()
     assert after["attn_pages_read_full"] - before["attn_pages_read_full"] == 13
     assert after["attn_pages_read_window"] \
         - before["attn_pages_read_window"] == 6
+    assert after["attn_pages_fetched_window"] \
+        - before["attn_pages_fetched_window"] == 6
     assert after["window_pages_released"] == eng.window_pages_released > 0
 
 

@@ -7,6 +7,12 @@
   starts at every edge of a page and of a block, table entries below the
   start naming the null page; a start of 0 is the result without one, bit
   for bit in the plain form;
+* at a block of 32 pages of 16 (the size at which a slot's last block has
+  its shorter products): windows over three blocks, starts and lengths at
+  every edge of a page, of the shorter products and of a block, live slots
+  between empty ones, jamba's shape; every pool row a slot does not see
+  filled with NaN and Inf; and the rule ``pages_fetched`` counts by against
+  what the kernel reads and against the engine's ``pages_read``;
 * an engine step through the kernel emits the tokens the plain form's step
   emits, for both model families;
 * what ``GPTFamily._attend_step`` used to be held to: the family's step
@@ -146,6 +152,175 @@ def test_a_start_of_zero_is_the_plain_form_without_one_bit_for_bit():
     np.testing.assert_array_equal(kernel[0], kernel[1])
 
 
+# a block of 32 pages of 16 positions: a slot's last block is contracted
+# over 8 pages (128 positions) when it holds no more than that
+BPG, BPB, BNB = 16, 32, 80
+B, Q, P = BPB * BPG, BPB // 4 * BPG, BPG  # a block, its quarter, a page
+EDGES = {
+    # (lengths, starts); None: from the first position on
+    "a_window_over_three_blocks": ([BNB * P, 1100, 1031],
+                                   [BNB * P - 1024, 76, 7]),
+    "a_window_of_two_whole_blocks": ([1024 + B, 1024, 1024 + 3 * P],
+                                     [B, 0, 3 * P]),
+    "starts_at_a_page": ([1200, 1200, 1200], [10 * P - 1, 10 * P, 10 * P + 1]),
+    "starts_at_a_quarter": ([1200, 1200, 1200], [Q - 1, Q, Q + 1]),
+    "starts_at_a_block": ([BNB * P] * 3, [B - 1, B, B + 1]),
+    "ends_at_a_page": ([20 * P - 1, 20 * P, 20 * P + 1], None),
+    "ends_at_a_quarter": ([Q - 1, Q, Q + 1], None),
+    "ends_at_a_block": ([B - 1, B, B + 1], None),
+    "ends_at_two_blocks": ([2 * B - 1, 2 * B, 2 * B + 1], None),
+    "a_window_that_ends_at_a_block": ([1024 + B - 1, 1024 + B, 1024 + B + 1],
+                                      [B - 1, B, B + 1]),
+    "a_quarter_past_a_block": ([B + Q - 1, B + Q, B + Q + 1], None),
+    "a_window_a_quarter_past_a_block": ([700 + B + Q, 700 + B + Q + P, 900],
+                                        [700, 700 + 1, 900 - B - Q + 1]),
+    "one_page_one_quarter_one_block": ([P, Q, B], None),
+    "live_slots_between_empty_ones": ([0, 700, 0, 0, Q + 2, 0], None),
+    "windows_between_empty_slots": ([0, 1100, 0, 1279, 0],
+                                    [0, 76, 0, 255, 0]),
+}
+
+
+def _disjoint(rng, S, NB, width, pools, page=BPG):
+    """Pools and a table in which no two slots share a row."""
+    rows = 1 + S * NB
+    made = [jnp.asarray(rng.standard_normal((rows, page, width)),
+                        jnp.bfloat16) for _ in range(pools)]
+    bt = 1 + rng.permutation(S * NB).reshape(S, NB).astype(np.int32)
+    return made[0], made[-1], bt
+
+
+@pytest.mark.parametrize("case", EDGES, ids=list(EDGES))
+@pytest.mark.parametrize("pools", [2, 1], ids=["keys_and_values",
+                                               "one_pool_as_both"])
+def test_kernel_matches_the_plain_form_at_the_edges_of_a_real_block(
+        pools, case):
+    rng = np.random.default_rng(37)
+    lengths, starts = EDGES[case]
+    S, H, W = len(lengths), 4, 48
+    kpool, vpool, bt = _disjoint(rng, S, BNB, W, pools)
+    q = jnp.asarray(rng.standard_normal((S, H, W)), jnp.float32)
+    seen = jnp.asarray(lengths, jnp.int32)
+    first = None if starts is None else jnp.asarray(starts, jnp.int32)
+    want = pa.plain_line_attention(q, kpool, vpool, jnp.asarray(bt), seen,
+                                   0.25, first)
+    got = pa.kernel_line_attention(q, kpool, vpool, jnp.asarray(bt), seen,
+                                   0.25, first, pages_per_block=BPB,
+                                   interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=0)
+    assert not np.asarray(got)[np.asarray(lengths) == 0].any()
+
+
+def _poisoned(pool, bt, lengths, starts, page):
+    """``pool`` with every row that ``bt`` names outside a slot's visible
+    pages (before its start's, past its length's) and the null row filled
+    with NaN and Inf by turns."""
+    first, count = pa.visible_pages(np.asarray(lengths), np.asarray(starts),
+                                    page)
+    at = np.arange(bt.shape[1])[None, :]
+    unseen = (at < first[:, None]) | (at >= (first + count)[:, None])
+    rows = np.concatenate([[0], bt[unseen]])
+    bad = np.where(np.arange(len(rows)) % 2, np.nan, np.inf)
+    return pool.at[rows].set(jnp.asarray(bad, pool.dtype)[:, None, None])
+
+
+POISONED = {
+    "tails_of_every_kind": ([B + 3, Q - 5, 1], [0, 0, 0]),
+    "heads_and_tails_of_windows": ([BNB * P - 9, 1100, 1031],
+                                   [BNB * P - 9 - 1024, 76, 7]),
+    "a_window_inside_one_page": ([700, 3 * P, 0], [700 - 5, 2 * P + 1, 0]),
+    "whole_blocks_and_an_empty_slot": ([2 * B, 0, B], [0, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("case", POISONED, ids=list(POISONED))
+@pytest.mark.parametrize("pools", [2, 1], ids=["keys_and_values",
+                                               "one_pool_as_both"])
+def test_rows_a_slot_does_not_see_never_reach_its_output(pools, case):
+    # a weight of 0 times NaN is NaN: a page that is not visible must not
+    # be multiplied, whatever lies in it or in the buffer it was not copied
+    # to (the interpreter hands the kernel buffers full of NaN: a first
+    # call on fresh buffers every time)
+    rng = np.random.default_rng(38)
+    lengths, starts = POISONED[case]
+    S, H, W = len(lengths), 4, 48
+    kpool, vpool, bt = _disjoint(rng, S, BNB, W, pools)
+    q = jnp.asarray(rng.standard_normal((S, H, W)), jnp.float32)
+    seen, first = jnp.asarray(lengths, jnp.int32), jnp.asarray(starts,
+                                                               jnp.int32)
+    want = pa.plain_line_attention(q, kpool, vpool, jnp.asarray(bt), seen,
+                                   0.25, first)
+    kbad = _poisoned(kpool, bt, lengths, starts, BPG)
+    vbad = kbad if pools == 1 else _poisoned(vpool, bt, lengths, starts, BPG)
+    for blocks in (BPB, 4):
+        got = np.asarray(pa.kernel_line_attention(
+            q, kbad, vbad, jnp.asarray(bt), seen, 0.25, first,
+            pages_per_block=blocks, interpret=True))
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, np.asarray(want), atol=1e-5, rtol=0)
+
+
+def test_the_kernel_reads_every_page_the_rule_names():
+    # the other half of the rule: a page ``visible_pages`` counts is read
+    # (each holds a visible position, so a NaN there reaches the output)
+    rng = np.random.default_rng(39)
+    lengths, starts = [1100, B + 1, 5], [76, 0, 0]
+    S, H, W = 3, 4, 48
+    kpool, _, bt = _disjoint(rng, S, BNB, W, 1)
+    q = jnp.asarray(rng.standard_normal((S, H, W)), jnp.float32)
+    first, count = pa.visible_pages(np.asarray(lengths), np.asarray(starts),
+                                    BPG)
+    assert list(first) == [4, 0, 0] and list(count) == [65, 33, 1]
+    for slot in range(S):
+        for at in {int(first[slot]), int(first[slot] + count[slot]) - 1}:
+            bad = kpool.at[bt[slot, at]].set(jnp.nan)
+            got = np.asarray(pa.kernel_line_attention(
+                q, bad, bad, jnp.asarray(bt), jnp.asarray(lengths), 0.25,
+                jnp.asarray(starts), pages_per_block=BPB, interpret=True))
+            assert np.isnan(got[slot]).all(), (slot, at)
+            assert np.isfinite(np.delete(got, slot, axis=0)).all()
+
+
+def test_kernel_matches_the_plain_form_at_jambas_shape():
+    # pages of 64 positions, 20 queries a slot (padded to 32 rows) over one
+    # 128-wide line, keys and values in a pool each, the block size derived
+    rng = np.random.default_rng(40)
+    lengths = [1000, 24 * 64, 0, 77, 6 * 64 + 1]
+    S, H, W, NB = len(lengths), 20, 128, 24
+    kpool, vpool, bt = _disjoint(rng, S, NB, W, 2, page=64)
+    q = jnp.asarray(rng.standard_normal((S, H, W)), jnp.float32)
+    seen = jnp.asarray(lengths, jnp.int32)
+    want = pa.plain_line_attention(q, kpool, vpool, jnp.asarray(bt), seen,
+                                   0.09)
+    got = pa.kernel_line_attention(q, kpool, vpool, jnp.asarray(bt), seen,
+                                   0.09, interpret=True)
+    assert got.shape == (S, H, W)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("page", [4, 16, 64])
+@pytest.mark.parametrize("window", [None, 10, 1024])
+def test_pages_fetched_is_the_pages_read_by_kind(page, window):
+    # the engine's ``pages_read`` of a layer's kind (lm_engine._dispatch)
+    # against the kernel's rule: within a page a slot (they are equal)
+    rng = np.random.default_rng(41)
+    seen = rng.integers(1, 6000, 64)
+    seen[::7] = rng.integers(1, 6000 // page, len(seen[::7])) * page
+    starts = (np.zeros_like(seen) if window is None
+              else np.maximum(seen - window, 0))
+    read = int((-(-seen // page)).sum()) - int((starts // page).sum())
+    fetched = pa.pages_fetched(seen, starts, page)
+    assert abs(fetched - read) <= len(seen)
+    assert fetched == read
+    # an empty slot among them fetches nothing
+    seen[3] = starts[3] = 0
+    first, count = pa.visible_pages(seen, starts, page)
+    assert count[3] == 0 and pa.pages_fetched(seen, starts, page) == \
+        int(count.sum())
+
+
 def _gpt():
     from nnstreamer_tpu.models.lm_serving import tiny
     from nnstreamer_tpu.models.transformer import init_params
@@ -247,7 +422,7 @@ def test_pages_per_block_follows_the_lines_bytes(monkeypatch):
 
     monkeypatch.setattr(pa, "_call", call)
     rows, q = jnp.zeros((16, 128), jnp.int32), None
-    for width, pages in ((2048, 8), (640, 32), (256, 64)):
+    for width, pages in ((2048, 16), (640, 64), (512, 64), (256, 128)):
         pool = jax.ShapeDtypeStruct((10, 16, width), jnp.bfloat16)
         pa.kernel_line_attention(q, pool, pool, rows, None, 1.0)
         assert seen["pages"] == pages, width
@@ -271,9 +446,14 @@ def test_step_counts_the_pages_it_reads_against_the_padding():
     # the first token comes from the prompt's last chunk; three steps see
     # 11, 12 and 13 positions of one live slot of three
     assert [s.attrs["pages_read"] for s in steps] == [3, 3, 4]
+    # the kernel copies the pages that hold a visible position, and no more
+    assert [s.attrs["pages_fetched"] for s in steps] == [3, 3, 4]
     assert {s.attrs["pages_padded"] for s in steps} == {3 * (64 // 4)}
     snap = sched.metrics_snapshot()
     assert snap["attn_pages_read"] == eng.attn_pages["attn_pages_read"] == 10
+    assert snap["attn_pages_fetched"] == \
+        eng.attn_pages["attn_pages_fetched"] == 10
     assert snap["attn_pages_padded"] == 3 * 48
     assert "nns_serving_attn_pages_read_total" in text
+    assert "nns_serving_attn_pages_fetched_total" in text
     assert "nns_serving_attn_pages_padded_total" in text

@@ -1,4 +1,4 @@
-"""Stand-alone measurement behind the decode step's attention (PR 28).
+"""Stand-alone measurement behind the decode step's attention (PR 28, 37).
 
 One layer's step attention at the benchmark cells' shapes, over pools of
 the cells' size filled from a seed, in each form tried:
@@ -14,16 +14,27 @@ the cells' size filled from a seed, in each form tried:
 
 Shapes: ``opt`` 16 slots × 128 pages of ``(16, 2048)``, keys and values in
 two pools of 24 layers × 1025 rows; ``kanana`` 32 slots × 192 pages of
-``(16, 640)``, one pool of 8 layers × 6145 rows. Lengths as the cells' are:
-one slot of 300 (chat), sixteen of 300–830 (saturated), thirty-two of
-400–3072 (kanana), and every slot full (the guard). Each form runs every
-layer of the pool once a call (the rows differ by layer as in the engine),
-so the time printed is per layer with the pool's lines cold in HBM.
+``(16, 640)``, one pool of 8 layers × 6145 rows; ``mellum_full`` and
+``mellum_window`` 32 slots × 768 pages of ``(16, 512)``, two pools of 3
+layers × 13313 rows and of 9 × 3073, the second seen from ``length − 1024``
+on with the table's entries behind that given back; ``jamba`` 128 slots ×
+96 pages of ``(64, 128)``, two pools of 2 layers × 12289 rows. Lengths as
+the cells' are: one slot of 300 (chat), sixteen of 300–830 (saturated),
+thirty-two of 400–3072 × 0.66 (kanana), thirty-two of 2.3k–9k (mellum),
+128 of 1.0k–4.6k (jamba), and every slot full (the guard; from the first
+position on in every shape). Each form runs every layer of the pool once a
+call (the rows differ by layer as in the engine), so the time printed is
+per layer with the pool's lines cold in HBM.
 
-Prints one JSON line per (shape, lengths, form): ms a layer and the largest
-difference from ``gather``'s output (float32 at ``HIGHEST``).
+Prints one JSON line per (shape, lengths, form): ms a layer, the GB/s of
+visible lines that is (a v5e's HBM gives 819), the pages the kernel copies
+over the pages that hold a visible line (``ops.paged_attention.
+pages_fetched``, the kernel's own rule: 1.0 since PR 37, when it stopped
+copying whole blocks), and the largest difference from ``gather``'s output
+(float32 at ``HIGHEST``).
 
-    chiprun -- python tools/paged_attention_forms.py [form prefix ...]
+    chiprun -- python tools/paged_attention_forms.py [form prefix ...] \
+        [shape=<name prefix>]
 """
 import functools
 import json
@@ -40,16 +51,25 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from nnstreamer_tpu.ops import paged_attention as pa  # noqa: E402
 
 EXACT = jax.lax.Precision.HIGHEST
-PG = 16
+HBM_GB_S = 819  # a v5e's, benchmark/lib/peaks.py
 SHAPES = {
-    # slots, heads (queries a slot), line, blocks a slot, layers, pages, pools
-    "opt": dict(S=16, H=32, W=2048, NB=128, L=24, pages=1024, pools=2),
-    "kanana": dict(S=32, H=32, W=640, NB=192, L=8, pages=6144, pools=1),
+    # slots, heads (queries a slot), line, page, blocks a slot, layers,
+    # pages, pools, the positions a layer looks back, the cells' lengths
+    "opt": dict(S=16, H=32, W=2048, pg=16, NB=128, L=24, pages=1024,
+                pools=2, window=None, mixes=("chat", "saturated", "full")),
+    "kanana": dict(S=32, H=32, W=640, pg=16, NB=192, L=8, pages=6144,
+                   pools=1, window=None, mixes=("kanana", "full")),
+    "mellum_full": dict(S=32, H=32, W=512, pg=16, NB=768, L=3, pages=13312,
+                        pools=2, window=None, mixes=("mellum", "full")),
+    "mellum_window": dict(S=32, H=32, W=512, pg=16, NB=768, L=9, pages=3072,
+                          pools=2, window=1024, mixes=("mellum",)),
+    "jamba": dict(S=128, H=20, W=128, pg=64, NB=96, L=2, pages=12288,
+                  pools=2, window=None, mixes=("jamba", "full")),
 }
 
 
 def lengths_of(shape, name, rng):
-    S, ctx = shape["S"], shape["NB"] * PG
+    S, ctx = shape["S"], shape["NB"] * shape["pg"]
     out = np.zeros((S,), np.int32)
     if name == "chat":
         out[3] = 300
@@ -57,6 +77,10 @@ def lengths_of(shape, name, rng):
         out[:] = rng.integers(300, 831, S)
     elif name == "kanana":
         out[:] = rng.integers(400, ctx + 1, S) * 0.66  # mean 37% of ctx
+    elif name == "mellum":
+        out[:] = rng.integers(2300, 9001, S)
+    elif name == "jamba":
+        out[:] = rng.integers(1000, 4601, S)
     elif name == "full":
         out[:] = ctx
     return out
@@ -64,6 +88,7 @@ def lengths_of(shape, name, rng):
 
 def blocked(q, kpool, vpool, rows, lengths, scale, PB=8):
     S, NB = rows.shape
+    PG = kpool.shape[1]
     T = PB * PG
     H = q.shape[1]
 
@@ -106,56 +131,76 @@ FORMS = {
     "jaxlib": jaxlib,
     **{f"kernel_pb{pb}": functools.partial(pa.kernel_line_attention,
                                            pages_per_block=pb)
-       for pb in (None, 4, 8, 16, 32)},
+       for pb in (None, 4, 8, 16, 32, 64)},
 }
 
 
 def main():
-    only = sys.argv[1:]
+    only = [a for a in sys.argv[1:] if "=" not in a]
+    which = [a.split("=", 1)[1] for a in sys.argv[1:]
+             if a.startswith("shape=")]
     rng = np.random.default_rng(28)
     for shape_name, shape in SHAPES.items():
-        S, H, W, NB, L = (shape[k] for k in ("S", "H", "W", "NB", "L"))
+        if which and not any(shape_name.startswith(w) for w in which):
+            continue
+        S, H, W, NB, L, pg = (shape[k] for k in
+                              ("S", "H", "W", "NB", "L", "pg"))
         R = shape["pages"] + 1
         keys = jax.random.split(jax.random.PRNGKey(28), 3)
-        pools = tuple(jax.random.normal(k, (L * R, PG, W), jnp.bfloat16)
+        pools = tuple(jax.random.normal(k, (L * R, pg, W), jnp.bfloat16)
                       for k in keys[:shape["pools"]])
         kpool, vpool = pools[0], pools[-1]
         q = jax.random.normal(keys[2], (S, H, W), jnp.float32)
-        bt = np.stack([rng.permutation(shape["pages"])[:NB] + 1
-                       for _ in range(S)]).astype(np.int32)
         scale = 0.125
-        mixes = (("chat", "saturated", "full") if shape_name == "opt"
-                 else ("kanana", "full"))
-        for mix in mixes:
+        for mix in shape["mixes"]:
             lengths = lengths_of(shape, mix, rng)
+            windowed = shape["window"] is not None
+            starts = (np.maximum(lengths - shape["window"], 0) if windowed
+                      else np.zeros_like(lengths))
+            bt = np.stack([rng.permutation(shape["pages"])[:NB] + 1
+                           for _ in range(S)]).astype(np.int32)
+            # the pages behind the window were given back
+            bt[np.arange(NB)[None, :] < (starts // pg)[:, None]] = 0
+            visible = int((lengths - starts).sum())
+            held = int(((-(-lengths // pg) - starts // pg)
+                        * (lengths > 0)).sum())
             ref = None
             for form, fn in FORMS.items():
                 if only and form != "gather" and not any(
                         form.startswith(o) for o in only):
                     continue  # named forms only, beside their oracle
-                if form == "kernel_pb32" and shape_name == "opt":
-                    continue  # 2 MB a buffer, four buffers: nothing to learn
+                if form in ("blocked", "jaxlib") and (windowed or pg != 16):
+                    continue  # neither knows a first visible position
+                named = int(form[9:]) if form[9:].isdigit() else 0
+                if named > NB or named * pg * W * 2 > 2 * pa.BLOCK_BYTES:
+                    continue  # megabytes a buffer: nothing to learn
 
-                def layers(q, bt, lengths, kpool, vpool, fn=fn):
-                    out = []
-                    for li in range(L):
-                        out.append(fn(q, kpool, vpool, li * R + bt, lengths,
-                                      scale))
+                def layers(q, bt, lengths, starts, kpool, vpool, fn=fn):
+                    more = (starts,) if windowed else ()
+                    out = [fn(q, kpool, vpool, li * R + bt, lengths, scale,
+                              *more) for li in range(L)]
                     return out[0], sum(o.sum() for o in out)
 
                 row = {"shape": shape_name, "lengths": mix, "form": form,
-                       "tokens": int(lengths.sum())}
+                       "tokens": visible}
                 try:
                     run = jax.jit(layers)
-                    args = (q, bt, lengths, kpool, vpool)
+                    args = (q, bt, lengths, starts, kpool, vpool)
                     first, _ = jax.block_until_ready(run(*args))
                     reps = 5
                     t0 = time.perf_counter()
                     for _ in range(reps):
                         out = run(*args)
                     jax.block_until_ready(out)
-                    row["ms_per_layer"] = round(
-                        1e3 * (time.perf_counter() - t0) / reps / L, 4)
+                    ms = 1e3 * (time.perf_counter() - t0) / reps / L
+                    row["ms_per_layer"] = round(ms, 4)
+                    row["visible_gb_s"] = round(
+                        visible * W * 2 * len(pools) / ms / 1e6, 1)
+                    row["of_peak"] = round(
+                        row["visible_gb_s"] / HBM_GB_S, 3)
+                    if form.startswith("kernel"):
+                        row["fetched_over_visible"] = round(
+                            pa.pages_fetched(lengths, starts, pg) / held, 3)
                     if form == "gather":
                         ref = first
                     row["max_diff"] = float(jnp.abs(first - ref).max())
