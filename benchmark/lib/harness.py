@@ -15,6 +15,22 @@ Adding to the benchmark is adding files and entries:
   ``layer_metrics/<name>.py`` with ``read(facts) -> number | None``. A name
   ``x.suffix`` without a file of its own is read by ``x.py``: one quantity
   split over cells that report different end-to-end metrics.
+
+One entry a quantity and moved metric, never one a cell. The quantity is
+the reader file's name, ``x``. Where ``x`` has one entry and no end-to-end
+metric is called ``x``, the entry is ``x``. Where it moves ``tpot_p50_ms``
+in some cells and ``ttft_p50_ms`` in others, or is itself an end-to-end
+metric's name recorded where it is not judged, each entry is ``x.<moved>``,
+``<moved>`` being the moved metric's name up to its first underscore:
+``batch_occupancy.tpot``, ``batch_occupancy.ttft``, ``ttft_p50_ms.tpot``
+(the first-token time recorded in the cells judged by the token gap).
+
+An entry without ``workloads`` is read in every cell that reports the
+metric it ``moves``, so what every serving cell has by construction of the
+scheduler, the engine, the pool and the load generator lists no cells: a
+new cell joins the moved metric's list in ``end_to_end`` and inherits
+them. It adds its cell to the lists of the quantities it shares with some
+cells, and entries for what is its own, at the end.
 """
 from __future__ import annotations
 
@@ -50,27 +66,40 @@ def find_cell(bench: dict, name: str):
 
 def metrics_of(bench: dict, group: str, cell_name: str) -> list:
     """The entries of ``end_to_end`` or ``per_layer`` that this cell
-    reports: those that list it, and those that list no cells."""
-    return [m for m in bench[group]
-            if "workloads" not in m or cell_name in m["workloads"]]
+    reports: those that list it; an end-to-end metric that lists no cells;
+    a per-layer metric that lists none where the cell reports the metric
+    it ``moves``."""
+    every = [c["name"] for c in bench["workloads"]]
+    moved = {m["name"]: m.get("workloads", every)
+             for m in bench["end_to_end"]}
+    return [m for m in bench[group] if cell_name in m.get(
+        "workloads", moved.get(m.get("moves"), every))]
 
 
-def reader_for(metric_name: str):
-    """The ``read`` function of a per-layer metric's file."""
+def reader_file(metric_name: str) -> str:
+    """The quantity behind a per-layer metric's name: the stem of the file
+    under ``layer_metrics/`` that reads it, its own or, for ``x.suffix``
+    without one, ``x``."""
     folder = os.path.join(BENCH_DIR, "layer_metrics")
     stems = [metric_name]
     if "." in metric_name:
         stems.append(metric_name.rsplit(".", 1)[0])
     for stem in stems:
-        path = os.path.join(folder, f"{stem}.py")
-        if os.path.exists(path):
-            spec = importlib.util.spec_from_file_location(
-                "benchmark.layer_metrics." + stem.replace(".", "_"), path)
-            module = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(module)
-            return module.read
+        if os.path.exists(os.path.join(folder, f"{stem}.py")):
+            return stem
     raise FileNotFoundError(
         f"per-layer metric {metric_name!r} has no reader under {folder}")
+
+
+def reader_for(metric_name: str):
+    """The ``read`` function of a per-layer metric's file."""
+    stem = reader_file(metric_name)
+    spec = importlib.util.spec_from_file_location(
+        "benchmark.layer_metrics." + stem.replace(".", "_"),
+        os.path.join(BENCH_DIR, "layer_metrics", f"{stem}.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.read
 
 
 def driver_for(config: dict):

@@ -31,6 +31,8 @@ Definitions, as the contract has them:
 from __future__ import annotations
 
 import bisect
+import functools
+import itertools
 import re
 from collections import defaultdict
 
@@ -40,11 +42,13 @@ _OP = re.compile(r"^%?([\w.\-]+) = (?:\()?([a-z0-9]+\[[0-9,]*\])")
 _MODULE = re.compile(r"^(?:jit_)?(.*?)(?:\(\d+\))?$")
 
 
+@functools.lru_cache(maxsize=None)
 def op_label(hlo_text: str, numbered: bool = True) -> str:
     """``%copy.9 = bf16[24,1025]{1,0:T(8,128)} copy(...)`` →
     ``copy.9_bf16_24_1025_`` (operation name, result type and shape);
     without ``numbered`` the name loses its number, ``copy_bf16_24_1025_``:
-    the kind that the instances of one operation share."""
+    the kind that the instances of one operation share. Kept by text: a
+    trace holds millions of events of a few thousand operations."""
     m = _OP.match(hlo_text)
     if not m:
         return re.sub(r"[^\w.\-]+", "_", hlo_text)[:64]
@@ -98,7 +102,7 @@ def reduce_trace(path: str) -> dict:
         marks = [t for _, ops in chips for _, a, b in ops for t in (a, b)]
         marks += [t for _, a, b in spans for t in (a, b)]
         w0, w1 = min(marks), max(marks)
-    spans = [s for s in spans if s[0] != WINDOW_SPAN]
+    spans = _Spans(s for s in spans if s[0] != WINDOW_SPAN)
 
     programs = defaultdict(lambda: {"count": 0, "total_s": 0.0,
                                     "ops": defaultdict(float),
@@ -125,7 +129,7 @@ def reduce_trace(path: str) -> dict:
         edges = [w0] + [t for iv in busy for t in iv] + [w1]
         for g0, g1 in zip(edges[0::2], edges[1::2]):
             if g1 > g0:
-                _charge(gaps, g0, g1, spans)
+                _charge(gaps, g0, g1, spans.near(g0, g1))
     n = len(chips)
     top_ops = sorted(
         ((f"{p}:{kind}" + (f"(x{len(d['instances'][kind])})"
@@ -145,9 +149,30 @@ def reduce_trace(path: str) -> dict:
     }
 
 
+class _Spans:
+    """Host spans sorted by their start, beside the running maximum of
+    their ends: those that can overlap an interval are a slice found by
+    bisection, so charging a trace's gaps does not grow with gaps x spans
+    (a window of 760 steps holds some ten thousand of each)."""
+
+    def __init__(self, spans):
+        self.spans = sorted(spans, key=lambda s: s[1])
+        self.starts = [a for _, a, _ in self.spans]
+        self.ends = list(itertools.accumulate(
+            (b for _, _, b in self.spans), max))
+
+    def near(self, g0, g1) -> list:
+        """Every span that overlaps [g0, g1), and maybe some that end
+        before it: none before the slice ends after ``g0``, none after it
+        starts before ``g1``."""
+        return self.spans[bisect.bisect_right(self.ends, g0):
+                          bisect.bisect_left(self.starts, g1)]
+
+
 def _charge(gaps, g0, g1, spans):
     """Charge the idle interval [g0, g1) to the host spans that overlap
-    it: where spans nest, the shortest one covering a moment takes it."""
+    it: where spans nest, the shortest one covering a moment takes it.
+    ``spans`` need hold only those that can overlap it (``_Spans.near``)."""
     cuts = sorted({g0, g1, *(t for _, a, b in spans for t in (a, b)
                              if g0 < t < g1)})
     for a, b in zip(cuts, cuts[1:]):

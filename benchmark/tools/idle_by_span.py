@@ -24,7 +24,6 @@ and the prefix's spans), and the union of the ``XLA Ops`` intervals.
 from __future__ import annotations
 
 import argparse
-import bisect
 import os
 import sys
 from collections import defaultdict
@@ -65,11 +64,8 @@ def idle_by_span(path: str, prefix: str) -> dict:
         w0, w1 = min(marks), max(marks)
 
     # _charge cuts the prefix it knows off a name: hand it ours under that
-    spans.sort(key=lambda ev: ev[1])
-    renamed = [(xplane.SPAN_PREFIX + n[len(prefix):], a, b)
-               for n, a, b in spans]
-    starts = [a for _, a, _ in renamed]
-    longest = max((b - a for _, a, b in renamed), default=0.0)
+    renamed = xplane._Spans((xplane.SPAN_PREFIX + n[len(prefix):], a, b)
+                            for n, a, b in spans)
     gaps, idle_ns = defaultdict(float), 0.0
     for ops in chips:
         busy = xplane._union((max(a, w0), min(b, w1)) for _, a, b in ops
@@ -78,11 +74,7 @@ def idle_by_span(path: str, prefix: str) -> dict:
         for g0, g1 in zip(edges[0::2], edges[1::2]):
             if g1 > g0:
                 idle_ns += g1 - g0
-                # only spans that can overlap the gap: a trace holds
-                # thousands of each
-                near = renamed[bisect.bisect_left(starts, g0 - longest):
-                               bisect.bisect_left(starts, g1)]
-                xplane._charge(gaps, g0, g1, near)
+                xplane._charge(gaps, g0, g1, renamed.near(g0, g1))
     n = len(chips)
     by_name = defaultdict(list)
     for name, a, b in spans:

@@ -74,13 +74,19 @@ def test_no_traced_part_is_nothing_to_read(ring):
 
 def test_the_entry_lists_the_cells_whose_steps_it_moves():
     (entry,) = [m for m in BENCH["per_layer"] if m["name"] == NAME]
+    entry = dict(entry)
+    listed = entry.pop("workloads")
     assert entry == {
         "name": NAME, "unit": "%", "better": "lower",
         "source": "program_counter",
         "layer": "engine + pool (serving/lm_engine.py, kv_pool.py)",
-        "moves": "tpot_p50_ms",
-        "workloads": ["opt1b3_chat", "opt1b3_saturated",
-                      "kanana2_decode_saturated"]}
-    assert BENCH["per_layer"][-1] is entry, "new entries go at the end"
+        "moves": "tpot_p50_ms"}
+    # a kernel's counter: it keeps its list (a family whose step does not
+    # count its pages has nothing to read), one entry for every cell in it
     judged = {m["name"]: m for m in BENCH["end_to_end"]}["tpot_p50_ms"]
-    assert set(entry["workloads"]) <= set(judged["workloads"])
+    assert set(listed) <= set(judged["workloads"])
+    assert {"opt1b3_chat", "opt1b3_saturated",
+            "kanana2_decode_saturated"} <= set(listed)
+    assert callable(harness.reader_for(NAME))
+    assert not [m for m in BENCH["per_layer"]
+                if m["name"].startswith(NAME + ".")]

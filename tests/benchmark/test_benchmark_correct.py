@@ -33,6 +33,28 @@ def rehearsal_ctx(workload: str, seed: int, seconds: float) -> dict:
             "tracer": None, "t_start": time.monotonic(), "rehearse": True}
 
 
+def run_counting_tokens_home(driver, proxy_class, ctx) -> dict:
+    """A driver's ``run`` of an expert family's cell, with
+    ``out["tokens_home"]``: for every ``step()`` call of the proxy (keyed by
+    the time it notes for the step) how many tokens the call brought home.
+    The loop runs one step ahead, so those tokens, and the expert counts
+    that came home behind them, are of the step dispatched a call earlier,
+    not of the batch the proxy holds at the call."""
+    home = {}
+    real_step = proxy_class.step
+
+    def counting(self):
+        out = real_step(self)
+        home[self.moe_steps[-1][0]] = int((np.asarray(out) >= 0).sum())
+        return out
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(proxy_class, "step", counting)
+        out = driver.run(ctx)
+    out["tokens_home"] = home
+    return out
+
+
 def test_served_gaps_is_zero_for_the_best_and_the_distance_otherwise():
     scores = np.array([[0.1, 0.9, 0.3], [2.0, -1.0, 1.5]], np.float32)
     assert served_gaps(scores, np.array([1, 0])).tolist() == [0.0, 0.0]

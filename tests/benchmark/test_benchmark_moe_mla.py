@@ -17,7 +17,10 @@ sys.path.insert(0, ROOT)
 
 from benchmark.drivers import lm_serving, lm_serving_moe_mla  # noqa: E402
 from benchmark.lib import harness, opcount_moe_mla, peaks  # noqa: E402
-from tests.benchmark.test_benchmark_correct import rehearsal_ctx  # noqa: E402
+from tests.benchmark.test_benchmark_correct import (  # noqa: E402
+    rehearsal_ctx,
+    run_counting_tokens_home,
+)
 
 BENCH = harness.load_benchmark()
 CELL = "kanana2_decode_saturated"
@@ -30,7 +33,8 @@ V5E = peaks.peaks_for("TPU v5 lite")
 @pytest.fixture(scope="module")
 def sound_run():
     ctx = rehearsal_ctx(CELL, 2**31 + 27, 1.5)
-    return ctx, lm_serving_moe_mla.run(ctx)
+    return ctx, run_counting_tokens_home(
+        lm_serving_moe_mla, lm_serving_moe_mla.MoEProxy, ctx)
 
 
 def test_a_sound_run_of_the_new_family_is_correct(sound_run):
@@ -52,12 +56,18 @@ def test_every_decode_step_of_the_window_has_its_expert_counts(sound_run):
     assert [t for t, _ in moe] == [s[0] for s in steps]
     slots = facts["moe_expert_slots"]
     assert slots == 2 * 8  # two expert layers of eight at rehearsal sizes
-    for (_, active, _, _), (_, c) in zip(steps, moe):
-        # two experts a token, two expert layers, nothing dropped
-        assert c["moe_assignments"] == active * 2 * 2
-        assert 0 < c["moe_experts_touched"] <= min(slots,
-                                                   c["moe_assignments"])
-        assert c["moe_expert_slots"] == slots
+    home = out["tokens_home"]
+    for t, c in moe:
+        # the counts are those of the step whose tokens this call brought
+        # home: two experts a token, two expert layers, nothing dropped
+        assert c["moe_assignments"] == home[t] * 2 * 2
+        assert c["moe_experts_touched"] <= min(slots, c["moe_assignments"])
+        assert (c["moe_experts_touched"] > 0) == (home[t] > 0)
+        assert c["moe_expert_slots"] == (slots if home[t] else 0)
+    # a call's batch is the one the next call's counts belong to, but for
+    # a slot that joined or left between them: the window's sums agree to
+    # within the joins
+    assert 0 < sum(home[t] for t, _ in moe) <= sum(s[1] for s in steps)
     read = harness.reader_for("moe_experts_touched_share")
     share = read(dict(facts, metric=None))
     assert 0 < share <= 100

@@ -23,7 +23,10 @@ from benchmark.lib import (  # noqa: E402
     peaks,
     traffic,
 )
-from tests.benchmark.test_benchmark_correct import rehearsal_ctx  # noqa: E402
+from tests.benchmark.test_benchmark_correct import (  # noqa: E402
+    rehearsal_ctx,
+    run_counting_tokens_home,
+)
 
 BENCH = harness.load_benchmark()
 CELL = "mellum2_longctx_decode"
@@ -36,7 +39,8 @@ V5E = peaks.peaks_for("TPU v5 lite")
 @pytest.fixture(scope="module")
 def sound_run():
     ctx = rehearsal_ctx(CELL, 2**31 + 31, 2.5)
-    return ctx, lm_serving_moe_window.run(ctx)
+    return ctx, run_counting_tokens_home(
+        lm_serving_moe_window, lm_serving_moe_window.WindowProxy, ctx)
 
 
 def test_a_sound_run_of_the_new_family_is_correct(sound_run):
@@ -74,23 +78,32 @@ def test_every_decode_step_has_its_counts_and_its_pages_by_kind(sound_run):
     slots = facts["moe_expert_slots"]
     assert slots == 4 * 8  # four expert layers of eight at rehearsal sizes
     window, held = ctx["config"]["sliding_window"], 6
-    for (_, active, context, pages), (_, c) in zip(steps, moe):
-        assert c["moe_assignments"] == active * 2 * 4  # nothing dropped
-        assert 0 < c["moe_experts_touched"] <= min(slots,
-                                                   c["moe_assignments"])
+    home = out["tokens_home"]
+    for (t, active, context, pages), (_, c) in zip(steps, moe):
+        # the counts are those of the step whose tokens this call brought
+        # home: two experts a token, four expert layers, nothing dropped
+        assert c["moe_assignments"] == home[t] * 2 * 4
+        assert c["moe_experts_touched"] <= min(slots, c["moe_assignments"])
+        assert (c["moe_experts_touched"] > 0) == (home[t] > 0)
+        # what the proxy notes itself is of the batch it holds at the call
         assert c["ctx_window"] <= min(context, active * window)
         assert c["pages_full"] == pages
         assert c["pages_window"] <= active * held
+    # a call's batch is the one the next call's counts belong to, but for
+    # a slot that joined or left between them: the window's sums agree to
+    # within the joins
+    assert sum(c["moe_assignments"] for _, c in moe) == 8 * sum(
+        home[t] for t, _ in moe)
+    assert 0 < sum(home[t] for t, _ in moe) <= sum(s[1] for s in steps)
     peaks_by_kind = facts["pool_pages_used_peak_by_kind"]
     assert 0 < peaks_by_kind["window"] <= 3 * held
     assert peaks_by_kind["full"] == facts["pool_pages_used_peak"]
     assert facts["pool_pages"] == 96 and facts["pool_pages_by_kind"] == {
         "full": 96, "window": 32}
-    for name in ("moe_experts_touched_share.mellum",
-                 "pool_pages_used_peak.mellum", "pool_live_share.mellum",
-                 "batch_occupancy.mellum"):
+    for name in ("moe_experts_touched_share", "pool_pages_used_peak.tpot",
+                 "pool_live_share.tpot", "batch_occupancy.tpot"):
         assert 0 < harness.reader_for(name)(dict(facts, metric=None)) <= 100
-    assert harness.reader_for("moe_max_load_over_mean.mellum")(facts) >= 1.0
+    assert harness.reader_for("moe_max_load_over_mean")(facts) >= 1.0
     assert harness.reader_for("ramp_s")(facts) == facts["ramp_s"]
 
 
@@ -186,29 +199,43 @@ def test_the_traffic_is_issue_31s_to_the_number():
     assert [it["after"] for it in items[32:]] == list(range(32))
 
 
+OWN = ("moe_step_dev_share", "attn_window_step_share",
+       "attn_full_step_share", "window_pages_read_share",
+       "moe_topk_roofline", "gqa_window_decode_roofline",
+       "moe_gqa_step_roofline")
+
+
 def test_the_cell_and_its_metrics_are_at_the_end_of_their_lists():
-    assert BENCH["configs"][-1]["name"] == "mellum2_12b_a2.5b_l12"
-    cell = BENCH["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
-        CELL, "mellum2_12b_a2.5b_l12", "mellum_longctx_closed", 1)
+    """Found by name: a later cell, configuration or entry stands after
+    them, as the contract puts it."""
+    (config,) = [c for c in BENCH["configs"]
+                 if c["name"] == "mellum2_12b_a2.5b_l12"]
+    assert config["file"] == "benchmark/configs/mellum2_12b_a2.5b_l12.json"
+    (cell,) = [c for c in BENCH["workloads"] if c["name"] == CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "mellum2_12b_a2.5b_l12", "mellum_longctx_closed", 1)
     assert len(cell["why"]) <= 200
-    listed = {m["name"]: m for m in BENCH["end_to_end"]}
-    assert listed["tpot_p50_ms"]["workloads"][-1] == CELL
-    mine = harness.metrics_of(BENCH, "per_layer", CELL)
-    named = [m["name"] for m in mine if "workloads" in m]
-    assert named == [m["name"] for m in BENCH["per_layer"][-len(named):]]
-    for m in mine:
-        harness.reader_for(m["name"])  # every entry has a reader
+    judged = {m["name"]: m for m in BENCH["end_to_end"]}
+    assert CELL in judged["tpot_p50_ms"]["workloads"]
+    cells = [c["name"] for c in BENCH["workloads"]]
+    mine = {m["name"]: m for m in harness.metrics_of(BENCH, "per_layer",
+                                                     CELL)}
+    for name, m in mine.items():
+        harness.reader_for(name)  # every entry has a reader
+        assert m["moves"] in ("setup_s", "tpot_p50_ms")
         if "workloads" in m:
-            assert m["workloads"] == [CELL]
-            assert m["moves"] == ("setup_s" if m["name"] == "ramp_s"
-                                  else "tpot_p50_ms")
-    assert {"moe_step_dev_share", "attn_window_step_share",
-            "attn_full_step_share", "window_pages_read_share", "ramp_s",
-            "moe_topk_roofline", "gqa_window_decode_roofline",
-            "moe_gqa_step_roofline"} <= set(named)
+            assert CELL in m["workloads"]
+            assert set(m["workloads"]) <= set(
+                judged[m["moves"]].get("workloads", cells))
+    # what is the window family's alone lists this cell and no other
+    for name in OWN:
+        assert mine[name]["workloads"] == [CELL]
+        assert mine[name]["moves"] == "tpot_p50_ms"
+    assert mine["ramp_s"]["moves"] == "setup_s"
+    assert {"moe_experts_touched_share", "moe_max_load_over_mean",
+            "attn_pages_read_share", "out_tokens_per_s"} <= set(mine)
     # nothing that reads a launch or a first token of the window is listed
-    assert not {n for n in named if n.startswith((
+    assert not {n for n in mine if n.startswith((
         "prefill_", "chunk_host", "ttft_", "moe_dev_share"))}
 
 

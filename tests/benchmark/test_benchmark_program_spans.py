@@ -15,7 +15,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from benchmark.lib import harness, xplane  # noqa: E402
+from benchmark.lib import harness, traffic, xplane  # noqa: E402
 from benchmark.tools import idle_by_span  # noqa: E402
 
 BENCH = harness.load_benchmark()
@@ -117,24 +117,100 @@ def test_a_reader_of_program_spans_returns_nothing_with_nothing_to_read(
     assert read(dict(ring, metric={"name": name})) is None
 
 
+STARTUP = {"setup_engine_build_s": 3.5 - 1.0, "setup_trace_lower_s": 3.25,
+           "setup_cache_load_s": 4.0, "setup_fresh_compile_s": 1.5,
+           "setup_fresh_compiles": 9}
+
+
+def test_the_startup_readers_split_set_up_at_the_windows_opening(monkeypatch):
+    """The five readers over the program's account of its start-up
+    (``lib/startup.py``): what began before the window opened, the build's
+    spans less jax's seconds charged to them."""
+    from types import SimpleNamespace as Span
+
+    from nnstreamer_tpu.obs import context as ctx
+
+    spans = [Span(name="setup.params", start_s=10.0, dur_s=2.0,
+                  attrs={"trace_s": 0.3, "compile_s": 0.2}),
+             Span(name="setup.engine", start_s=12.0, dur_s=1.5,
+                  attrs={"lower_s": 0.5}),
+             Span(name="program.first_call", start_s=14.0, dur_s=4.0,
+                  attrs={"trace_s": 1.0}),           # no part of the build
+             Span(name="setup.engine", start_s=70.0, dur_s=9.0, attrs={})]
+    asked = []
+
+    def account(since=None, until=None):
+        asked.append((since, until))
+        return {"totals": {"trace_own_s": 2.0, "lower_own_s": 1.25,
+                           "load_s": 4.0, "fresh_s": 1.5, "fresh": 9}}
+
+    monkeypatch.setattr(ctx, "startup_spans", lambda: spans)
+    monkeypatch.setattr(ctx, "compile_account", account)
+    # the traced part began 12 s into a window of 48: it opened at 50
+    facts = {"trace_bounds": (62.0, 72.0), "window_s": 48.0,
+             "mix": {"trace": {"start_s": 12.0, "seconds": 10.0}}}
+    for name, value in STARTUP.items():
+        assert harness.reader_for(name)(facts) == pytest.approx(value)
+    assert set(asked) == {(None, 50.0)}
+    assert {m["name"] for m in BENCH["per_layer"]} >= set(STARTUP)
+    # a window shorter than the mix's offset is traced from where run.py
+    # starts it: 2 s less the traced 10 is the window's own start
+    del asked[:]
+    harness.reader_for("setup_cache_load_s")(dict(facts, window_s=2.0))
+    assert asked == [(None, 62.0)]
+    # nothing to read: no traced part, no span of the build, no account
+    read = harness.reader_for("setup_engine_build_s")
+    assert read(dict(facts, trace_bounds=None)) is None
+    monkeypatch.setattr(ctx, "startup_spans", lambda: spans[2:])
+    assert read(facts) is None
+    monkeypatch.delattr(ctx, "compile_account")
+    assert read(facts) is None
+
+
 def test_the_new_entries_follow_the_suffix_rule():
-    entries = {m["name"]: m for m in BENCH["per_layer"]}
-    mine = {n: m for n, m in entries.items() if n.split(".")[0] in NEW}
-    assert len(mine) == 15
-    layers = {m["layer"] for n, m in entries.items() if n not in mine}
-    for name, m in mine.items():
-        assert m["layer"] in layers and m["better"] == "lower"
-        suffix = name.partition(".")[2]
-        lane = name.startswith("prefill_lane_wait")
-        cells = {"": ["opt1b3_longprompt"] if lane
-                 else ["opt1b3_chat", "opt1b3_saturated"],
-                 "long": ["opt1b3_longprompt"], "chat": ["opt1b3_chat"],
-                 "sat": ["opt1b3_saturated"]}[suffix]
-        assert m["workloads"] == cells
-        assert m["moves"] == ("ttft_p50_ms" if cells == ["opt1b3_longprompt"]
-                              else "tpot_p50_ms")
-        assert m["source"] == ("program_counter" if name.startswith(
-            "passes_with_chunk_share") else "program_span")
+    """``harness.py``'s naming rule, for every entry: one entry a quantity
+    (a reader file) and moved metric; ``x`` where the quantity has one
+    entry and no end-to-end metric is called ``x``, else ``x.<moved>`` with
+    the moved metric's name up to its first underscore."""
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    cells = [c["name"] for c in BENCH["workloads"]]
+    by_quantity = {}
+    for m in BENCH["per_layer"]:
+        by_quantity.setdefault(harness.reader_file(m["name"]), []).append(m)
+    for quantity, entries in by_quantity.items():
+        moves = [m["moves"] for m in entries]
+        assert len(set(moves)) == len(moves), quantity  # no copy for a cell
+        split = len(entries) > 1 or quantity in e2e
+        for m in entries:
+            suffix = "." + m["moves"].split("_")[0] if split else ""
+            assert m["name"] == quantity + suffix
+            # read only where the metric it moves is reported
+            assert set(m.get("workloads", ())) <= set(
+                e2e[m["moves"]].get("workloads", cells))
+        for key in ("unit", "better", "source", "layer"):
+            assert len({m[key] for m in entries}) == 1, (quantity, key)
+    # PR 24's seven: in every serving cell or, for what reads a launch or
+    # a first token, in the cells whose traced part holds one
+    assert set(NEW) <= set(by_quantity)
+    layers = {m["layer"] for q, ms in by_quantity.items() if q not in NEW
+              for m in ms}
+    for quantity in NEW:
+        entries = {m["name"]: m for m in by_quantity[quantity]}
+        assert set(entries) == {quantity + ".tpot", quantity + ".ttft"}
+        for name, m in entries.items():
+            assert m["layer"] in layers and m["better"] == "lower"
+            assert m["moves"] == name.rsplit(".", 1)[1] + "_p50_ms"
+            assert m["source"] == ("program_counter" if quantity
+                                   == "passes_with_chunk_share"
+                                   else "program_span")
+            read_in = [c for c in cells if m in harness.metrics_of(
+                BENCH, "per_layer", c)]
+            judged = e2e[m["moves"]]["workloads"]
+            if quantity in ("chunk_host_ms", "prefill_lane_wait_p50_ms"):
+                assert m["workloads"] == read_in and set(read_in) <= set(
+                    judged)
+            else:
+                assert "workloads" not in m and read_in == judged
 
 
 @pytest.mark.parametrize("workload", [c["name"] for c in BENCH["workloads"]])
@@ -149,10 +225,26 @@ def test_a_traced_rehearsal_prints_every_new_metric_of_the_cell(
              "JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
     assert run.returncode == 0, run.stderr[-2000:]
     line = json.loads(run.stdout.strip().splitlines()[-1])
-    mine = [m for m in harness.metrics_of(BENCH, "per_layer", workload)
-            if m["name"].split(".")[0] in NEW]
-    assert len(mine) == 7
+    listed = harness.metrics_of(BENCH, "per_layer", workload)
+    assert set(line["metrics"]) <= {m["name"] for m in listed}
+    mine = [m for m in listed if harness.reader_file(m["name"]) in NEW]
+    # the five that every pass has; a launch's and a first token's where
+    # the cell lists them
+    assert 5 <= len(mine) <= 7
+    # whether the traced part held a pass at all, by a counter that
+    # another reader takes from the same passes: a closed loop of a few
+    # rounds (mellum's rehearsal: six requests) has ended by the time the
+    # profiler has started on a CPU, and then a reader of passes returns
+    # nothing, never 0
+    (cell,) = [c for c in BENCH["workloads"] if c["name"] == workload]
+    mix = traffic.load(cell["traffic"])
+    held_a_pass = bool({"attn_pages_read_share", "prefill_fill_share.ttft"}
+                       & set(line["metrics"]))
+    assert held_a_pass or {**mix, **mix["rehearsal"]}.get("rounds", 99) < 10
     for m in mine:
+        if not held_a_pass:
+            assert m["name"] not in line["metrics"]
+            continue
         got = line["metrics"][m["name"]]  # none left out
         if m["source"] == "program_counter":
             assert 0.0 <= got["value"] <= 100.0

@@ -82,6 +82,52 @@ def test_idle_gaps_are_charged_to_the_host_span_that_covers_them(reduced):
     assert gaps["unattributed"] < 0.002
 
 
+@pytest.mark.parametrize("trace", sorted(
+    f for f in os.listdir(os.path.join(ROOT, "benchmark", "testdata"))
+    if f.endswith(".xplane.pb")))
+def test_bisecting_the_sorted_spans_charges_as_scanning_them_all_did(
+        trace, monkeypatch):
+    path = os.path.join(ROOT, "benchmark", "testdata", trace)
+    found = xplane.reduce_trace(path)["idle_gaps"]
+    # the rule as it ran until PR 38: every idle gap looks at every span
+    monkeypatch.setattr(xplane._Spans, "near",
+                        lambda self, g0, g1: self.spans)
+    # to the last digit: the same cuts, summed in the same order
+    assert found == xplane.reduce_trace(path)["idle_gaps"]
+    assert found
+
+
+def test_the_spans_near_a_gap_are_all_that_can_overlap_it():
+    import random
+    from collections import defaultdict
+
+    rng = random.Random(38)
+    spans = []
+    for i in range(400):  # passes of 100 with a box or two nested in each
+        t = 100.0 * i + rng.uniform(0, 5)
+        spans.append(("bench:step", t, t + rng.uniform(20, 90)))
+        spans.append(("bench:inner", t + 2, t + rng.uniform(3, 19)))
+    # another thread's, across the passes, and a stall of thirty passes
+    spans += [("bench:submit", 100.0 * i + 80, 100.0 * i + 130)
+              for i in range(0, 400, 7)]
+    spans.append(("bench:stall", 20050.0, 23000.0))
+    rng.shuffle(spans)
+    index = xplane._Spans(spans)
+    assert index.starts == sorted(index.starts)
+    everything, near = defaultdict(float), defaultdict(float)
+    for _ in range(500):
+        g0 = rng.uniform(-50, 40100)
+        g1 = g0 + rng.choice((0.5, 7.0, 60.0, 450.0))
+        found = index.near(g0, g1)
+        assert {s for s in spans if s[1] < g1 and s[2] > g0} <= set(found)
+        # and not many more: what began since the longest span still open
+        assert len(found) < (20 if g1 < 20000 or g0 > 23100 else 80)
+        xplane._charge(everything, g0, g1, spans)
+        xplane._charge(near, g0, g1, found)
+    assert dict(near) == dict(everything)  # exactly
+    assert set(near) == {"step", "inner", "submit", "stall", "unattributed"}
+
+
 def test_hlo_text_becomes_a_short_label():
     assert xplane.op_label(
         "%copy.9 = bf16[24,1025,32,16,64]{4,3,2,1,0:T(8,128)(2,1)} "

@@ -96,8 +96,10 @@ def test_closed_loop_clients_wait_for_their_own_last_request(name):
         assert x["after"] == (None if i < clients else i - clients)
         assert [int(x["prompt"].size), x["steps"]] == mix["requests"][
             i % len(mix["requests"])]
-    # every request fits the configuration's positions
-    assert max(p + s for p, s in mix["requests"]) <= 2048
+    # every request fits the positions of each configuration it is sent to
+    limits = [harness.find_cell(BENCH, c["name"])[1]["max_position_embeddings"]
+              for c in BENCH["workloads"] if c["traffic"] == name]
+    assert limits and max(p + s for p, s in mix["requests"]) <= min(limits)
 
 
 def test_a_traffic_kind_without_a_generator_is_refused(tmp_path, monkeypatch):
@@ -216,6 +218,7 @@ def test_benchmark_json_keeps_the_contracts_static_rules():
         assert 0.01 <= m["bound"] <= 0.1 and UNIT.match(m["unit"])
         assert m["better"] in ("lower", "higher")
         assert set(m.get("workloads", cells)) <= set(cells)
+    assert 1 <= len(BENCH["per_layer"]) <= 128
     for m in BENCH["per_layer"]:
         assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
                                           "layer", "moves"}
@@ -224,12 +227,238 @@ def test_benchmark_json_keeps_the_contracts_static_rules():
         assert UNIT.match(m["unit"]) and "\n" not in m["layer"]
         assert m["moves"] in e2e
         moved = e2e[m["moves"]]
-        assert set(m.get("workloads", cells)) <= set(
+        # an entry that lists no cells is read where the moved metric is
+        assert set(m.get("workloads", ())) <= set(
             moved.get("workloads", cells))
     for name, cell in cells.items():
         mine = [m["name"] for m in harness.metrics_of(BENCH, "end_to_end", name)]
         assert "setup_s" in mine and len(mine) >= 2
         assert harness.metrics_of(BENCH, "per_layer", name)
+
+
+# what each cell printed at PR 37, before one entry stood for a quantity in
+# every cell that has it (the ledger keeps a metric's history under these)
+BEFORE = {
+    "opt1b3_chat": (
+        "setup_compile_s compiles_in_window batch_occupancy "
+        "pool_pages_used_peak pool_live_share decode_step_dev_ms "
+        "decode_step_roofline serving_device_idle ttft_p50_ms.chat "
+        "ttft_p90_ms.chat gen_late_p99_ms.chat queue_wait_p50_ms.chat "
+        "prefill_chunks_per_req.chat prefill_chunk_dev_ms.chat "
+        "sched_self_ms_per_pass passes_with_chunk_share step_host_ms "
+        "chunk_host_ms step_pull_wait_ms prefill_lane_wait_p50_ms.chat "
+        "host_serial_share attn_pages_read_share "
+        "prefill_fill_share.chat").split(),
+    "opt1b3_longprompt": (
+        "setup_compile_s gen_late_p99_ms compiles_in_window.long "
+        "queue_wait_p50_ms batch_occupancy.long prefill_chunks_per_req "
+        "pool_pages_used_peak.long pool_live_share.long prefill_chunk_dev_ms "
+        "decode_step_dev_ms.long serving_device_idle.long ttft_p90_ms "
+        "tpot_p50_ms.long sched_self_ms_per_pass.long "
+        "passes_with_chunk_share.long step_host_ms.long chunk_host_ms.long "
+        "step_pull_wait_ms.long prefill_lane_wait_p50_ms "
+        "host_serial_share.long prefill_fill_share").split(),
+    "opt1b3_saturated": (
+        "setup_compile_s compiles_in_window batch_occupancy "
+        "pool_pages_used_peak pool_live_share decode_step_dev_ms "
+        "decode_step_roofline serving_device_idle gen_late_p99_ms.sat "
+        "queue_wait_p50_ms.sat prefill_chunks_per_req.sat "
+        "prefill_chunk_dev_ms.sat ttft_p50_ms.sat out_tokens_per_s "
+        "sched_self_ms_per_pass passes_with_chunk_share step_host_ms "
+        "chunk_host_ms step_pull_wait_ms prefill_lane_wait_p50_ms.sat "
+        "host_serial_share attn_pages_read_share "
+        "prefill_fill_share.sat").split(),
+    "kanana2_decode_saturated": (
+        "setup_compile_s decode_step_dev_ms.kanana "
+        "prefill_chunk_dev_ms.kanana serving_device_idle.kanana "
+        "batch_occupancy.kanana passes_with_chunk_share.kanana "
+        "pool_live_share.kanana pool_pages_used_peak.kanana "
+        "step_host_ms.kanana chunk_host_ms.kanana step_pull_wait_ms.kanana "
+        "host_serial_share.kanana sched_self_ms_per_pass.kanana "
+        "compiles_in_window.kanana out_tokens_per_s.kanana ttft_p50_ms.kanana "
+        "prefill_lane_wait_p50_ms.kanana gen_late_p99_ms.kanana moe_dev_share "
+        "mla_dev_share moe_experts_touched_share moe_max_load_over_mean "
+        "moe_roofline mla_decode_roofline moe_mla_step_roofline "
+        "attn_pages_read_share prefill_fill_share.kanana").split(),
+    "mellum2_longctx_decode": (
+        "setup_compile_s decode_step_dev_ms.mellum serving_device_idle.mellum "
+        "batch_occupancy.mellum passes_with_chunk_share.mellum "
+        "pool_live_share.mellum pool_pages_used_peak.mellum "
+        "step_host_ms.mellum step_pull_wait_ms.mellum "
+        "host_serial_share.mellum sched_self_ms_per_pass.mellum "
+        "compiles_in_window.mellum out_tokens_per_s.mellum "
+        "gen_late_p99_ms.mellum attn_pages_read_share.mellum "
+        "moe_experts_touched_share.mellum moe_max_load_over_mean.mellum "
+        "moe_step_dev_share attn_window_step_share attn_full_step_share "
+        "window_pages_read_share ramp_s moe_topk_roofline "
+        "gqa_window_decode_roofline moe_gqa_step_roofline").split(),
+    "jamba2_reasoning_saturated": (
+        "setup_compile_s decode_step_dev_ms.jamba prefill_chunk_dev_ms.jamba "
+        "serving_device_idle.jamba batch_occupancy.jamba "
+        "pool_live_share.jamba pool_pages_used_peak.jamba "
+        "attn_pages_read_share.jamba prefill_fill_share.jamba "
+        "compiles_in_window.jamba out_tokens_per_s.jamba "
+        "gen_late_p99_ms.jamba ttft_p50_ms.jamba sched_self_ms_per_pass.jamba "
+        "passes_with_chunk_share.jamba step_host_ms.jamba chunk_host_ms.jamba "
+        "step_pull_wait_ms.jamba prefill_lane_wait_p50_ms.jamba "
+        "host_serial_share.jamba ramp_s.jamba ssm_step_dev_share "
+        "attn_mqa_step_share mlp_step_dev_share ssm_scan_chunk_share "
+        "state_live_share ssm_step_roofline ssm_chunk_scan_roofline "
+        "ssm_mqa_step_roofline").split(),
+}
+# new name: the names it had, one a cell or two
+RENAMED = {
+    "gen_late_p99_ms.ttft": ["gen_late_p99_ms"],
+    "compiles_in_window.tpot": [
+        "compiles_in_window", "compiles_in_window.kanana",
+        "compiles_in_window.mellum", "compiles_in_window.jamba"],
+    "compiles_in_window.ttft": ["compiles_in_window.long"],
+    "queue_wait_p50_ms.ttft": ["queue_wait_p50_ms"],
+    "batch_occupancy.tpot": [
+        "batch_occupancy", "batch_occupancy.kanana", "batch_occupancy.mellum",
+        "batch_occupancy.jamba"],
+    "batch_occupancy.ttft": ["batch_occupancy.long"],
+    "prefill_chunks_per_req.ttft": ["prefill_chunks_per_req"],
+    "pool_pages_used_peak.tpot": [
+        "pool_pages_used_peak", "pool_pages_used_peak.kanana",
+        "pool_pages_used_peak.mellum", "pool_pages_used_peak.jamba"],
+    "pool_pages_used_peak.ttft": ["pool_pages_used_peak.long"],
+    "pool_live_share.tpot": [
+        "pool_live_share", "pool_live_share.kanana", "pool_live_share.mellum",
+        "pool_live_share.jamba"],
+    "pool_live_share.ttft": ["pool_live_share.long"],
+    "prefill_chunk_dev_ms.ttft": ["prefill_chunk_dev_ms"],
+    "decode_step_dev_ms.tpot": [
+        "decode_step_dev_ms", "decode_step_dev_ms.kanana",
+        "decode_step_dev_ms.mellum", "decode_step_dev_ms.jamba"],
+    "decode_step_dev_ms.ttft": ["decode_step_dev_ms.long"],
+    "serving_device_idle.tpot": [
+        "serving_device_idle", "serving_device_idle.kanana",
+        "serving_device_idle.mellum", "serving_device_idle.jamba"],
+    "serving_device_idle.ttft": ["serving_device_idle.long"],
+    "ttft_p90_ms.ttft": ["ttft_p90_ms"],
+    "ttft_p50_ms.tpot": [
+        "ttft_p50_ms.chat", "ttft_p50_ms.sat", "ttft_p50_ms.kanana",
+        "ttft_p50_ms.jamba"],
+    "ttft_p90_ms.tpot": ["ttft_p90_ms.chat"],
+    "gen_late_p99_ms.tpot": [
+        "gen_late_p99_ms.chat", "gen_late_p99_ms.sat",
+        "gen_late_p99_ms.kanana", "gen_late_p99_ms.mellum",
+        "gen_late_p99_ms.jamba"],
+    "queue_wait_p50_ms.tpot": [
+        "queue_wait_p50_ms.chat", "queue_wait_p50_ms.sat"],
+    "prefill_chunks_per_req.tpot": [
+        "prefill_chunks_per_req.chat", "prefill_chunks_per_req.sat"],
+    "prefill_chunk_dev_ms.tpot": [
+        "prefill_chunk_dev_ms.chat", "prefill_chunk_dev_ms.sat",
+        "prefill_chunk_dev_ms.kanana", "prefill_chunk_dev_ms.jamba"],
+    "tpot_p50_ms.ttft": ["tpot_p50_ms.long"],
+    "sched_self_ms_per_pass.tpot": [
+        "sched_self_ms_per_pass", "sched_self_ms_per_pass.kanana",
+        "sched_self_ms_per_pass.mellum", "sched_self_ms_per_pass.jamba"],
+    "sched_self_ms_per_pass.ttft": ["sched_self_ms_per_pass.long"],
+    "passes_with_chunk_share.tpot": [
+        "passes_with_chunk_share", "passes_with_chunk_share.kanana",
+        "passes_with_chunk_share.mellum", "passes_with_chunk_share.jamba"],
+    "passes_with_chunk_share.ttft": ["passes_with_chunk_share.long"],
+    "step_host_ms.tpot": [
+        "step_host_ms", "step_host_ms.kanana", "step_host_ms.mellum",
+        "step_host_ms.jamba"],
+    "step_host_ms.ttft": ["step_host_ms.long"],
+    "chunk_host_ms.tpot": [
+        "chunk_host_ms", "chunk_host_ms.kanana", "chunk_host_ms.jamba"],
+    "chunk_host_ms.ttft": ["chunk_host_ms.long"],
+    "step_pull_wait_ms.tpot": [
+        "step_pull_wait_ms", "step_pull_wait_ms.kanana",
+        "step_pull_wait_ms.mellum", "step_pull_wait_ms.jamba"],
+    "step_pull_wait_ms.ttft": ["step_pull_wait_ms.long"],
+    "prefill_lane_wait_p50_ms.ttft": ["prefill_lane_wait_p50_ms"],
+    "prefill_lane_wait_p50_ms.tpot": [
+        "prefill_lane_wait_p50_ms.chat", "prefill_lane_wait_p50_ms.sat",
+        "prefill_lane_wait_p50_ms.kanana", "prefill_lane_wait_p50_ms.jamba"],
+    "host_serial_share.tpot": [
+        "host_serial_share", "host_serial_share.kanana",
+        "host_serial_share.mellum", "host_serial_share.jamba"],
+    "host_serial_share.ttft": ["host_serial_share.long"],
+    "out_tokens_per_s": [
+        "out_tokens_per_s.kanana", "out_tokens_per_s.mellum",
+        "out_tokens_per_s.jamba"],
+    "prefill_fill_share.ttft": ["prefill_fill_share"],
+    "prefill_fill_share.tpot": [
+        "prefill_fill_share.sat", "prefill_fill_share.chat",
+        "prefill_fill_share.kanana", "prefill_fill_share.jamba"],
+    "attn_pages_read_share": [
+        "attn_pages_read_share.mellum", "attn_pages_read_share.jamba"],
+    "moe_experts_touched_share": ["moe_experts_touched_share.mellum"],
+    "moe_max_load_over_mean": ["moe_max_load_over_mean.mellum"],
+    "ramp_s": ["ramp_s.jamba"],
+}
+OLD_TO_NEW = {old: new for new, olds in RENAMED.items() for old in olds}
+STARTUP = {"setup_engine_build_s", "setup_trace_lower_s", "setup_cache_load_s",
+           "setup_fresh_compile_s", "setup_fresh_compiles"}
+# what every serving cell has by construction of the scheduler, the engine,
+# the pool and the load generator: these entries list no cells
+COMMON = {"batch_occupancy", "decode_step_dev_ms", "serving_device_idle",
+          "step_host_ms", "step_pull_wait_ms", "host_serial_share",
+          "sched_self_ms_per_pass", "passes_with_chunk_share",
+          "compiles_in_window", "pool_live_share", "pool_pages_used_peak",
+          "gen_late_p99_ms"}
+
+
+def _cells_reading(bench, metric_name):
+    return [c["name"] for c in bench["workloads"] if metric_name in {
+        m["name"] for m in harness.metrics_of(bench, "per_layer", c["name"])}]
+
+
+def test_an_entry_without_cells_is_read_where_what_it_moves_is_reported():
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    cells = [c["name"] for c in BENCH["workloads"]]
+    listless = [m for m in BENCH["per_layer"] if "workloads" not in m]
+    assert {m["name"].split(".")[0] for m in listless} >= COMMON
+    for m in listless:
+        assert _cells_reading(BENCH, m["name"]) == e2e[m["moves"]].get(
+            "workloads", cells)
+    # the token gap's are not read where the first token is judged
+    assert "opt1b3_longprompt" not in _cells_reading(
+        BENCH, "batch_occupancy.tpot")
+    assert _cells_reading(BENCH, "batch_occupancy.ttft") == [
+        "opt1b3_longprompt"]
+    assert _cells_reading(BENCH, "setup_compile_s") == cells
+    # an end-to-end metric without a list is every cell's, as before
+    assert all("setup_s" in {m["name"] for m in harness.metrics_of(
+        BENCH, "end_to_end", c)} for c in cells)
+
+
+@pytest.mark.parametrize("cell", sorted(BEFORE))
+def test_every_cell_reads_every_quantity_it_read_before(cell):
+    now = {m["name"] for m in harness.metrics_of(BENCH, "per_layer", cell)}
+    kept = {OLD_TO_NEW.get(old, old) for old in BEFORE[cell]}
+    assert len(kept) == len(BEFORE[cell])  # no two of a cell's became one
+    assert kept <= now
+    # and what came with PR 38: the program's account of its start-up
+    assert now - kept >= STARTUP
+    for old in BEFORE[cell]:  # the same reader file serves the new name
+        assert harness.reader_file(OLD_TO_NEW.get(old, old)) == \
+            harness.reader_file(old)
+
+
+def test_a_new_cell_in_the_moved_metrics_list_inherits_the_common_metrics():
+    bench = json.loads(json.dumps(BENCH))
+    bench["workloads"].append({**bench["workloads"][-1], "name": "made_up",
+                               "traffic": "made_up_mix"})
+    {m["name"]: m for m in bench["end_to_end"]}["tpot_p50_ms"][
+        "workloads"].append("made_up")
+    mine = {m["name"] for m in harness.metrics_of(bench, "per_layer",
+                                                  "made_up")}
+    assert mine == {q + ".tpot" for q in COMMON} | STARTUP | {
+        "setup_compile_s"}
+    assert len(COMMON) == 12
+    for name in mine:
+        assert callable(harness.reader_for(name))
+    # no other cell's list changed, and the first-token copies stay away
+    for c in BENCH["workloads"]:
+        assert harness.metrics_of(bench, "per_layer", c["name"]) == \
+            harness.metrics_of(BENCH, "per_layer", c["name"])
 
 
 def test_every_per_layer_metric_has_a_reader_and_every_reader_a_metric():
