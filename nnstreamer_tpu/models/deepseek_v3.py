@@ -206,6 +206,7 @@ class DeepseekV3Family:
     counters = moe_dropless.COUNTERS
     state_lines = ()       # no layer keeps a state a sequence
     serves_verify = False  # speculative verification: not in this family yet
+    chunk_precision = "highest"    # f32 queries over a bf16 pool
 
     def __init__(self, cfg: DeepseekV3Config):
         self.cfg = cfg
@@ -260,21 +261,12 @@ class DeepseekV3Family:
         return (jnp.concatenate([q_lat, q_rope, q_pad], axis=-1),
                 (jnp.concatenate([c, kr, line_pad], axis=-1),))
 
-    def attend(self, blk, q, ctxs, visible, mode):
-        """One slot's chunk: ``q (1, C, H, line)`` over ``ctxs[0] (1, ctx,
-        line)``, ``visible (C, ctx)``: scores on the whole line (latent and
-        rotary parts in one contraction), the weighted sum of whole lines,
-        then :meth:`_project_out`."""
-        import jax
-        import jax.numpy as jnp
+    @property
+    def chunk_heads(self) -> tuple:
+        # every head reads the whole line, as keys and as values
+        return (1, self.cfg.num_attention_heads)
 
-        (ctx,) = ctxs
-        exact = jax.lax.Precision.HIGHEST    # f32 queries over a bf16 pool
-        att = (jnp.einsum("bqhl,bcl->bqhc", q, ctx, precision=exact)
-               * self.attention_scale)
-        att = jnp.where(visible[None, :, None, :], att, -1e30)
-        att = jax.nn.softmax(att, axis=-1)
-        o = jnp.einsum("bqhc,bcl->bqhl", att, ctx, precision=exact)
+    def chunk_output(self, blk, o):
         return self._project_out(blk, o)
 
     @property

@@ -52,11 +52,23 @@ A family says:
 * ``project(blk, x, pos, kind)`` — from the input ``x (B, Q, D)`` of a
   layer of ``kind``: the query side and the lines to write, one per pool,
   each ``(B, Q, width)``.
-* ``attend(blk, q, ctxs, visible, mode)`` — the queries over the gathered
-  lines ``ctxs`` (one ``(B, ctx, width)`` per pool), output projection
-  applied: what the residual adds. ``mode`` is the program: ``"chunk"``
-  (one slot's chunk, ``visible (C, ctx)``) or ``"verify"``
-  (``visible (S, K, ctx)``).
+* ``chunk_heads``, ``chunk_precision``, ``chunk_output(blk, o)`` — a
+  prefill launch's context is
+  one slot's, so its lines are split by key head there (and only there):
+  ``chunk_heads`` is ``(KV, G)``, how many heads' keys lie side by side in
+  a line (``1`` where every head reads the whole line) and how many query
+  heads read each; ``chunk_precision`` names the ``jax.lax.Precision`` of
+  the launch's two attention products (``"highest"``: float32 over the
+  bfloat16 pool, nothing lowered; ``None``: jax's default, what the
+  ``gpt`` family's launch has always had and its configurations state).
+  The engine hands
+  ``ops.paged_attention.chunk_line_attention`` the launch's queries grouped
+  so, it walks the blocks the slot holds, and the family finishes the
+  ``(1, C, KV * G, line width / KV)`` float32 result (output projection
+  applied: what the residual adds).
+* ``attend_verify(blk, q, ctxs, visible)`` — where ``serves_verify``: ``K``
+  queries a slot over the gathered lines ``ctxs`` (one ``(S, ctx, width)``
+  per pool), ``visible (S, K, ctx)``, output projection applied.
 * ``step_queries(q)``, ``attention_scale``, ``step_output(blk, o)`` — the
   decode step gathers nothing: every family's step is ``H`` queries a slot
   over one shared line a token, so the engine hands
@@ -78,11 +90,13 @@ class GroupedQueryLines:
     """What the families whose attention layers keep keys and values of
     ``num_key_value_heads`` heads side by side share (``mellum``,
     ``jamba``): two lines of ``kv_heads * head_dim`` a token, the step's
-    block-diagonal query over whole lines, and a chunk's attention with its
-    lines split by key head. ``self.cfg`` has ``num_attention_heads``,
+    block-diagonal query over whole lines, and a launch's lines split by
+    key head. ``self.cfg`` has ``num_attention_heads``,
     ``num_key_value_heads``, ``head_dim`` and ``line_width``; the family's
     ``project`` makes the queries ``(B, Q, H, head_dim)`` (and rotates
     them, if it has positions)."""
+
+    chunk_precision = "highest"    # f32 queries over a bf16 pool
 
     @property
     def cache_lines(self) -> tuple:
@@ -123,27 +137,14 @@ class GroupedQueryLines:
             cfg.head_dim).sum(axis=2)
         return o.reshape(S, 1, -1) @ blk["wo"]
 
-    def attend(self, blk, q, ctxs, visible, mode):
-        """One slot's chunk: ``q (1, C, H, head_dim)`` over the gathered
-        lines ``ctxs`` ``(1, ctx, kv_heads * head_dim)`` each, ``visible
-        (C, ctx)``. The context is one slot's, so its lines are split by
-        key head here (and only here)."""
-        import jax
-        import jax.numpy as jnp
-
+    @property
+    def chunk_heads(self) -> tuple:
         cfg = self.cfg
-        KV, Dh = cfg.num_key_value_heads, cfg.head_dim
-        ck, cv = ctxs
-        C, ctx = q.shape[1], ck.shape[1]
-        exact = jax.lax.Precision.HIGHEST    # f32 queries over a bf16 pool
-        qg = q[0].reshape(C, KV, -1, Dh)
-        att = jnp.einsum("qkgd,ckd->kgqc", qg, ck[0].reshape(ctx, KV, Dh),
-                         precision=exact) * self.attention_scale
-        att = jax.nn.softmax(jnp.where(visible[None, None], att, -1e30),
-                             axis=-1)
-        o = jnp.einsum("kgqc,ckd->qkgd", att, cv[0].reshape(ctx, KV, Dh),
-                       precision=exact)
-        return o.reshape(1, C, -1) @ blk["wo"]
+        return (cfg.num_key_value_heads,
+                cfg.num_attention_heads // cfg.num_key_value_heads)
+
+    def chunk_output(self, blk, o):
+        return o.reshape(*o.shape[:2], -1) @ blk["wo"]
 
 
 class GPTFamily:
@@ -157,6 +158,7 @@ class GPTFamily:
     counters = ()          # nothing an expert layer would count
     state_lines = ()       # no layer keeps a state a sequence
     serves_verify = True   # speculative verification (``_verify``)
+    chunk_precision = None  # a launch's products at jax's default, as ever
 
     def __init__(self, cfg: TransformerConfig):
         self.cfg = cfg
@@ -195,12 +197,16 @@ class GPTFamily:
         q, k, v = jnp.split(_rmsnorm(x, blk["ln1"]) @ blk["wqkv"], 3, axis=-1)
         return q, (k, v)
 
-    def attend(self, blk, q, ctxs, visible, mode):
-        return getattr(self, f"_attend_{mode}")(q, *ctxs, visible) @ blk["wo"]
-
     @property
     def attention_scale(self) -> float:
         return self.cfg.head_dim ** -0.5
+
+    @property
+    def chunk_heads(self) -> tuple:
+        return (self.cfg.heads, 1)
+
+    def chunk_output(self, blk, o):
+        return o.reshape(*o.shape[:2], -1) @ blk["wo"]
 
     def _own(self):
         import jax.numpy as jnp
@@ -230,30 +236,12 @@ class GPTFamily:
         o = jnp.where(self._own()[None], o, 0.0).sum(axis=1)
         return o.reshape(S, 1, self.cfg.dim) @ blk["wo"]
 
-    def _attend_chunk(self, q, ck, cv, visible):
-        import jax
-        import jax.numpy as jnp
-
-        from .decoding import _split_heads
-
-        # the context is one slot's: splitting its lines by head (a
-        # re-tiled copy on a TPU) is cheap here and only here
-        cfg = self.cfg
-        H, Dh, ctx = cfg.heads, cfg.head_dim, ck.shape[1]
-        ck = ck.reshape(1, ctx, H, Dh)
-        cv = cv.reshape(1, ctx, H, Dh)
-        att = (jnp.einsum("shqd,schd->shqc", _split_heads(cfg, q), ck)
-               / jnp.sqrt(cfg.head_dim))
-        att = jnp.where(visible[None, None], att, -1e30)
-        att = jax.nn.softmax(att, axis=-1)
-        return jnp.einsum("shqc,schd->sqhd", att, cv).reshape(
-            1, q.shape[1], cfg.dim)
-
-    def _attend_verify(self, q, ck, cv, visible):
+    def attend_verify(self, blk, q, ctxs, visible):
         import jax
         import jax.numpy as jnp
 
         cfg = self.cfg
+        ck, cv = ctxs
         H, Dh = cfg.heads, cfg.head_dim
         S, K = q.shape[0], q.shape[1]
         ctx = ck.shape[1]
@@ -271,7 +259,7 @@ class GPTFamily:
         att = jnp.where(visible[..., None], att, -1e30)
         att = jax.nn.softmax(att, axis=2)
         o = (att[..., None] * cv[:, None]).sum(2)   # (S, K, H, Dh)
-        return o.reshape(S, K, cfg.dim)
+        return o.reshape(S, K, cfg.dim) @ blk["wo"]
 
     def ffn(self, blk, x, live):
         from .decoding import _ffn

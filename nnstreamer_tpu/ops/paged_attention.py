@@ -51,6 +51,14 @@ Two forms, chosen in one place (:func:`paged_line_attention`) by
   test_paged_attention.py``) and what runs where a TPU kernel would only be
   interpreted, so the CPU suites keep their token-exact parity with
   ``models.decoding.make_generate``.
+
+A prefill launch (``_prefill_chunk``) has the other shape: ``C`` rows of ONE
+slot. :func:`chunk_line_attention` is its op, one form everywhere: a loop
+over the blocks of pages that hold a position some row of the launch sees
+(:func:`chunk_walk`: the rule the host counts ``ctx_read`` by), each block's
+lines taken from the pool through the slot's rows and split by key head,
+online softmax in float32 across blocks. How many blocks is a value of the
+call, so one compiled launch serves every ``start``.
 """
 from __future__ import annotations
 
@@ -138,6 +146,132 @@ def pages_fetched(lengths, starts, page):
     """Pages one call of the kernel copies from a pool, over all slots
     (host arrays): every page that holds a visible position, once."""
     return int(visible_pages(lengths, starts, page)[1].sum())
+
+
+#: most bytes of one block's float32 scores in a launch's walk: a block is
+#: the largest power of two of pages whose scores, ``rows of queries x
+#: positions``, fit (256 positions for 32 heads x 256 rows). The walk reads
+#: whole blocks, so a larger one wastes more positions past a launch's last
+#: row and a smaller one pays the loop's fixed cost more often
+SCORE_BYTES = 8 * 1024 * 1024
+
+
+def chunk_block_pages(query_rows: int, page: int, blocks: int) -> int:
+    """Pages a block of a launch's walk: from the launch's query rows
+    (heads x rows) and the page's positions, by ``SCORE_BYTES``; at least
+    one page and at most the slot's ``blocks``."""
+    fit = max(1, SCORE_BYTES // (4 * query_rows * page))
+    return min(blocks, 1 << (fit.bit_length() - 1))
+
+
+def chunk_walk(start, n_valid, span, page, pages_per_block):
+    """The walk's rule, a launch of rows ``start .. start + n_valid - 1``
+    in a layer whose queries look back ``span`` positions (the window, or
+    the serving limit where a layer sees everything): the first page of the
+    slot's table that holds a position the launch's first row sees, and how
+    many blocks of ``pages_per_block`` pages from there on reach the last
+    row's. Python or numpy integers on the host, jax's on the device: the
+    loop's bounds are this function's output, and so is the engine's
+    ``ctx_read``."""
+    first = start - span + 1
+    first = first * (first > 0) // page
+    pages = -(-(start + n_valid) // page) - first
+    return first, -(-pages // pages_per_block)
+
+
+def chunk_line_attention(q, kpool, vpool, rows, start, n_valid, scale, span,
+                         *, precision=jax.lax.Precision.HIGHEST,
+                         pages_per_block=None):
+    """One slot's launch over the lines its slot holds (module docstring).
+
+    ``q (C, KV, G, Wk // KV)`` float32: row ``c`` is position ``start + c``,
+    ``G`` query heads read each of the ``KV`` key heads that lie side by
+    side in a line; ``rows (NB,)`` the pool row of every block of the slot;
+    ``start``, ``n_valid`` int32 scalars; ``span`` how far back a query
+    sees (static). Row ``c`` sees positions ``p`` with ``0 <= start + c - p
+    < span``; a padded row (``c >= n_valid``) sees what the last real row
+    sees, so no line past the launch's own is ever scored. Returns ``(C,
+    KV, G, Wv // KV)`` float32. ``precision`` is that of the two products
+    (queries by keys, the softmax's weights by values), a
+    ``jax.lax.Precision`` or its name: ``HIGHEST`` is float32 arithmetic over the bfloat16 pool with nothing lowered, as the
+    step's kernel has it; a family whose launch has always multiplied at
+    jax's default says so (``family.chunk_precision``).
+    ``pages_per_block`` is derived unless a test or a stand-alone timing
+    names it.
+
+    The walk is a jitted function of its own, so a program that calls it
+    once a layer traces and lowers it once a shape and not once a layer
+    (the launch's trace is paid at every start-up)."""
+    C, KV, G, _ = q.shape
+    PB = pages_per_block or chunk_block_pages(KV * G * C, kpool.shape[1],
+                                              rows.shape[0])
+    return _walk(q, kpool, None if vpool is kpool else vpool, rows, start,
+                 n_valid, scale=float(scale), span=int(span),
+                 precision=jax.lax.Precision(precision or "default"),
+                 pages_per_block=int(PB))
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "span", "precision",
+                                             "pages_per_block"))
+def _walk(q, kpool, vpool, rows, start, n_valid, *, scale, span, precision,
+          pages_per_block):
+    C, KV, G, _ = q.shape
+    NB, pg, PB = rows.shape[0], kpool.shape[1], pages_per_block
+    T = PB * pg
+    first, blocks = chunk_walk(start, n_valid, span, pg, PB)
+    # row c sees position p where 0 <= seen[c] - p < span; ``seen`` from
+    # the walk's first position on, and a block's positions from its own
+    seen = (jnp.minimum(jnp.arange(C), n_valid - 1) + start - first * pg)
+    at = jnp.arange(T)
+    # the walk's last block may run past the table: padded with its last
+    # row, whose positions there are past every row's
+    rows = jnp.concatenate([rows, jnp.broadcast_to(rows[-1:], (PB,))])
+    q = q * scale
+    # the program is loaded at every start-up by the count of its
+    # instructions (PERF.md section 6, PR 42), and this body is there once
+    # a layer: one slice of the table, one plain gather a pool (the table
+    # holds rows the pool handed out, so nothing is clamped or wrapped),
+    # one comparison a side of the mask
+    take = jax.lax.GatherDimensionNumbers(
+        offset_dims=(1, 2), collapsed_slice_dims=(0,), start_index_map=(0,))
+
+    def lines(pool, r):
+        got = jax.lax.gather(
+            pool, r[:, None], take, (1, *pool.shape[1:]),
+            mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+        return got.reshape(T, KV, -1)
+
+    def block(b, carry):
+        m, l, o = carry                       # (KV, C, G), ..., (.., Wv/KV)
+        r = jax.lax.dynamic_slice(rows, (first + b * PB,), (PB,))
+        k = lines(kpool, r)
+        v = k if vpool is None else lines(vpool, r)
+        # (C, KV, G, D) x (T, KV, D) -> (KV, C, G, T)
+        sc = jax.lax.dot_general(q, k, (((3,), (2,)), ((1,), (1,))),
+                                 precision=precision,
+                                 preferred_element_type=jnp.float32)
+        back = (seen - b * T)[:, None] - at[None, :]
+        visible = back >= 0
+        if span < NB * pg:    # a layer that looks back a window only
+            visible &= back < span
+        sc = jnp.where(visible[:, None, :], sc, _MASKED)
+        # a row that has seen nothing yet keeps m at _MASKED and weighs the
+        # block's lines by 1; the first score it sees wipes that (alpha 0),
+        # and every row sees its own position before the walk ends
+        m_new = jnp.maximum(m, sc.max(axis=-1))
+        p = jnp.exp(sc - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        o = alpha[..., None] * o + jax.lax.dot_general(
+            p, v, (((3,), (0,)), ((0,), (1,))), precision=precision,
+            preferred_element_type=jnp.float32)
+        return m_new, alpha * l + p.sum(axis=-1), o
+
+    Wv = (kpool if vpool is None else vpool).shape[2]
+    m, l, o = jax.lax.fori_loop(0, blocks, block, (
+        jnp.full((KV, C, G), _MASKED, jnp.float32),
+        jnp.zeros((KV, C, G), jnp.float32),
+        jnp.zeros((KV, C, G, Wv // KV), jnp.float32)))
+    return jnp.moveaxis(o / l[..., None], 0, 1)
 
 
 def _kernel(rows_ref, meta_ref, q_ref, *refs, S, NB, PB, sizes, H, scale,

@@ -113,7 +113,7 @@ class PagedLMEngine(DecodeEngine):
     * **model family** — what a layer is and what it keeps per token comes
       from ``models/families.py`` (``family_of(cfg)``, by the
       configuration's type): the embedding, per layer the query side and
-      the lines to write, attention over gathered lines, the feed-forward,
+      the lines to write, the finish of each attention form, the feed-forward,
       the head, and the pool's geometry (``cache_lines``: one width per
       pool). The GPT block keeps keys and values (two pools); the
       DeepSeek-V3 block keeps one latent line that every head reads.
@@ -167,13 +167,15 @@ class PagedLMEngine(DecodeEngine):
     * **serving limit** — ``max_seq``, the positions a slot may hold: the
       family's ``max_positions`` (the ``gpt`` family's position table, the
       rotary families' ``max_position_embeddings``) or ``max_positions=``
-      below it. A full layer's prefill chunk (and a verify round) attends
-      over that many padded positions, a window layer's over the window's
-      pages and the chunk's own; a decode step reads the pages each live
-      slot holds, in a window layer from the window's first page on, and
-      no more (``ops/paged_attention.py``; ``attn_pages`` and the
-      ``engine.step.prepare`` span count them, by kind, against the
-      padding).
+      below it. A verify round attends over that many padded positions.
+      A prefill chunk reads whole blocks of pages from the first page its
+      first row sees (position 0 in a full layer, the window's first in a
+      window layer) to its last row's, and a decode step the pages each
+      live slot holds, in a window layer from the window's first page on:
+      neither reads more (``ops/paged_attention.py``; ``chunk_ctx`` and
+      the ``engine.chunk.prepare`` span count the positions of the one,
+      ``attn_pages`` and the ``engine.step.prepare`` span the pages of the
+      other, by kind, against the padding).
     * **chunked prefill** — ``admit_start`` queues the prompt and
       ``prefill_tick`` ingests ONE fixed-size chunk per call, so a long
       prompt interleaves with running decode instead of stalling the
@@ -196,13 +198,14 @@ class PagedLMEngine(DecodeEngine):
       a request.
 
     Parity contract: a position a slot does not see has exact-zero softmax
-    weight (masked at -1e30 in the gathered forms, never read by the
-    step's kernel), so on the CPU, where the step's attention runs in its
-    plain form over ``max_seq`` gathered positions, the paged step is
-    token-exact against ``models.decoding.make_generate`` (asserted in
-    test_kv_paged.py).
-    On a TPU the kernel's online softmax sums in another order: agreement
-    to float32 rounding, tokens under ``chip_smoke.near_tie``.
+    weight (masked at -1e30 in the gathered forms and in a block of a
+    chunk's walk, never read by the step's kernel), so on the CPU, where
+    the step's attention runs in its plain form over ``max_seq`` gathered
+    positions, the paged engine is token-exact against
+    ``models.decoding.make_generate`` (asserted in test_kv_paged.py).
+    A chunk's walk and, on a TPU, the step's kernel sum their online
+    softmax in another order: agreement to float32 rounding, tokens on a
+    TPU under ``chip_smoke.near_tie``.
     """
 
     def __init__(self, cfg, params, slots: int = 4, page_size: int = 16,
@@ -218,6 +221,9 @@ class PagedLMEngine(DecodeEngine):
 
         from ..models.families import family_of
         from ..ops.paged_attention import (
+            chunk_block_pages,
+            chunk_line_attention,
+            chunk_walk,
             gathered_lines,
             paged_line_attention,
             pages_fetched,
@@ -338,7 +344,7 @@ class PagedLMEngine(DecodeEngine):
         NS = len(self._states)
         self.state_slot_bytes = int(sum(
             s.nbytes for s in self._states) // slots) if NS else 0
-        ctx = NB * page_size  # == max_seq: what a full layer's chunk gathers
+        ctx = NB * page_size  # == max_seq: what a verify round gathers
 
         # host mirrors. The block tables, ``_pos`` and ``_mask`` are
         # authoritative and ride into every step as numpy arguments
@@ -444,16 +450,6 @@ class PagedLMEngine(DecodeEngine):
                     counts = counts + c
             return x, pools, counts, states
 
-        def _gathered(mode, tables, visible):
-            # attention over a gathered copy of a block table (``tables``
-            # and ``visible`` by kind): the programs whose context is one
-            # slot's (a chunk) or that score several queries a slot (verify)
-            def attend(kind, row0, blk, q, pools):
-                ctxs = tuple(gathered_lines(pool, row0 + tables[kind])
-                             for pool in pools)
-                return fam.attend(blk, q, ctxs, visible[kind], mode)
-            return attend
-
         def _step(p, token, pos, mask, *rest):
             self.compile_count += 1  # trace-time only: one step program
             bts, pools = rest[:K], rest[K:K + K * P]
@@ -513,9 +509,13 @@ class PagedLMEngine(DecodeEngine):
                 1, *range(4 + K, 4 + K + K * P + NS))),
                 4 + K + K * P + NS, ((slots,), jnp.int32)), params)
 
-        # the blocks of a window layer that a chunk's queries can see
-        NW = self.held_blocks.get("window", 0)
-
+        # a launch's attention walks the blocks its slot holds
+        # (``chunk_line_attention``): how far back a layer of each kind
+        # sees, and the pages of one block of the walk
+        KV, G = fam.chunk_heads
+        span = self._span = {"full": max_seq, "window": window}
+        PB = self.chunk_block_pages = chunk_block_pages(KV * G * C, pg, NB)
+        self._chunk_walk = chunk_walk
         def _prefill_chunk(p, toks, start, n_valid, *rest):
             # toks (C,) padded; ingest positions start..start+n_valid-1 of
             # ONE slot. C is static — the only compiled prefill shape.
@@ -530,19 +530,17 @@ class PagedLMEngine(DecodeEngine):
             dests = tuple(jnp.where(valid, bt[lp // pg], 0) for bt in bts)
             offs = lp % pg
             x = fam.embed(p, toks, lp)[None]        # (1, C, D)
-            tables, visible = {}, {}
-            for kind, bt in zip(kinds, bts):
-                if kind == "full":  # the whole table, max_seq positions
-                    positions = jnp.arange(ctx)
-                    tables[kind] = bt[None]
-                    visible[kind] = positions[None, :] <= q_pos[:, None]
-                else:  # the window's pages and the chunk's own
-                    first = jnp.clip((start - window + 1) // pg, 0, NB - NW)
-                    positions = first * pg + jnp.arange(NW * pg)
-                    tables[kind] = jax.lax.dynamic_slice(
-                        bt, (first,), (NW,))[None]
-                    back = q_pos[:, None] - positions[None, :]
-                    visible[kind] = (back >= 0) & (back < window)
+
+            def attend(kind, row0, blk, q, pools):
+                # the launch's one attention form (ops/paged_attention.py):
+                # its rows' queries by key head over the blocks the slot
+                # holds, read from the pool's rows where they lie
+                o = chunk_line_attention(
+                    q[0].reshape(C, KV, G, -1), pools[0], pools[-1],
+                    row0 + bts[kinds.index(kind)], start, n_valid,
+                    fam.attention_scale, span[kind],
+                    precision=fam.chunk_precision, pages_per_block=PB)
+                return fam.chunk_output(blk, o.reshape(1, C, KV * G, -1))
 
             def mix(i, blk, x, states):
                 # one slot's launch through the i-th state layer: from
@@ -558,8 +556,7 @@ class PagedLMEngine(DecodeEngine):
 
             x, pools, counts, states = _layers(
                 p, x, lp[None], valid[None], dests, offs, pools,
-                lambda line: line[0], _gathered("chunk", tables, visible),
-                states, mix)
+                lambda line: line[0], attend, states, mix)
             with jax.named_scope("head"):
                 logits = fam.head(p, x[0])  # (C, V)
             if NC:
@@ -627,10 +624,16 @@ class PagedLMEngine(DecodeEngine):
             x = fam.embed(p, toks, lp)
             positions = jnp.arange(ctx)
             visible = (positions[None, None, :] <= q_pos[:, :, None])
+
+            def attend(kind, row0, blk, q, pools):
+                # K queries a slot over a gathered copy of its block table
+                ctxs = tuple(gathered_lines(pool, row0 + bt)
+                             for pool in pools)
+                return fam.attend_verify(blk, q, ctxs, visible)
+
             x, pools, _, _ = _layers(
                 p, x, lp, jnp.broadcast_to(mask[:, None], (S, K)), (dest,),
-                offs, pools, lambda line: line,
-                _gathered("verify", {"full": bt}, {"full": visible}))
+                offs, pools, lambda line: line, attend)
             logits = fam.head(p, x)  # (S, K, V)
             return (logits, *pools)
 
@@ -867,6 +870,22 @@ class PagedLMEngine(DecodeEngine):
                                "steps": steps}
         self._lane.pop(slot, None)
 
+    def chunk_ctx(self, start: int, n_valid: int) -> "tuple[int, int]":
+        """``(ctx_read, ctx_padded)`` of a launch of rows ``start .. start +
+        n_valid - 1``: the positions its attention layers read, every
+        layer's added up (whole blocks of the walk from the first page a
+        row sees, by ``ops.paged_attention.chunk_walk``), and what a
+        gathered copy of everything a slot may hold would have (the serving
+        limit a full layer, the held blocks a window layer)."""
+        pg, PB = self.page_size, self.chunk_block_pages
+        read = padded = 0
+        for kind, n in self.kind_layers.items():
+            blocks = self._chunk_walk(start, n_valid, self._span[kind], pg,
+                                      PB)[1]
+            read += n * blocks * PB * pg
+            padded += n * self.held_blocks[kind] * pg
+        return read, padded
+
     def prefill_stamp(self, slot: int) -> "tuple[float, int]":
         """``(first_chunk_t, chunks)`` of the prompt in ``slot``: when its
         first chunk was dispatched (``time.monotonic``; until then it
@@ -893,6 +912,10 @@ class PagedLMEngine(DecodeEngine):
             self._ensure_writable(slot, start, start + n_valid)
             padded = np.zeros((self.chunk,), np.int32)
             padded[:n_valid] = tokens[start:start + n_valid]
+            # how far the launch's attention follows what its slot holds
+            # (the walk's own rule, ``ops.paged_attention.chunk_walk``)
+            prepare.attrs["ctx_read"], prepare.attrs["ctx_padded"] = \
+                self.chunk_ctx(start, n_valid)
             state_args = ()
             if self._states:  # the launch that starts a sequence zeroes it
                 prepare.attrs["state_reset"] = int(start == 0)

@@ -204,8 +204,14 @@ def test_absorbed_attention_equals_expanded_on_one_layer():
     q, (line,) = fam.project(blk, x[None], pos)
     assert q.shape == (1, S, 4, 128) and line.shape == (1, S, 128)
     assert not np.asarray(line[..., 24:]).any(), "the stored line's padding"
-    visible = jnp.tril(jnp.ones((S, S), bool))
-    got = fam.attend(blk, q, (line,), visible, "chunk")[0]
+    # a launch's view: the walk over a pool of one page of S lines
+    from nnstreamer_tpu.ops.paged_attention import chunk_line_attention
+
+    o = chunk_line_attention(
+        q[0].reshape(S, *fam.chunk_heads, -1), line, line,
+        jnp.zeros((1,), jnp.int32), jnp.int32(0), jnp.int32(S),
+        fam.attention_scale, S)
+    got = fam.chunk_output(blk, o.reshape(1, S, 4, -1))[0]
     with jax.default_matmul_precision("highest"):
         want = ref.attention(x, blk, sz)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),

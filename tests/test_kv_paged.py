@@ -27,6 +27,7 @@ The properties the paged data plane exists for, each asserted directly:
   ``engine.release`` and page refcounts reach zero (the NNS_LEAKCHECK
   ledger asserts the same pairing at the acquire/release sites).
 """
+import functools
 import math
 import re
 
@@ -772,6 +773,86 @@ class TestPoolInPlaceOnTpu:
         if program == "_step":
             assert compiled.as_text().count("paged_line_attention") >= 4
             _no_gathered_context(compiled, S * max_seq * 512)
+
+
+    def test_the_cells_launch_walks_and_gathers_no_padded_context(
+            self, v5e_chip):
+        """``_prefill_chunk`` of the ``opt_1.3b`` cell's engine, at the
+        cell's widths, limit and pool and the width a v5e derives, two
+        layers deep (24 compile for a quarter of a minute on every core,
+        under the other workers' tests) (PR 42): the launch's
+        attention is a loop a layer over blocks of 256 positions, nothing
+        of a gathered context's shape is made anywhere in the program (the
+        loops' bodies included), and nothing of a pool's size is copied."""
+        import json
+        import os
+
+        import jax
+        import jax.numpy as jnp
+
+        from nnstreamer_tpu.models.transformer import (
+            TransformerConfig,
+            init_params,
+        )
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "benchmark", "configs",
+                               "opt_1.3b.json")) as fh:
+            config = json.load(fh)
+        cfg = TransformerConfig(
+            vocab=config["vocab_size"], dim=config["hidden_size"],
+            heads=config["num_attention_heads"],
+            layers=2,
+            mlp_mult=config["ffn_dim"] // config["hidden_size"],
+            max_seq=config["max_position_embeddings"])
+        geo = config["engine"]
+        C, pg, ctx = 256, geo["page_size"], cfg.max_seq
+        # the programs close over the sizes only: a two-page pool to build
+        eng = PagedLMEngine(cfg, {"embed": jnp.zeros((1, 1), jnp.bfloat16)},
+                            slots=1, page_size=pg, pages=2, chunk=C)
+        assert eng.chunk_block_pages * pg == 256
+
+        def shape(s, dt):
+            return jax.ShapeDtypeStruct(s, dt, sharding=v5e_chip)
+
+        params = jax.tree_util.tree_map(
+            lambda a: shape(a.shape, jnp.bfloat16),
+            jax.eval_shape(functools.partial(init_params, cfg)))
+        pool = shape((cfg.layers * (geo["pages"] + 1), pg, cfg.dim),
+                     jnp.bfloat16)
+        hlo = eng._prefill_chunk.func.lower(
+            params, shape((C,), jnp.int32), shape((), jnp.int32),
+            shape((), jnp.int32), shape((ctx // pg,), jnp.int32),
+            pool, pool).compile().as_text()
+        assert len(re.findall(r" while\(", hlo)) == cfg.layers
+        # the family's launch multiplies at jax's default, as its
+        # configuration states and as its gathered form did
+        assert eng.family.chunk_precision is None and "highest" not in hlo
+        H, Dh = cfg.heads, cfg.head_dim
+        # the limit's lines split by head, a table's worth of gathered
+        # lines, a launch's scores over the limit (the hidden size is the
+        # limit here: (rows, 2048) and (2048, 2048) are activations and
+        # weights, and say nothing)
+        gathered = {f"[{ctx},{H},{Dh}]", f"[{H},{ctx},{Dh}]",
+                    f"[{H},{Dh},{ctx}]", f"[1,{ctx},{cfg.dim}]",
+                    f"[{ctx // pg},{pg},{cfg.dim}]", f"[{H},{C},{ctx}]",
+                    f"[1,{H},{C},{ctx}]", f"[{H},1,{C},{ctx}]"}
+        pool_dims = f"[{pool.shape[0]},{pg},{cfg.dim}]"
+        made, copied = [], []
+        for line in hlo.splitlines():
+            m = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(",
+                         line)
+            if not m or m.group(3) in ("parameter", "get-tuple-element",
+                                       "tuple", "while", "bitcast"):
+                continue
+            name, result, op = m.groups()
+            shapes = set(re.findall(r"\w+(\[[\d,]+\])", result))
+            if shapes & gathered:
+                made.append(f"{op} {name} {sorted(shapes & gathered)}")
+            if pool_dims in shapes and (op == "copy" or "copy" in name):
+                copied.append(f"{op} {name}")
+        assert not made, f"the launch still makes a padded context: {made}"
+        assert not copied, f"the launch copies a pool: {copied}"
 
 
 class TestExpertsStreamOnTpu:

@@ -10,14 +10,20 @@ curve. The rule is switched off here (the chip lookup answers "unknown", so
 
 At the rule's width each configuration is timed once more with the serving
 limit cut to that width (``max_positions=``): a launch whose attention
-covers the chunk's own positions and no padding. The difference is what
-gathering, masking and scoring ``max_seq`` padded positions costs a wide
-launch: ``padded_attention_share``, the number a chunk kernel over the
-pages a slot holds (ROADMAP S3) starts from.
+covers the chunk's own positions and nothing else. Until PR 42 the
+difference was what gathering, masking and scoring ``max_seq`` padded
+positions cost a wide launch (``padded_attention_share``: the estimate the
+walk over a slot's blocks, ``ops.paged_attention.chunk_line_attention``,
+started from). Since the launch walks the blocks its slot holds, the rule's
+width is also timed at ``start`` 0, mid-prompt and at the serving limit's
+end, each beside ``ctx_read`` (the positions its layers read, the engine's
+own count) and ``over_cut_limit_ms``: what the walk still costs over no
+context but the launch's own.
 
-Prints one JSON line per (configuration, width, context).
+Prints one JSON line per (configuration, width, context, start).
 
     chiprun -- python tools/prefill_width_forms.py [configuration ...]
+        [widths=256,512]
 
 ``--rehearse`` runs the same path at the files' ``rehearsal`` sizes (the
 CPU: what it prints there is no device time).
@@ -45,9 +51,10 @@ CONFIGS = ("opt_1.3b", "kanana2_30b_a3b_l8")
 SEED, REPS = 30, 10
 
 
-def launch_ms(config: dict, width: int, positions=None) -> dict:
-    """Mean device-bound ms of one launch: ``REPS`` back to back, the pools
-    handed from one to the next as the engine does, one wait at the end."""
+def launch_ms(config: dict, width: int, positions=None, start=512) -> dict:
+    """Mean device-bound ms of one launch at ``start`` (or as near below it
+    as the context allows): ``REPS`` back to back, the pools handed from
+    one to the next as the engine does, one wait at the end."""
     config = copy.deepcopy(config)
     config["engine"]["chunk"] = width
     if positions is not None:
@@ -59,7 +66,7 @@ def launch_ms(config: dict, width: int, positions=None) -> dict:
         NB = engine.blocks_per_slot
         # a chunk in the middle of a prompt where the context allows one:
         # every row valid, the slot's table full of distinct pages
-        start = min(512, engine.max_seq - width)
+        start = min(start, engine.max_seq - width)
         args = (jnp.arange(width, dtype=jnp.int32) % engine.family.vocab,
                 jnp.asarray(start, jnp.int32), jnp.asarray(width, jnp.int32),
                 jnp.asarray(1 + np.arange(NB, dtype=np.int32)))
@@ -80,14 +87,18 @@ def launch_ms(config: dict, width: int, positions=None) -> dict:
         ms = 1e3 * (time.perf_counter() - t0) / REPS
     finally:
         sched.close()
-    return {"width": width, "context": engine.max_seq,
+    return {"width": width, "context": engine.max_seq, "start": start,
+            "ctx_read": engine.chunk_ctx(start, width)[0],
             "ms_per_launch": round(ms, 3),
             "ms_per_token": round(ms / width, 4),
             "first_call_s": round(compile_s, 1)}
 
 
 def main():
-    names = [a for a in sys.argv[1:] if not a.startswith("--")] or CONFIGS
+    names = [a for a in sys.argv[1:]
+             if not a.startswith("--") and "=" not in a] or CONFIGS
+    widths = next((tuple(map(int, a[7:].split(","))) for a in sys.argv[1:]
+                   if a.startswith("widths=")), WIDTHS)
     rehearse = "--rehearse" in sys.argv  # the files' CPU sizes: no timing
     device = jax.devices()[0]
     configs = {}
@@ -108,7 +119,7 @@ def main():
                 "rule_width": rule}
         served = {}
         for width in dict.fromkeys(
-                w for w in (*WIDTHS, rule) if w <= limit):
+                w for w in (*widths, rule) if w <= limit):
             try:
                 served[width] = launch_ms(config, width)
             except Exception as e:  # a width that does not compile or fit
@@ -119,11 +130,19 @@ def main():
         if "error" in served[rule]:
             continue
         bare = launch_ms(config, rule, positions=rule)
-        share = 1.0 - bare["ms_per_launch"] / served[rule]["ms_per_launch"]
-        print(json.dumps({**head, **bare,
-                          "padded_attention_share": round(share, 4)}),
-              flush=True)
+        print(json.dumps({**head, **bare}), flush=True)
         gc.collect()
+        # the walk: the first launch of a prompt, one mid-prompt (timed
+        # above) and the one that ends at the serving limit
+        for start in dict.fromkeys(min(s, limit - rule)
+                                   for s in (0, 512, limit - rule)):
+            walk = (served[rule] if start == served[rule]["start"]
+                    else launch_ms(config, rule, start=start))
+            over = walk["ms_per_launch"] - bare["ms_per_launch"]
+            print(json.dumps({**head, **walk,
+                              "over_cut_limit_ms": round(over, 3)}),
+                  flush=True)
+            gc.collect()
 
 
 if __name__ == "__main__":
