@@ -203,6 +203,7 @@ class DeepseekV3Family:
     name = "deepseek_v3"
     attention_scopes = {"full": "mla"}
     window = None          # every layer sees the whole context
+    passes = 1             # the stack once a token
     counters = moe_dropless.COUNTERS
     state_lines = ()       # no layer keeps a state a sequence
     serves_verify = False  # speculative verification: not in this family yet

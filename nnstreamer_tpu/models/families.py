@@ -16,7 +16,33 @@ A family says:
   gives a window layer's pages back behind the window. ``gpt`` and
   ``deepseek_v3`` say "all full"; ``mellum`` (``models/mellum.py``) has
   full and window layers; ``jamba`` (``models/jamba.py``) full and state
-  layers.
+  layers; ``ouro`` (``models/ouro.py``) says "all full".
+* ``passes`` — how many times a token runs the stack, with the same
+  weights every time. ``gpt``, ``deepseek_v3``, ``mellum`` and ``jamba``
+  say 1: the stack once, then ``head``; nothing closes their one pass and
+  their programs hold no loop. ``ouro`` says its ``total_ut_steps``. Where
+  ``passes > 1`` the engine keeps a cache line for every pass of every
+  layer (pass ``t`` of the ``i``-th layer of a kind owns the rows of
+  pass-layer ``t * layers of the kind + i`` and reads no other pass's),
+  runs the stack inside ONE loop over ``t`` in each program, and asks the
+  family what closes a pass:
+
+  - ``open_passes(x) -> carry`` — before the first pass, for activations
+    ``x (B, Q, D)``: whatever the family carries from pass to pass (``ouro``:
+    the rows chosen so far, the exit rule's running sum ``c_t``, the
+    running product of ``1 - lambda`` and who has left). The engine
+    threads it through the loop and never looks into it;
+  - ``close_pass(p, x, carry, t, live) -> (x', carry', counts)`` — after
+    the last layer of pass ``t`` (0-based, a traced scalar): ``x'`` is
+    what the next pass starts from (``ouro``: the final norm of ``x``),
+    ``counts`` the int32 vector ``counters`` names, counted over the rows
+    in ``live`` (``ouro``: how many left at this pass), added to what
+    ``ffn`` counted. Under the ``jax.named_scope`` ``loop.exit``;
+  - ``exit_rows(carry) -> (B, Q, D)`` — once the passes are done: the rows
+    ``head`` reads (``ouro``: each row's hidden state at the pass where
+    its ``c_t`` first reached ``early_exit_threshold``, the last pass's if
+    none; the final norm is in them already, so its ``head`` is the
+    output matrix alone).
 * ``state_lines`` — what a *slot* keeps in every state layer, whatever the
   sequence's length: one ``(shape, dtype)`` per array, a dtype of ``None``
   the cache's (``jamba``: the conv's last inputs ``(3 * 5120,)``, flat, and
@@ -97,6 +123,7 @@ class GroupedQueryLines:
     them, if it has positions)."""
 
     chunk_precision = "highest"    # f32 queries over a bf16 pool
+    passes = 1                     # the stack once a token
 
     @property
     def cache_lines(self) -> tuple:
@@ -155,6 +182,7 @@ class GPTFamily:
     name = "gpt"
     attention_scopes = {"full": "attention"}
     window = None          # every layer sees the whole context
+    passes = 1             # the stack once a token
     counters = ()          # nothing an expert layer would count
     state_lines = ()       # no layer keeps a state a sequence
     serves_verify = True   # speculative verification (``_verify``)
@@ -270,23 +298,28 @@ class GPTFamily:
         return _rmsnorm(x, p["out_norm"]) @ p["embed"].T
 
 
+def _families() -> tuple:
+    """``(configuration type, family)`` for every family there is: the one
+    table :func:`family_of` dispatches on."""
+    from .deepseek_v3 import DeepseekV3Config, DeepseekV3Family
+    from .jamba import JambaConfig, JambaFamily
+    from .mellum import MellumConfig, MellumFamily
+    from .ouro import OuroConfig, OuroFamily
+
+    return ((TransformerConfig, GPTFamily),
+            (DeepseekV3Config, DeepseekV3Family),
+            (MellumConfig, MellumFamily),
+            (JambaConfig, JambaFamily),
+            (OuroConfig, OuroFamily))
+
+
 def family_of(cfg):
     """The family of a configuration, by its type."""
-    if isinstance(cfg, TransformerConfig):
-        return GPTFamily(cfg)
-    from .deepseek_v3 import DeepseekV3Config, DeepseekV3Family
-
-    if isinstance(cfg, DeepseekV3Config):
-        return DeepseekV3Family(cfg)
-    from .mellum import MellumConfig, MellumFamily
-
-    if isinstance(cfg, MellumConfig):
-        return MellumFamily(cfg)
-    from .jamba import JambaConfig, JambaFamily
-
-    if isinstance(cfg, JambaConfig):
-        return JambaFamily(cfg)
+    table = _families()
+    for config_type, family in table:
+        if isinstance(cfg, config_type):
+            return family(cfg)
     raise TypeError(
         f"no model family serves a configuration of type "
-        f"{type(cfg).__name__} (have TransformerConfig, DeepseekV3Config, "
-        f"MellumConfig, JambaConfig)")
+        f"{type(cfg).__name__} (have "
+        f"{', '.join(t.__name__ for t, _ in table)})")

@@ -151,6 +151,23 @@ class PagedLMEngine(DecodeEngine):
       ``projected_page_bytes`` charges no request for it. Prefix sharing
       is refused for such a family: a hit would need the state as it was
       at the prefix's last token.
+    * **passes** — a family may run its stack several times a token with
+      the same weights (``family.passes``; ``ouro``): it keeps a line for
+      every pass of every layer, so wherever this class counts the layers
+      of a kind (``kind_layers``, the pools' rows, ``token_bytes``,
+      ``page_bytes``, ``projected_page_bytes``, ``memory_bytes``, the page
+      movers, preempt blobs, copy-on-write) it counts ``passes x layers``
+      pass-layers, and pass ``t`` of the ``i``-th layer of a kind owns the
+      rows of pass-layer ``t * layers of the kind + i`` and reads no other
+      pass's. The passes are ONE ``fori_loop`` in ``_step`` and in
+      ``_prefill_chunk`` whose body is the stack and whose carry holds the
+      pools (aliased in and out: nothing of a pool's size is copied), the
+      activations, the counts and what the family carries between passes
+      (``open_passes`` / ``close_pass`` / ``exit_rows``: the engine never
+      looks into it). A family with one pass takes no loop and has the
+      programs it had before passes existed. Prefix sharing serves such a
+      family as any other (a page carries every pass-layer's lines);
+      speculative verification does not.
     * **pool layout** — per kind of layer and kind of line ``(layers of
       the kind * (pages+1), page, width)`` device arrays: one row per page
       of one layer, one contiguous ``width`` line per token, so row-major
@@ -258,6 +275,11 @@ class PagedLMEngine(DecodeEngine):
                 f"family yet (a hit would need its state layers' state as "
                 f"it was at the prefix's last token, and no snapshot of it "
                 f"is kept); build it with share_prefixes=False")
+        if fam.passes > 1 and fam.state_lines:
+            raise NotImplementedError(
+                f"lm_engine: the {fam.name} family runs its stack "
+                f"{fam.passes} times a token and has state layers; a state "
+                f"a pass of a layer is not kept")
         self.cfg = cfg
         self.family = fam
         self.kinds = kinds
@@ -315,7 +337,15 @@ class PagedLMEngine(DecodeEngine):
             layers_of[kind] += 1
         # a state layer keeps no pages: its count stands beside the kinds'
         self.state_layers = layers_of.pop("state")
+        # a family that runs its stack ``passes`` times a token keeps a
+        # line for every pass of every layer: from here on a kind's count
+        # is of pass-layers, and pass t of the i-th layer of a kind is
+        # pass-layer ``t * stack_of[kind] + i`` (one pass: the layers)
+        T = self.passes = fam.passes
+        stack_of = dict(layers_of)
+        layers_of = {kind: T * n for kind, n in layers_of.items()}
         self.kind_layers = layers_of
+        self.pass_layers = sum(layers_of.values())
         # rows of one layer: its null page 0, then the kind's pages
         R = {kind: pages[kind] + 1 for kind in kinds}
         self.token_bytes = (sum(layers_of.values())
@@ -416,8 +446,8 @@ class PagedLMEngine(DecodeEngine):
             # one whole line per token
             return pool.at[row0 + dest, offs].set(rows.astype(pool.dtype))
 
-        def _layers(p, x, pos, live, dests, offs, pools, unbatch, attend,
-                    states=(), mix=None):
+        def _stack(p, x, pos, live, dests, offs, pools, unbatch, attend,
+                   states=(), mix=None, first=None):
             # the skeleton every program shares: per layer, by its kind.
             # An attention layer: write the new lines into its kind's
             # arrays (``dests``: the page of each row, by kind), attend
@@ -426,7 +456,9 @@ class PagedLMEngine(DecodeEngine):
             # ``mix(i, blk, x, states)`` reads the rows' state of the i-th
             # state layer, calls the family and writes it back. Then feed
             # forward. ``unbatch`` strips the axis a program's lines do
-            # not have
+            # not have. ``first``: by kind, the first pass-layer of the
+            # pass at hand, a traced scalar (``None``: the family's one
+            # pass, whose rows are constants of the program)
             counts = jnp.zeros((NC,), jnp.int32) if NC else None
             for li, blk in enumerate(fam.blocks(p)):
                 kind = fam.layer_kinds[li]
@@ -435,7 +467,8 @@ class PagedLMEngine(DecodeEngine):
                     x = x + y
                 else:
                     k = kinds.index(kind)
-                    row0 = index[li] * R[kind]
+                    row0 = index[li] * R[kind] if first is None else (
+                        (first[kind] + index[li]) * R[kind])
                     with jax.named_scope(fam.attention_scopes[kind]):
                         q, lines = fam.project(blk, x, pos, kind)
                         mine = tuple(
@@ -449,6 +482,33 @@ class PagedLMEngine(DecodeEngine):
                 if c is not None:
                     counts = counts + c
             return x, pools, counts, states
+
+        def _passes(p, x, pos, live, dests, offs, pools, unbatch, attend,
+                    states=(), mix=None):
+            # a family whose tokens run the stack several times: ONE loop
+            # over the passes in the program, its body the stack. The
+            # pools go round as the loop's carry (written and read at the
+            # pass's own rows, where they lie), beside the activations,
+            # the counts and what the family carries from pass to pass;
+            # ``close_pass`` ends each one and ``exit_rows`` says what the
+            # head reads. No layer of such a family keeps a state
+            def one(t, carry):
+                x, pools, counts, kept = carry
+                x, pools, c, _ = _stack(
+                    p, x, pos, live, dests, offs, pools, unbatch, attend,
+                    first={kind: t * n for kind, n in stack_of.items()})
+                x, kept, closed = fam.close_pass(p, x, kept, t, live)
+                for more in (c, closed):
+                    counts = counts if more is None else counts + more
+                return (x, pools, counts, kept)
+
+            _, pools, counts, kept = jax.lax.fori_loop(
+                0, T, one, (x, pools, jnp.zeros((NC,), jnp.int32),
+                            fam.open_passes(x)))
+            return (fam.exit_rows(kept), pools, counts if NC else None,
+                    states)
+
+        _layers = _stack if T == 1 else _passes
 
         def _step(p, token, pos, mask, *rest):
             self.compile_count += 1  # trace-time only: one step program
@@ -907,6 +967,8 @@ class PagedLMEngine(DecodeEngine):
         n_valid = min(self.chunk, tokens.size - start)
         attrs = {"slot": slot, "start": start, "n_valid": n_valid}
         with obs_context.span("engine.chunk.prepare", width=self.chunk,
+                              passes=self.passes,
+                              pass_layers=self.pass_layers,
                               **attrs) as prepare:
             self._release_behind(slot, start)
             self._ensure_writable(slot, start, start + n_valid)
@@ -984,7 +1046,9 @@ class PagedLMEngine(DecodeEngine):
         what ``_flight`` holds of it."""
         slots = np.flatnonzero(who)
         live = len(slots)
-        with obs_context.span("engine.step.prepare", live=live) as prepare:
+        with obs_context.span("engine.step.prepare", live=live,
+                              passes=self.passes,
+                              pass_layers=self.pass_layers) as prepare:
             for s in slots:
                 if self._pos[s] < self.max_seq:
                     self._release_behind(int(s), int(self._pos[s]))
@@ -1006,7 +1070,7 @@ class PagedLMEngine(DecodeEngine):
             fetched = {kind: self._pages_fetched(seen, first[kind],
                                                  self.page_size)
                        for kind in by_kind}
-            layers = sum(self.kind_layers.values())
+            layers = self.pass_layers
             padded = self.slots * self.blocks_per_slot
             prepare.attrs["pages_padded"] = padded
             self.attn_pages["attn_pages_padded"] += padded

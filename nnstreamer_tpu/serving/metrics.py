@@ -112,6 +112,10 @@ class ServingMetrics:
         self.moe_expert_slots = 0      # experts held x expert layers x calls
         self.moe_assignments = 0       # (token, expert) pairs served
         self.moe_max_load = 0          # largest load of one expert, summed
+        # what a family that runs its stack several times a token counted,
+        # both programs: ``exit_pass_<t>``, the live rows whose logits came
+        # from pass t (empty for every other family)
+        self.exit_passes: dict = {}
         # how far a decode step's attention follows what is visible
         # (PagedLMEngine.attn_pages), summed over steps
         self.attn_pages_read = 0       # pages the live slots held
@@ -222,8 +226,9 @@ class ServingMetrics:
     def record_layer_counts(self, counts: dict) -> None:
         """What the engine counted since the last pass (the growth of
         ``DecodeEngine.counters()``): its expert layers (``moe_*``, both
-        programs added up), its steps' attention (``attn_pages_*``), its
-        state layers' cache (``state_slots*``) and how its steps ran ahead
+        programs added up), the passes its tokens left at (``exit_pass_*``),
+        its steps' attention (``attn_pages_*``), its state layers' cache
+        (``state_slots*``) and how its steps ran ahead
         (``steps_ahead``, ``steps_collected_early``, ``surplus_steps``)."""
         with self._lock:
             self.attn_pages_read += counts.get("attn_pages_read", 0)
@@ -245,6 +250,10 @@ class ServingMetrics:
             self.moe_expert_slots += counts.get("moe_expert_slots", 0)
             self.moe_assignments += counts.get("moe_assignments", 0)
             self.moe_max_load += counts.get("moe_max_load", 0)
+            for name, n in counts.items():
+                if name.startswith("exit_pass_"):
+                    self.exit_passes[name] = \
+                        self.exit_passes.get(name, 0) + n
 
     def record_early_retire(self) -> None:
         with self._lock:
@@ -302,6 +311,7 @@ class ServingMetrics:
                 "steps_ahead": self.steps_ahead,
                 "steps_collected_early": self.steps_collected_early,
                 "surplus_steps": self.surplus_steps,
+                **self.exit_passes,
             }
         out["device"] = self.device.snapshot()
         out["queue_wait"] = self.queue_wait.snapshot()

@@ -2,15 +2,19 @@
 
 For every configuration ``BENCHMARK.json`` lists whose driver kind this
 script knows (``lm_serving``, ``lm_serving_moe_mla``,
-``lm_serving_moe_window``): ``_step`` and ``_prefill_chunk`` of the paged
-engine at the configuration's sizes and engine geometry, a launch of 256
-rows, in the forms a TPU runs (the step's attention kernel, the experts'
-kernel), compiled for a described v5e with no chip attached. Writes one
+``lm_serving_moe_window``, ``lm_serving_ssm``, ``lm_serving_looped``):
+``_step`` and ``_prefill_chunk`` of the paged engine at the configuration's
+sizes and engine geometry, a launch of 256 rows, in the forms a TPU runs
+(the step's attention kernel, the experts' kernel; a state layer's products
+in their plain form), compiled for a described v5e with no chip attached.
+Writes one
 file a program, ``<outdir>/<config>.<program>.ops``, one line an
 instruction of the optimized module in order: opcode, result type and
 shape (names, numbers, layouts, metadata and the kernels' serialized
 bodies left out: they hold source lines), and prints instructions, argument,
-aliased and temporary bytes a program.
+aliased and temporary bytes a program. A fourth argument ``text`` also
+writes the optimized module whole, ``<config>.<program>.hlo`` (to read,
+not to compare: it holds source lines).
 
 A PR that touches ``serving/lm_engine.py`` or a family runs it on an
 unpacked parent and on its own tree and compares the files:
@@ -55,10 +59,18 @@ def _model(config: dict):
         from nnstreamer_tpu.models.mellum import MellumConfig
 
         return MellumConfig.from_published(config)
+    if kind == "lm_serving_ssm":
+        from nnstreamer_tpu.models.jamba import JambaConfig
+
+        return JambaConfig.from_published(config)
+    if kind == "lm_serving_looped":
+        from nnstreamer_tpu.models.ouro import OuroConfig
+
+        return OuroConfig.from_published(config)
     return None
 
 
-def main(root: str, out: str) -> int:
+def main(root: str, out: str, text: bool = False) -> int:
     root = os.path.abspath(root)
     sys.path.insert(0, root)
     os.makedirs(out, exist_ok=True)
@@ -111,14 +123,24 @@ def main(root: str, out: str) -> int:
         pools = [shape((probe.kind_layers[k] * (by_kind[k] + 1),
                         geo["page_size"], w), jnp.bfloat16)
                  for k in probe.kinds for w in probe.line_widths]
+        # what a slot keeps in a state layer, for every slot
+        states = [shape((s.shape[0], S, *s.shape[2:]), s.dtype)
+                  for s in probe._states]
         programs = {
             "_step": (shape((S, 1), i32), shape((S,), i32),
-                      shape((S,), jnp.bool_), *[shape((S, NB), i32)] * K),
+                      shape((S,), jnp.bool_), *[shape((S, NB), i32)] * K,
+                      *pools, *states),
             "_prefill_chunk": (shape((width,), i32), shape((), i32),
-                               shape((), i32), *[shape((NB,), i32)] * K)}
+                               shape((), i32), *[shape((NB,), i32)] * K,
+                               *pools, *([shape((), i32)] if states else []),
+                               *states)}
         for name, args in programs.items():
             compiled = getattr(probe, name).func.lower(
-                params, *args, *pools).compile()
+                params, *args).compile()
+            if text:
+                with open(os.path.join(
+                        out, f"{entry['name']}.{name}.hlo"), "w") as fh:
+                    fh.write(compiled.as_text())
             lines = []
             for line in compiled.as_text().splitlines():
                 found = _INSTRUCTION.match(line.strip())
@@ -139,6 +161,6 @@ def main(root: str, out: str) -> int:
 
 
 if __name__ == "__main__":
-    if len(sys.argv) != 3:
+    if len(sys.argv) not in (3, 4):
         raise SystemExit(__doc__)
-    sys.exit(main(sys.argv[1], sys.argv[2]))
+    sys.exit(main(sys.argv[1], sys.argv[2], sys.argv[3:] == ["text"]))
