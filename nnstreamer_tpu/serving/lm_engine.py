@@ -79,6 +79,32 @@ def prefill_width(chunk: int, max_seq: int, page_size: int,
     return min(width, max_seq)
 
 
+def write_rows(pool, row0, dest, offs, lines):
+    """``lines (..., width)`` -> position ``offs`` of page ``dest`` of the
+    layer whose rows start at ``row0``: a scatter on the pool's two leading
+    axes, one update a line (a step's and a verify round's form: their
+    lines are single positions of different slots)."""
+    return pool.at[row0 + dest, offs].set(lines.astype(pool.dtype))
+
+
+def write_pages(pool, row0, pages, own, lines):
+    """A launch's lines into ``pool`` a page at a time: ``lines (n, page,
+    width)``, the launch's rows cut into the pages they fill, go to rows
+    ``row0 + pages (n,)`` of the pool's leading axis as ONE scatter of ``n``
+    updates, each a whole page, where the row form issues an update a line
+    (a scatter costs its updates, about 0.2 us each on a v5e whatever
+    their bytes). ``own (n, page, 1)`` says which positions are the
+    launch's: at the others a page keeps what it held (in a prompt's last
+    page the positions a decode step has yet to write; all of a layer's
+    null page, where the pages wholly past the launch's rows go). The
+    launch starts on a page's edge (``admit_start`` sees to it)."""
+    import jax.numpy as jnp
+
+    rows = row0 + pages
+    return pool.at[rows].set(
+        jnp.where(own, lines.astype(pool.dtype), pool[rows]))
+
+
 class _LowersShort:
     """A jitted program whose last argument joined it later: ``lower`` also
     takes the argument list as it was before (``short`` arguments; what the
@@ -178,9 +204,10 @@ class PagedLMEngine(DecodeEngine):
       inactive/pad writes route to (no branches in the scatter). A slot's
       logical position ``p`` lives at ``(i*(pages+1) + block_table[p //
       page], p % page)``. No program slices a layer out of the pool: a
-      write is a scatter on the two leading axes, a chunk's context read
-      one ``take`` of the slot's rows, a step's attention a walk over the
-      rows themselves.
+      step's write is a scatter on the two leading axes (``write_rows``),
+      a launch's one of whole pages on the first (``write_pages``, where
+      its width is whole pages), a chunk's context read one ``take`` of
+      the slot's rows, a step's attention a walk over the rows themselves.
     * **serving limit** — ``max_seq``, the positions a slot may hold: the
       family's ``max_positions`` (the ``gpt`` family's position table, the
       rotary families' ``max_position_embeddings``) or ``max_positions=``
@@ -440,14 +467,8 @@ class PagedLMEngine(DecodeEngine):
 
         NC = len(fam.counters)
 
-        def _write(pool, row0, dest, offs, rows):
-            # rows (..., width) -> position offs of page dest of the layer
-            # whose rows start at row0: a scatter on the two leading axes,
-            # one whole line per token
-            return pool.at[row0 + dest, offs].set(rows.astype(pool.dtype))
-
         def _stack(p, x, pos, live, dests, offs, pools, unbatch, attend,
-                   states=(), mix=None, first=None):
+                   states=(), mix=None, first=None, write=write_rows):
             # the skeleton every program shares: per layer, by its kind.
             # An attention layer: write the new lines into its kind's
             # arrays (``dests``: the page of each row, by kind), attend
@@ -458,7 +479,8 @@ class PagedLMEngine(DecodeEngine):
             # forward. ``unbatch`` strips the axis a program's lines do
             # not have. ``first``: by kind, the first pass-layer of the
             # pass at hand, a traced scalar (``None``: the family's one
-            # pass, whose rows are constants of the program)
+            # pass, whose rows are constants of the program). ``write``: the
+            # form of the write, ``write_rows`` or ``write_pages``
             counts = jnp.zeros((NC,), jnp.int32) if NC else None
             for li, blk in enumerate(fam.blocks(p)):
                 kind = fam.layer_kinds[li]
@@ -472,7 +494,7 @@ class PagedLMEngine(DecodeEngine):
                     with jax.named_scope(fam.attention_scopes[kind]):
                         q, lines = fam.project(blk, x, pos, kind)
                         mine = tuple(
-                            _write(pool, row0, dests[k], offs, unbatch(line))
+                            write(pool, row0, dests[k], offs, unbatch(line))
                             for pool, line in zip(pools[k * P:(k + 1) * P],
                                                   lines))
                         pools = pools[:k * P] + mine + pools[(k + 1) * P:]
@@ -484,7 +506,7 @@ class PagedLMEngine(DecodeEngine):
             return x, pools, counts, states
 
         def _passes(p, x, pos, live, dests, offs, pools, unbatch, attend,
-                    states=(), mix=None):
+                    states=(), mix=None, write=write_rows):
             # a family whose tokens run the stack several times: ONE loop
             # over the passes in the program, its body the stack. The
             # pools go round as the loop's carry (written and read at the
@@ -496,7 +518,8 @@ class PagedLMEngine(DecodeEngine):
                 x, pools, counts, kept = carry
                 x, pools, c, _ = _stack(
                     p, x, pos, live, dests, offs, pools, unbatch, attend,
-                    first={kind: t * n for kind, n in stack_of.items()})
+                    first={kind: t * n for kind, n in stack_of.items()},
+                    write=write)
                 x, kept, closed = fam.close_pass(p, x, kept, t, live)
                 for more in (c, closed):
                     counts = counts if more is None else counts + more
@@ -576,6 +599,12 @@ class PagedLMEngine(DecodeEngine):
         span = self._span = {"full": max_seq, "window": window}
         PB = self.chunk_block_pages = chunk_block_pages(KV * G * C, pg, NB)
         self._chunk_walk = chunk_walk
+        # a launch of whole pages writes its lines a page at a time
+        # (``write_pages``, bound here as the ops above are); a width the
+        # page does not divide, row by row
+        self.chunk_pages = C // pg if C % pg == 0 else None
+        pages_form = write_pages
+
         def _prefill_chunk(p, toks, start, n_valid, *rest):
             # toks (C,) padded; ingest positions start..start+n_valid-1 of
             # ONE slot. C is static — the only compiled prefill shape.
@@ -588,7 +617,21 @@ class PagedLMEngine(DecodeEngine):
             valid = jnp.arange(C) < n_valid
             lp = jnp.clip(q_pos, 0, max_seq - 1)
             dests = tuple(jnp.where(valid, bt[lp // pg], 0) for bt in bts)
-            offs = lp % pg
+            if self.chunk_pages:
+                # ``start`` is on a page's edge: every ``pg`` rows fill one
+                # page, named by their first row's entry of ``dests`` (the
+                # null page where the whole page is past ``n_valid``), and
+                # ``offs`` says which of its positions the launch owns
+                dests = tuple(d[::pg] for d in dests)
+                offs, write = valid.reshape(-1, pg, 1), pages_form
+
+                def unbatch(line):
+                    return line[0].reshape(-1, pg, line.shape[-1])
+            else:
+                offs, write = lp % pg, write_rows
+
+                def unbatch(line):
+                    return line[0]
             x = fam.embed(p, toks, lp)[None]        # (1, C, D)
 
             def attend(kind, row0, blk, q, pools):
@@ -616,7 +659,7 @@ class PagedLMEngine(DecodeEngine):
 
             x, pools, counts, states = _layers(
                 p, x, lp[None], valid[None], dests, offs, pools,
-                lambda line: line[0], attend, states, mix)
+                unbatch, attend, states, mix, write=write)
             with jax.named_scope("head"):
                 logits = fam.head(p, x[0])  # (C, V)
             if NC:
@@ -924,8 +967,11 @@ class PagedLMEngine(DecodeEngine):
             if pages:
                 self._bt[slot, :len(pages)] = pages
                 # always recompute >=1 position: the final prompt token's
-                # logits seed the first generated token
-                covered = min(covered, tokens.size - 1)
+                # logits seed the first generated token. From a page's edge:
+                # a launch writes whole pages (``write_pages``), and a cover
+                # of the whole prompt would leave its start mid-page
+                covered = min(covered, (tokens.size - 1)
+                              // self.page_size * self.page_size)
         self._pending[slot] = {"tokens": tokens, "next": covered,
                                "steps": steps}
         self._lane.pop(slot, None)
@@ -945,6 +991,16 @@ class PagedLMEngine(DecodeEngine):
             read += n * blocks * PB * pg
             padded += n * self.held_blocks[kind] * pg
         return read, padded
+
+    def chunk_lines(self, n_valid: int) -> "tuple[int, int]":
+        """``(lines_rows, lines_updates)`` of a launch of ``n_valid`` rows:
+        the lines it writes, every pool's of every pass-layer, and the
+        scatter updates it issues for them: one a line in the row form,
+        one a page the rows cover in the page form (``write_pages``)."""
+        writes = self.pass_layers * len(self.line_widths)
+        updates = -(-n_valid // self.page_size) if self.chunk_pages \
+            else n_valid
+        return writes * n_valid, writes * updates
 
     def prefill_stamp(self, slot: int) -> "tuple[float, int]":
         """``(first_chunk_t, chunks)`` of the prompt in ``slot``: when its
@@ -978,6 +1034,9 @@ class PagedLMEngine(DecodeEngine):
             # (the walk's own rule, ``ops.paged_attention.chunk_walk``)
             prepare.attrs["ctx_read"], prepare.attrs["ctx_padded"] = \
                 self.chunk_ctx(start, n_valid)
+            # the lines the launch writes and the updates they go in
+            prepare.attrs["lines_rows"], prepare.attrs["lines_updates"] = \
+                self.chunk_lines(n_valid)
             state_args = ()
             if self._states:  # the launch that starts a sequence zeroes it
                 prepare.attrs["state_reset"] = int(start == 0)

@@ -775,15 +775,13 @@ class TestPoolInPlaceOnTpu:
             _no_gathered_context(compiled, S * max_seq * 512)
 
 
-    def test_the_cells_launch_walks_and_gathers_no_padded_context(
-            self, v5e_chip):
+    @staticmethod
+    def _cells_launch(v5e_chip):
         """``_prefill_chunk`` of the ``opt_1.3b`` cell's engine, at the
         cell's widths, limit and pool and the width a v5e derives, two
         layers deep (24 compile for a quarter of a minute on every core,
-        under the other workers' tests) (PR 42): the launch's
-        attention is a loop a layer over blocks of 256 positions, nothing
-        of a gathered context's shape is made anywhere in the program (the
-        loops' bodies included), and nothing of a pool's size is copied."""
+        under the other workers' tests): ``(engine, configuration, pool's
+        shape, arguments)`` to lower it by."""
         import json
         import os
 
@@ -820,10 +818,19 @@ class TestPoolInPlaceOnTpu:
             jax.eval_shape(functools.partial(init_params, cfg)))
         pool = shape((cfg.layers * (geo["pages"] + 1), pg, cfg.dim),
                      jnp.bfloat16)
-        hlo = eng._prefill_chunk.func.lower(
+        return eng, cfg, pool, (
             params, shape((C,), jnp.int32), shape((), jnp.int32),
-            shape((), jnp.int32), shape((ctx // pg,), jnp.int32),
-            pool, pool).compile().as_text()
+            shape((), jnp.int32), shape((ctx // pg,), jnp.int32), pool, pool)
+
+    def test_the_cells_launch_walks_and_gathers_no_padded_context(
+            self, v5e_chip):
+        """The cell's launch (PR 42): its attention is a loop a layer over
+        blocks of 256 positions, nothing of a gathered context's shape is
+        made anywhere in the program (the loops' bodies included), and
+        nothing of a pool's size is copied."""
+        eng, cfg, pool, args = self._cells_launch(v5e_chip)
+        C, pg, ctx = eng.chunk, eng.page_size, cfg.max_seq
+        hlo = eng._prefill_chunk.func.lower(*args).compile().as_text()
         assert len(re.findall(r" while\(", hlo)) == cfg.layers
         # the family's launch multiplies at jax's default, as its
         # configuration states and as its gathered form did
@@ -853,6 +860,51 @@ class TestPoolInPlaceOnTpu:
                 copied.append(f"{op} {name}")
         assert not made, f"the launch still makes a padded context: {made}"
         assert not copied, f"the launch copies a pool: {copied}"
+
+    def test_the_cells_launch_writes_its_lines_a_page_at_a_time(
+            self, v5e_chip):
+        """The cell's launch (PR 44): each of its four writes (two layers,
+        keys and values) is one scatter of 16 whole pages where it was one
+        of 256 lines, both pools are aliased and neither is copied, and
+        the program is no larger: at most 3% over the 1,161 instructions
+        this launch had at PR 43 (a program's load follows its
+        instructions, and ``setup_s`` the load)."""
+        import jax
+
+        eng, cfg, pool, args = self._cells_launch(v5e_chip)
+        C, pg = eng.chunk, eng.page_size
+        assert eng.chunk_pages == C // pg == 16
+
+        def scatters(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "scatter":
+                    yield tuple(v.aval.shape for v in eqn.invars)
+                for inner in jax.core.jaxprs_in_params(eqn.params):
+                    yield from scatters(inner)
+
+        over_a_pool = [shapes for shapes in scatters(
+            jax.make_jaxpr(eng._prefill_chunk.func)(*args).jaxpr)
+            if shapes[0] == pool.shape]
+        assert over_a_pool == [
+            (pool.shape, (16, 1), (16, pg, cfg.dim))] * (2 * cfg.layers)
+        compiled = eng._prefill_chunk.func.lower(*args).compile()
+        hlo = compiled.as_text()
+        # every instruction whose result is a pool: (name, opcode, line)
+        pool_dims = f"bf16[{pool.shape[0]},{pg},{cfg.dim}]"
+        makes_a_pool = [
+            (*m.groups(), line) for line in hlo.splitlines()
+            for m in [re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = " + re.escape(
+                pool_dims) + r"\S* ([a-z][\w\-]*)\(", line)] if m]
+        written = [line for _, op, line in makes_a_pool if op == "scatter"]
+        assert len(written) == 2 * cfg.layers
+        assert all("update_window_dims={1,2}" in line for line in written)
+        copied = [name for name, op, _ in makes_a_pool if "copy" in op]
+        assert not copied, f"the launch copies a pool: {copied}"
+        assert compiled.memory_analysis().alias_size_in_bytes \
+            >= 2 * int(np.prod(pool.shape)) * 2
+        instructions = len(re.findall(r"^\s+(?:ROOT )?%?[\w.\-]+ = ", hlo,
+                                      re.M))
+        assert instructions <= 1161 * 1.03, instructions
 
 
 class TestExpertsStreamOnTpu:

@@ -579,7 +579,9 @@ def test_a_family_with_one_pass_lowers_to_the_parents_program_text():
     text is in ``tests/golden/serving_programs.json``, written there by
     ``python tests/test_ouro_serving.py <tree>`` run on that tree). The same
     input to the same compiler: no program of theirs changed. A PR that
-    means to change one of these programs writes the file anew and says so.
+    means to change one of these programs writes the file anew and says so:
+    PR 44 did for the four ``_prefill_chunk`` (a launch writes its lines a
+    page at a time); the four ``_step`` hashes are still PR 42's.
     """
     with open(GOLDEN) as fh:
         golden = json.load(fh)

@@ -20,10 +20,19 @@ end, each beside ``ctx_read`` (the positions its layers read, the engine's
 own count) and ``over_cut_limit_ms``: what the walk still costs over no
 context but the launch's own.
 
+Since PR 44 a launch of whole pages writes its lines a page at a time
+(``serving.lm_engine.write_pages``). Beside the rule's width's ms stand the
+ms of the launch's writes alone, in the row form and in the page form
+(``writes_ms``: the engine's pools, a full table and one array of lines a
+pool through every write the launch issues, nothing else in the program),
+the updates each form issues, and the us a write.
+
 Prints one JSON line per (configuration, width, context, start).
 
     chiprun -- python tools/prefill_width_forms.py [configuration ...]
         [widths=256,512]
+
+Any configuration ``BENCHMARK.json`` lists (the two above by default).
 
 ``--rehearse`` runs the same path at the files' ``rehearsal`` sizes (the
 CPU: what it prints there is no device time).
@@ -51,10 +60,83 @@ CONFIGS = ("opt_1.3b", "kanana2_30b_a3b_l8")
 SEED, REPS = 30, 10
 
 
-def launch_ms(config: dict, width: int, positions=None, start=512) -> dict:
+def _timed(run, reps=REPS) -> "tuple[float, float]":
+    """``(first call's seconds, mean ms of reps more)`` of ``run(reps)``."""
+    t0 = time.perf_counter()
+    run(1)
+    first_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    run(reps)
+    return first_s, 1e3 * (time.perf_counter() - t0) / reps
+
+
+def _tables(engine) -> tuple:
+    """A slot's table by kind of layer, full of distinct pages."""
+    return tuple(jnp.asarray(1 + np.arange(engine.blocks_per_slot,
+                                           dtype=np.int32) % pool.pages)
+                 for pool in engine.pools_by_kind.values())
+
+
+def writes_ms(engine, start: int, n_valid: int) -> dict:
+    """The writes of one launch alone, by form: every pool of every
+    pass-layer written once with the launch's ``(C, width)`` lines, at the
+    rows its table names, through ``lm_engine.write_rows`` (an update a
+    line) and ``lm_engine.write_pages`` (an update a page), the pools
+    donated from call to call as the engine's are."""
+    C, pg, P = engine.chunk, engine.page_size, len(engine.line_widths)
+    writes = engine.chunk_lines(1)[0]  # one a pool a pass-layer
+    q = start + jnp.arange(C)
+    lines = tuple(jnp.ones((C, w), jnp.float32) for w in engine.line_widths)
+
+    def program(form):
+        def run(tables, lines, n_valid, *pools):
+            # a value of the call, as the launch's is: nothing folds away
+            valid = jnp.arange(C) < n_valid
+            out = []
+            for k, (kind, pool) in enumerate(engine.pools_by_kind.items()):
+                dest = jnp.where(valid, tables[k][q // pg], 0)
+                for mine, line in zip(pools[k * P:(k + 1) * P], lines):
+                    for i in range(engine.kind_layers[kind]):
+                        row0 = i * (pool.pages + 1)
+                        if form == "rows":
+                            mine = lm_engine.write_rows(
+                                mine, row0, dest, q % pg, line + i)
+                        else:
+                            mine = lm_engine.write_pages(
+                                mine, row0, dest[::pg],
+                                valid.reshape(-1, pg, 1),
+                                (line + i).reshape(-1, pg, line.shape[-1]))
+                    out.append(mine)
+            return tuple(out)
+
+        return jax.jit(run, donate_argnums=tuple(
+            range(3, 3 + len(engine._pools))))
+
+    got, tables = {"writes": writes}, _tables(engine)
+    rows = jnp.asarray(n_valid, jnp.int32)
+    for form in ("rows", "pages") if engine.chunk_pages else ("rows",):
+        jitted = program(form)
+
+        def run(reps):
+            for _ in range(reps):
+                engine._pools = jitted(tables, lines, rows, *engine._pools)
+            jax.block_until_ready(engine._pools)
+
+        # a call is a fraction of a ms: enough of them in flight that the
+        # device, not the host's dispatch, sets the time
+        ms = _timed(run, 10 * REPS)[1]
+        got[f"writes_{form}_ms"] = round(ms, 4)
+        got[f"us_a_write_{form}"] = round(1e3 * ms / writes, 2)
+    got["updates_rows"], got["updates_pages"] = engine.chunk_lines(n_valid)
+    return got
+
+
+def launch_ms(config: dict, width: int, positions=None, start=512,
+              writes=False) -> dict:
     """Mean device-bound ms of one launch at ``start`` (or as near below it
     as the context allows): ``REPS`` back to back, the pools handed from
-    one to the next as the engine does, one wait at the end."""
+    one to the next as the engine does, one wait at the end. ``writes``:
+    the launch's writes alone beside it (``writes_ms``)."""
     config = copy.deepcopy(config)
     config["engine"]["chunk"] = width
     if positions is not None:
@@ -63,35 +145,33 @@ def launch_ms(config: dict, width: int, positions=None, start=512) -> dict:
     try:
         engine = proxy._engine
         assert engine.chunk == width, (engine.chunk, width)
-        NB = engine.blocks_per_slot
         # a chunk in the middle of a prompt where the context allows one:
         # every row valid, the slot's table full of distinct pages
         start = min(start, engine.max_seq - width)
         args = (jnp.arange(width, dtype=jnp.int32) % engine.family.vocab,
                 jnp.asarray(start, jnp.int32), jnp.asarray(width, jnp.int32),
-                jnp.asarray(1 + np.arange(NB, dtype=np.int32)))
+                *_tables(engine))
+        kept = len(engine._pools) + len(engine._states)
+        slot = jnp.int32(0)
 
         def run(reps):
-            pools = engine._pools
             for _ in range(reps):
-                _logits, *rest = engine._prefill_chunk(*args, *pools)
-                pools = tuple(rest[-len(pools):])
-            engine._pools = pools
-            jax.block_until_ready(pools)
+                # a family with state layers: slot 0's rows of its arrays
+                _logits, *rest = engine._prefill_chunk(
+                    *args, *engine._pools,
+                    *((slot, *engine._states) if engine._states else ()))
+                engine._keep(rest[-kept:])
+            jax.block_until_ready(engine._pools)
 
-        t0 = time.perf_counter()
-        run(1)
-        compile_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        run(REPS)
-        ms = 1e3 * (time.perf_counter() - t0) / REPS
+        compile_s, ms = _timed(run)
+        alone = writes_ms(engine, start, width) if writes else {}
     finally:
         sched.close()
     return {"width": width, "context": engine.max_seq, "start": start,
             "ctx_read": engine.chunk_ctx(start, width)[0],
             "ms_per_launch": round(ms, 3),
             "ms_per_token": round(ms / width, 4),
-            "first_call_s": round(compile_s, 1)}
+            "first_call_s": round(compile_s, 1), **alone}
 
 
 def main():
@@ -121,7 +201,8 @@ def main():
         for width in dict.fromkeys(
                 w for w in (*widths, rule) if w <= limit):
             try:
-                served[width] = launch_ms(config, width)
+                served[width] = launch_ms(config, width,
+                                          writes=width == rule)
             except Exception as e:  # a width that does not compile or fit
                 served[width] = {"width": width, "error":
                                  f"{type(e).__name__}: {str(e)[:300]}"}

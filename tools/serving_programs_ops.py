@@ -12,7 +12,9 @@ file a program, ``<outdir>/<config>.<program>.ops``, one line an
 instruction of the optimized module in order: opcode, result type and
 shape (names, numbers, layouts, metadata and the kernels' serialized
 bodies left out: they hold source lines), and prints instructions, argument,
-aliased and temporary bytes a program. A fourth argument ``text`` also
+aliased and temporary bytes and the bytes of generated code a program (a
+program's load at every start follows the last: two programs of one
+instruction count can differ by a sixth in it, PERF.md section 6, PR 44). A fourth argument ``text`` also
 writes the optimized module whole, ``<config>.<program>.hlo`` (to read,
 not to compare: it holds source lines).
 
@@ -156,7 +158,8 @@ def main(root: str, out: str, text: bool = False) -> int:
                 "instructions": len(lines),
                 "argument_bytes": m.argument_size_in_bytes,
                 "alias_bytes": m.alias_size_in_bytes,
-                "temp_bytes": m.temp_size_in_bytes}), flush=True)
+                "temp_bytes": m.temp_size_in_bytes,
+                "code_bytes": m.generated_code_size_in_bytes}), flush=True)
     return 0
 
 
