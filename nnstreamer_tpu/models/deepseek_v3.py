@@ -226,6 +226,9 @@ class DeepseekV3Family:
     def init_params(self, seed: int):
         return init_params(self.cfg, seed=seed)
 
+    def stored(self, params):
+        return params              # served as they come
+
     def with_positions(self, positions: int) -> "DeepseekV3Family":
         from dataclasses import replace
 

@@ -73,6 +73,23 @@ A family says:
   layer. The GPT block keeps keys and values, two pools of
   ``heads * head_dim``; the latent-attention block keeps one line that
   every head reads as keys and as values (``models/deepseek_v3.py``).
+* ``stored(params) -> params`` — how the family keeps its parameters for
+  serving. The engine calls it once, where it keeps the tree, and its
+  programs read what comes back (``engine.params``); what a configuration's
+  parameters look like from outside (``init_params``, a reference's
+  ``program_params``, a checkpoint) does not change. ``gpt``,
+  ``deepseek_v3``, ``mellum`` and ``jamba`` return the tree they were
+  given, the same object. ``ouro`` returns it with every layer's ``wq`` and
+  ``wk`` transposed, ``(heads * head_dim, hidden)``, which its ``project``
+  contracts over their second axis: re-laid on the device once at build,
+  the caller's arrays neither donated nor deleted. The rule: a family
+  re-lays only what a compiled module showed copied through HBM on every
+  call (``tools/serving_programs_ops.py`` prints the count: 96 ``copy
+  bf16[2048,2048]`` in each of ``ouro_2.6b``'s two programs, 0.8 GB
+  written and read again a call, PERF.md section 6, PR 45), never on a
+  guess at the compiler's layout assignment: the same compiler prefetches
+  the same kind of matrix into fast memory in ``mellum`` and ``kanana``,
+  and that is the matrix's one read.
 * ``embed(p, toks, pos)`` — tokens at positions → float32 activations.
 * ``blocks(p)`` — the per-layer parameter groups, in order.
 * ``project(blk, x, pos, kind)`` — from the input ``x (B, Q, D)`` of a
@@ -124,6 +141,9 @@ class GroupedQueryLines:
 
     chunk_precision = "highest"    # f32 queries over a bf16 pool
     passes = 1                     # the stack once a token
+
+    def stored(self, params):
+        return params              # served as they come
 
     @property
     def cache_lines(self) -> tuple:
@@ -205,6 +225,9 @@ class GPTFamily:
         from .transformer import init_params
 
         return init_params(self.cfg, seed=seed)
+
+    def stored(self, params):
+        return params              # served as they come
 
     def with_positions(self, positions: int) -> "GPTFamily":
         from dataclasses import replace

@@ -359,7 +359,9 @@ class _LMServingEntry:
                 page_size=eng.page_size, chunk=eng.chunk,
                 pool_bytes={kind: of["bytes"] for kind, of
                             in eng.memory_bytes()["kinds"].items()},
-                state_bytes=eng.state_slot_bytes * slots)
+                state_bytes=eng.state_slot_bytes * slots,
+                relaid_matrices=eng.relaid["matrices"],
+                relaid_bytes=eng.relaid["bytes"])
         sp.attrs["bytes"] = eng.param_bytes
         if draft is None:
             return eng

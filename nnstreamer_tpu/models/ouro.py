@@ -167,6 +167,24 @@ class OuroFamily(GroupedQueryLines):
     def init_params(self, seed: int):
         return init_params(self.cfg, seed=seed)
 
+    def stored(self, params):
+        """The tree with every layer's ``wq`` and ``wk`` as :meth:`project`
+        reads them, ``(heads * head_dim, hidden)``: each re-laid once on the
+        device, every other leaf the caller's own object. As ``(hidden,
+        heads * head_dim)`` the compiler transposed all of them through HBM
+        ahead of the loop over passes, in every call of both programs
+        (PERF.md section 6, PR 45). A tree without layers (a probe built for
+        its programs' shapes) has nothing to re-lay."""
+        if "blocks" not in params:
+            return params
+        import jax
+        import jax.numpy as jnp
+
+        relaid = jax.jit(jnp.transpose)    # not donated: the caller's stay
+        return {**params, "blocks": [
+            {**blk, "wq": relaid(blk["wq"]), "wk": relaid(blk["wk"])}
+            for blk in params["blocks"]]}
+
     def with_positions(self, positions: int) -> "OuroFamily":
         from dataclasses import replace
 
@@ -185,13 +203,18 @@ class OuroFamily(GroupedQueryLines):
         """``x (B, Q, D)`` at ``pos (B, Q)`` → the rotated queries ``(B, Q,
         H, head_dim)`` and the two lines to write, rotated keys and values,
         ``(B, Q, kv_heads * head_dim)``."""
+        import jax.numpy as jnp
+
         cfg = self.cfg
         H, KV, Dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
                      cfg.head_dim)
         freq, factor = self._rope
         h = rms_norm(x, blk["ln1"], cfg.rms_norm_eps)
-        q = (h @ blk["wq"]).reshape(*x.shape[:2], H, Dh)
-        k = (h @ blk["wk"]).reshape(*x.shape[:2], KV, Dh)
+        # the two matrices lie as ``stored`` left them: (N, D)
+        q = jnp.einsum("bqd,nd->bqn", h, blk["wq"]).reshape(
+            *x.shape[:2], H, Dh)
+        k = jnp.einsum("bqd,nd->bqn", h, blk["wk"]).reshape(
+            *x.shape[:2], KV, Dh)
         q = rotate_half(q, pos[..., None], freq, factor)
         k = rotate_half(k, pos[..., None], freq, factor)
         return q, (k.reshape(*x.shape[:2], KV * Dh), h @ blk["wv"])

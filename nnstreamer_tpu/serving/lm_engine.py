@@ -311,7 +311,14 @@ class PagedLMEngine(DecodeEngine):
         self.family = fam
         self.kinds = kinds
         self.max_seq = max_seq
-        self.params = params
+        # the family's stored form is what the programs read; the leaves it
+        # re-laid are the ones that are no longer the caller's
+        given = jax.tree_util.tree_leaves(params)
+        self.params = params = fam.stored(params)
+        relaid = [b for a, b in zip(given, jax.tree_util.tree_leaves(params))
+                  if a is not b]
+        self.relaid = {"matrices": len(relaid),
+                       "bytes": int(sum(b.nbytes for b in relaid))}
         self.slots = slots
         self.page_size = page_size
         self.blocks_per_slot = max_seq // page_size

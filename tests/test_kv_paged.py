@@ -906,6 +906,83 @@ class TestPoolInPlaceOnTpu:
                                       re.M))
         assert instructions <= 1161 * 1.03, instructions
 
+    @pytest.mark.parametrize("program", ["_step", "_prefill_chunk"])
+    def test_the_looped_familys_programs_copy_no_weight_through_hbm(
+            self, v5e_chip, program, monkeypatch):
+        """``ouro_2.6b``'s programs at the published widths and the cell's
+        engine geometry, two layers deep, the parameters as the family
+        stores them (PR 45): no ``copy`` whose result has a weight matrix's
+        shape lies outside memory space 1 (as given, the compiler
+        transposed ``W_q`` and ``W_k`` of every layer through HBM ahead of
+        the loop over passes, in every call: four such copies at this
+        depth), and the temporaries stay under a sixth of one layer's
+        weights (the four copies alone are a third)."""
+        import json
+        import os
+
+        import jax
+        import jax.numpy as jnp
+
+        from nnstreamer_tpu.models.ouro import (
+            OuroConfig,
+            OuroFamily,
+            init_params,
+        )
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "benchmark", "configs",
+                               "ouro_2.6b.json")) as fh:
+            config = json.load(fh)
+        cfg = OuroConfig.from_published(
+            {**config, "num_hidden_layers": 2,
+             "layer_types": config["layer_types"][:2]})
+        geo = config["engine"]
+        S, pg, C = geo["slots"], geo["page_size"], 256
+        monkeypatch.setattr(paged_attention, "paged_line_attention",
+                            paged_attention.kernel_line_attention)
+        # the programs close over the sizes only: a two-page pool to build
+        eng = PagedLMEngine(cfg, {"embed": jnp.zeros((1, 1), jnp.bfloat16)},
+                            slots=S, page_size=pg, pages=2, chunk=C,
+                            share_prefixes=False)
+        assert eng.passes == 4 and eng.kind_layers == {"full": 8}
+
+        def shape(s, dt):
+            return jax.ShapeDtypeStruct(s, dt, sharding=v5e_chip)
+
+        stored = jax.eval_shape(
+            lambda: OuroFamily(cfg).stored(init_params(cfg)))
+        blk = stored["blocks"][0]
+        assert blk["wq"].shape == blk["wk"].shape == (2048, 2048)
+        params = jax.tree_util.tree_map(
+            lambda a: shape(a.shape, jnp.bfloat16), stored)
+        NB = cfg.max_position_embeddings // pg
+        pool = shape((eng.kind_layers["full"] * (geo["pages"] + 1), pg,
+                      cfg.line_width), jnp.bfloat16)
+        if program == "_step":
+            args = (shape((S, 1), jnp.int32), shape((S,), jnp.int32),
+                    shape((S,), jnp.bool_), shape((S, NB), jnp.int32))
+        else:
+            args = (shape((C,), jnp.int32), shape((), jnp.int32),
+                    shape((), jnp.int32), shape((NB,), jnp.int32))
+        compiled = getattr(eng, program).func.lower(
+            params, *args, pool, pool).compile()
+
+        matrices = {f"bf16[{','.join(map(str, dims))}]"
+                    for a in jax.tree_util.tree_leaves(stored) if a.ndim == 2
+                    for dims in (a.shape, a.shape[::-1])}
+        copied = [
+            line.strip()[:120] for line in compiled.as_text().splitlines()
+            for m in [re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = (\w+\[[\d,]*\])"
+                               r"(\S*) copy\(", line)]
+            if m and m.group(1) in matrices and "S(1)" not in m.group(2)]
+        assert not copied, \
+            f"{program} copies a weight matrix through HBM: {copied}"
+        layer = 2 * sum(int(np.prod(a.shape))
+                        for a in jax.tree_util.tree_leaves(blk)
+                        if a.ndim == 2)
+        assert layer == 2 * (4 * 2048 * 2048 + 3 * 2048 * 5632)
+        assert compiled.memory_analysis().temp_size_in_bytes < layer // 6
+
 
 class TestExpertsStreamOnTpu:
     """What the compiled programs of the two expert families hold on a

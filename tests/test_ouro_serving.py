@@ -506,6 +506,135 @@ def test_spans_and_counters_carry_the_passes():
     assert text.count("stablehlo.dot_general") < 2 * LAYERS * 8
 
 
+# -- the stored form -----------------------------------------------------------
+
+def _as_they_come(monkeypatch):
+    """The family as it served before it kept a stored form: the tree as
+    given, the two products over ``(hidden, heads * head_dim)``. The oracle
+    of the cases below, kept here and nowhere in the program."""
+    from nnstreamer_tpu.models.mellum import rotate_half
+    from nnstreamer_tpu.models.ouro import rms_norm
+
+    def project(self, blk, x, pos, kind="full"):
+        cfg = self.cfg
+        H, KV, Dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                     cfg.head_dim)
+        freq, factor = self._rope
+        h = rms_norm(x, blk["ln1"], cfg.rms_norm_eps)
+        q = (h @ blk["wq"]).reshape(*x.shape[:2], H, Dh)
+        k = (h @ blk["wk"]).reshape(*x.shape[:2], KV, Dh)
+        q = rotate_half(q, pos[..., None], freq, factor)
+        k = rotate_half(k, pos[..., None], freq, factor)
+        return q, (k.reshape(*x.shape[:2], KV * Dh), h @ blk["wv"])
+
+    monkeypatch.setattr(OuroFamily, "project", project)
+    monkeypatch.setattr(OuroFamily, "stored", lambda self, params: params)
+
+
+def _serve(eng, prompts, steps):
+    """Every prompt through a scheduler: the tokens, and each launch's
+    ``(start, n_valid, logits)``."""
+    chunks = _spy_chunks(eng)
+    sched = DecodeScheduler(eng, name="ouro-stored")
+    try:
+        reqs = [sched.submit(p, steps=steps) for p in prompts]
+        outs = [np.asarray(r.result(timeout=300)[0]) for r in reqs]
+    finally:
+        sched.close()
+    return outs, chunks
+
+
+@pytest.mark.parametrize("kv_heads", [4, 2])
+def test_the_stored_form_serves_what_the_given_form_served(kv_heads,
+                                                           monkeypatch):
+    cfg = OuroConfig.from_published(
+        {**SIZES, "num_key_value_heads": kv_heads})
+    fam = OuroFamily(cfg)
+    # the matrices at the file's std of 0.15, so that tokens differ
+    params = jax.tree_util.tree_map(
+        lambda a: a * 7.5 if a.ndim == 2 else a, fam.init_params(3))
+    given = jax.tree_util.tree_leaves_with_path(params)
+    rng = np.random.default_rng(4)
+    prompts = [_prompt(rng, n) for n in (37, 7, 21, 12)]
+
+    eng = _entry(cfg, params).make_continuous(**ENGINE)
+    span = [s for s in obs_context.startup_spans()
+            if s.name == "setup.engine"][-1]
+    outs, chunks = _serve(eng, prompts, 24)
+
+    # the tree the engine keeps: the two matrices a layer transposed, every
+    # other leaf the caller's own object; the caller's tree as it was
+    stored = jax.tree_util.tree_leaves_with_path(eng.params)
+    assert [path for path, _ in stored] == [path for path, _ in given]
+    relaid = 0
+    for (path, was), (_, now) in zip(given, stored):
+        if jax.tree_util.keystr(path)[-6:] in ("['wq']", "['wk']"):
+            assert now.shape == was.shape[::-1], path
+            assert now.shape[1] == cfg.hidden_size
+            np.testing.assert_array_equal(np.asarray(now),
+                                          np.asarray(was).T)
+            relaid += now.nbytes
+        else:
+            assert now is was, path
+        assert not was.is_deleted(), path
+    assert eng.relaid == {"matrices": 2 * LAYERS, "bytes": relaid}
+    assert span.attrs["relaid_matrices"] == 2 * LAYERS
+    assert span.attrs["relaid_bytes"] == relaid
+    assert eng.param_bytes == sum(a.nbytes for _, a in given)
+
+    _as_they_come(monkeypatch)
+    old = _entry(cfg, params).make_continuous(**ENGINE)
+    assert old.relaid == {"matrices": 0, "bytes": 0}
+    assert all(a is b for a, b in zip(
+        jax.tree_util.tree_leaves(old.params),
+        jax.tree_util.tree_leaves(params)))
+    old_outs, old_chunks = _serve(old, prompts, 24)
+    for served, before in zip(outs, old_outs):
+        assert served.tolist() == before.tolist()
+    assert len({int(t) for o in outs for t in o}) > 4, \
+        "the toy model does not say one token"
+    assert len(chunks) == len(old_chunks)
+    for (start, n, logits), (start0, n0, logits0) in zip(chunks, old_chunks):
+        assert (start, n) == (start0, n0)
+        np.testing.assert_allclose(logits[:n], logits0[:n0],
+                                   atol=LOGIT_TOL, rtol=0)
+
+
+def _single_pass_families():
+    from nnstreamer_tpu.models.families import _families
+
+    return [pytest.param(config_type, family, id=family.name)
+            for config_type, family in _families()
+            if family is not OuroFamily]
+
+
+@pytest.mark.parametrize("config_type, family", _single_pass_families())
+def test_a_family_with_nothing_to_re_lay_keeps_the_tree_it_was_given(
+        config_type, family):
+    cfg = config_type()
+    fam = family(cfg)
+    params = fam.init_params(0)
+    assert fam.stored(params) is params
+    entry = _entry(cfg, params)
+    eng = entry.make_continuous(slots=2, page_size=4, chunk=8,
+                                share_prefixes=False)
+    span = [s for s in obs_context.startup_spans()
+            if s.name == "setup.engine"][-1]
+    try:
+        assert eng.params is params
+        assert eng.relaid == {"matrices": 0, "bytes": 0}
+        assert span.attrs["relaid_matrices"] == 0
+        assert span.attrs["relaid_bytes"] == 0
+    finally:
+        eng.close()
+
+
+def test_a_probe_without_layers_has_nothing_to_re_lay():
+    cfg, _, _, _ = _model()
+    stub = {"embed": jnp.zeros((1, 1), jnp.float32)}
+    assert OuroFamily(cfg).stored(stub) is stub
+
+
 # -- a family with one pass takes no loop ----------------------------------------
 
 def _rehearsal_programs():

@@ -408,11 +408,14 @@ def test_the_split_adds_up_to_the_setup_it_was_given():
         "cache_hits": 1}]
 
 
-@pytest.mark.parametrize("cell,programs", [
-    ("opt1b3_chat", {"_prefill_chunk", "_step"}),
-    ("jamba2_reasoning_saturated", {"_prefill_chunk", "_step"}),
+@pytest.mark.parametrize("cell,programs,relaid", [
+    ("opt1b3_chat", {"_prefill_chunk", "_step"}, 0),
+    ("jamba2_reasoning_saturated", {"_prefill_chunk", "_step"}, 0),
+    # wq and wk of the rehearsal's four layers (``family.stored``)
+    ("ouro_reasoning_saturated", {"_prefill_chunk", "_step"}, 8),
 ])
-def test_a_rehearsed_cell_prints_the_split_of_its_setup(cell, programs):
+def test_a_rehearsed_cell_prints_the_split_of_its_setup(cell, programs,
+                                                        relaid):
     """The tool runs the benchmark's command in its process (the CPU, the
     cell's rehearsal sizes), leaves its lines as they are and adds one."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -445,5 +448,7 @@ def test_a_rehearsed_cell_prints_the_split_of_its_setup(cell, programs):
     assert (marks["setup_params_s"] <= marks["setup_engine_s"]
             <= marks["setup_engine_end_s"] <= marks["first_program_call_s"]
             <= marks["window_opened_s"])
+    assert got["engine"]["relaid_matrices"] == relaid
+    assert (got["engine"]["relaid_bytes"] > 0) == (relaid > 0)
     assert got["ring"]["oldest_before_traced_part"] is True
     assert got["ring"]["retained"] <= got["ring"]["max_finished"]

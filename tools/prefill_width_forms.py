@@ -27,10 +27,17 @@ ms of the launch's writes alone, in the row form and in the page form
 pool through every write the launch issues, nothing else in the program),
 the updates each form issues, and the us a write.
 
+``--pair`` (since PR 45) is the short form for a change to what both
+programs run: one build a configuration at the rule's width, ``_step``
+with every slot live at the middle of the serving limit (``step_ms``, the
+pools handed from step to step, one wait at the end), then the launch at
+the same ``start``, and nothing else. A parent and a change are two runs of
+it in one chip call, each from its own tree.
+
 Prints one JSON line per (configuration, width, context, start).
 
     chiprun -- python tools/prefill_width_forms.py [configuration ...]
-        [widths=256,512]
+        [widths=256,512] [--pair]
 
 Any configuration ``BENCHMARK.json`` lists (the two above by default).
 
@@ -70,10 +77,12 @@ def _timed(run, reps=REPS) -> "tuple[float, float]":
     return first_s, 1e3 * (time.perf_counter() - t0) / reps
 
 
-def _tables(engine) -> tuple:
-    """A slot's table by kind of layer, full of distinct pages."""
-    return tuple(jnp.asarray(1 + np.arange(engine.blocks_per_slot,
-                                           dtype=np.int32) % pool.pages)
+def _tables(engine, slots=None) -> tuple:
+    """A slot's table by kind of layer, full of distinct pages; ``slots``
+    of them, each slot's pages its own as far as the pool goes."""
+    shape = (*(() if slots is None else (slots,)), engine.blocks_per_slot)
+    blocks = np.arange(np.prod(shape), dtype=np.int32).reshape(shape)
+    return tuple(jnp.asarray(1 + blocks % pool.pages)
                  for pool in engine.pools_by_kind.values())
 
 
@@ -131,12 +140,37 @@ def writes_ms(engine, start: int, n_valid: int) -> dict:
     return got
 
 
+def step_ms(engine, pos: int) -> dict:
+    """Mean device-bound ms of one ``_step`` with every slot live at
+    ``pos``: ``REPS`` back to back, the token and the pools handed from one
+    to the next as the engine does, one wait at the end."""
+    S = engine.slots
+    fixed = (np.full((S,), pos, np.int32), np.ones((S,), bool),
+             *_tables(engine, S))
+    join = np.full((S,), -1, np.int32)
+    kept = len(engine._pools) + len(engine._states)
+    token = jnp.zeros((S, 1), jnp.int32)
+
+    def run(reps):
+        nonlocal token
+        for _ in range(reps):
+            _out, token, *rest = engine._step(
+                token, *fixed, *engine._pools, *engine._states, join)
+            engine._keep(rest[-kept:])
+        jax.block_until_ready(engine._pools)
+
+    first_s, ms = _timed(run)
+    return {"program": "_step", "slots": S, "pos": pos,
+            "ms_per_step": round(ms, 3), "first_call_s": round(first_s, 1)}
+
+
 def launch_ms(config: dict, width: int, positions=None, start=512,
-              writes=False) -> dict:
+              writes=False, step=False) -> dict:
     """Mean device-bound ms of one launch at ``start`` (or as near below it
     as the context allows): ``REPS`` back to back, the pools handed from
     one to the next as the engine does, one wait at the end. ``writes``:
-    the launch's writes alone beside it (``writes_ms``)."""
+    the launch's writes alone beside it (``writes_ms``). ``step``: the same
+    engine's ``_step`` first, under ``"step"`` (``step_ms``)."""
     config = copy.deepcopy(config)
     config["engine"]["chunk"] = width
     if positions is not None:
@@ -145,6 +179,8 @@ def launch_ms(config: dict, width: int, positions=None, start=512,
     try:
         engine = proxy._engine
         assert engine.chunk == width, (engine.chunk, width)
+        stepped = ({"step": step_ms(engine, engine.max_seq // 2)}
+                   if step else {})
         # a chunk in the middle of a prompt where the context allows one:
         # every row valid, the slot's table full of distinct pages
         start = min(start, engine.max_seq - width)
@@ -171,7 +207,7 @@ def launch_ms(config: dict, width: int, positions=None, start=512,
             "ctx_read": engine.chunk_ctx(start, width)[0],
             "ms_per_launch": round(ms, 3),
             "ms_per_token": round(ms / width, 4),
-            "first_call_s": round(compile_s, 1), **alone}
+            "first_call_s": round(compile_s, 1), **alone, **stepped}
 
 
 def main():
@@ -197,6 +233,15 @@ def main():
         limit = config["max_position_embeddings"]
         head = {"config": name, "device": device.device_kind,
                 "rule_width": rule}
+        if "--pair" in sys.argv:
+            page = config["engine"]["page_size"]
+            pair = launch_ms(config, rule, start=limit // 2 // page * page,
+                             step=True)
+            print(json.dumps({**head, **pair.pop("step")}), flush=True)
+            print(json.dumps({**head, "program": "_prefill_chunk", **pair}),
+                  flush=True)
+            gc.collect()
+            continue
         served = {}
         for width in dict.fromkeys(
                 w for w in (*widths, rule) if w <= limit):

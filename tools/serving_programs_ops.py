@@ -14,7 +14,16 @@ shape (names, numbers, layouts, metadata and the kernels' serialized
 bodies left out: they hold source lines), and prints instructions, argument,
 aliased and temporary bytes and the bytes of generated code a program (a
 program's load at every start follows the last: two programs of one
-instruction count can differ by a sixth in it, PERF.md section 6, PR 44). A fourth argument ``text`` also
+instruction count can differ by a sixth in it, PERF.md section 6, PR 44).
+The parameters have the shapes the engine runs with: the family's stored
+layout (``family.stored``, since PR 45; a tree without it is lowered with
+the shapes as they come). ``weight_copies`` and ``weight_copy_bytes`` count
+the ``copy`` instructions whose result has a weight matrix's shape, either
+way round, and does not lie in memory space 1 (no ``S(1)`` in its layout):
+a matrix written to HBM and read again on every call, which storing it
+otherwise would save. A copy into memory space 1 is the compiler's
+prefetch of the matrix into fast memory, its one read, and is not counted.
+A fourth argument ``text`` also
 writes the optimized module whole, ``<config>.<program>.hlo`` (to read,
 not to compare: it holds source lines).
 
@@ -40,6 +49,9 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 _INSTRUCTION = re.compile(
     r"(?:ROOT )?%?[\w.\-]+ = (\(?[a-z0-9]+\[[0-9,]*\][^ ]*(?:, [^ ]+\))?) "
     r"([\w\-]+)\(")
+
+
+_HLO_TYPE = {"bfloat16": "bf16", "float32": "f32", "float16": "f16"}
 
 
 def _model(config: dict):
@@ -117,10 +129,19 @@ def main(root: str, out: str, text: bool = False) -> int:
             **{**geo, "slots": 1,
                "pages": dict.fromkeys(by_kind, 2) if by_kind else 2})
         by_kind = by_kind or {probe.kinds[0]: pages}
+        # as the engine keeps them (a parent of PR 45 has no such method)
+        stored = getattr(probe.family, "stored", lambda tree: tree)
         params = jax.tree_util.tree_map(
             lambda a: shape(a.shape, a.dtype),
-            jax.eval_shape(lambda k: reference.program_params(
-                k, sz, jnp.bfloat16), jax.random.key(0)))
+            jax.eval_shape(lambda k: stored(reference.program_params(
+                k, sz, jnp.bfloat16)), jax.random.key(0)))
+        # a weight matrix's result type as the module writes it, either
+        # way round → its bytes
+        matrices = {
+            f"{_HLO_TYPE[a.dtype.name]}[{','.join(map(str, dims))}]":
+            a.size * a.dtype.itemsize
+            for a in jax.tree_util.tree_leaves(params)
+            if a.ndim == 2 for dims in (a.shape, a.shape[::-1])}
         S, NB, K = geo["slots"], probe.blocks_per_slot, len(probe.kinds)
         pools = [shape((probe.kind_layers[k] * (by_kind[k] + 1),
                         geo["page_size"], w), jnp.bfloat16)
@@ -143,12 +164,15 @@ def main(root: str, out: str, text: bool = False) -> int:
                 with open(os.path.join(
                         out, f"{entry['name']}.{name}.hlo"), "w") as fh:
                     fh.write(compiled.as_text())
-            lines = []
+            lines, copied = [], []
             for line in compiled.as_text().splitlines():
                 found = _INSTRUCTION.match(line.strip())
                 if found:
                     result = re.sub(r"[{][^}]*[}]", "", found.group(1))
                     lines.append(f"{found.group(2)} {result}")
+                    if (found.group(2) == "copy" and result in matrices
+                            and "S(1)" not in found.group(1)):
+                        copied.append(matrices[result])
             with open(os.path.join(out, f"{entry['name']}.{name}.ops"),
                       "w") as fh:
                 fh.write("\n".join(lines) + "\n")
@@ -159,7 +183,9 @@ def main(root: str, out: str, text: bool = False) -> int:
                 "argument_bytes": m.argument_size_in_bytes,
                 "alias_bytes": m.alias_size_in_bytes,
                 "temp_bytes": m.temp_size_in_bytes,
-                "code_bytes": m.generated_code_size_in_bytes}), flush=True)
+                "code_bytes": m.generated_code_size_in_bytes,
+                "weight_copies": len(copied),
+                "weight_copy_bytes": sum(copied)}), flush=True)
     return 0
 
 

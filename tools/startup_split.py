@@ -23,6 +23,9 @@ split by what the program itself recorded before the window opened
   these holds (the interpreter and imports, the backend's start, the
   benchmark's seeded weights, its warm-up requests' device time);
 * ``timeline``: when each part began, in seconds from the process's start;
+* ``engine``: what the ``setup.engine`` span says was built (slots, page
+  size, launch width, pool and state bytes, and the weight matrices the
+  family re-laid for serving: ``relaid_matrices``, ``relaid_bytes``);
 * ``first_calls``: every ``program.first_call`` span with what jax charged
   to it, and ``fresh``: jax's names of the programs compiled fresh;
 * ``window``: backend compiles inside the window, by the account, by the
@@ -113,6 +116,8 @@ def report(outcome: dict, t_start: float) -> dict:
         "ramp_from_s": (setup_s - facts["ramp_s"]
                         if facts.get("ramp_s") is not None else None),
         "window_opened_s": setup_s}
+    out["engine"] = next((dict(s.attrs) for s in spans
+                          if s.name == "setup.engine"), None)
     out["first_calls"] = first_calls(spans)
     out["fresh"] = [{"fun": e.fun, "seconds": e.seconds, "span": e.span}
                     for e in before["events"]
