@@ -12,7 +12,7 @@ their locks through the named factories here:
 **Disabled (the default), the factories return raw ``threading``
 primitives** — no wrapper object, no extra frame, zero steady-state
 overhead; the only cost is one function call at construction
-(``tools/bench_service.py --smoke`` asserts this bypass). Enabled
+(``tests/test_concurrency.py`` asserts this bypass). Enabled
 (:func:`enable`, or ``NNS_TSAN=1`` under pytest — see conftest.py),
 they return instrumented wrappers that
 
@@ -40,8 +40,8 @@ into one ledger via :func:`note_acquire` / :func:`note_release`.
 
 Disabled (the default), every ``note_*`` call is a single module-global
 check and immediate return — no allocation, no lock, nothing on any
-steady-state path (``tools/microbench_overhead.py`` gates this fast
-path at <= 2% like the tracing/profiler/memory legs). Enabled
+steady-state path (``tests/test_lifecycle.py`` holds that the disabled
+ledger is a no-op; its cost is not measured on the chip). Enabled
 (:func:`enable_leakcheck`, or ``NNS_LEAKCHECK=1`` under pytest — see
 conftest.py), each acquisition lands in a per-(kind, key) ledger with
 the acquiring thread and call site; the test fixture asserts ZERO
@@ -72,8 +72,8 @@ wire encode/decode, queue hand-off — do two things under the check:
   data-plane work (ROADMAP item 2) its before/after scoreboard.
 
 Disabled (the default), every hook is a single module-global check and
-immediate return, same contract as tsan-lite/leakcheck (microbench
-gated <= 2%). The test fixture asserts zero NEW violations per test,
+immediate return, same contract as tsan-lite/leakcheck (its cost is not
+measured on the chip). The test fixture asserts zero NEW violations per test,
 and the fused steady-state E2E asserts zero unintended device→host
 bytes per buffer.
 
@@ -95,7 +95,7 @@ still round-trips byte-identically), or a violation — ``hang``
 fixture asserts zero NEW violations, same as the other halves; the
 codec choke points account clean decodes via the same
 ``_note_wire_bytes`` hook the transfer ledger uses (one module-global
-check when off — the microbench wirefuzz leg gates it <= 2%).
+check when off; its cost is not measured on the chip).
 """
 from __future__ import annotations
 
@@ -419,7 +419,7 @@ class _TsanCondition:
 # ---------------------------------------------------------------------------
 
 # module-global fast path: note_acquire/note_release check this and only
-# this when the leak sanitizer is off (the microbench leg gates it)
+# this when the leak sanitizer is off (tests/test_lifecycle.py: a no-op)
 LEAK = False
 
 _leak_lock = threading.Lock()   # guards the ledger tables below
@@ -533,8 +533,8 @@ def leak_report() -> dict:
 # ---------------------------------------------------------------------------
 
 # module-global fast path: note_transfer/no_implicit_d2h check this and
-# only this when the transfer sanitizer is off (the microbench leg
-# gates it)
+# only this when the transfer sanitizer is off (nothing enters the
+# ledger)
 XFER = False
 
 _xfer_lock = threading.Lock()   # guards the transfer tables below
@@ -667,7 +667,7 @@ def xfer_report() -> dict:
 # ---------------------------------------------------------------------------
 
 # module-global fast path: note_frame_event/note_mutant check this and
-# only this when the fuzzer is off (the microbench wirefuzz leg gates it)
+# only this when the fuzzer is off (nothing enters the scoreboard)
 WIREFUZZ = False
 
 #: outcomes that satisfy the wire contract; anything else is a violation

@@ -112,8 +112,8 @@ def _builtin_models() -> Dict[str, Callable[[dict], Callable]]:
         # a model with a KNOWN heavy compile (threefry weight
         # initialization folds at XLA compile time: seconds of compile
         # for a few-KB StableHLO module) — the compile-bound stand-in
-        # the AOT cold-start bench restarts against
-        # (tools/bench_service.py --cold-start): cold pays the full
+        # for a restart against the AOT cache (docs/aot.md; the
+        # contract is tests/test_aot.py): cold pays the full
         # trace+compile, a warm NNS_AOT_CACHE restart loads the
         # artifact. Deterministic: weights derive from fixed PRNG keys.
         import jax
@@ -141,7 +141,7 @@ def _builtin_models() -> Dict[str, Callable[[dict], Callable]]:
         # a model with a KNOWN fixed service time (host callback sleeps
         # inside the jitted computation, so it costs per INVOKE, not per
         # trace): the deterministic capacity limiter the autoscaler
-        # load-ramp chaos/bench legs saturate — ms of real work per
+        # load-ramp chaos leg saturates — ms of real work per
         # request without burning CPU (tools/chaos.py load-ramp)
         import time as _time
 

@@ -58,7 +58,7 @@ cross-subsystem. Three pieces, one contract (near-zero cost when idle):
   the process boundary into one Perfetto document, and serves the SLO
   engine / autoscaler fleet-merged burn windows. ``nns_fleet_*``
   gauges, ``GET /fleet``, ``obs fleet``. :mod:`.promtext` is the shared
-  Prometheus text-format parser the scraper and the benches read
+  Prometheus text-format parser the scraper and the tests read
   ``GET /metrics`` with.
 
 See docs/observability.md for the span model, propagation rules,

@@ -27,7 +27,7 @@ flag in it. Each enters a ``jax.profiler.TraceAnnotation("nns:<name>")``,
 so it lies in the profiler's host plane on the device trace's time base
 whenever a session runs, and lands in the same bounded ring on
 ``time.monotonic``. Budget: a few microseconds a span with no session
-(``tools/microbench_overhead.py`` measures it), no id string, no flight
+(``tests/test_serving_spans.py`` holds it), no id string, no flight
 event.
 
 The compile account (:func:`compile_account`) is the program's own record
@@ -61,7 +61,7 @@ from ..analysis import sanitizer as _san
 from . import flight
 
 # module-global fast path: instrumented call sites check this and only
-# this when tracing is off (the microbench overhead gate measures it)
+# this when tracing is off (tests/test_serving_spans.py: its spans stay gated)
 TRACING = False
 
 # per-process id prefix so traces from different processes (a remote

@@ -45,7 +45,7 @@ promotes canaries across them. This module is the parent-side join:
 
 Cost contract: the fleet plane adds ZERO hot-path cost — everything
 happens on the scrape tick thread (``fleet:<name>``); no data-plane
-hook changes. The microbench disabled-path gates are untouched by
+hook changes, so the disabled paths of the other planes are untouched by
 construction.
 
 Surfaces: ``nns_fleet_*`` gauges (per-replica labeled + fleet rollups)

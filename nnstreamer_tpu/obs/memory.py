@@ -39,8 +39,8 @@ what make placement decisions transfer to real hardware (arxiv
   serving bytes past the watermark is shed with a typed
   ``MemoryPressureError`` at submit time instead of OOM-ing mid-batch.
 
-Cost contract (gated by tools/microbench_overhead.py, same family as
-tracing/profiler/placement): with accounting off every hook is ONE
+Cost contract (same family as tracing/profiler/placement; its cost is
+not measured on the chip): with accounting off every hook is ONE
 module-global check (:data:`ACTIVE`); the static-estimate capture costs
 one extra lowering per segment trace generation and runs only while
 accounting is on (a placement calibration window or an explicit
@@ -66,8 +66,8 @@ from . import flight as obs_flight
 from . import metrics as obs_metrics
 
 # module-global fast path: the fused-dispatch / filter-open hooks check
-# this and only this when accounting is off (the microbench gate
-# measures it)
+# this and only this when accounting is off (tests/test_memory.py: it
+# then records nothing)
 ACTIVE = False
 
 #: env var naming a process-wide device byte budget (bytes) for farms

@@ -27,9 +27,9 @@ Four attribution channels, all riding hooks that already exist:
   (:class:`WindowedSeries`), the substrate the SLO engine
   (:mod:`.slo`) evaluates burn rates from.
 
-Cost contract (same as tracing, gated by tools/microbench_overhead.py):
+Cost contract (same as tracing; its cost is not measured on the chip):
 with profiling off every hook is ONE module-global check
-(:data:`ACTIVE`); enabled overhead is reported, not gated — turning the
+(:data:`ACTIVE`); enabled overhead is not bounded either: turning the
 profiler on is a deliberate trade, and the per-sample cost is two
 timestamps plus one log-bucket insert.
 
@@ -53,7 +53,7 @@ from ..analysis.sanitizer import named_lock
 from . import metrics as obs_metrics
 
 # module-global fast path: queue/fusion/serving/fabric hooks check this
-# and only this when profiling is off (the microbench gate measures it)
+# and only this when profiling is off (tests/test_profiling.py: none recorded)
 ACTIVE = False
 
 

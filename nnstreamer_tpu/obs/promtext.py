@@ -1,10 +1,10 @@
 """Prometheus text-exposition parser: samples, labels, scrape helpers (L7).
 
 Every consumer of a ``GET /metrics`` endpoint in this repo used to carry
-its own ad-hoc line splitter (``tools/bench_fabric.py`` grew the first
-one); this module is the ONE parser they share — the fleet scraper
-(:mod:`.fleet`), the failover/fleet benches, and anything else that
-reads the text format an external Prometheus would.
+its own ad-hoc line splitter; this module is the ONE parser they share:
+the fleet scraper (:mod:`.fleet`), the tests that read a live control
+server's scrape (``tests/test_fleet.py``, ``tests/test_obs.py``), and
+anything else that reads the text format an external Prometheus would.
 
 The parser understands exactly what our renderer (:mod:`.metrics`)
 emits — and the corners the naive splitters got wrong:
@@ -24,7 +24,7 @@ API surface (stdlib only):
 * :func:`sample` — one value out of a text blob, matched by name +
   label SUBSET (the caller names the labels it cares about);
 * :func:`scrape_metric` / :func:`wait_metric` — the HTTP conveniences
-  the benches poll evict/readmit counters with.
+  a scrape loop polls a counter with (evictions, readmissions).
 """
 from __future__ import annotations
 
@@ -146,7 +146,7 @@ def samples_named(text: str, name: str) -> List[Sample]:
     return [s for s in parse_samples(text) if s[0] == name]
 
 
-# -- HTTP conveniences (the bench scrape loop) --------------------------------
+# -- HTTP conveniences (a scrape loop's) -------------------------------------
 
 def fetch(endpoint: str, timeout: float = 5.0) -> str:
     """``GET <endpoint>/metrics`` → exposition text. ``endpoint`` is the
@@ -171,8 +171,8 @@ def wait_metric(endpoint: str, name: str, labels: Dict[str, str],
                 want: float, timeout: float = 15.0,
                 poll_s: float = 0.02) -> Optional[float]:
     """Poll the endpoint until ``name`` reaches ``want``; returns the
-    observation time (``time.monotonic()``) or None on timeout — the
-    benches' evict/readmit clock reads the same scrape surface a
+    observation time (``time.monotonic()``) or None on timeout — an
+    evict/readmit clock that reads the same scrape surface a
     monitoring stack would."""
     import http.client
 

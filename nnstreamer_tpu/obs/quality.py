@@ -48,8 +48,8 @@ the profiler, built on the same keying and persistence machinery:
   ``QualityGateError`` when the canary's output sketch diverges from
   the primary's (service/models.py).
 
-Cost contract (gated by tools/microbench_overhead.py, same family as
-tracing/profiler/memory): with taps off every hook is ONE module-global
+Cost contract (same family as tracing/profiler/memory; its cost is not
+measured on the chip): with taps off every hook is ONE module-global
 check (:data:`ACTIVE` on the fused path, ``trace.ACTIVE`` on the pad
 path); sampling cost is one small reduction every ``SAMPLE_EVERY``
 buffers per edge. Taps only *read* tensors — byte parity of a sampled
@@ -74,7 +74,7 @@ from . import metrics as obs_metrics
 from .profile import QuantileDigest
 
 # module-global fast path: the fused-dispatch / serving hooks check this
-# and only this when the taps are off (the microbench gate measures it);
+# and only this when the taps are off (tests/test_quality.py: none recorded);
 # the pad tap additionally hides behind trace.ACTIVE (tracer install)
 ACTIVE = False
 

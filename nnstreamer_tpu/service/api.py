@@ -140,7 +140,7 @@ def _make_handler(manager: ServiceManager):
 
         def _reply_metrics(self) -> None:
             """GET /metrics: Prometheus text, not JSON — scrapers
-            (tools/bench_fabric.py, a real Prometheus) read it as-is."""
+            (obs/promtext.py, a real Prometheus) read it as-is."""
             try:
                 body = obs_metrics.render().encode()
             except Exception as e:  # noqa: BLE001 - endpoint must answer

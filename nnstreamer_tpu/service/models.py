@@ -258,7 +258,7 @@ class ModelSlots:
                              rate: float, rounds: int) -> None:
         """Record a (draft, target) pair's observed draft-acceptance
         rate over ``rounds`` speculative rounds (the serving plane's
-        ``spec_acceptance_rate`` snapshot, or a bench canary). The most
+        ``spec_acceptance_rate`` snapshot, or a canary's driver). The most
         recent observation per version is what
         :meth:`promote_canary`'s acceptance gate arbitrates against."""
         with self._lock:

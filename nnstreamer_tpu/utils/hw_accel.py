@@ -43,7 +43,7 @@ def pallas_interpret(platform: str) -> bool:
 def enable_compilation_cache() -> str:
     """Turn on jax's persistent compilation cache for this process and
     return the directory in use. Process entry points call this before
-    their first compile (the CLI, ``chip_smoke.py``, the bench scripts);
+    their first compile (the CLI, ``chip_smoke.py``, ``benchmark/run.py``);
     importing the library never does.
 
     ``JAX_COMPILATION_CACHE_DIR`` set: jax already honours it, the cache

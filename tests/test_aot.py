@@ -69,6 +69,22 @@ class TestExport:
             np.testing.assert_allclose(np.asarray(out[0]), 2.0)
         assert len(traces) == 1  # one compilation across ALL buckets
 
+    def test_plain_jit_traces_once_a_bucket(self):
+        """What the artifact retires (NNL008's recompile storm): the same
+        model under a plain ``jax.jit`` traces anew at every bucket."""
+        import jax
+
+        traces = []
+
+        def model(x):
+            traces.append(1)
+            return (x * 2.0,)
+
+        jitted = jax.jit(model)
+        for bucket in (1, 2, 4, 8, 16):
+            jitted(np.ones((bucket, 8), np.float32))
+        assert len(traces) == 5
+
     def test_compatibility_contract(self):
         blob, _meta, _ = aot.export_stage(
             lambda x: (x + 1,), (np.ones((2, 4), np.float32),), poly=True)

@@ -242,6 +242,68 @@ class TestPlannerByteCap:
         assert _stage_bytes(art, [_El()]) == 128
         assert _stage_bytes(None, [_El()]) == 0
 
+    @staticmethod
+    def _referee(stages, n_dev, budget):
+        """(least makespan among the assignments that fit, least budget
+        under which any fits), by brute force over the plan's own table."""
+        import itertools
+
+        best = tightest = None
+        for combo in itertools.product(range(n_dev), repeat=len(stages)):
+            load, mem = [0.0] * n_dev, [0] * n_dev
+            for st, dev in zip(stages, combo):
+                load[dev] += st.cost_ms
+                mem[dev] += st.bytes
+            if tightest is None or max(mem) < tightest:
+                tightest = max(mem)
+            if max(mem) <= budget and (best is None or max(load) < best):
+                best = max(load)
+        return best, tightest
+
+    def test_a_captured_artifact_and_a_stated_budget_cap_the_plan(self):
+        """The whole loop a deployment runs: one profiled, accounted run
+        captures the artifact; a planner given ONLY that and an HBM
+        budget (no stage-count knob) makes a plan that fits and is the
+        latency optimum among the assignments that fit — under a
+        generous budget and under the tightest one that admits a plan,
+        where the cap binds and one device cannot hold every stage."""
+        import jax
+
+        mm = "! " + MATMUL
+        four = (SRC + f"! {ADD}{mm * 4}! queue name=q0 ! {ADD}{mm * 2}"
+                f"! queue name=q1 ! {ADD}{mm * 2}! queue name=q2 "
+                f"! {ADD}{mm}! tensor_sink name=out max-stored=1")
+        obs_profile.reset()
+        obs_profile.start()
+        obs_memory.start()
+        try:
+            pipe = parse_launch(four.format(n=40))
+            pipe.run(timeout=120)
+        finally:
+            obs_profile.stop()
+            obs_memory.stop()
+        art = obs_profile.ProfileArtifact.capture(pipe)
+        obs_profile.reset()
+        assert art.memory, "the accounted run captured no memory section"
+        devices = jax.devices()[:2]
+
+        def fits_and_is_the_optimum(budget):
+            plan = Planner(devices=devices, hbm_budget_bytes=budget).plan(
+                parse_launch(four.format(n=40)), artifact=art)
+            best, tightest = self._referee(plan.stages, 2, budget)
+            assert plan.balance["byte_feasible"]
+            assert plan.balance["max_device_bytes"] <= budget
+            assert plan.balance["max_stage_ms"] == pytest.approx(
+                best, abs=1e-6)
+            return plan, tightest
+
+        total = sum(c.get("total_bytes", 0) for c in art.memory.values())
+        plan, tightest = fits_and_is_the_optimum(2 * total)
+        assert plan.source == "profile"
+        assert len(plan.stages) == 4 and all(s.bytes > 0 for s in plan.stages)
+        plan, _ = fits_and_is_the_optimum(tightest)
+        assert plan.balance["stage_bytes_total"] > tightest  # the cap binds
+
     def test_auto_budget_from_env(self, monkeypatch):
         monkeypatch.setenv(obs_memory.BUDGET_ENV, "4096")
         budgets = Planner(devices=[None, None]).device_budgets()

@@ -5,8 +5,8 @@ tiny shapes hide layout/donation/sharding bugs that only appear when
 tensors have real extents. This suite runs a >=25M-parameter transformer
 on the virtual 8-device mesh: one sharded train step per parallelism
 mode, asserting the sharded loss matches the single-device loss within
-tolerance, and printing per-mode step times (the same numbers
-tools/bench_multichip.py records for BENCH_SUITE rows).
+tolerance, and printing per-mode step times (CPU times of a virtual
+mesh: not measured on the chip, and no benchmark cell spans chips yet).
 """
 import time
 

@@ -264,6 +264,36 @@ class TestMetricsRegistry:
         finally:
             pool.close()
 
+    def test_failover_counters_reach_the_scrape(self):
+        """What an external monitor clocks a failover by: the pool's
+        eviction and readmission counters and the replica's up gauge are
+        on the plane as the pool counts them, read by the one parser."""
+        from nnstreamer_tpu.obs import promtext
+        from nnstreamer_tpu.service.fabric import ReplicaPool
+
+        # no probe comes due inside the test: readmission is ours to make
+        pool = ReplicaPool("obs-failover-pool", CAPS,
+                           quarantine_base_s=3600.0)
+        labels = {"pool": "obs-failover-pool"}
+
+        def read(name, **more):
+            return promtext.sample(obs_metrics.render(), name,
+                                   dict(labels, **more))
+
+        try:
+            replica = pool.add_endpoint("127.0.0.1", 9, replica_id="r0")
+            assert read("nns_fabric_evictions_total") == 0
+            assert read("nns_fabric_replica_up", replica="r0") == 1
+            pool.evict("r0", "test")
+            assert read("nns_fabric_evictions_total") == 1
+            assert read("nns_fabric_readmissions_total") == 0
+            assert read("nns_fabric_replica_up", replica="r0") == 0
+            pool._readmit(replica)
+            assert read("nns_fabric_readmissions_total") == 1
+            assert read("nns_fabric_replica_up", replica="r0") == 1
+        finally:
+            pool.close()
+
 
 # ---------------------------------------------------------------------------
 # chrometrace fixes (satellite)

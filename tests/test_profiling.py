@@ -714,7 +714,7 @@ class TestSurfaces:
                                                      tmp_path):
         from nnstreamer_tpu.__main__ import main
 
-        # artifact emission via the CLI (what PROFILE_r08.json is)
+        # artifact emission via the CLI (a ProfileArtifact as JSON)
         out = tmp_path / "art.json"
         rc = main(["obs", "profile", "--launch", CHAIN3.format(n=24),
                    "--out", str(out), "--model-version", "cli-v1"])

@@ -1,9 +1,9 @@
 """Host-runtime throughput proof (VERDICT r02 weak #1 / next #2).
 
-The BASELINE target is >=2000 fps on TPU. The device does the FLOPs, but
+BASELINE's north star was >=2000 fps on TPU. The device does the FLOPs, but
 the HOST runtime must batch, queue, dispatch, and sink frames at that rate
 or it becomes the ceiling no matter how fast the chip is. This suite runs
-the EXACT bench topology (bench.py: tensor_src -> tensor_aggregator ->
+the README stream line's topology (tensor_src -> tensor_aggregator ->
 queue -> tensor_filter -> queue -> tensor_sink) with an instant identity
 backend, so every measured microsecond is framework overhead — a
 device-excluded proof that the plumbing sustains the target rate.

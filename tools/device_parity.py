@@ -35,8 +35,8 @@ def main() -> None:
     _log(f"jax platform: {platform}")
     # Parity is a correctness check: pin full-f32 matmul/conv passes. On
     # TPU the default f32 precision runs bf16 MXU passes — measured r5:
-    # 2/64 top-1 flips on near-tie frames vs the CPU interpreter. Perf
-    # rows (bench_suite) keep the default; only parity pays for exactness.
+    # 2/64 top-1 flips on near-tie frames vs the CPU interpreter. The
+    # benchmark's cells keep the default; only parity pays for exactness.
     jax.config.update("jax_default_matmul_precision", "highest")
 
     from nnstreamer_tpu.utils.parity import (
