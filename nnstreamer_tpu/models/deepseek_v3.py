@@ -206,6 +206,7 @@ class DeepseekV3Family:
     passes = 1             # the stack once a token
     counters = moe_dropless.COUNTERS
     state_lines = ()       # no layer keeps a state a sequence
+    drafts = 0             # no layer of it drafts a token
     serves_verify = False  # speculative verification: not in this family yet
     chunk_precision = "highest"    # f32 queries over a bf16 pool
 

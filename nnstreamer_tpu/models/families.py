@@ -16,7 +16,8 @@ A family says:
   gives a window layer's pages back behind the window. ``gpt`` and
   ``deepseek_v3`` say "all full"; ``mellum`` (``models/mellum.py``) has
   full and window layers; ``jamba`` (``models/jamba.py``) full and state
-  layers; ``ouro`` (``models/ouro.py``) says "all full".
+  layers; ``ouro`` (``models/ouro.py``) says "all full"; ``exaone_moe``
+  (``models/exaone_moe.py``) has full and window layers.
 * ``passes`` — how many times a token runs the stack, with the same
   weights every time. ``gpt``, ``deepseek_v3``, ``mellum`` and ``jamba``
   say 1: the stack once, then ``head``; nothing closes their one pass and
@@ -43,6 +44,38 @@ A family says:
     its ``c_t`` first reached ``early_exit_threshold``, the last pass's if
     none; the final norm is in them already, so its ``head`` is the
     output matrix alone).
+* ``drafts`` — how many tokens a layer of the family drafts a pass. The
+  five elder families say 0: their decode program is ``_step``, one token
+  a slot. ``exaone_moe`` says 1 (its MTP layer, ``num_nextn_predict_layers``)
+  and its decode program is the *round* (``serving/lm_engine.py``
+  ``_round``): the engine carries ``[token, draft, position]`` a slot on
+  the device, runs both tokens through the stack at ``position, position +
+  1`` (two queries a slot in every attention layer, the second seeing the
+  first's line), accepts the draft where the stack's best token after the
+  first row is the draft, runs the drafting block on the committed rows
+  and takes the next draft from the last of them; a prefill launch runs
+  the drafting block too, shifted by one token, and leaves the first
+  draft. The engine keeps ``drafts`` more cache layers of ``draft_kind``
+  (``"full"``) behind the stack's for the drafting block's lines. What a
+  family that drafts owes for it, beside ``project``, ``ffn`` and the two
+  ``step_*`` finishes taking ``K`` rows a slot (``q (S, K, H, head_dim)``
+  → ``(S, K * H, line)`` → ``(S, K, D)``):
+
+  - ``mtp_input(p, x, toks) -> u`` — the stack's output ``x (B, Q, D)`` at
+    some positions (before the output norm) and ``toks (B, Q)``, the
+    tokens after them → the drafting block's input (``exaone_moe``:
+    ``[RMSNorm(Emb(t)) ; RMSNorm(x)] W_eh``). Under ``mtp.embed``;
+  - ``mtp_block(p) -> blk`` — the drafting block's parameters: one block
+    of ``draft_kind`` that ``project``, the attention forms and ``ffn``
+    take like any of ``blocks(p)``. The engine runs it under
+    ``mtp.block`` (the family's own scopes nest inside);
+  - ``mtp_head(p, x) -> logits`` — the block's output rows ``x (n, D)`` →
+    their scores of the token two positions on (``exaone_moe``: its own
+    output norm, the main model's head). Under ``mtp.head``.
+
+  A family that drafts has one pass and no state layer, and
+  ``serves_verify`` stays ``False``: a host-side draft beside its own is
+  refused (``models/lm_serving.py``).
 * ``state_lines`` — what a *slot* keeps in every state layer, whatever the
   sequence's length: one ``(shape, dtype)`` per array, a dtype of ``None``
   the cache's (``jamba``: the conv's last inputs ``(3 * 5120,)``, flat, and
@@ -90,6 +123,14 @@ A family says:
   guess at the compiler's layout assignment: the same compiler prefetches
   the same kind of matrix into fast memory in ``mellum`` and ``kanana``,
   and that is the matrix's one read.
+* ``vocab`` — the vocabulary served: ids ``0 .. vocab - 1``, what a request's
+  tokens are checked against and what ``head`` scores. A family may hold a
+  slice of a larger one (``exaone_moe``: ``vocab_held (first, count)``, the
+  rows of the embedding and the columns of the head that this chip holds
+  of a vocabulary divided over chips, as ``experts_held`` is its share of
+  the routed experts): then ``vocab`` is ``count``, the ids are the
+  slice's own, logits, argmax and the served ids are over it, and nothing
+  stands in for the absent rows.
 * ``embed(p, toks, pos)`` — tokens at positions → float32 activations.
 * ``blocks(p)`` — the per-layer parameter groups, in order.
 * ``project(blk, x, pos, kind)`` — from the input ``x (B, Q, D)`` of a
@@ -141,6 +182,7 @@ class GroupedQueryLines:
 
     chunk_precision = "highest"    # f32 queries over a bf16 pool
     passes = 1                     # the stack once a token
+    drafts = 0                     # no layer of it drafts a token
 
     def stored(self, params):
         return params              # served as they come
@@ -203,6 +245,7 @@ class GPTFamily:
     attention_scopes = {"full": "attention"}
     window = None          # every layer sees the whole context
     passes = 1             # the stack once a token
+    drafts = 0             # no layer of it drafts a token
     counters = ()          # nothing an expert layer would count
     state_lines = ()       # no layer keeps a state a sequence
     serves_verify = True   # speculative verification (``_verify``)
@@ -325,6 +368,7 @@ def _families() -> tuple:
     """``(configuration type, family)`` for every family there is: the one
     table :func:`family_of` dispatches on."""
     from .deepseek_v3 import DeepseekV3Config, DeepseekV3Family
+    from .exaone_moe import ExaoneMoeConfig, ExaoneMoeFamily
     from .jamba import JambaConfig, JambaFamily
     from .mellum import MellumConfig, MellumFamily
     from .ouro import OuroConfig, OuroFamily
@@ -333,7 +377,8 @@ def _families() -> tuple:
             (DeepseekV3Config, DeepseekV3Family),
             (MellumConfig, MellumFamily),
             (JambaConfig, JambaFamily),
-            (OuroConfig, OuroFamily))
+            (OuroConfig, OuroFamily),
+            (ExaoneMoeConfig, ExaoneMoeFamily))
 
 
 def family_of(cfg):

@@ -340,10 +340,18 @@ class _LMServingEntry:
                 f"have to roll its state layers' state back to the accepted "
                 f"token, and no snapshot of it is kept); build it without "
                 f"draft=")
+        if draft is not None and fam.drafts:
+            raise NotImplementedError(
+                f"lm_serving: the {fam.name} family drafts on the device "
+                f"(its own layer, verified by the paged engine's round); a "
+                f"host-side draft beside it is not served: build it without "
+                f"draft=")
         if draft is not None and not fam.serves_verify:
             raise NotImplementedError(
-                f"lm_serving: speculative verification (_verify) does not "
-                f"serve the {fam.name} family yet; build it without draft=")
+                f"lm_serving: speculative verification (_verify: one "
+                f"full-kind block table, one pass, keys and values gathered "
+                f"a head) does not serve the {fam.name} family; build it "
+                f"without draft=")
         from ..obs import context as obs_context
 
         # start-up's spans (docs/observability.md): the weights in the

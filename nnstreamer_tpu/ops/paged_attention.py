@@ -16,6 +16,15 @@ latent family by construction). :func:`paged_line_attention` is that op:
   looks back a window only); ``None`` is 0 for every slot. Pages wholly
   below it are not read: their table entries may name any row.
 
+A verify round has several queries a slot (``queries=K``, ``serving/
+lm_engine.py`` ``_round``): ``q (S, K * H, Wk)``, row ``r * H + n`` head
+``n`` of the slot's ``r``-th query, which stands ``r`` positions after the
+first and sees ``lengths + r`` positions; ``starts (S, K)`` then gives each
+query's first position. The lines of all ``K`` positions are in the pool
+before the call, so a later query sees an earlier one's line and no earlier
+one a later's. ``K = 1`` is the step, and compiles to the kernel it had
+before rounds existed.
+
 It returns ``(S, H, Wv)`` float32: softmax(q · lines) · lines, zeros for an
 empty slot. Float32 queries, scores, softmax and weighted sum over a
 bfloat16 pool: the meaning of ``Precision.HIGHEST`` with nothing lowered.
@@ -86,15 +95,16 @@ BLOCK_BYTES = 1280 * 1024
 _MASKED = -1e30
 
 
-def paged_line_attention(q, kpool, vpool, rows, lengths, scale, starts=None):
+def paged_line_attention(q, kpool, vpool, rows, lengths, scale, starts=None,
+                         queries=1):
     """The step's attention (module docstring), in the form this platform
     runs: Mosaic on a TPU, the plain form where the kernel would be
     interpreted."""
     if hw_accel.pallas_interpret(jax.default_backend()):
         return plain_line_attention(q, kpool, vpool, rows, lengths, scale,
-                                    starts)
+                                    starts, queries)
     return kernel_line_attention(q, kpool, vpool, rows, lengths, scale,
-                                 starts)
+                                 starts, queries=queries)
 
 
 def gathered_lines(pool, rows):
@@ -109,16 +119,26 @@ def gathered_lines(pool, rows):
     return lines.reshape(rows.shape[0], -1, pool.shape[-1])
 
 
-def plain_line_attention(q, kpool, vpool, rows, lengths, scale, starts=None):
+def plain_line_attention(q, kpool, vpool, rows, lengths, scale, starts=None,
+                         queries=1):
     """Gather, mask, softmax: every slot's whole block table."""
     exact = jax.lax.Precision.HIGHEST
     ck = gathered_lines(kpool, rows)
     cv = ck if vpool is kpool else gathered_lines(vpool, rows)
     att = jnp.einsum("shj,scj->shc", q, ck, precision=exact) * scale
-    visible = jnp.arange(ck.shape[1])[None, :] < lengths[:, None]
-    if starts is not None:
-        visible &= jnp.arange(ck.shape[1])[None, :] >= starts[:, None]
-    att = jax.nn.softmax(jnp.where(visible[:, None, :], att, _MASKED), axis=-1)
+    if queries == 1:
+        visible = jnp.arange(ck.shape[1])[None, :] < lengths[:, None]
+        if starts is not None:
+            visible &= jnp.arange(ck.shape[1])[None, :] >= starts[:, None]
+        visible = visible[:, None, :]
+    else:
+        # (S, K, ctx), then every head of a query alike
+        at = jnp.arange(ck.shape[1])
+        visible = at < (lengths[:, None] + jnp.arange(queries))[..., None]
+        if starts is not None:
+            visible &= at >= starts[..., None]
+        visible = jnp.repeat(visible, q.shape[1] // queries, axis=1)
+    att = jax.nn.softmax(jnp.where(visible, att, _MASKED), axis=-1)
     out = jnp.einsum("shc,scj->shj", att, cv, precision=exact)
     return jnp.where((lengths > 0)[:, None, None], out, 0.0)
 
@@ -275,7 +295,7 @@ def _walk(q, kpool, vpool, rows, start, n_valid, *, scale, span, precision,
 
 
 def _kernel(rows_ref, meta_ref, q_ref, *refs, S, NB, PB, sizes, H, scale,
-            shared):
+            shared, K=1, Hq=None):
     if shared:
         k_hbm, o_ref, kbuf, sems, q3_ref, p3_ref, m_ref, l_ref, state = refs
         v_hbm, vbuf = k_hbm, kbuf
@@ -373,11 +393,28 @@ def _kernel(rows_ref, meta_ref, q_ref, *refs, S, NB, PB, sizes, H, scale,
             preferred_element_type=jnp.float32)          # (3H, T)
         sc = sc3[:H] + sc3[H:2 * H] + sc3[2 * H:]
         at = at0 + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        sc = jnp.where((at >= start) & (at < length), sc, _MASKED)
+        if K == 1:
+            sc = jnp.where((at >= start) & (at < length), sc, _MASKED)
+        else:
+            # row r * Hq + n is the slot's r-th query: one position more
+            # visible a query, from that query's own first position
+            row = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0)
+            lo = jnp.full(sc.shape, start, jnp.int32)
+            hi = jnp.full(sc.shape, length, jnp.int32)
+            for r in range(1, K):
+                later = row >= r * Hq
+                lo = jnp.where(later, meta_ref[6 * S + 1 + (r - 1) * S + s],
+                               lo)
+                hi = hi + later.astype(jnp.int32)
+            sc = jnp.where((at >= lo) & (at < hi), sc, _MASKED)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
-        # every block holds a visible position, so m_new is a score and
-        # a masked one's weight is exp(-1e30 - m_new) == 0
+        # every block holds a position the slot's first query sees, so its
+        # m_new is a score and a masked one's weight is exp(-1e30 - m_new)
+        # == 0. A later query may see nothing in the walk's first block
+        # (its first position lies a page on): it weighs that block's
+        # lines by 1 until the first score it sees wipes them (alpha 0),
+        # and it sees its own position before the walk ends
         p = jnp.exp(sc - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
@@ -426,10 +463,12 @@ _AHEAD = 2
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("scale", "pages_per_block", "interpret"))
+                   static_argnames=("scale", "pages_per_block", "interpret",
+                                    "queries"))
 def _call(q, kpool, vpool, rows, lengths, starts, *, scale,
-          pages_per_block, interpret):
+          pages_per_block, interpret, queries=1):
     shared = vpool is None
+    K = queries
     S, H0, Wk = q.shape
     NB = rows.shape[1]
     pg = kpool.shape[1]
@@ -444,9 +483,20 @@ def _call(q, kpool, vpool, rows, lengths, starts, *, scale,
     if H != H0:
         q = jnp.pad(q, ((0, 0), (0, H - H0), (0, 0)))
     lengths = jnp.clip(lengths, 0, NB * pg)
-    # a live slot sees a position: every block it visits holds one
-    starts = (jnp.zeros_like(lengths) if starts is None
-              else jnp.clip(starts, 0, jnp.maximum(lengths - 1, 0)))
+    if K == 1:
+        # a live slot sees a position: every block it visits holds one
+        starts = (jnp.zeros_like(lengths) if starts is None
+                  else jnp.clip(starts, 0, jnp.maximum(lengths - 1, 0)))
+        last, later = lengths, ()
+    else:
+        # the walk runs from the first query's first position to the last
+        # query's last; each query's own bounds ride in ``meta``
+        seen = lengths[:, None] + jnp.arange(K, dtype=lengths.dtype)
+        starts = (jnp.zeros_like(seen) if starts is None
+                  else jnp.clip(starts, 0, jnp.maximum(seen - 1, 0)))
+        last = jnp.where(lengths > 0,
+                         jnp.minimum(lengths + (K - 1), NB * pg), 0)
+        starts, later = starts[:, 0], tuple(starts[:, 1:].T)
     live = lengths > 0
     idx = jnp.arange(S, dtype=jnp.int32)
     # an empty slot's program touches nothing: its query and output blocks
@@ -457,8 +507,8 @@ def _call(q, kpool, vpool, rows, lengths, starts, *, scale,
     after = jax.lax.cummin(jnp.where(live, idx, S), reverse=True)
     next_live = jnp.concatenate([after[1:], jnp.full((1,), S, jnp.int32)])
     meta = jnp.concatenate([lengths, stay, next_live, starts,
-                            *visible_pages(lengths, starts, pg),
-                            after[:1]]).astype(jnp.int32)
+                            *visible_pages(last, starts, pg),
+                            after[:1], *later]).astype(jnp.int32)
 
     def block(width):
         return pl.BlockSpec((None, H, width),
@@ -469,7 +519,7 @@ def _call(q, kpool, vpool, rows, lengths, starts, *, scale,
             for p in pools]
     out = pl.pallas_call(
         functools.partial(_kernel, S=S, NB=NB, PB=PB, sizes=sizes, H=H,
-                          scale=scale, shared=shared),
+                          scale=scale, shared=shared, K=K, Hq=H0 // K),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(S,),
@@ -495,7 +545,8 @@ def _call(q, kpool, vpool, rows, lengths, starts, *, scale,
 
 
 def kernel_line_attention(q, kpool, vpool, rows, lengths, scale, starts=None,
-                          *, pages_per_block=None, interpret=False):
+                          *, queries=1, pages_per_block=None,
+                          interpret=False):
     """The Pallas kernel (module docstring). ``pages_per_block`` is derived
     from the line's bytes unless a test or a stand-alone timing names it;
     ``interpret`` runs the kernel through the Pallas interpreter (tests on
@@ -506,4 +557,4 @@ def kernel_line_attention(q, kpool, vpool, rows, lengths, scale, starts=None,
         pages_per_block = min(rows.shape[1], 1 << (fit.bit_length() - 1))
     return _call(q, kpool, None if vpool is kpool else vpool, rows, lengths,
                  starts, scale=float(scale), pages_per_block=int(pages_per_block),
-                 interpret=interpret)
+                 interpret=interpret, queries=int(queries))

@@ -15,7 +15,8 @@ import numpy as np
 
 class DecodeEngine:
     """Base of every engine a ``DecodeScheduler`` drives
-    (``lm_engine.PagedLMEngine``, ``speculative.SpeculativeLMEngine``)."""
+    (``lm_engine.PagedLMEngine``, alone or with its own round, and
+    ``speculative.SpeculativeLMEngine``)."""
 
     slots: int                 # fixed batch capacity
     compile_count = 0          # programs traced so far
@@ -26,7 +27,11 @@ class DecodeEngine:
     #                            is the first of them (below)
     # a burst engine (1..K tokens a slot a pass) defines ``step_tokens() ->
     # list[list[int]]``, which the scheduler then calls in place of ``step``,
-    # with ``acceptance_rate()`` and ``spec_rounds|proposed|accepted``
+    # with ``acceptance_rate()`` and ``spec_rounds|proposed|accepted``: the
+    # paged engine itself where its family drafts on the device (its
+    # ``_round``: 1 or 2 tokens a slot, ``[]`` for a slot that was not in
+    # the round whose tokens came home), and ``SpeculativeLMEngine`` around
+    # a paged engine with a host-side draft
     step_tokens = None
 
     def validate(self, tokens: np.ndarray, steps: int) -> None:

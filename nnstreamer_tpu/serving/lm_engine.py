@@ -22,6 +22,11 @@ Join protocol (``engine.DecodeEngine``, driven by ``DecodeScheduler``):
   host routes, retires and admits (``PagedLMEngine.step``); ``collect()``
   brings home what is in flight for a caller that wants each step's own
   tokens;
+* ``step_tokens()``: where the family drafts (``family.drafts``), the pass
+  is a *round* in place of a step: the draft verified over two positions a
+  slot and the next one drafted, one device call, 1 or 2 tokens a slot
+  (``PagedLMEngine._step_tokens``; ``step`` then raises). One round in
+  flight, as a step is;
 * ``release(slot)`` returns the slot's pages to the pool.
 
 Greedy (argmax) decoding only — sampling policy belongs to the caller's
@@ -194,6 +199,42 @@ class PagedLMEngine(DecodeEngine):
       programs it had before passes existed. Prefix sharing serves such a
       family as any other (a page carries every pass-layer's lines);
       speculative verification does not.
+    * **rounds** — a family may draft (``family.drafts``: ``exaone_moe``'s
+      MTP layer, one token): then the decode program is ``_round`` and the
+      engine is its own burst engine (``step_tokens``, ``acceptance_rate``,
+      ``spec_rounds | proposed | accepted | emitted``; no wrapper, no
+      host-side draft). The device carries ``[token, draft, position]`` a
+      slot from round to round. A round (a) runs ``[token, draft]`` at
+      ``position, position + 1`` through the stack, writing both positions'
+      lines into every kind's pool through its own table and attending
+      with two queries a slot (``paged_line_attention(queries=2)``: the
+      second row sees the first's line, inside the window on a window
+      layer; nothing of ``max_seq`` positions is gathered); (b) takes the
+      stack's best token after each row, ``c0, c1``; the draft holds iff
+      ``c0 == draft`` and the position budget allows two; emits ``[c0]`` or
+      ``[draft, c1]``; (c) runs the drafting block (``family.mtp_input |
+      mtp_block | mtp_head``) on the committed rows, ``(x at position,
+      c0)`` and, where the draft held, ``(x at position + 1, c1)``, writes
+      their lines into the cache layer kept for it behind the stack's
+      (``kind_layers[draft_kind]`` counts it), and takes the next draft
+      from the last committed row; (d) advances the position by 1 or 2. A
+      rejected position's lines stay hidden behind the position (the
+      ``<= position`` rule of every attention form) until the next round
+      overwrites them, and a window layer's pages go back behind the
+      *committed* position only. ``_prefill_chunk`` also runs the drafting
+      block, shifted by one token (row ``i`` pairs with token ``i + 1``, the
+      prompt's last row with the first token), computes the head on the
+      launch's last row alone and leaves ``[first token, first draft]``.
+      The round's answer is ``[n_emit | c0 c1 | next draft]`` a slot in
+      one small array, pulled like a step's tokens, the expert layers'
+      counts behind it (the stack's and the drafting block's summed). The
+      host dispatches the next round before it has read the last one's
+      answer: its ``_pos`` and ``_left`` are then bounds (a round in flight
+      counted as one token a slot) that ``collect`` makes exact, so it
+      keeps one position more writable a slot and counts ``pages_read |
+      fetched`` from the bound. ``draft=False`` builds the engine as if
+      the family drafted nothing (``_step``, no drafting block's cache
+      layer): the stream a test holds the round's to, token for token.
     * **pool layout** — per kind of layer and kind of line ``(layers of
       the kind * (pages+1), page, width)`` device arrays: one row per page
       of one layer, one contiguous ``width`` line per token, so row-major
@@ -255,7 +296,8 @@ class PagedLMEngine(DecodeEngine):
     def __init__(self, cfg, params, slots: int = 4, page_size: int = 16,
                  pages=None, chunk: int = 32,
                  share_prefixes: bool = True,
-                 max_positions: Optional[int] = None):
+                 max_positions: Optional[int] = None,
+                 draft: bool = True):
         if slots < 1:
             raise ValueError(f"slots={slots} must be >= 1")
         import functools
@@ -307,6 +349,18 @@ class PagedLMEngine(DecodeEngine):
                 f"lm_engine: the {fam.name} family runs its stack "
                 f"{fam.passes} times a token and has state layers; a state "
                 f"a pass of a layer is not kept")
+        # the tokens a layer of the family drafts a pass (``family.drafts``:
+        # its MTP layer's one). ``draft=False`` builds the engine as if the
+        # family drafted nothing, ``_step`` and no MTP line: what a test
+        # holds the round's stream to, not a serving knob
+        D = self.drafts = fam.drafts if draft else 0
+        if D > 1 or (D and (fam.passes > 1 or fam.state_lines)):
+            raise NotImplementedError(
+                f"lm_engine: the {fam.name} family drafts {D} tokens a pass"
+                f"{', runs its stack several times' * (fam.passes > 1)}"
+                f"{', keeps a state a slot' * bool(fam.state_lines)}; the "
+                f"round verifies one draft of a family with one pass and no "
+                f"state layer")
         self.cfg = cfg
         self.family = fam
         self.kinds = kinds
@@ -378,6 +432,10 @@ class PagedLMEngine(DecodeEngine):
         T = self.passes = fam.passes
         stack_of = dict(layers_of)
         layers_of = {kind: T * n for kind, n in layers_of.items()}
+        if D:
+            # the drafting block keeps lines too: one more cache layer of
+            # its kind, behind the stack's
+            layers_of[fam.draft_kind] += D
         self.kind_layers = layers_of
         self.pass_layers = sum(layers_of.values())
         # rows of one layer: its null page 0, then the kind's pages
@@ -431,11 +489,27 @@ class PagedLMEngine(DecodeEngine):
         # token is in flight is left out of the next dispatch
         self._left = np.zeros((slots,), np.int64)
         self._tok_dev = jnp.asarray(self._tok)  # an upload: no program
+        if D:
+            # a round's carry is ``[token, draft, position]`` a slot, all
+            # on the device: a round leaves it there for the next, so the
+            # next is dispatched before the host knows what this one
+            # accepted. ``_pos`` and ``_left`` are then bounds (a round in
+            # flight counted as one token a slot) that ``collect`` makes
+            # exact; ``next_draft`` is the draft the host last saw of a slot
+            self.next_draft = np.zeros((slots,), np.int32)
+            self._join = np.full((slots, 3), -1, np.int32)
+            self._tok_dev = jnp.zeros((slots, 3), jnp.int32)
+            self.step_tokens = self._step_tokens
+        # running sums over rounds (a burst engine's account,
+        # ``serving/engine.py``): rounds dispatched, drafts proposed (one a
+        # live slot a round), drafts the stack agreed with, tokens emitted
+        self.spec_rounds = self.spec_proposed = self.spec_accepted = 0
+        self.spec_emitted = 0
         # the step in flight, ``(its tokens on the device, the slots it
         # stepped)``, and the tokens a drain brought home early, kept for
         # the next ``step()`` to return (``-1``: none for this slot)
         self._flight: Optional[tuple] = None
-        self._kept = np.full((slots,), -1, np.int32)
+        self._kept = np.full((slots, 1 + D) if D else (slots,), -1, np.int32)
         # running sums (``counters``): steps dispatched while another's
         # tokens were still on the device, drains that ``preempt``,
         # ``restore``, ``verify_commit`` or ``close`` forced, and slot-steps
@@ -474,6 +548,20 @@ class PagedLMEngine(DecodeEngine):
 
         NC = len(fam.counters)
 
+        def _attention(kind, row0, blk, x, pos, dests, offs, pools, unbatch,
+                       attend, write):
+            # one attention layer of ``kind`` whose rows start at ``row0``:
+            # write the new lines into its kind's arrays, attend over the
+            # slots' lines; what comes back is the residual stream
+            k = kinds.index(kind)
+            with jax.named_scope(fam.attention_scopes[kind]):
+                q, lines = fam.project(blk, x, pos, kind)
+                mine = tuple(
+                    write(pool, row0, dests[k], offs, unbatch(line))
+                    for pool, line in zip(pools[k * P:(k + 1) * P], lines))
+                pools = pools[:k * P] + mine + pools[(k + 1) * P:]
+                return x + attend(kind, row0, blk, q, mine), pools
+
         def _stack(p, x, pos, live, dests, offs, pools, unbatch, attend,
                    states=(), mix=None, first=None, write=write_rows):
             # the skeleton every program shares: per layer, by its kind.
@@ -495,17 +583,10 @@ class PagedLMEngine(DecodeEngine):
                     y, states = mix(index[li], blk, x, states)
                     x = x + y
                 else:
-                    k = kinds.index(kind)
                     row0 = index[li] * R[kind] if first is None else (
                         (first[kind] + index[li]) * R[kind])
-                    with jax.named_scope(fam.attention_scopes[kind]):
-                        q, lines = fam.project(blk, x, pos, kind)
-                        mine = tuple(
-                            write(pool, row0, dests[k], offs, unbatch(line))
-                            for pool, line in zip(pools[k * P:(k + 1) * P],
-                                                  lines))
-                        pools = pools[:k * P] + mine + pools[(k + 1) * P:]
-                        x = x + attend(kind, row0, blk, q, mine)
+                    x, pools = _attention(kind, row0, blk, x, pos, dests,
+                                          offs, pools, unbatch, attend, write)
                 y, c = fam.ffn(blk, x, live)
                 x = x + y
                 if c is not None:
@@ -599,6 +680,101 @@ class PagedLMEngine(DecodeEngine):
                 1, *range(4 + K, 4 + K + K * P + NS))),
                 4 + K + K * P + NS, ((slots,), jnp.int32)), params)
 
+        def _draft(p, x, toks, pos, live, dests, offs, pools, unbatch,
+                   attend, write=write_rows):
+            # the family's drafting block over the stack's output ``x`` at
+            # ``pos`` and the tokens after them (``toks``): its input, one
+            # block of ``draft_kind`` whose lines go to the cache layer
+            # behind the stack's, its feed-forward. Returns the block's
+            # output rows, the pools and what its expert layer counted
+            kind = fam.draft_kind
+            u = fam.mtp_input(p, x, toks)
+            blk = fam.mtp_block(p)
+            with jax.named_scope("mtp.block"):
+                u, pools = _attention(
+                    kind, stack_of[kind] * R[kind], blk, u, pos, dests, offs,
+                    pools, unbatch, attend, write)
+                y, c = fam.ffn(blk, u, live)
+            return u + y, pools, c
+
+        def _round(p, carry, mask, *rest):
+            # a drafting family's decode program: verify the draft and
+            # draft the next, ONE call. ``carry (S, 3)`` is ``[token,
+            # draft, position]`` a slot as the last round left it; the
+            # stack runs both tokens at ``position, position + 1`` (the
+            # second row sees the first's line), the draft is accepted
+            # where the stack's best token after the first row is the
+            # draft, and the drafting block runs on the committed rows.
+            # A rejected position's lines stay hidden behind the position
+            # until the next round overwrites them
+            self.compile_count += 1  # trace-time only: one round program
+            bts, pools, join = rest[:K], rest[K:K + K * P], rest[-1]
+            S, Q = carry.shape[0], 2
+            # where the host knows better (a join, a restore) its row goes in
+            carry = jnp.where(join[:, :1] >= 0, join, carry)
+            toks, pos = carry[:, :Q], carry[:, Q]
+            q_pos = pos[:, None] + jnp.arange(Q)[None, :]        # (S, Q)
+            lp = jnp.clip(q_pos, 0, max_seq - 1)
+            x = fam.embed(p, toks, lp)                           # (S, Q, D)
+            live = mask[:, None] & (q_pos < max_seq)
+            at = (jnp.arange(S)[:, None], lp // pg)
+
+            def dests_of(rows):  # the page of each row, by kind
+                return tuple(jnp.where(rows, bt[at], 0) for bt in bts)
+
+            offs = lp % pg
+            lengths = jnp.where(mask, jnp.minimum(pos + 1, max_seq), 0)
+            starts = {"full": None}
+            if window is not None:
+                starts["window"] = jnp.maximum(
+                    lengths[:, None] + jnp.arange(Q)[None, :] - window, 0)
+
+            def attend(kind, row0, blk, q, pools):
+                # the step's attention form with two queries a slot
+                o = paged_line_attention(
+                    fam.step_queries(q), pools[0], pools[-1],
+                    row0 + bts[kinds.index(kind)], lengths,
+                    fam.attention_scale, starts[kind], queries=Q)
+                return fam.step_output(blk, o)
+
+            def unbatch(line):
+                return line
+
+            x, pools, counts, _ = _stack(
+                p, x, lp, live, dests_of(live), offs, pools, unbatch, attend)
+            with jax.named_scope("head"):
+                logits = fam.head(p, x.reshape(S * Q, -1))
+            best = jnp.argmax(logits, -1).astype(jnp.int32).reshape(S, Q)
+            budget = max_seq - pos                   # positions left
+            runs = mask & (budget > 0)
+            ok = runs & (best[:, 0] == toks[:, 1]) & (budget > 1)
+            n_emit = runs.astype(jnp.int32) + ok
+            # the drafting block on the committed rows: ``(x at position,
+            # the token after it)``, the second only where the draft held
+            commit = jnp.stack([runs, ok], axis=1)
+            u, pools, c = _draft(p, x, best, lp, commit, dests_of(commit),
+                                 offs, pools, unbatch, attend)
+            if c is not None:
+                counts = counts + c
+            last = jnp.maximum(n_emit - 1, 0)[:, None]
+            u = jnp.take_along_axis(u, last[..., None], axis=1)[:, 0]
+            draft = jnp.argmax(fam.mtp_head(p, u), -1).astype(jnp.int32)
+            token = jnp.take_along_axis(best, last, axis=1)[:, 0]
+            carry = jnp.where(runs[:, None], jnp.stack(
+                [token, draft, pos + n_emit], axis=1), carry)
+            # ``[n_emit | the stack's two tokens | the next draft]`` a slot
+            # in one small array (and the counts behind it): one pull
+            out = jnp.concatenate(
+                [n_emit[:, None], best, draft[:, None]], axis=1).reshape(-1)
+            if NC:
+                out = jnp.concatenate([out, counts])
+            return (out, carry, *pools)
+
+        if D:
+            self._round = functools.partial(
+                jax.jit(_round, donate_argnums=(
+                    1, *range(3 + K, 3 + K + K * P))), params)
+
         # a launch's attention walks the blocks its slot holds
         # (``chunk_line_attention``): how far back a layer of each kind
         # sees, and the pages of one block of the walk
@@ -667,6 +843,28 @@ class PagedLMEngine(DecodeEngine):
             x, pools, counts, states = _layers(
                 p, x, lp[None], valid[None], dests, offs, pools,
                 unbatch, attend, states, mix, write=write)
+            if D:
+                # a drafting family's launch also runs its drafting block,
+                # shifted by one token: row i pairs with token i + 1, the
+                # launch's last real row with ``rest[-1]``, the prompt's
+                # next token, or (below zero: the prompt ends here) with
+                # the first token, which only that row's scores are needed
+                # for. It leaves ``[first token, first draft]``
+                end = n_valid - 1
+                with jax.named_scope("head"):
+                    first = jnp.argmax(fam.head(p, x[0, end][None]),
+                                       -1).astype(jnp.int32)
+                after = jnp.concatenate([toks[1:], toks[:1]]).at[end].set(
+                    jnp.where(rest[-1] >= 0, rest[-1], first[0]))
+                u, pools, c = _draft(
+                    p, x, after[None], lp[None], valid[None], dests, offs,
+                    pools, unbatch, attend, write)
+                if c is not None:
+                    counts = counts + c
+                draft = jnp.argmax(fam.mtp_head(p, u[0, end][None]),
+                                   -1).astype(jnp.int32)
+                ends = jnp.concatenate([first, draft])
+                return (ends, *((counts,) if NC else ()), *pools)
             with jax.named_scope("head"):
                 logits = fam.head(p, x[0])  # (C, V)
             if NC:
@@ -827,18 +1025,26 @@ class PagedLMEngine(DecodeEngine):
         P = len(self._pools)
         self._pools, self._states = tuple(arrays[:P]), tuple(arrays[P:])
 
-    def _hand_over(self, slot: int, token: int, pos: int, left: int) -> None:
+    def _hand_over(self, slot: int, token: int, pos: int, left: int,
+                   draft: int = 0) -> None:
         """``slot`` is live from the next step on, at ``pos`` with ``token``
         as its input (a prompt's last launch, ``restore``): the host's
         mirrors take it, and ``_join`` hands the token to the device's
-        carry inside the next ``_step`` call. Nothing is uploaded here:
+        carry inside the next ``_step`` call (a drafting engine: the
+        token, the ``draft`` that follows it and the position, to the next
+        ``_round``). Nothing is uploaded here:
         with a step in flight ``_tok`` is one token old for every other
         slot, and a whole-array upload would roll them back. Block tables
         are not device-resident either: they ride into every call as
         numpy arguments (the committed-call conversion is ~10x cheaper
         than a device mirror that page-boundary crossings would re-upload
         mid-decode)."""
-        self._tok[slot, 0] = self._join[slot] = token
+        self._tok[slot, 0] = token
+        if self.drafts:
+            self.next_draft[slot] = draft
+            self._join[slot] = (token, draft, pos)
+        else:
+            self._join[slot] = token
         self._pos[slot] = pos
         self._left[slot] = left
         self._mask[slot] = True
@@ -1048,6 +1254,12 @@ class PagedLMEngine(DecodeEngine):
             if self._states:  # the launch that starts a sequence zeroes it
                 prepare.attrs["state_reset"] = int(start == 0)
                 state_args = (jnp.asarray(slot, jnp.int32), *self._states)
+            elif self.drafts:
+                # the token after the launch's last row, which the drafting
+                # block pairs it with: the prompt's next, or none yet
+                more = start + n_valid < tokens.size
+                state_args = (jnp.asarray(
+                    tokens[start + n_valid] if more else -1, jnp.int32),)
         with obs_context.span("engine.chunk.dispatch", **attrs) as dispatch:
             logits, *rest = self._run(
                 "_prefill_chunk", self._prefill_chunk, jnp.asarray(padded),
@@ -1068,10 +1280,14 @@ class PagedLMEngine(DecodeEngine):
         # prompt complete: seed the decode carry from the last REAL row
         del self._pending[slot]
         with obs_context.span("engine.chunk.pull", **attrs) as pull:
-            first = int(np.argmax(np.asarray(logits[n_valid - 1])))
+            if self.drafts:  # the launch left [first token, first draft]
+                first, draft = map(int, np.asarray(logits))
+            else:
+                first = int(np.argmax(np.asarray(logits[n_valid - 1])))
+                draft = 0
             pull.attrs.update(self._pull_chunk_counts())
         self.pull_s += pull.dur_s
-        self._hand_over(slot, first, tokens.size, st["steps"] - 1)
+        self._hand_over(slot, first, tokens.size, st["steps"] - 1, draft)
         if self.share_prefixes:
             # register FULL pages only: a later prompt sharing just the
             # prefix (not the tail) still hits, and registered pages are
@@ -1101,36 +1317,77 @@ class PagedLMEngine(DecodeEngine):
         May raise PagePoolExhausted when an active slot crosses into a
         page the pool cannot supply (scheduler preempts a victim and
         retries): from prepare, before the step in flight is touched."""
+        if self.drafts:
+            raise TypeError(
+                f"lm_engine: the {self.family.name} family drafts, so a pass "
+                f"yields 1 or 2 tokens a slot: call step_tokens(), not "
+                f"step()")
+        return self._advance()
+
+    def _advance(self) -> np.ndarray:
+        """Dispatch the next step or round, then bring home the one before."""
         who = self._mask & (self._left > 0)
         flight = self._dispatch(who) if who.any() else None
         tok = self.collect()
         self._flight = flight
         return tok
 
+    def _step_tokens(self) -> "list[list[int]]":
+        """A drafting engine's pass (``DecodeEngine.step_tokens``, bound
+        where the family drafts): one round over every slot, run one round
+        ahead as ``step`` is. This call dispatches the next round from the
+        carry the last one left on the device, before anyone knows what
+        that one accepted, and then brings home (``collect``) the tokens of
+        the round dispatched by the call before: per slot the 1 or 2 it
+        emitted, ``[]`` for a slot that was not in it. Until a round is
+        collected the host counts it as one token a slot (``_pos`` and
+        ``_left`` are bounds): it keeps the pages of one position more
+        writable, gives a window layer's pages back behind the lower bound
+        only, and a request's last round may be followed by one whose
+        tokens the scheduler drops."""
+        return [[int(t) for t in row if t >= 0] for row in self._advance()]
+
+    def acceptance_rate(self) -> float:
+        """Drafts the stack agreed with over drafts proposed, so far."""
+        return self.spec_accepted / max(self.spec_proposed, 1)
+
     def _dispatch(self, who: np.ndarray) -> tuple:
-        """Prepare and dispatch one step of the slots in ``who``; returns
-        what ``_flight`` holds of it."""
+        """Prepare and dispatch one step (a drafting engine: one round) of
+        the slots in ``who``; returns what ``_flight`` holds of it."""
         slots = np.flatnonzero(who)
         live = len(slots)
+        D = self.drafts
+        # the positions a pass may write from a slot's ``_pos`` on: one, a
+        # round's second, and one more where a round in flight may have
+        # advanced the slot a position past the host's bound
+        reach = np.full(who.shape, 1 + D)
+        if D and self._flight is not None:
+            reach += self._flight[1]
         with obs_context.span("engine.step.prepare", live=live,
                               passes=self.passes,
                               pass_layers=self.pass_layers) as prepare:
             for s in slots:
                 if self._pos[s] < self.max_seq:
                     self._release_behind(int(s), int(self._pos[s]))
-                    self._ensure_writable(int(s), int(self._pos[s]),
-                                          int(self._pos[s]) + 1)
+                    self._ensure_writable(
+                        int(s), int(self._pos[s]),
+                        min(int(self._pos[s] + reach[s]), self.max_seq))
             # how far the step's attention follows what is visible: the
             # pages the live slots hold against every slot's whole table
             # (by kind of layer where kinds differ: a window layer reads
             # from the window's first page on)
             # ``pages_fetched`` beside them is what the step's kernel
             # copies, by its own rule (ops/paged_attention.py)
-            seen = np.minimum(self._pos[slots] + 1, self.max_seq)
+            # a round's second query sees one position more, and its walk
+            # starts at the first query's first position (from the host's
+            # bound: an accepted draft in flight puts them one position low)
+            seen = np.minimum(self._pos[slots] + 1 + D, self.max_seq)
             first = {"full": np.zeros_like(seen)}
             by_kind = {"full": int((-(-seen // self.page_size)).sum())}
             if "window" in self.kinds:
-                first["window"] = np.maximum(seen - self.family.window, 0)
+                first["window"] = np.maximum(
+                    np.minimum(self._pos[slots] + 1, self.max_seq)
+                    - self.family.window, 0)
                 by_kind["window"] = by_kind["full"] - int(
                     (first["window"] // self.page_size).sum())
             fetched = {kind: self._pages_fetched(seen, first[kind],
@@ -1160,23 +1417,35 @@ class PagedLMEngine(DecodeEngine):
                                      state_slots=self.slots)
                 self.state_slots["state_slots_live"] += live
                 self.state_slots["state_slots"] += self.slots
+            if D:
+                # a round's account: two rows a live slot, one draft each;
+                # ``collect`` adds what it accepted and emitted
+                prepare.attrs.update(rounds=1, rows=2 * live, proposed=live,
+                                     accepted=0, emitted=0)
+                self.spec_rounds += 1
+                self.spec_proposed += live
         ahead = int(self._flight is not None)
         with obs_context.span("engine.step.dispatch", live=live,
                               ahead=ahead) as dispatch:
             # every host argument a copy: the mirrors move on below and
             # at the next join or release, while this step may still read
-            tok_dev, self._tok_dev, *rest = self._run(
-                "_step", self._step,
-                self._tok_dev, self._pos.copy(), who.copy(),
-                *self._tables(), *self._pools, *self._states,
-                self._join.copy())
+            if D:
+                tok_dev, self._tok_dev, *rest = self._run(
+                    "_round", self._round, self._tok_dev, who.copy(),
+                    *self._tables(), *self._pools, self._join.copy())
+            else:
+                tok_dev, self._tok_dev, *rest = self._run(
+                    "_step", self._step,
+                    self._tok_dev, self._pos.copy(), who.copy(),
+                    *self._tables(), *self._pools, *self._states,
+                    self._join.copy())
             self._keep(rest)
         self.host_s += prepare.dur_s + dispatch.dur_s
         self.run_ahead["steps_ahead"] += ahead
         self._pos += who
         self._left -= who
         self._join[:] = -1
-        return tok_dev, who
+        return tok_dev, who, prepare
 
     def collect(self) -> np.ndarray:
         """The tokens no ``step()`` has returned yet, ``(slots,)`` with
@@ -1184,12 +1453,12 @@ class PagedLMEngine(DecodeEngine):
         for here, and those a drain kept. "``step()``, then ``collect()``"
         is the synchronous step: each step's own tokens, nothing left in
         flight."""
-        tok, self._kept = self._kept, np.full((self.slots,), -1, np.int32)
+        tok, self._kept = self._kept, np.full_like(self._kept, -1)
         flight, self._flight = self._flight, None
         live = 0 if flight is None else int(flight[1].sum())
         with obs_context.span("engine.step.pull", live=live) as pull:
             if flight is not None:
-                tok_dev, who = flight
+                tok_dev, who, prepared = flight
                 # nnlint: disable=NNL101 — one (slots,) pull per decode
                 # step: the scheduler needs host ints to append/retire
                 # (documented contract); explicit device_get, so it stays
@@ -1198,13 +1467,38 @@ class PagedLMEngine(DecodeEngine):
                 got = self._jax.device_get(tok_dev)
                 if self.family.counters:
                     # an expert family's counts came home behind the tokens
-                    got, counts = got[:self.slots], got[self.slots:]
+                    n = self.slots * (4 if self.drafts else 1)
+                    got, counts = got[:n], got[n:]
                     pull.attrs.update(self._note_counts("step", counts))
                     self._pull_chunk_counts()
-                self._tok[who, 0] = tok[who] = got[who]
-            pull.attrs["no_token"] = int((self._mask & (tok < 0)).sum())
+                if self.drafts:
+                    self._commit(got.reshape(self.slots, 4), who, tok,
+                                 prepared)
+                else:
+                    self._tok[who, 0] = tok[who] = got[who]
+            pull.attrs["no_token"] = int(
+                (self._mask & (tok.reshape(self.slots, -1)[:, 0] < 0)).sum())
         self.pull_s += pull.dur_s
         return tok
+
+    def _commit(self, got, who, tok, prepared) -> None:
+        """A round's answer, ``[n_emit | the stack's two tokens | the next
+        draft]`` a slot, for the slots in ``who``: their tokens into
+        ``tok (slots, 2)``, the host's mirrors made exact (the dispatch
+        counted one token a slot), the round's account closed."""
+        n_emit = np.where(who, got[:, 0], 0)
+        for q in range(2):
+            took = n_emit > q
+            tok[took, q] = got[took, 1 + q]
+        ran = n_emit > 0
+        self._tok[ran, 0] = got[ran, np.maximum(n_emit[ran], 1)]
+        self.next_draft[ran] = got[ran, 3]
+        self._pos[who] += n_emit[who] - 1
+        self._left[who] -= n_emit[who] - 1
+        accepted, emitted = int((n_emit == 2).sum()), int(n_emit.sum())
+        self.spec_accepted += accepted
+        self.spec_emitted += emitted
+        prepared.attrs.update(accepted=accepted, emitted=emitted)
 
     def verify_commit(self, draft: np.ndarray):
         """Fused speculative round: verify ``draft`` (slots, K) AND
@@ -1249,14 +1543,16 @@ class PagedLMEngine(DecodeEngine):
             bt[slot] = 0
         self._held_from[slot] = 0
 
-    def _leave(self, slot: int) -> int:
+    def _leave(self, slot: int):
         """``slot`` is out of every later step; returns the token it is
-        owed (``-1``: none): one that a drain kept and no ``step()`` has
-        returned yet."""
+        owed (``-1``: none; a drafting engine: a round's two, ``-1`` where
+        it emitted fewer): what a drain kept and no ``step()`` has returned
+        yet."""
         self._mask[slot] = False
         self._join[slot] = -1
         self._left[slot] = 0
-        owed, self._kept[slot] = int(self._kept[slot]), -1
+        owed = self._kept[slot].copy()
+        self._kept[slot] = -1
         return owed
 
     def release(self, slot: int) -> None:
@@ -1270,7 +1566,7 @@ class PagedLMEngine(DecodeEngine):
             self._pending.pop(slot, None)
             self._lane.pop(slot, None)
             self._drop_pages(slot)
-            dropped = int(self._leave(slot) >= 0)
+            dropped = int((self._leave(slot) >= 0).any())
             if self._flight is not None and self._flight[1][slot]:
                 self._flight[1][slot] = False
                 dropped += 1
@@ -1303,7 +1599,8 @@ class PagedLMEngine(DecodeEngine):
         blob = {"pages": (), "used": {}, "state": (), "held_from": int(
                     self._held_from[slot]),
                 "tok": int(self._tok[slot, 0]), "pos": int(self._pos[slot]),
-                "left": int(self._left[slot])}
+                "left": int(self._left[slot]),
+                "draft": int(self.next_draft[slot]) if self.drafts else 0}
         with obs_context.span("engine.preempt", slot=slot) as sp:
             for kind in self.kinds:
                 row = self._bts[kind][slot, self._held_span(kind, slot)]
@@ -1362,7 +1659,8 @@ class PagedLMEngine(DecodeEngine):
                     tuple(self._jnp.asarray(b) for b in blob["state"]),
                     *self._states)
                 sp.attrs["state_bytes"] = self.state_slot_bytes
-            self._hand_over(slot, blob["tok"], blob["pos"], blob["left"])
+            self._hand_over(slot, blob["tok"], blob["pos"], blob["left"],
+                            blob["draft"])
             self._kept[slot] = blob["owed"]
         self.pool.note_restore()
 

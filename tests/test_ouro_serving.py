@@ -149,7 +149,7 @@ def test_every_other_family_says_one_pass():
     from nnstreamer_tpu.models.families import _families
 
     others = [family for _, family in _families() if family is not OuroFamily]
-    assert len(others) == 4
+    assert len(others) >= 4  # every family there is but this one
     for family in others:
         assert family.passes == 1, family
         assert not hasattr(family, "close_pass"), family

@@ -2,8 +2,9 @@
 
 For every configuration ``BENCHMARK.json`` lists whose driver kind this
 script knows (``lm_serving``, ``lm_serving_moe_mla``,
-``lm_serving_moe_window``, ``lm_serving_ssm``, ``lm_serving_looped``):
-``_step`` and ``_prefill_chunk`` of the paged engine at the configuration's
+``lm_serving_moe_window``, ``lm_serving_ssm``, ``lm_serving_looped``,
+``lm_serving_moe_mtp``): ``_step`` (``_round`` where the family drafts,
+since PR 47) and ``_prefill_chunk`` of the paged engine at the configuration's
 sizes and engine geometry, a launch of 256 rows, in the forms a TPU runs
 (the step's attention kernel, the experts' kernel; a state layer's products
 in their plain form), compiled for a described v5e with no chip attached.
@@ -81,6 +82,12 @@ def _model(config: dict):
         from nnstreamer_tpu.models.ouro import OuroConfig
 
         return OuroConfig.from_published(config)
+    if kind == "lm_serving_moe_mtp":
+        from benchmark.lib import harness
+        from nnstreamer_tpu.models.exaone_moe import ExaoneMoeConfig
+
+        return ExaoneMoeConfig.from_published(
+            harness.reference_for(config).model_config(config))
     return None
 
 
@@ -157,6 +164,15 @@ def main(root: str, out: str, text: bool = False) -> int:
                                shape((), i32), *[shape((NB,), i32)] * K,
                                *pools, *([shape((), i32)] if states else []),
                                *states)}
+        if getattr(probe, "drafts", 0):
+            # a drafting family's decode program is the round, and its
+            # launch takes the token after its last row
+            programs = {
+                "_round": (shape((S, 3), i32), shape((S,), jnp.bool_),
+                           *[shape((S, NB), i32)] * K, *pools,
+                           shape((S, 3), i32)),
+                "_prefill_chunk": (*programs["_prefill_chunk"],
+                                   shape((), i32))}
         for name, args in programs.items():
             compiled = getattr(probe, name).func.lower(
                 params, *args).compile()
