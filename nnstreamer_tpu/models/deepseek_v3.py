@@ -278,6 +278,9 @@ class DeepseekV3Family:
     def attention_scale(self) -> float:
         return self.cfg.qk_head_dim ** -0.5
 
+    def step_by_head(self, queries: int) -> bool:
+        return False    # one line every head reads whole
+
     def step_queries(self, q):
         return q[:, 0]  # (S, H, line): the absorbed queries as they are
 

@@ -291,25 +291,46 @@ class ExaoneMoeFamily(GroupedQueryLines):
             k = rotate_half(k, pos[..., None], freq, factor)
         return q, (k.reshape(*x.shape[:2], KV * Dh), h @ blk["wv"])
 
+    def step_by_head(self, queries: int) -> bool:
+        """Whether a step of ``queries`` rows a slot hands the kernel
+        head-wide rows: the kernel's rule, asked at this block's shapes."""
+        from ..ops.paged_attention import contracts_by_head
+
+        cfg = self.cfg
+        return contracts_by_head(3 * queries * cfg.num_attention_heads,
+                                 cfg.num_key_value_heads, cfg.head_dim)
+
     def step_queries(self, q):
         """``q (S, K, H, head_dim)``, ``K`` rows a slot (a step's one, a
-        round's two) → ``(S, K * H, line)``: row ``r * H + n`` holds head
+        round's two) → where the kernel contracts by key head
+        (``step_by_head``) the rows as they are, those of one key head
+        together, ``(S, KV * K * G, head_dim)`` in the order ``(KV, K,
+        G)``; else ``(S, K * H, line)``: row ``r * H + n`` holds head
         ``n``'s query of row ``r`` in the block of its key head."""
         import jax.numpy as jnp
 
-        S, K, H, _ = q.shape
-        tiled = jnp.tile(q, (1, 1, 1, self.cfg.num_key_value_heads))
+        S, K, H, Dh = q.shape
+        KV = self.cfg.num_key_value_heads
+        if self.step_by_head(K):
+            return q.reshape(S, K, KV, H // KV, Dh).swapaxes(1, 2).reshape(
+                S, K * H, Dh)
+        tiled = jnp.tile(q, (1, 1, 1, KV))
         return jnp.where(self._own()[None, None], tiled, 0.0).reshape(
             S, K * H, -1)
 
     def step_output(self, blk, o):
-        """``o (S, K * H, line)`` → ``(S, K, D)``: of every row its own key
-        head's block, through the output projection."""
+        """What the kernel gave for ``step_queries``' rows → ``(S, K, D)``:
+        head-wide rows ``(S, KV * K * G, head_dim)`` back in the order
+        ``(K, H)``, or of every whole-line row ``(S, K * H, line)`` its own
+        key head's block; through the output projection."""
         import jax.numpy as jnp
 
         cfg = self.cfg
         S = o.shape[0]
         H, KV = cfg.num_attention_heads, cfg.num_key_value_heads
+        if self.step_by_head(o.shape[1] // H):
+            o = o.reshape(S, KV, -1, H // KV, cfg.head_dim).swapaxes(1, 2)
+            return o.reshape(S, o.shape[1], -1) @ blk["wo"]
         o = o.reshape(S, -1, H, KV, cfg.head_dim)
         own = self._own().reshape(H, KV, cfg.head_dim)
         o = jnp.where(own[None, None], o, 0.0).sum(axis=3)

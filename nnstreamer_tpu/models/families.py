@@ -153,13 +153,31 @@ A family says:
 * ``attend_verify(blk, q, ctxs, visible)`` — where ``serves_verify``: ``K``
   queries a slot over the gathered lines ``ctxs`` (one ``(S, ctx, width)``
   per pool), ``visible (S, K, ctx)``, output projection applied.
-* ``step_queries(q)``, ``attention_scale``, ``step_output(blk, o)`` — the
-  decode step gathers nothing: every family's step is ``H`` queries a slot
-  over one shared line a token, so the engine hands
-  ``ops.paged_attention.paged_line_attention`` the step's queries over
-  whole lines ``(S, H, width)`` and the score scale, and the family
-  finishes the ``(S, H, width)`` float32 result (its own part of the line,
-  output projection applied).
+* ``step_queries(q)``, ``attention_scale``, ``step_output(blk, o)``,
+  ``step_by_head(queries)`` — the decode step gathers nothing: every
+  family's step is ``H`` queries a slot over one shared line a token, so
+  the engine hands ``ops.paged_attention.paged_line_attention`` the step's
+  queries and the score scale, and the family finishes the float32 result
+  (output projection applied). The queries come in one of two operand
+  forms, and the result goes back in the same one:
+
+  - whole lines, ``(S, K * H, width)``: row ``r * H + n`` is head ``n`` of
+    the slot's ``r``-th query over the whole line, zeros outside its key
+    head's block (the block-diagonal query) or the latent family's
+    absorbed query as it is; the family takes its own part of each
+    ``(S, K * H, width)`` result row;
+  - head-wide, ``(S, KV * K * G, head_dim)``: the queries as ``project``
+    made them, the rows of one key head together in the order ``(KV, K,
+    G)``; the kernel contracts each key head's rows with that head's
+    ``head_dim`` values of a line alone and gives ``(S, KV * K * G,
+    head_dim)`` back.
+
+  Which form is the kernel's rule, a function of the call's shapes
+  (``ops.paged_attention.contracts_by_head``): a family whose lines hold
+  several key heads asks it in ``step_by_head(queries)`` and lays its rows
+  out so (``exaone_moe``); the others answer ``False`` and hand whole
+  lines. The engine writes the answer on its ``engine.step.prepare`` span
+  (``attn_by_head``: the round's kernel calls that contract by head).
 * ``ffn(blk, x, live)`` — norm and feed-forward of ``x (B, Q, D)`` →
   ``(y, counts)``; ``live (B, Q)`` marks the rows that are real, and
   ``counts`` is ``None`` or the int32 vector ``counters`` names.
@@ -186,6 +204,9 @@ class GroupedQueryLines:
 
     def stored(self, params):
         return params              # served as they come
+
+    def step_by_head(self, queries: int) -> bool:
+        return False               # the step's queries span whole lines
 
     @property
     def cache_lines(self) -> tuple:
@@ -271,6 +292,9 @@ class GPTFamily:
 
     def stored(self, params):
         return params              # served as they come
+
+    def step_by_head(self, queries: int) -> bool:
+        return False               # the step's queries span whole lines
 
     def with_positions(self, positions: int) -> "GPTFamily":
         from dataclasses import replace

@@ -5,7 +5,16 @@ of attention for every model family: ``H`` queries a slot over one shared
 line a token (the ``gpt`` family through its block-diagonal query, the
 latent family by construction). :func:`paged_line_attention` is that op:
 
-* ``q (S, H, Wk)`` float32 — a slot's queries over whole lines;
+* ``q (S, H, Wk)`` float32 — a slot's queries, in one of two operand
+  forms, told apart by their width: over whole lines (``Wk`` the pool's
+  ``W``: the block-diagonal query, the latent family's absorbed one), or
+  head-wide (``Wk = W / KV``, where a line holds ``KV`` key heads side by
+  side: the queries as the heads have them, the ``H / KV`` rows of one key
+  head together, key head after key head), each key head's rows then
+  contracted with that head's ``W / KV`` values of a line alone: the same
+  sums without the block-diagonal query's zeros.
+  :func:`contracts_by_head` says from a call's shapes which form pays; a
+  family asks it and lays its rows out so (``models/families.py``);
 * ``kpool``, ``vpool`` ``(rows, page, W)`` bfloat16 — the engine's pools,
   untouched (a family with one kind of line passes its pool as both);
 * ``rows (S, NB)`` int32 — the pool row of every block of every slot
@@ -18,14 +27,17 @@ latent family by construction). :func:`paged_line_attention` is that op:
 
 A verify round has several queries a slot (``queries=K``, ``serving/
 lm_engine.py`` ``_round``): ``q (S, K * H, Wk)``, row ``r * H + n`` head
-``n`` of the slot's ``r``-th query, which stands ``r`` positions after the
+``n`` of the slot's ``r``-th query (head-wide: row ``(g * K + r) * G + n``
+the ``n``-th of key head ``g``'s ``G`` query heads, order ``(KV, K, G)``),
+which stands ``r`` positions after the
 first and sees ``lengths + r`` positions; ``starts (S, K)`` then gives each
 query's first position. The lines of all ``K`` positions are in the pool
 before the call, so a later query sees an earlier one's line and no earlier
 one a later's. ``K = 1`` is the step, and compiles to the kernel it had
 before rounds existed.
 
-It returns ``(S, H, Wv)`` float32: softmax(q · lines) · lines, zeros for an
+It returns ``(S, H, Wv)`` float32 (head-wide: ``(S, H, Wv / KV)``, the rows
+in the order they came): softmax(q · lines) · lines, zeros for an
 empty slot. Float32 queries, scores, softmax and weighted sum over a
 bfloat16 pool: the meaning of ``Precision.HIGHEST`` with nothing lowered.
 
@@ -54,7 +66,13 @@ Two forms, chosen in one place (:func:`paged_line_attention`) by
   stacked along the rows, so one pass of the pool's bfloat16 lines through
   the matrix unit gives the float32 product exactly (the lines are
   bfloat16 already: the three further passes of a float32 × float32
-  product would multiply zeros).
+  product would multiply zeros). Head-wide rows change the two products
+  and nothing else: key head ``g``'s stacked rows against lanes ``g * W /
+  KV ..`` of the block's key lines, its softmax terms against the same
+  lanes of the value lines, one pair of products a key head, spelled out
+  (``KV`` static lane offsets); the walk, the ring, the waits, the mask
+  and the softmax are over all rows at once as before, and ``KV = 1`` is
+  the whole-line kernel, instruction for instruction.
 * :func:`plain_line_attention` — gather every slot's ``NB`` pages, mask,
   softmax: the oracle the kernel is pinned to (``tests/
   test_paged_attention.py``) and what runs where a TPU kernel would only be
@@ -95,6 +113,40 @@ BLOCK_BYTES = 1280 * 1024
 _MASKED = -1e30
 
 
+def contracts_by_head(stacked_rows: int, key_heads: int,
+                      head_width: int) -> bool:
+    """Whether a call whose slot stacks ``stacked_rows`` bfloat16 query rows
+    (3 terms x queries x heads) over lines of ``key_heads`` heads of
+    ``head_width`` values contracts each key head's rows with that head's
+    part of a line alone (head-wide operands), or all rows with whole lines
+    (the block-diagonal query). The one place the form is chosen: a family
+    asks before it lays its step's queries out.
+
+    A tile of lines takes the matrix unit its own load (128 rows' worth)
+    however few rows ride through, so whole lines cost one pass of every
+    tile while a slot's stacked rows fit a tile, and ``ceil(rows / 128)``
+    passes beyond; by head every tile is loaded once for ``rows /
+    key_heads`` rows, and the block-diagonal query and result, ``key_heads``
+    times the heads' own, are never built. By head pays where the rows pass
+    a tile more than once and a head's rows fill a bfloat16 tile (16); the
+    head's part of a line must be whole lanes (128) to be sliced where it
+    lies.
+
+    Stand-alone on a v5e (``tools/paged_attention_forms.py``, PR 48), ms a
+    layer, whole lines | by head, at the cells' lengths and with every slot
+    full: K-EXAONE's round (384 rows, 48 a head) full kind 1.259 | 0.594 and
+    3.044 | 1.574, window kind 0.461 | 0.258 and 0.468 | 0.240; its step
+    (192 rows, 24 a head) full 0.953 | 0.694 and 1.905 | 1.584, window
+    0.271 | 0.240 and 0.231 | 0.236; a mellum full layer (96 rows, 24 a
+    head) 0.725 | 0.607 and 1.351 | 1.155: by head pays there too, left to
+    its own issue (a round of rows more than a tile is the rule's line for
+    now); ``opt_1.3b`` (96 rows, 3 a head of 64, padded to 16) 0.121 | 0.137
+    saturated, 0.031 | 0.035 chat, 0.376 | 0.375 full
+    """
+    return (key_heads > 1 and head_width % 128 == 0 and stacked_rows > 128
+            and stacked_rows // key_heads >= 16)
+
+
 def paged_line_attention(q, kpool, vpool, rows, lengths, scale, starts=None,
                          queries=1):
     """The step's attention (module docstring), in the form this platform
@@ -121,25 +173,41 @@ def gathered_lines(pool, rows):
 
 def plain_line_attention(q, kpool, vpool, rows, lengths, scale, starts=None,
                          queries=1):
-    """Gather, mask, softmax: every slot's whole block table."""
+    """Gather, mask, softmax: every slot's whole block table. Both operand
+    forms: whole-line queries, or head-wide ones over each key head's part
+    of the gathered lines."""
     exact = jax.lax.Precision.HIGHEST
     ck = gathered_lines(kpool, rows)
     cv = ck if vpool is kpool else gathered_lines(vpool, rows)
-    att = jnp.einsum("shj,scj->shc", q, ck, precision=exact) * scale
+    S, H, Dk = q.shape
+    KV = ck.shape[2] // Dk            # key heads whose rows lie together
+    if KV > 1:
+        q = q.reshape(S, KV, H // KV, Dk)
+        ck, cv = (c.reshape(S, c.shape[1], KV, -1) for c in (ck, cv))
+        att = jnp.einsum("sgrj,scgj->sgrc", q, ck, precision=exact)
+        att = att.reshape(S, H, -1) * scale
+    else:
+        att = jnp.einsum("shj,scj->shc", q, ck, precision=exact) * scale
     if queries == 1:
         visible = jnp.arange(ck.shape[1])[None, :] < lengths[:, None]
         if starts is not None:
             visible &= jnp.arange(ck.shape[1])[None, :] >= starts[:, None]
         visible = visible[:, None, :]
     else:
-        # (S, K, ctx), then every head of a query alike
+        # (S, K, ctx), then every head of a query alike, in every key
+        # head's rows
         at = jnp.arange(ck.shape[1])
         visible = at < (lengths[:, None] + jnp.arange(queries))[..., None]
         if starts is not None:
             visible &= at >= starts[..., None]
-        visible = jnp.repeat(visible, q.shape[1] // queries, axis=1)
+        visible = jnp.tile(jnp.repeat(visible, H // KV // queries, axis=1),
+                           (1, KV, 1))
     att = jax.nn.softmax(jnp.where(visible, att, _MASKED), axis=-1)
-    out = jnp.einsum("shc,scj->shj", att, cv, precision=exact)
+    if KV > 1:
+        out = jnp.einsum("sgrc,scgj->sgrj", att.reshape(S, KV, H // KV, -1),
+                         cv, precision=exact).reshape(S, H, -1)
+    else:
+        out = jnp.einsum("shc,scj->shj", att, cv, precision=exact)
     return jnp.where((lengths > 0)[:, None, None], out, 0.0)
 
 
@@ -294,8 +362,11 @@ def _walk(q, kpool, vpool, rows, start, n_valid, *, scale, span, precision,
     return jnp.moveaxis(o / l[..., None], 0, 1)
 
 
-def _kernel(rows_ref, meta_ref, q_ref, *refs, S, NB, PB, sizes, H, scale,
-            shared, K=1, Hq=None):
+def _kernel(rows_ref, meta_ref, q_ref, *refs, S, NB, PB, sizes, R, scale,
+            shared, K=1, Hq=None, KV=1):
+    # ``KV`` key heads' rows, ``R`` each, one head after the other: every
+    # product below is a key head's rows by that head's part of the lines
+    # (``KV = 1``: all rows by whole lines)
     if shared:
         k_hbm, o_ref, kbuf, sems, q3_ref, p3_ref, m_ref, l_ref, state = refs
         v_hbm, vbuf = k_hbm, kbuf
@@ -383,22 +454,47 @@ def _kernel(rows_ref, meta_ref, q_ref, *refs, S, NB, PB, sizes, H, scale,
 
             jax.lax.fori_loop(0, n, one, 0)
 
+    def stack(ref, terms, width):
+        # a key head's three terms one under the other, head after head
+        for j, term in enumerate(terms):
+            for g in range(KV):
+                ref[(3 * g + j) * R:(3 * g + j + 1) * R, :width] = \
+                    term[g * R:(g + 1) * R]
+
     def contract(at0, buf, size):
         # the products over the first ``size`` pages of buffer ``buf``,
         # whose first line is position ``at0``
         T = size * pg
-        k = kbuf[buf, :size].reshape(T, kbuf.shape[-1])
-        sc3 = jax.lax.dot_general(
-            q3_ref[...], k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (3H, T)
-        sc = sc3[:H] + sc3[H:2 * H] + sc3[2 * H:]
+
+        def lines(ref, g):
+            # key head ``g``'s part of the block's lines: whole lanes
+            width = ref.shape[-1] // KV
+            return ref[buf, :size, :, g * width:(g + 1) * width].reshape(
+                T, width)
+
+        def products(terms, width, g, lines, over):
+            # head ``g``'s three terms by its lines, one under the other:
+            # their sum is the float32 product of its rows
+            return jax.lax.dot_general(
+                terms[3 * R * g:3 * R * (g + 1), :width], lines,
+                (((1,), (over,)), ((), ())),
+                preferred_element_type=jnp.float32)      # (3R, ...)
+
+        sc = []
+        for g in range(KV):
+            sc3 = products(q3_ref, q3_ref.shape[1], g, lines(kbuf, g), 1)
+            sc.append(sc3[:R] + sc3[R:2 * R] + sc3[2 * R:])
+        sc = sc[0] if KV == 1 else jnp.concatenate(sc, axis=0)   # (H, T)
         at = at0 + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
         if K == 1:
             sc = jnp.where((at >= start) & (at < length), sc, _MASKED)
         else:
-            # row r * Hq + n is the slot's r-th query: one position more
-            # visible a query, from that query's own first position
+            # row r * Hq + n of a key head's is the slot's r-th query: one
+            # position more visible a query, from that query's own first
+            # position
             row = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0)
+            if KV > 1:
+                row = jax.lax.rem(row, R)
             lo = jnp.full(sc.shape, start, jnp.int32)
             hi = jnp.full(sc.shape, length, jnp.int32)
             for r in range(1, K):
@@ -419,18 +515,16 @@ def _kernel(rows_ref, meta_ref, q_ref, *refs, S, NB, PB, sizes, H, scale,
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
         m_ref[...] = m_new
-        for j, term in enumerate(_three_terms(p)):
-            p3_ref[j * H:(j + 1) * H, :T] = term
-        v = vbuf[buf, :size].reshape(T, vbuf.shape[-1])
-        o3 = jnp.dot(p3_ref[:, :T], v,
-                     preferred_element_type=jnp.float32)  # (3H, Wv)
-        o_ref[...] = (alpha * o_ref[...]
-                      + o3[:H] + o3[H:2 * H] + o3[2 * H:])
+        stack(p3_ref, _three_terms(p), T)
+        for g in range(KV):
+            own = slice(g * R, (g + 1) * R)
+            o3 = products(p3_ref, T, g, lines(vbuf, g), 0)
+            o_ref[own, :] = (alpha[own] * o_ref[own, :]
+                             + o3[:R] + o3[R:2 * R] + o3[2 * R:])
 
     @pl.when(count > 0)
     def _():
-        for i, term in enumerate(_three_terms(q_ref[...] * scale)):
-            q3_ref[i * H:(i + 1) * H, :] = term
+        stack(q3_ref, _three_terms(q_ref[...] * scale), q3_ref.shape[1])
         m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
@@ -469,19 +563,26 @@ def _call(q, kpool, vpool, rows, lengths, starts, *, scale,
           pages_per_block, interpret, queries=1):
     shared = vpool is None
     K = queries
-    S, H0, Wk = q.shape
+    S, H0, Dk = q.shape
     NB = rows.shape[1]
     pg = kpool.shape[1]
-    Wv = Wk if shared else vpool.shape[2]
+    # the key heads whose rows are contracted apart: 1 where the queries
+    # span whole lines
+    KV = kpool.shape[2] // Dk
+    Dv = (kpool if shared else vpool).shape[2] // KV
     PB = pages_per_block
     # a slot's last block is contracted over a quarter of a block's lines
     # when it holds no more pages than that: 128 positions at the least
     short = max(PB // 4, -(-128 // pg))
     sizes = (PB, short) if short < PB else (PB,)
     # rows of the stacked bfloat16 operands start on a tile row (16)
-    H = -(-H0 // 16) * 16
-    if H != H0:
-        q = jnp.pad(q, ((0, 0), (0, H - H0), (0, 0)))
+    R0 = H0 // KV
+    R = -(-R0 // 16) * 16
+    H = KV * R
+    if R != R0:
+        # every key head's rows padded apart
+        q = jnp.pad(q.reshape(S * KV, R0, Dk),
+                    ((0, 0), (0, R - R0), (0, 0))).reshape(S, H, Dk)
     lengths = jnp.clip(lengths, 0, NB * pg)
     if K == 1:
         # a live slot sees a position: every block it visits holds one
@@ -518,29 +619,31 @@ def _call(q, kpool, vpool, rows, lengths, starts, *, scale,
     bufs = [pltpu.VMEM((_AHEAD + 1, PB, pg, p.shape[2]), p.dtype)
             for p in pools]
     out = pl.pallas_call(
-        functools.partial(_kernel, S=S, NB=NB, PB=PB, sizes=sizes, H=H,
-                          scale=scale, shared=shared, K=K, Hq=H0 // K),
+        functools.partial(_kernel, S=S, NB=NB, PB=PB, sizes=sizes, R=R,
+                          scale=scale, shared=shared, K=K, Hq=R0 // K, KV=KV),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(S,),
-            in_specs=[block(Wk)] + [pl.BlockSpec(memory_space=pl.ANY)
+            in_specs=[block(Dk)] + [pl.BlockSpec(memory_space=pl.ANY)
                                     for _ in pools],
-            out_specs=block(Wv),
+            out_specs=block(Dv),
             scratch_shapes=[
                 *bufs,
                 pltpu.SemaphoreType.DMA((len(pools), _AHEAD + 1)),
-                pltpu.VMEM((3 * H, Wk), jnp.bfloat16),        # the queries
+                pltpu.VMEM((3 * H, Dk), jnp.bfloat16),        # the queries
                 pltpu.VMEM((3 * H, PB * pg), jnp.bfloat16),   # the weights
                 pltpu.VMEM((H, 1), jnp.float32),              # running max
                 pltpu.VMEM((H, 1), jnp.float32),              # running sum
                 pltpu.SMEM((4,), jnp.int32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((S, H, Wv), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((S, H, Dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_line_attention",
     )(rows.reshape(-1).astype(jnp.int32), meta, q, *pools)
+    if KV > 1 and R != R0:
+        out = out.reshape(S * KV, R, Dv)[:, :R0].reshape(S, H0, Dv)
     return jnp.where(live[:, None, None], out[:, :H0], 0.0)
 
 

@@ -438,6 +438,9 @@ class PagedLMEngine(DecodeEngine):
             layers_of[fam.draft_kind] += D
         self.kind_layers = layers_of
         self.pass_layers = sum(layers_of.values())
+        # how many of a step's (a round's) kernel calls, one a pass-layer,
+        # contract by key head: the kernel's rule as the family asked it
+        self.attn_by_head = self.pass_layers * fam.step_by_head(1 + D)
         # rows of one layer: its null page 0, then the kind's pages
         R = {kind: pages[kind] + 1 for kind in kinds}
         self.token_bytes = (sum(layers_of.values())
@@ -1395,6 +1398,7 @@ class PagedLMEngine(DecodeEngine):
                        for kind in by_kind}
             layers = self.pass_layers
             padded = self.slots * self.blocks_per_slot
+            prepare.attrs["attn_by_head"] = self.attn_by_head
             prepare.attrs["pages_padded"] = padded
             self.attn_pages["attn_pages_padded"] += padded
             for what, pages in (("read", by_kind), ("fetched", fetched)):

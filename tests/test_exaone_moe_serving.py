@@ -560,3 +560,106 @@ def test_the_expert_counters_sum_the_stacks_layers_and_the_mtp_block():
     # a launch counts its stack's layers and its MTP block too
     assert eng.layer_counts["chunk"]["moe_expert_slots"] == 5 * 5 * 8
     eng.close()
+
+
+# -- the step's operand forms (PR 48) --------------------------------------------
+
+def _by_head_always(monkeypatch):
+    """The kernel's rule answering "by head" at any shape: the rehearsal
+    sizes (4 heads over 2 key heads of 16) then take the head-wide form,
+    which the plain form serves at any width."""
+    from nnstreamer_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(pa, "contracts_by_head", lambda *shape: True)
+
+
+@pytest.mark.parametrize("draft", [True, False], ids=["round", "step"])
+@pytest.mark.parametrize("seed", [1, 2])
+def test_head_wide_rows_emit_the_tokens_the_block_diagonal_query_emitted(
+        seed, draft, monkeypatch):
+    cfg, _, _, params = _model(seed=seed)
+    prompts, steps = _prompts(seed), 20
+    assert not family_of(cfg).step_by_head(2)
+    want, account = _run(cfg, params, prompts, steps, draft=draft)
+    _by_head_always(monkeypatch)
+    assert family_of(cfg).step_by_head(2)
+    got, again = _run(cfg, params, prompts, steps, draft=draft)
+    assert got == want and again == account
+
+
+def test_head_wide_rows_go_in_by_key_head_and_come_back_by_query(
+        monkeypatch):
+    cfg, _, _, params = _model()
+    fam = family_of(cfg)
+    H, KV, Dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                 cfg.head_dim)
+    q = jnp.arange(3 * 2 * H * Dh, dtype=jnp.float32).reshape(3, 2, H, Dh)
+    wide = fam.step_queries(q)
+    assert wide.shape == (3, 2 * H, KV * Dh)
+    _by_head_always(monkeypatch)
+    rows = fam.step_queries(q)
+    assert rows.shape == (3, 2 * H, Dh)
+    # row (g, r, n): query r's head g * G + n, as project made it
+    G = H // KV
+    for g, r, n in [(0, 0, 0), (0, 1, 1), (1, 0, 1), (1, 1, 0)]:
+        assert (rows[:, (g * 2 + r) * G + n] == q[:, r, g * G + n]).all()
+    # and what the kernel gives for those rows goes back in (K, H) order
+    # through the output projection: the same function of the heads as the
+    # whole-line form's own blocks
+    blk = params["blocks"][0]
+    back = fam.step_output(blk, rows)
+    monkeypatch.undo()
+    np.testing.assert_allclose(
+        np.asarray(back), np.asarray(fam.step_output(blk, wide)),
+        rtol=1e-6, atol=1e-6)
+
+
+def test_the_prepare_span_says_how_many_calls_contract_by_head(monkeypatch):
+    from nnstreamer_tpu.obs import context as ctx
+
+    cfg, _, _, params = _model(seed=8, vocab_size=8)
+
+    def spans(**engine):
+        ctx.reset()
+        _run(cfg, params, _prompts(8, vocab=8), 6, **engine)
+        got = [s.attrs["attn_by_head"] for s in ctx.finished_spans()
+               if s.name == "engine.step.prepare"]
+        ctx.reset()
+        assert got
+        return set(got)
+
+    # the rehearsal's 24 stacked rows ride through a tile in one pass
+    assert spans() == {0}
+    _by_head_always(monkeypatch)
+    # five layers and the MTP block a round; the stack alone a step
+    assert spans() == {6}
+    assert spans(draft=False) == {5}
+
+
+def test_a_round_through_the_kernel_by_head_emits_the_plain_forms_tokens(
+        monkeypatch):
+    """32 heads over 4 key heads of 128: a round stacks 192 rows, 48 a key
+    head, and the rule says by head with nothing steered. The plain form
+    and the kernel (interpreted) then get the same head-wide rows."""
+    from nnstreamer_tpu.ops import paged_attention as pa
+
+    cfg, _, _, params = _model(seed=3, num_attention_heads=32,
+                               num_key_value_heads=4, head_dim=128)
+    assert family_of(cfg).step_by_head(2)
+    assert not family_of(cfg).step_by_head(1)   # 96 rows: one pass
+    prompts, steps = _prompts(3, lengths=(21, 5)), 6
+    want, account = _run(cfg, params, prompts, steps)
+    calls = []
+
+    def through_the_kernel(q, kpool, *rest, **kw):
+        calls.append((q.shape, kpool.shape))
+        return pa.kernel_line_attention(q, kpool, *rest, **kw,
+                                        pages_per_block=2, interpret=True)
+
+    monkeypatch.setattr(pa, "paged_line_attention", through_the_kernel)
+    got, again = _run(cfg, params, prompts, steps)
+    assert got == want and again == account
+    # one call a layer and one for the MTP block, every one head-wide
+    assert len(calls) == 6
+    assert {c[0][1:] for c in calls} == {(2 * 32, 128)}
+    assert {c[1][2] for c in calls} == {4 * 128}

@@ -984,6 +984,47 @@ class TestPoolInPlaceOnTpu:
         assert compiled.memory_analysis().temp_size_in_bytes < layer // 6
 
 
+class TestAttentionByHeadOnTpu:
+    """The step's attention kernel with head-wide operands (PR 48), at the
+    widths of the cell that takes them (64 slots, 64 heads over 8 key
+    heads of 128, pages of ``(16, 1024)``, blocks of 32 pages), compiled
+    for a described v5e: lane slices of 128 a key head, 48 stacked rows a
+    product, and what the interpreter cannot refuse (a slice off the
+    tiling, more fast memory than a kernel may use)."""
+
+    # queries a slot, a window layer's starts
+    CALLS = [(2, False), (2, True), (1, False)]
+
+    @pytest.mark.parametrize("K, window", CALLS)
+    def test_the_kernel_compiles_at_the_cells_widths(self, v5e_chip, K,
+                                                     window):
+        import jax
+        import jax.numpy as jnp
+
+        from nnstreamer_tpu.ops import paged_attention as pa
+
+        S, H, KV, Dh, pg, NB, rows = 64, 64, 8, 128, 16, 256, 2 * 2049
+        assert pa.contracts_by_head(3 * K * H, KV, Dh)
+
+        def shape(dims, dt):
+            return jax.ShapeDtypeStruct(dims, dt, sharding=v5e_chip)
+
+        def call(q, kpool, vpool, table, lengths, starts):
+            return pa.kernel_line_attention(
+                q, kpool, vpool, table, lengths, Dh ** -0.5,
+                starts if window else None, queries=K)
+
+        pool = shape((rows, pg, KV * Dh), jnp.bfloat16)
+        compiled = jax.jit(call).lower(
+            shape((S, K * H, Dh), jnp.float32), pool, pool,
+            shape((S, NB), jnp.int32), shape((S,), jnp.int32),
+            shape((S, K) if K > 1 else (S,), jnp.int32)).compile()
+        hlo = compiled.as_text()
+        assert "paged_line_attention" in hlo and "tpu_custom_call" in hlo
+        # head-wide in, head-wide out: nothing of a whole line a row
+        assert f"f32[{S},{K * H},{KV * Dh}]" not in hlo
+
+
 class TestExpertsStreamOnTpu:
     """What the compiled programs of the two expert families hold on a
     TPU (PR 32): the record of where ``ops/moe_grouped.py``'s kernel
