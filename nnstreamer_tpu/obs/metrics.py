@@ -460,7 +460,14 @@ def _collect_serving(reg: Registry) -> None:
              "or close brought home before the next step was dispatched"),
             ("surplus_steps", "surplus_steps",
              "slot-steps whose token was dropped: the one step a slot "
-             "runs over an ending the engine could not foresee (EOS)"))}
+             "runs over an ending the engine could not foresee (EOS)"),
+            ("joins_ahead", "joins_ahead",
+             "prompts whose last launch had the pass's decode step "
+             "dispatched behind it before its first token was pulled"),
+            ("joins_drained", "joins_drained",
+             "prompts whose first token was pulled before anything else "
+             "was dispatched: no step in flight to ride behind, no slot "
+             "with a token to make, or no page for the step"))}
     state_bytes = reg.gauge(
         "nns_serving_state_bytes",
         "bytes of the state layers' cache: a fixed cost a slot, resident "

@@ -14,7 +14,12 @@ Join protocol (``engine.DecodeEngine``, driven by ``DecodeScheduler``):
   ``prefill_tick()`` ingests one fixed-size chunk of one pending prompt a
   call: the chunk is the ONLY compiled prefill shape, so ``compile_count``
   stays flat across prompt lengths. Its width is the engine's to choose
-  (``prefill_width``): as wide as the chip's ridge, where the chip is known;
+  (``prefill_width``): as wide as the chip's ridge, where the chip is known.
+  A launch runs no head. Behind a prompt's last launch ``_seed`` makes
+  the first token on the device (the head over the launch's one last real
+  row) and puts it into the decode carry there; with a step in flight the
+  pass's step is dispatched behind them before the token is pulled, so the
+  device never waits for the host to have read it (``prefill_tick``);
 * ``step()``: one decode step over ALL slots. Inactive slots write to a
   null page and read nothing (static shapes are the point). The engine
   keeps ONE step in flight: a call dispatches the next step and only then
@@ -39,8 +44,10 @@ of a program is split where the host does three different things,
 ``engine.<step|chunk>.prepare`` (page bookkeeping, padding), ``.dispatch``
 (uploads and the jitted call until it returns) and ``.pull`` (the device's
 answer brought to the host; a step's pull is the wait for the step BEFORE
-the one just dispatched). ``host_s`` and ``pull_s`` are the running sums
-of the first two and of the third. Start-up has its own: the serving entry
+the one just dispatched; a chunk's pull is a prompt's first token, behind
+the pass's step where that rode ahead). ``host_s`` and ``pull_s`` are the
+running sums of the first two and of the third. Start-up has its own: the
+serving entry
 builds the engine under ``setup.engine``
 (``models.lm_serving.make_continuous``), and the first call of each jitted
 program runs under a ``program.first_call`` child of the span that made it,
@@ -509,16 +516,25 @@ class PagedLMEngine(DecodeEngine):
         self.spec_rounds = self.spec_proposed = self.spec_accepted = 0
         self.spec_emitted = 0
         # the step in flight, ``(its tokens on the device, the slots it
-        # stepped)``, and the tokens a drain brought home early, kept for
-        # the next ``step()`` to return (``-1``: none for this slot)
+        # stepped, its prepare span)``; behind it the step that a joining
+        # ``prefill_tick`` dispatched for the pass's ``step()`` to find
+        # (``_ride``); and the tokens a drain brought home early, a step's
+        # an entry and the oldest first, kept for the next ``step()`` calls
+        # to return (``-1``: none for this slot)
         self._flight: Optional[tuple] = None
-        self._kept = np.full((slots, 1 + D) if D else (slots,), -1, np.int32)
+        self._ahead: Optional[tuple] = None
+        self._kept: "list[np.ndarray]" = []
+        self._no_tokens = np.full((slots, 1 + D) if D else (slots,), -1,
+                                  np.int32)
         # running sums (``counters``): steps dispatched while another's
         # tokens were still on the device, drains that ``preempt``,
-        # ``restore``, ``verify_commit`` or ``close`` forced, and slot-steps
-        # whose token was dropped (the one step an EOS ending runs over)
+        # ``restore``, ``verify_commit`` or ``close`` forced, slot-steps
+        # whose token was dropped (the one step an EOS ending runs over),
+        # and the prompts whose last launch had the pass's step dispatched
+        # behind it before its token was pulled, beside those that had not
         self.run_ahead = {"steps_ahead": 0, "steps_collected_early": 0,
-                          "surplus_steps": 0}
+                          "surplus_steps": 0, "joins_ahead": 0,
+                          "joins_drained": 0}
         self._pending: "dict[int, dict]" = {}  # slot -> chunked-prefill state
         # slot -> [when its first chunk was dispatched, chunks so far]:
         # outlives _pending, until the slot is released (prefill_stamp)
@@ -868,16 +884,40 @@ class PagedLMEngine(DecodeEngine):
                                    -1).astype(jnp.int32)
                 ends = jnp.concatenate([first, draft])
                 return (ends, *((counts,) if NC else ()), *pools)
-            with jax.named_scope("head"):
-                logits = fam.head(p, x[0])  # (C, V)
-            if NC:
-                return (logits, counts, *pools, *states)
-            return (logits, *pools, *states)
+            # no head here: the launch hands back its rows as the stack
+            # left them, of which ``_seed`` makes the prompt's first token
+            # where the prompt ends in this launch (from the last real row;
+            # any other launch's rows are read by nobody). All of them, not
+            # that row: a slice here sinks up through the last layers'
+            # row-wise operations and the compiler then schedules the whole
+            # launch otherwise, 0.3 ms slower at the ``opt_1.3b`` cells'
+            # sizes (PERF.md section 6, PR 49)
+            return (x[0], *((counts,) if NC else ()), *pools, *states)
 
         self._prefill_chunk = functools.partial(
             jax.jit(_prefill_chunk, donate_argnums=(
                 *range(4 + K, 4 + K + K * P),
                 *range(5 + K + K * P, 5 + K + K * P + NS))), params)
+
+        def _seed(p, carry, slot, ends, at):
+            # behind a prompt's last launch: what it left on the device goes
+            # into the decode carry's row of the slot that joins, and the
+            # next ``_step`` / ``_round`` reads it there before the host has
+            # seen it. The launch left its rows, and the head over the last
+            # real one, row ``at``, makes the prompt's first token here; a
+            # drafting family's launch needs the token itself and left
+            # ``[first token, first draft]``, behind which a round's carry
+            # holds the position, ``at (1,)``. Returns the carry and the
+            # token (and draft) that went in
+            if D:
+                return carry.at[slot].set(jnp.concatenate([ends, at])), ends
+            with jax.named_scope("head"):
+                first = jnp.argmax(fam.head(p, ends[at][None]),
+                                   -1).astype(jnp.int32)
+            return carry.at[slot].set(first), first
+
+        self._seed = functools.partial(
+            jax.jit(_seed, donate_argnums=1), params)
 
         # page movers, one set per kind of layer (compiled when first used)
         def movers(kind):
@@ -1028,37 +1068,57 @@ class PagedLMEngine(DecodeEngine):
         P = len(self._pools)
         self._pools, self._states = tuple(arrays[:P]), tuple(arrays[P:])
 
-    def _hand_over(self, slot: int, token: int, pos: int, left: int,
-                   draft: int = 0) -> None:
-        """``slot`` is live from the next step on, at ``pos`` with ``token``
-        as its input (a prompt's last launch, ``restore``): the host's
-        mirrors take it, and ``_join`` hands the token to the device's
-        carry inside the next ``_step`` call (a drafting engine: the
-        token, the ``draft`` that follows it and the position, to the next
-        ``_round``). Nothing is uploaded here:
-        with a step in flight ``_tok`` is one token old for every other
-        slot, and a whole-array upload would roll them back. Block tables
-        are not device-resident either: they ride into every call as
-        numpy arguments (the committed-call conversion is ~10x cheaper
-        than a device mirror that page-boundary crossings would re-upload
-        mid-decode)."""
-        self._tok[slot, 0] = token
-        if self.drafts:
-            self.next_draft[slot] = draft
-            self._join[slot] = (token, draft, pos)
-        else:
-            self._join[slot] = token
+    def _hand_over(self, slot: int, pos: int, left: int) -> None:
+        """``slot`` is live from the next step on, at ``pos`` and with
+        ``left`` steps to take: the host's mirrors say so. Its input token
+        reaches the device's carry by one of two ways. A prompt's last
+        launch left it on the device, and ``_seed`` put it into the carry
+        there (``prefill_tick``); ``restore`` knows it on the host and says
+        so in ``_join``, which the next ``_step`` / ``_round`` merges.
+        Nothing is uploaded whole in either: with a step in flight ``_tok``
+        is one token old for every other slot, and a whole-array upload
+        would roll them back. Block tables are not device-resident either:
+        they ride into every call as numpy arguments (the committed-call
+        conversion is ~10x cheaper than a device mirror that page-boundary
+        crossings would re-upload mid-decode)."""
         self._pos[slot] = pos
         self._left[slot] = left
         self._mask[slot] = True
 
+    def _ride(self) -> bool:
+        """After a prompt's last launch and its ``_seed`` are dispatched
+        and before its token is pulled: dispatch the pass's step, the
+        slot that joins in it, behind them, so that the device has work
+        queued while the host waits for four bytes. The ``step()`` /
+        ``step_tokens()`` of the same pass finds it in ``_ahead`` and
+        dispatches nothing. True where it rode ahead. It does not where
+        nothing is in flight to run ahead of (no caller is stepping: the
+        first pass after idle, a caller that collects every step, a wrapper
+        that verifies instead of stepping), where an earlier ride has not
+        been taken up, where no slot has a token to make, or where the pool
+        cannot supply the step's pages (``step()`` raises that again where
+        the scheduler handles it): then the pass is the plain one, the
+        token pulled before anything else is dispatched."""
+        from .kv_pool import PagePoolExhausted
+
+        if self._flight is None or self._ahead is not None:
+            return False
+        who = self._mask & (self._left > 0)
+        if not who.any():
+            return False
+        try:
+            self._ahead = self._dispatch(who)
+        except PagePoolExhausted:
+            return False
+        return True
+
     def _drain(self) -> None:
         """Before anything reads or moves a slot's sequence state
-        (``preempt``, ``restore``, ``verify_commit``, ``close``): the step
-        in flight comes home first, and its tokens are kept for the next
-        ``step()`` to return."""
-        if self._flight is not None:
-            self._kept = self.collect()
+        (``preempt``, ``restore``, ``verify_commit``, ``close``): whatever
+        is in flight comes home first, and its tokens are kept for the next
+        ``step()`` calls to return, a step's a call."""
+        while self._flight is not None or self._ahead is not None:
+            self._kept.append(self._wait())
             self.run_ahead["steps_collected_early"] += 1
 
     # -- page bookkeeping -----------------------------------------------------
@@ -1229,7 +1289,15 @@ class PagedLMEngine(DecodeEngine):
         """Ingest ONE chunk of ONE pending prompt (oldest first);
         returns [(slot, first_token)] when that prompt completes, else
         []. The scheduler calls this once per loop pass so prefill
-        interleaves with running decode instead of stalling it."""
+        interleaves with running decode instead of stalling it.
+
+        Behind a prompt's last launch ``_seed`` makes its first token on
+        the device and puts it into the decode carry there. Where a step is in
+        flight, the pass's step, the slot that joins in it, is dispatched
+        behind them (``_ride``), and only then is the token pulled: the
+        device has the launch and a step queued while the host waits, and
+        the pass's ``step()`` only collects. The token is still returned
+        by this call."""
         if not self._pending:
             return []
         jnp = self._jnp
@@ -1253,6 +1321,7 @@ class PagedLMEngine(DecodeEngine):
             # the lines the launch writes and the updates they go in
             prepare.attrs["lines_rows"], prepare.attrs["lines_updates"] = \
                 self.chunk_lines(n_valid)
+            last = start + n_valid == tokens.size
             state_args = ()
             if self._states:  # the launch that starts a sequence zeroes it
                 prepare.attrs["state_reset"] = int(start == 0)
@@ -1260,11 +1329,10 @@ class PagedLMEngine(DecodeEngine):
             elif self.drafts:
                 # the token after the launch's last row, which the drafting
                 # block pairs it with: the prompt's next, or none yet
-                more = start + n_valid < tokens.size
                 state_args = (jnp.asarray(
-                    tokens[start + n_valid] if more else -1, jnp.int32),)
+                    -1 if last else tokens[start + n_valid], jnp.int32),)
         with obs_context.span("engine.chunk.dispatch", **attrs) as dispatch:
-            logits, *rest = self._run(
+            ends, *rest = self._run(
                 "_prefill_chunk", self._prefill_chunk, jnp.asarray(padded),
                 jnp.asarray(start, jnp.int32),
                 jnp.asarray(n_valid, jnp.int32), *self._tables(slot),
@@ -1274,23 +1342,40 @@ class PagedLMEngine(DecodeEngine):
                 # prompt's last chunk, or the next step's tokens)
                 self._chunk_counts.append(rest.pop(0))
             self._keep(rest)
+            if last:
+                # the prompt's first token, made of the launch's answer
+                # where it lies (its last real row; a drafting launch's
+                # own token and draft, with the position behind them) and
+                # put into the decode carry's row of the slot
+                self._tok_dev, ends = self._run(
+                    "_seed", self._seed, self._tok_dev,
+                    np.asarray(slot, np.int32), ends,
+                    np.asarray([tokens.size] if self.drafts
+                               else n_valid - 1, np.int32))
         self.host_s += prepare.dur_s + dispatch.dur_s
         lane = self._lane.setdefault(slot, [dispatch.start_s, 0])
         lane[1] += 1
         st["next"] = start + n_valid
-        if st["next"] < tokens.size:
+        if not last:
             return []
-        # prompt complete: seed the decode carry from the last REAL row
+        # prompt complete: the slot is live, and the pass's step rides
+        # behind the launch where it can, before the host waits for either
         del self._pending[slot]
-        with obs_context.span("engine.chunk.pull", **attrs) as pull:
-            if self.drafts:  # the launch left [first token, first draft]
-                first, draft = map(int, np.asarray(logits))
-            else:
-                first = int(np.argmax(np.asarray(logits[n_valid - 1])))
-                draft = 0
+        self._hand_over(slot, tokens.size, st["steps"] - 1)
+        ahead = self._ride()
+        self.run_ahead["joins_ahead" if ahead else "joins_drained"] += 1
+        with obs_context.span("engine.chunk.pull", ahead=int(ahead),
+                              **attrs) as pull:
+            # nnlint: disable=NNL101 — the prompt's first token (and, where
+            # the family drafts, its first draft): the scheduler routes it
+            # from this call's answer
+            first, *draft = map(int, self._jax.device_get(ends))
             pull.attrs.update(self._pull_chunk_counts())
         self.pull_s += pull.dur_s
-        self._hand_over(slot, first, tokens.size, st["steps"] - 1, draft)
+        # the host's mirrors of what ``_seed`` put into the carry
+        self._tok[slot, 0] = first
+        if self.drafts:
+            self.next_draft[slot] = draft[0]
         if self.share_prefixes:
             # register FULL pages only: a later prompt sharing just the
             # prefix (not the tail) still hits, and registered pages are
@@ -1328,11 +1413,19 @@ class PagedLMEngine(DecodeEngine):
         return self._advance()
 
     def _advance(self) -> np.ndarray:
-        """Dispatch the next step or round, then bring home the one before."""
-        who = self._mask & (self._left > 0)
-        flight = self._dispatch(who) if who.any() else None
+        """Dispatch the next step or round, unless this pass's joining
+        ``prefill_tick`` has (``_ride``), then bring home the one before."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is None:
+            who = self._mask & (self._left > 0)
+            ahead = self._dispatch(who) if who.any() else None
         tok = self.collect()
-        self._flight = flight
+        # behind a step still in flight only where a drain kept two steps'
+        # tokens and this call returned the older: the next call takes it up
+        if self._flight is None:
+            self._flight = ahead
+        else:
+            self._ahead = ahead
         return tok
 
     def _step_tokens(self) -> "list[list[int]]":
@@ -1452,13 +1545,19 @@ class PagedLMEngine(DecodeEngine):
         return tok_dev, who, prepare
 
     def collect(self) -> np.ndarray:
-        """The tokens no ``step()`` has returned yet, ``(slots,)`` with
-        ``-1`` where a slot has none: those of the step in flight, waited
-        for here, and those a drain kept. "``step()``, then ``collect()``"
-        is the synchronous step: each step's own tokens, nothing left in
-        flight."""
-        tok, self._kept = self._kept, np.full_like(self._kept, -1)
-        flight, self._flight = self._flight, None
+        """The oldest tokens no ``step()`` has returned yet, ``(slots,)``
+        with ``-1`` where a slot has none: those a drain kept or, with none
+        kept, those of the step in flight, waited for here. "``step()``,
+        then ``collect()``" is the synchronous step: each step's own
+        tokens, nothing left in flight."""
+        return self._kept.pop(0) if self._kept else self._wait()
+
+    def _wait(self) -> np.ndarray:
+        """The tokens of the oldest step in flight, waited for here (``-1``
+        everywhere with none in flight); the step that rode behind it, if
+        any, is the one in flight from here on."""
+        tok = self._no_tokens.copy()
+        flight, self._flight, self._ahead = self._flight, self._ahead, None
         live = 0 if flight is None else int(flight[1].sum())
         with obs_context.span("engine.step.pull", live=live) as pull:
             if flight is not None:
@@ -1547,16 +1646,17 @@ class PagedLMEngine(DecodeEngine):
             bt[slot] = 0
         self._held_from[slot] = 0
 
-    def _leave(self, slot: int):
-        """``slot`` is out of every later step; returns the token it is
-        owed (``-1``: none; a drafting engine: a round's two, ``-1`` where
-        it emitted fewer): what a drain kept and no ``step()`` has returned
-        yet."""
+    def _leave(self, slot: int) -> np.ndarray:
+        """``slot`` is out of every later step; returns the tokens it is
+        owed, a row a step that a drain kept and no ``step()`` has
+        returned yet (``-1``: none of that step; a drafting engine: a
+        round's two, ``-1`` where it emitted fewer)."""
         self._mask[slot] = False
         self._join[slot] = -1
         self._left[slot] = 0
-        owed = self._kept[slot].copy()
-        self._kept[slot] = -1
+        owed = np.array([kept[slot] for kept in self._kept], np.int32)
+        for kept in self._kept:
+            kept[slot] = -1
         return owed
 
     def release(self, slot: int) -> None:
@@ -1570,10 +1670,12 @@ class PagedLMEngine(DecodeEngine):
             self._pending.pop(slot, None)
             self._lane.pop(slot, None)
             self._drop_pages(slot)
-            dropped = int((self._leave(slot) >= 0).any())
-            if self._flight is not None and self._flight[1][slot]:
-                self._flight[1][slot] = False
-                dropped += 1
+            dropped = sum(bool((owed >= 0).any())
+                          for owed in self._leave(slot))
+            for flight in (self._flight, self._ahead):
+                if flight is not None and flight[1][slot]:
+                    flight[1][slot] = False
+                    dropped += 1
             self.run_ahead["surplus_steps"] += dropped
             self._tok[slot, 0] = 0
             self._pos[slot] = 0
@@ -1598,8 +1700,8 @@ class PagedLMEngine(DecodeEngine):
             raise ServingError(f"slot {slot} not active")
         self._drain()
         # ``left``: the steps its request may still take; ``owed``: the
-        # token the drain brought home for it, which ``restore`` keeps for
-        # the next ``step()`` to return
+        # tokens the drain brought home for it, which ``restore`` keeps for
+        # the next ``step()`` calls to return
         blob = {"pages": (), "used": {}, "state": (), "held_from": int(
                     self._held_from[slot]),
                 "tok": int(self._tok[slot, 0]), "pos": int(self._pos[slot]),
@@ -1663,9 +1765,19 @@ class PagedLMEngine(DecodeEngine):
                     tuple(self._jnp.asarray(b) for b in blob["state"]),
                     *self._states)
                 sp.attrs["state_bytes"] = self.state_slot_bytes
-            self._hand_over(slot, blob["tok"], blob["pos"], blob["left"],
-                            blob["draft"])
-            self._kept[slot] = blob["owed"]
+            self._hand_over(slot, blob["pos"], blob["left"])
+            # the host knows the slot's input token (a round's: and the
+            # draft behind it, and the position) and the device does not
+            self._tok[slot, 0] = blob["tok"]
+            if self.drafts:
+                self.next_draft[slot] = blob["draft"]
+                self._join[slot] = (blob["tok"], blob["draft"], blob["pos"])
+            else:
+                self._join[slot] = blob["tok"]
+            for i, owed in enumerate(blob["owed"]):
+                if i == len(self._kept):
+                    self._kept.append(self._no_tokens.copy())
+                self._kept[i][slot] = owed
         self.pool.note_restore()
 
     # -- introspection --------------------------------------------------------

@@ -99,7 +99,7 @@ class ServingMetrics:
         self.prefill_chunks = 0
         self.host_sched_s = 0.0    # passes less the engine's spans
         self.host_engine_s = 0.0   # prepare + dispatch, both programs
-        self.pull_wait_s = 0.0     # the engine's pulls (tokens, logits)
+        self.pull_wait_s = 0.0     # the engine's pulls, both programs
         # backend compiles jax reported while a pass ran (loads from the
         # persistent cache too) and their seconds: the decode loop's
         # thread's, by ``obs.context.compile_running``. 0 once every shape
@@ -136,10 +136,14 @@ class ServingMetrics:
         # (PagedLMEngine.run_ahead): steps dispatched while the step
         # before's tokens were still on the device, steps brought home
         # early (a preempt, restore, verify round or close), slot-steps
-        # whose token was dropped (the one step an EOS ending runs over)
+        # whose token was dropped (the one step an EOS ending runs over),
+        # and the prompts whose last launch had the pass's step dispatched
+        # behind it before its token was pulled, beside those that had not
         self.steps_ahead = 0
         self.steps_collected_early = 0
         self.surplus_steps = 0
+        self.joins_ahead = 0
+        self.joins_drained = 0
         # device channel: batch execution time (dispatch+block, the
         # reference-comparable number); reservoirs: per-request tails
         self.device = InvokeStats()
@@ -229,7 +233,8 @@ class ServingMetrics:
         programs added up), the passes its tokens left at (``exit_pass_*``),
         its steps' attention (``attn_pages_*``), its state layers' cache
         (``state_slots*``) and how its steps ran ahead
-        (``steps_ahead``, ``steps_collected_early``, ``surplus_steps``)."""
+        (``steps_ahead``, ``steps_collected_early``, ``surplus_steps``,
+        ``joins_ahead``, ``joins_drained``)."""
         with self._lock:
             self.attn_pages_read += counts.get("attn_pages_read", 0)
             self.attn_pages_fetched += counts.get("attn_pages_fetched", 0)
@@ -246,6 +251,8 @@ class ServingMetrics:
             self.steps_collected_early += counts.get(
                 "steps_collected_early", 0)
             self.surplus_steps += counts.get("surplus_steps", 0)
+            self.joins_ahead += counts.get("joins_ahead", 0)
+            self.joins_drained += counts.get("joins_drained", 0)
             self.moe_experts_touched += counts.get("moe_experts_touched", 0)
             self.moe_expert_slots += counts.get("moe_expert_slots", 0)
             self.moe_assignments += counts.get("moe_assignments", 0)
@@ -311,6 +318,8 @@ class ServingMetrics:
                 "steps_ahead": self.steps_ahead,
                 "steps_collected_early": self.steps_collected_early,
                 "surplus_steps": self.surplus_steps,
+                "joins_ahead": self.joins_ahead,
+                "joins_drained": self.joins_drained,
                 **self.exit_passes,
             }
         out["device"] = self.device.snapshot()

@@ -18,7 +18,7 @@ import sys
 
 import numpy as np
 import pytest
-from engine_util import step_now
+from engine_util import launch, spy_launches, step_now
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -83,15 +83,7 @@ def test_chunked_prefill_then_decode_matches_the_reference_forward():
     eng = _entry(cfg, params).make_continuous(
         slots=3, page_size=4, chunk=8, pages=48)
     assert isinstance(eng, PagedLMEngine) and eng.family.name == "deepseek_v3"
-    # keep every chunk's logits as the program returned them
-    chunk_logits, real = [], eng._prefill_chunk
-
-    def spy(*args):
-        out = real(*args)
-        chunk_logits.append((int(args[1]), int(args[2]), np.asarray(out[0])))
-        return out
-
-    eng._prefill_chunk = spy
+    chunk_scores = spy_launches(eng)
     sched = DecodeScheduler(eng, name="dsv3-a")
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, 96, n).astype(np.int32)
@@ -107,19 +99,21 @@ def test_chunked_prefill_then_decode_matches_the_reference_forward():
         gap = exact.max(-1) - np.take_along_axis(
             exact, served[:, None], 1)[:, 0]
         assert gap.max() <= GAP_TOL, "a served token is not the reference's"
-    # prefill logits, chunk by chunk, for the first prompt (alone in the
+    # the launches of the first prompt, and its last row's scores (alone in the
     # lane first: its chunks are the first three calls)
     prompt = prompts[0]
     full = ref.logits_for(
         key, sz, np.pad(prompt, (0, 48 - prompt.size))[None],
         np.arange(prompt.size, dtype=np.int32)[None])["none"][0]
     seen = 0
-    for start, n_valid, logits in chunk_logits[:3]:
+    for start, n_valid, scores in chunk_scores[:3]:
         assert start == seen
-        np.testing.assert_allclose(logits[:n_valid],
-                                   full[start:start + n_valid],
-                                   atol=LOGIT_TOL, rtol=0)
         seen += n_valid
+        if seen < prompt.size:
+            assert scores is None, "only a prompt's last launch runs the head"
+        else:
+            np.testing.assert_allclose(scores, full[seen - 1],
+                                       atol=LOGIT_TOL, rtol=0)
     assert seen == prompt.size
     # the expert layers counted what they did, in both programs
     slots = eng.family.expert_slots
@@ -176,20 +170,20 @@ def test_served_through_the_experts_kernel_matches_the_reference(monkeypatch):
 
 def test_a_bfloat16_cache_would_fail():
     # the same weights (exact in bfloat16) served with bfloat16 cache lines
-    # and products: the chunk's logits leave the reference by far more than
-    # LOGIT_TOL, which is what makes the limits above a test
+    # and products: the scores of the launch's last row leave the reference
+    # by far more than LOGIT_TOL, which is what makes the limits above a test
     cfg, sz, key, params = _model(dtype=jnp.bfloat16)
     eng = PagedLMEngine(cfg, params, slots=1, page_size=4, chunk=24, pages=16)
     assert eng._pools[0].dtype == jnp.bfloat16
+    launches = spy_launches(eng)
     prompt = np.random.default_rng(3).integers(0, 96, 21).astype(np.int32)
     eng._ensure_writable(0, 0, 21)
-    logits = np.asarray(eng._prefill_chunk(
-        jnp.asarray(np.pad(prompt, (0, 3))), jnp.int32(0), jnp.int32(21),
-        eng._bt[0], *eng._pools)[0], np.float32)[:21]
+    launch(eng, np.pad(prompt, (0, 3)), 0, 21)
+    scores = np.asarray(launches[0][2], np.float32)
     full = ref.logits_for(
         key, sz, np.pad(prompt, (0, 27))[None],
         np.arange(21, dtype=np.int32)[None])["none"][0]
-    assert np.abs(logits - full).max() > 10 * LOGIT_TOL
+    assert np.abs(scores - full[20]).max() > 10 * LOGIT_TOL
 
 
 # -- (b) absorbed against expanded attention, one layer ------------------------

@@ -24,7 +24,7 @@ import sys
 
 import numpy as np
 import pytest
-from engine_util import step_now
+from engine_util import spy_launches, step_now
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -165,14 +165,7 @@ def test_chunked_prefill_then_decode_matches_the_reference_forward():
     assert eng.kind_layers == {"full": 2}
     assert [s.shape for s in eng._states] == [(6, 3, 192), (6, 3, 16, 64)]
     assert eng._states[1].dtype == jnp.float32
-    chunk_logits, real = [], eng._prefill_chunk
-
-    def spy(*args):
-        out = real(*args)
-        chunk_logits.append((int(args[1]), int(args[2]), np.asarray(out[0])))
-        return out
-
-    eng._prefill_chunk = spy
+    chunk_scores = spy_launches(eng)
     dispatched, real_step = [], eng._step
 
     def count(*args):
@@ -196,19 +189,21 @@ def test_chunked_prefill_then_decode_matches_the_reference_forward():
     for prompt, served in zip(prompts, outs):
         assert _gaps(key, sz, prompt, served).max() <= GAP_TOL, \
             "a served token is not the reference's"
-    # prefill logits, chunk by chunk, for the first prompt (alone in the
+    # the launches of the first prompt, and its last row's scores (alone in the
     # lane first: its chunks are the first five calls)
     prompt = prompts[0]
     full = ref.logits_for(
         key, sz, np.pad(prompt, (0, LIMIT - prompt.size))[None],
         np.arange(prompt.size, dtype=np.int32)[None])["none"][0]
     seen = 0
-    for start, n_valid, logits in chunk_logits[:5]:
+    for start, n_valid, scores in chunk_scores[:5]:
         assert start == seen
-        np.testing.assert_allclose(logits[:n_valid],
-                                   full[start:start + n_valid],
-                                   atol=LOGIT_TOL, rtol=0)
         seen += n_valid
+        if seen < prompt.size:
+            assert scores is None, "only a prompt's last launch runs the head"
+        else:
+            np.testing.assert_allclose(scores, full[seen - 1],
+                                       atol=LOGIT_TOL, rtol=0)
     assert seen == prompt.size
     assert eng.compile_count == 2, "one step and one chunk program"
     # the state is a kind of cache of its own in the snapshot
@@ -254,8 +249,8 @@ def test_grouped_query_heads_serve_too():
 @pytest.mark.parametrize("chunk", [4, 8, 12])
 def test_a_prompt_in_one_launch_and_in_several_leaves_the_same_state(chunk):
     """29 tokens in one launch of 32, and in launches of 4 (ragged last: 1),
-    8 (5) and 12 (5): the same logits and the same state to the order of
-    float32 sums."""
+    8 (5) and 12 (5): the same scores of the last row and the same state
+    to the order of float32 sums."""
     cfg, sz, key, params = _model()
     rng = np.random.default_rng(4)
     prompt = _prompt(rng, 29)
@@ -263,16 +258,11 @@ def test_a_prompt_in_one_launch_and_in_several_leaves_the_same_state(chunk):
     def ingest(chunk):
         eng = _entry(cfg, params).make_continuous(**{**ENGINE,
                                                      "chunk": chunk})
-        logits, real = [], eng._prefill_chunk
-
-        def spy(*args):
-            out = real(*args)
-            logits.append(np.asarray(out[0])[:int(args[2])])
-            return out
-
-        eng._prefill_chunk = spy
+        launches = spy_launches(eng)
         first = eng.admit(2, prompt, 8)
-        return np.concatenate(logits), _states(eng, 2), first, eng
+        assert [scores is None for _, _, scores in launches] == \
+            [True] * (len(launches) - 1) + [False]
+        return launches[-1][2], _states(eng, 2), first, eng
 
     whole_logits, whole_state, first, _ = ingest(32)
     logits, state, again, eng = ingest(chunk)

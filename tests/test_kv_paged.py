@@ -866,8 +866,8 @@ class TestPoolInPlaceOnTpu:
         """The cell's launch (PR 44): each of its four writes (two layers,
         keys and values) is one scatter of 16 whole pages where it was one
         of 256 lines, both pools are aliased and neither is copied, and
-        the program is no larger: at most 3% over the 1,161 instructions
-        this launch had at PR 43 (a program's load follows its
+        the program is no larger: at most 3% over the instructions this
+        launch has had (1,161 at PR 43; a program's load follows its
         instructions, and ``setup_s`` the load)."""
         import jax
 
@@ -904,7 +904,43 @@ class TestPoolInPlaceOnTpu:
             >= 2 * int(np.prod(pool.shape)) * 2
         instructions = len(re.findall(r"^\s+(?:ROOT )?%?[\w.\-]+ = ", hlo,
                                       re.M))
+        # 1,158 until PR 49, which took the head out of the launch
         assert instructions <= 1161 * 1.03, instructions
+
+    def test_the_cells_launch_runs_no_head_and_the_seed_scores_one_row(
+            self, v5e_chip):
+        """The cell's launch (PR 49) runs no head: it hands back its rows
+        as the stack left them, 2 MB, and no ``(256, vocab)`` scores are
+        made anywhere in it (51 MB of float32 that one row was read of,
+        behind a prompt's last launch alone), nor any of one row.
+        ``_seed``, the program behind a prompt's last launch, scores the
+        last real row, takes the best token and puts it into the decode
+        carry: its answer is the carry and four bytes."""
+        import jax
+        import jax.numpy as jnp
+
+        eng, cfg, pool, args = self._cells_launch(v5e_chip)
+        C, V = eng.chunk, cfg.vocab
+        assert (C, V) == (256, 50272)
+        lowered = eng._prefill_chunk.func.lower(*args)
+        hlo = lowered.compile().as_text()
+        assert not re.findall(rf"\[(?:1,)?(?:{C}|1),{V}\]", hlo), \
+            "the launch still scores its rows"
+        assert " conditional(" not in hlo
+        out = lowered.out_info[0]
+        assert (out.shape, out.dtype) == ((C, cfg.dim), jnp.float32)
+
+        def shape(s, dt):
+            return jax.ShapeDtypeStruct(s, dt, sharding=v5e_chip)
+
+        seed = eng._seed.func.lower(
+            args[0], shape((eng.slots, 1), jnp.int32), shape((), jnp.int32),
+            shape((C, cfg.dim), jnp.float32), shape((), jnp.int32))
+        assert [(o.shape, o.dtype) for o in seed.out_info] == [
+            ((eng.slots, 1), jnp.int32), ((1,), jnp.int32)]
+        text = seed.compile().as_text()
+        assert re.search(rf"f32\[(?:1,)?{V}\]", text), "one row is scored"
+        assert not re.findall(rf"\[{C},{V}\]", text)
 
     @pytest.mark.parametrize("program", ["_step", "_prefill_chunk"])
     def test_the_looped_familys_programs_copy_no_weight_through_hbm(

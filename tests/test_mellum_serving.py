@@ -22,7 +22,7 @@ import sys
 
 import numpy as np
 import pytest
-from engine_util import step_now
+from engine_util import spy_launches, step_now
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -182,14 +182,7 @@ def test_chunked_prefill_then_decode_matches_the_reference_forward():
     assert isinstance(eng, PagedLMEngine) and eng.family.name == "mellum"
     assert eng.kinds == ("full", "window")
     assert eng.held_blocks == {"full": LIMIT // 4, "window": HELD}
-    chunk_logits, real = [], eng._prefill_chunk
-
-    def spy(*args):
-        out = real(*args)
-        chunk_logits.append((int(args[1]), int(args[2]), np.asarray(out[0])))
-        return out
-
-    eng._prefill_chunk = spy
+    chunk_scores = spy_launches(eng)
     sched = DecodeScheduler(eng, name="mellum-a")
     rng = np.random.default_rng(0)
     # 37 crosses the window inside prefill and ends past YaRN's original
@@ -207,19 +200,21 @@ def test_chunked_prefill_then_decode_matches_the_reference_forward():
     for prompt, served in zip(prompts, outs):
         assert _gaps(key, sz, prompt, served).max() <= GAP_TOL, \
             "a served token is not the reference's"
-    # prefill logits, chunk by chunk, for the first prompt (alone in the
+    # the launches of the first prompt, and its last row's scores (alone in the
     # lane first: its chunks are the first five calls)
     prompt = prompts[0]
     full = ref.logits_for(
         key, sz, np.pad(prompt, (0, LIMIT - prompt.size))[None],
         np.arange(prompt.size, dtype=np.int32)[None])["none"][0]
     seen = 0
-    for start, n_valid, logits in chunk_logits[:5]:
+    for start, n_valid, scores in chunk_scores[:5]:
         assert start == seen
-        np.testing.assert_allclose(logits[:n_valid],
-                                   full[start:start + n_valid],
-                                   atol=LOGIT_TOL, rtol=0)
         seen += n_valid
+        if seen < prompt.size:
+            assert scores is None, "only a prompt's last launch runs the head"
+        else:
+            np.testing.assert_allclose(scores, full[seen - 1],
+                                       atol=LOGIT_TOL, rtol=0)
     assert seen == prompt.size
     assert eng.compile_count == 2, "one step and one chunk program"
     assert eng.window_pages_released > 0

@@ -24,7 +24,7 @@ import sys
 
 import numpy as np
 import pytest
-from engine_util import step_now
+from engine_util import spy_launches, step_now
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -115,19 +115,6 @@ def _pools(eng):
             for p in eng._pools]
 
 
-def _spy_chunks(eng):
-    """Every launch's ``(start, n_valid, logits)`` from here on."""
-    seen, real = [], eng._prefill_chunk
-
-    def spy(*args):
-        out = real(*args)
-        seen.append((int(args[1]), int(args[2]), np.asarray(out[0])))
-        return out
-
-    eng._prefill_chunk = spy
-    return seen
-
-
 # -- the family ----------------------------------------------------------------
 
 def test_the_family_is_chosen_by_the_configurations_type_and_says_its_passes():
@@ -207,7 +194,7 @@ def test_chunked_prefill_then_decode_matches_the_reference_forward(threshold):
     assert isinstance(eng, PagedLMEngine) and eng.family.name == "ouro"
     assert eng.kinds == ("full",) and eng.passes == PASSES
     assert eng.kind_layers == {"full": PASSES * LAYERS}
-    chunks = _spy_chunks(eng)
+    chunks = spy_launches(eng)
     sched = DecodeScheduler(eng, name=f"ouro-{threshold}")
     rng = np.random.default_rng(0)
     # five launches with a ragged last one; one launch; six; two; one
@@ -236,12 +223,14 @@ def test_chunked_prefill_then_decode_matches_the_reference_forward(threshold):
         key, sz, np.pad(prompt, (0, LIMIT - prompt.size))[None],
         np.arange(prompt.size, dtype=np.int32)[None])["none"][0]
     seen = 0
-    for start, n_valid, logits in chunks[:5]:
+    for start, n_valid, scores in chunks[:5]:
         assert start == seen
-        np.testing.assert_allclose(logits[:n_valid],
-                                   full[start:start + n_valid],
-                                   atol=LOGIT_TOL, rtol=0)
         seen += n_valid
+        if seen < prompt.size:
+            assert scores is None, "only a prompt's last launch runs the head"
+        else:
+            np.testing.assert_allclose(scores, full[seen - 1],
+                                       atol=LOGIT_TOL, rtol=0)
     assert seen == prompt.size
     assert eng.compile_count == 2, "one step and one chunk program"
     # the counts: every position the served path computed left at the
@@ -297,14 +286,16 @@ def test_a_prompt_in_one_launch_and_in_several_leaves_the_same_lines(chunk):
     got = []
     for width in (32, chunk):
         cfg, sz, key, eng = _engine(engine={"chunk": width})
-        chunks = _spy_chunks(eng)
+        chunks = spy_launches(eng)
         first = eng.admit(0, prompt, 4)
         assert len(chunks) == -(-29 // width)
         pages = [int(p) for p in eng._bt[0] if p]
         assert len(pages) == 8
         lines = [pool[:, pages].reshape(PASSES * LAYERS, 32, -1)[:, :29]
                  for pool in _pools(eng)]
-        got.append((first, chunks[-1][2][chunks[-1][1] - 1], lines))
+        assert [c[2] is None for c in chunks] == [True] * (len(chunks) - 1) \
+            + [False]
+        got.append((first, chunks[-1][2], lines))
     (a_first, a_logits, a_lines), (b_first, b_logits, b_lines) = got
     assert a_first == b_first
     np.testing.assert_allclose(a_logits, b_logits, atol=LOGIT_TOL, rtol=0)
@@ -334,7 +325,7 @@ def test_a_pass_reads_its_own_lines_and_no_later_passs(leaves_at):
         cfg, sz, key, params = _model(early_exit_threshold=threshold)
         params = {**params, "gate_w": jnp.zeros_like(params["gate_w"])}
         eng = _entry(cfg, params).make_continuous(**ENGINE)
-        chunks = _spy_chunks(eng)
+        chunks = spy_launches(eng)
         eng.admit_start(0, prompt, 4)
         eng.prefill_tick()                      # positions 0..7
         rows = eng._pools[0].shape[0] // (PASSES * LAYERS)
@@ -533,8 +524,8 @@ def _as_they_come(monkeypatch):
 
 def _serve(eng, prompts, steps):
     """Every prompt through a scheduler: the tokens, and each launch's
-    ``(start, n_valid, logits)``."""
-    chunks = _spy_chunks(eng)
+    ``(start, n_valid, its last row's scores or None)``."""
+    chunks = spy_launches(eng)
     sched = DecodeScheduler(eng, name="ouro-stored")
     try:
         reqs = [sched.submit(p, steps=steps) for p in prompts]
@@ -594,10 +585,13 @@ def test_the_stored_form_serves_what_the_given_form_served(kv_heads,
     assert len({int(t) for o in outs for t in o}) > 4, \
         "the toy model does not say one token"
     assert len(chunks) == len(old_chunks)
-    for (start, n, logits), (start0, n0, logits0) in zip(chunks, old_chunks):
+    for (start, n, scores), (start0, n0, scores0) in zip(chunks, old_chunks):
         assert (start, n) == (start0, n0)
-        np.testing.assert_allclose(logits[:n], logits0[:n0],
-                                   atol=LOGIT_TOL, rtol=0)
+        assert (scores is None) == (scores0 is None)
+        if scores is not None:
+            np.testing.assert_allclose(scores, scores0, atol=LOGIT_TOL,
+                                       rtol=0)
+    assert sum(scores is not None for _, _, scores in chunks) == len(prompts)
 
 
 def _single_pass_families():
@@ -710,7 +704,11 @@ def test_a_family_with_one_pass_lowers_to_the_parents_program_text():
     input to the same compiler: no program of theirs changed. A PR that
     means to change one of these programs writes the file anew and says so:
     PR 44 did for the four ``_prefill_chunk`` (a launch writes its lines a
-    page at a time); the four ``_step`` hashes are still PR 42's.
+    page at a time) and PR 49 again (a launch runs no head: it hands back
+    its rows as the stack left them, of which ``_seed`` behind a prompt's
+    last launch makes the first token on the device; no ``f32[C, vocab]``
+    is made and no argument joined the program); the four
+    ``_step`` hashes are still PR 42's.
     """
     with open(GOLDEN) as fh:
         golden = json.load(fh)

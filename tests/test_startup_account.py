@@ -92,10 +92,13 @@ def test_make_continuous_leaves_one_span_for_the_weights_and_one_for_the_engine(
 def test_each_program_that_ran_has_one_first_call_under_the_span_that_made_it(
         started):
     calls = [s for s in started["startup"] if s.name == "program.first_call"]
+    # ``_seed``: the few bytes that put a prompt's first token into the
+    # decode carry behind its last launch (PR 49), in the launch's dispatch
     assert sorted(s.attrs["program"] for s in calls) == [
-        "_prefill_chunk", "_step"]
+        "_prefill_chunk", "_seed", "_step"]
     under = {s.attrs["program"]: s.parent.name for s in calls}
     assert under == {"_prefill_chunk": "engine.chunk.dispatch",
+                     "_seed": "engine.chunk.dispatch",
                      "_step": "engine.step.dispatch"}
     # every later call opened nothing: the dispatch spans are many
     dispatches = [s for s in started["spans"]
@@ -103,7 +106,7 @@ def test_each_program_that_ran_has_one_first_call_under_the_span_that_made_it(
                                 "engine.step.dispatch")]
     assert len(dispatches) > 4
     in_ring = [s for s in started["spans"] if s.name == "program.first_call"]
-    assert len(in_ring) == 2
+    assert len(in_ring) == 3
 
 
 def test_a_first_call_carries_what_jax_spent_on_it(started):

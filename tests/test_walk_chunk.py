@@ -12,9 +12,10 @@ the package). Against it:
   launches that have an edge: the first of a prompt, one that starts on and
   one off a block's edge, one with padded rows, the one that ends at the
   serving limit, a window that begins mid-page;
-* the engine's launches, family by family: the logits of every launch of a
-  prompt and the lines it left in the pools, through ``prefill_tick`` as the
-  scheduler drives it, and a launch over prefix pages another request wrote;
+* the engine's launches, family by family: the scores a prompt's last
+  launch makes its token from and the lines every launch left in the
+  pools, through ``prefill_tick`` as the scheduler drives it, and a launch
+  over prefix pages another request wrote;
 * the walk's rule (``chunk_walk``) on hand-made launches, and the engine's
   ``ctx_read`` / ``ctx_padded`` by it.
 """
@@ -23,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from engine_util import step_now
+from engine_util import spy_launches, step_now
 from nnstreamer_tpu.ops import paged_attention
 from nnstreamer_tpu.ops.paged_attention import (
     chunk_block_pages,
@@ -223,20 +224,13 @@ PROMPTS = {"one_launch": 24, "padded_rows": 13, "one_row": 1,
 
 def _engine(family):
     """An engine of ``family`` (whose launches attend by whatever form
-    ``paged_attention.chunk_line_attention`` is while it is built) and the
-    logits of every launch it runs."""
+    ``paged_attention.chunk_line_attention`` is while it is built) and,
+    of every launch it runs, ``(start, n_valid, the scores of its last row
+    where it made a token)``."""
     cfg, params, more = FAMILIES[family]()
     eng = PagedLMEngine(cfg, params, slots=2, page_size=PG, chunk=C, **more)
     assert eng.chunk_block_pages == 2
-    launches = []
-    program = eng._prefill_chunk
-
-    def recorded(*args):
-        out = program(*args)
-        launches.append((int(args[1]), int(args[2]), np.asarray(out[0])))
-        return out
-
-    eng._prefill_chunk = recorded
+    launches = spy_launches(eng)
     return eng, launches
 
 
@@ -318,9 +312,10 @@ def test_a_prompts_launches_equal_the_gathered_forms(family, prompt, engines):
     (got, *toks), (want, *want_toks) = (r[:3] for r in runs)
     assert [g[:2] for g in got] == [w[:2] for w in want]
     assert len(got) == -(-n // C)
-    for (start, n_valid, a), (_, _, b) in zip(got, want):
-        np.testing.assert_allclose(a[:n_valid], b[:n_valid], atol=TOL,
-                                   rtol=TOL, err_msg=f"launch at {start}")
+    assert [a is None for _, _, a in got] == [True] * (len(got) - 1) + [False]
+    for (start, n_valid, a), (_, _, b) in zip(got[-1:], want[-1:]):
+        np.testing.assert_allclose(a, b, atol=TOL, rtol=TOL,
+                                   err_msg=f"launch at {start}")
     assert toks == want_toks
     for a, b in zip(runs[0][3], runs[1][3]):
         np.testing.assert_allclose(a, b, atol=TOL, rtol=TOL)
@@ -349,9 +344,10 @@ def test_a_launch_over_another_requests_prefix_pages(family, shared,
         runs.append((list(launches), first))
         _give_back(eng, 0, 1)
     (got, first), (want, want_first) = runs
-    for (start, n_valid, a), (_, _, b) in zip(got, want):
-        np.testing.assert_allclose(a[:n_valid], b[:n_valid], atol=TOL,
-                                   rtol=TOL, err_msg=f"launch at {start}")
+    assert [a is None for _, _, a in got] == [True] * (len(got) - 1) + [False]
+    for (start, n_valid, a), (_, _, b) in zip(got[-1:], want[-1:]):
+        np.testing.assert_allclose(a, b, atol=TOL, rtol=TOL,
+                                   err_msg=f"launch at {start}")
     assert first == want_first
 
 

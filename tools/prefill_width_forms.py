@@ -192,10 +192,12 @@ def launch_ms(config: dict, width: int, positions=None, start=512,
 
         def run(reps):
             for _ in range(reps):
-                # a family with state layers: slot 0's rows of its arrays
-                _logits, *rest = engine._prefill_chunk(
+                # a family with state layers: slot 0's rows of its arrays;
+                # a drafting family: "no next token" (a prompt's last launch)
+                _row, *rest = engine._prefill_chunk(
                     *args, *engine._pools,
-                    *((slot, *engine._states) if engine._states else ()))
+                    *((slot, *engine._states) if engine._states else ()),
+                    *((jnp.int32(-1),) if engine.drafts else ()))
                 engine._keep(rest[-kept:])
             jax.block_until_ready(engine._pools)
 

@@ -29,7 +29,9 @@ split by what the program itself recorded before the window opened
 * ``first_calls``: every ``program.first_call`` span with what jax charged
   to it, and ``fresh``: jax's names of the programs compiled fresh;
 * ``window``: backend compiles inside the window, by the account, by the
-  ``serving.pass`` spans that carry ``compiles``, and by the benchmark;
+  ``serving.pass`` spans that carry ``compiles``, and by the benchmark; and
+  the prompts that joined inside it, ``joins_ahead`` / ``joins_drained``
+  (of the spans the ring still holds: ``ring`` says how far back it goes);
 * ``ring``: ``obs.context.stats()``, the age of the oldest span kept and
   whether it is older than the window's opening and the traced part.
 
@@ -130,6 +132,12 @@ def report(outcome: dict, t_start: float) -> dict:
                              if s.name == "serving.pass"
                              and opened <= s.start_s < closed),
         "bench_compiles_in_window": facts.get("compiles_in_window")}
+    # the prompts that joined inside the window, by whether the pass's step
+    # rode behind their last launch (``engine.chunk.pull``'s ``ahead``)
+    pulls = [s.attrs.get("ahead") for s in ring
+             if s.name == "engine.chunk.pull" and opened <= s.start_s < closed]
+    out["window"]["joins_ahead"] = pulls.count(1)
+    out["window"]["joins_drained"] = pulls.count(0)
     now = time.monotonic()
     oldest = ring[0].start_s if ring else None
     bounds = facts.get("trace_bounds")
