@@ -30,6 +30,7 @@ from dataclasses import dataclass, fields
 from typing import Any, Dict, Optional, Tuple
 
 from ..parallel import moe_dropless
+from .families import PlainStack
 
 
 @dataclass(frozen=True)
@@ -196,7 +197,7 @@ def rotate_pairs(x, pos, theta: float):
                      axis=-1).reshape(x.shape)
 
 
-class DeepseekV3Family:
+class DeepseekV3Family(PlainStack):
     """The block above as the paged engine takes it
     (``models/families.py`` has the contract)."""
 

@@ -182,13 +182,82 @@ A family says:
   ``(y, counts)``; ``live (B, Q)`` marks the rows that are real, and
   ``counts`` is ``None`` or the int32 vector ``counters`` names.
 * ``head(p, x)`` — final norm and output head of rows ``x (n, D)``.
+
+Three more things are a family's to say, and :class:`PlainStack` holds the
+answers of a family that has nothing to say (``gpt``, ``deepseek_v3``,
+``mellum``, ``jamba``, ``ouro`` and ``exaone_moe`` all keep it, and their
+programs are the ones they had before these existed). The skeleton has one
+path: it calls ``project_slot`` and ``ffn_carry`` for every family, and
+:class:`PlainStack` makes them of the ``project`` and ``ffn`` above.
+
+* ``slot_lines`` — what a *slot* keeps in every *attention* layer beside
+  the lines a token keeps there: one ``(shape, dtype)`` per array, flat, as
+  ``state_lines`` are; ``()`` where an attention layer is a function of the
+  launch's own rows. ``zaya`` (``models/zaya.py``) says one float32 line of
+  ``2 * 1280 + 128``: its projection mixes each row with the rows before it
+  (two causal convolutions of width 2 and a value taken from the token
+  before), so a step needs the last packed row, the last row between the
+  convolutions and the last shifted value. The engine keeps one device
+  array ``(attention layers, slots, *shape)`` per entry behind the state
+  layers' arrays, donated through ``_step`` and ``_prefill_chunk`` beside
+  the pools, and hands a layer its batch entries' rows:
+
+  - ``project_slot(blk, x, pos, kind, state, rows) -> (q, lines, state')``
+    — ``state`` one ``(B, *shape)`` array per entry of ``slot_lines``, what
+    each batch entry's sequence left (zeros where it starts: the engine's
+    to say), ``rows (B,)`` how many of the ``Q`` rows of each entry are
+    real (a step: 1 for a slot in the step, 0 for any other; a launch: its
+    ``n_valid``). ``state'`` is the state after the entry's last real row,
+    and with no real row the state as it was read, bit for bit: a padded
+    row moves nothing. The default is ``project`` with ``state`` (``()``)
+    handed back.
+
+  Prefix sharing is refused for such a family (a hit would need the
+  slot's rows as they were at the prefix's last token), as are several
+  passes and a drafting block; ``preempt`` carries the rows with the
+  pages.
+* ``open_stack(p, x) -> carry`` and ``ffn_carry(blk, x, live, carry) ->
+  (y, counts, carry')`` — one opaque value that goes down the stack inside
+  a token, from each layer's feed-forward to the next's: the family opens
+  it before layer 0 for activations ``x (B, Q, D)``, the engine threads it
+  and never looks into it (a drafting block is a stack of one layer: its
+  carry is opened anew). ``zaya``: the router's activations ``(B, Q,
+  router_hidden_size)`` after the layer's own addition (exponential depth
+  averaging; ``None`` before layer 0, where a first pipeline stage has
+  nothing to receive). The default opens ``None`` and is ``ffn`` with the
+  carry handed on.
+* ``merge(blk, x, y, part) -> x'`` — what the skeleton does with the
+  residual stream ``x`` and a part's output ``y`` (``part``: ``"attention"``,
+  ``"state"`` or ``"ffn"``). The default is ``x + y``; ``zaya`` scales and
+  shifts both with learned per-channel vectors (``scale_residual_merge``),
+  under the ``jax.named_scope`` ``merge``.
 """
 from __future__ import annotations
 
 from .transformer import TransformerConfig, _rmsnorm
 
 
-class GroupedQueryLines:
+class PlainStack:
+    """The answers of a family whose attention layers are functions of the
+    launch's own rows, whose layers hand each other the residual stream
+    alone and whose parts are added to it: every family but ``zaya``."""
+
+    slot_lines = ()                # no attention layer keeps a state a slot
+
+    def project_slot(self, blk, x, pos, kind, state, rows):
+        return (*self.project(blk, x, pos, kind), state)
+
+    def open_stack(self, p, x):
+        return None                # nothing but x goes down the stack
+
+    def ffn_carry(self, blk, x, live, carry):
+        return (*self.ffn(blk, x, live), carry)
+
+    def merge(self, blk, x, y, part):
+        return x + y               # the residual adds a part's output
+
+
+class GroupedQueryLines(PlainStack):
     """What the families whose attention layers keep keys and values of
     ``num_key_value_heads`` heads side by side share (``mellum``,
     ``jamba``): two lines of ``kv_heads * head_dim`` a token, the step's
@@ -257,7 +326,7 @@ class GroupedQueryLines:
         return o.reshape(*o.shape[:2], -1) @ blk["wo"]
 
 
-class GPTFamily:
+class GPTFamily(PlainStack):
     """The repo's GPT-2-shaped block (``models/transformer.py``): learned
     positions, one fused ``wqkv``, full multi-head attention over keys and
     values, a ReLU MLP (or the trainer's switch layer), a tied head."""
@@ -388,6 +457,17 @@ class GPTFamily:
         return _rmsnorm(x, p["out_norm"]) @ p["embed"].T
 
 
+def kept_state(family) -> str:
+    """What a slot keeps beside its pages, in the words the refusals use:
+    by where it lives, in state layers or in the attention layers; ``""``
+    for a family that keeps none."""
+    return " and ".join(
+        what for what, lines in (
+            ("its state layers' state", family.state_lines),
+            ("the state a slot keeps in its attention layers",
+             family.slot_lines)) if lines)
+
+
 def _families() -> tuple:
     """``(configuration type, family)`` for every family there is: the one
     table :func:`family_of` dispatches on."""
@@ -396,13 +476,15 @@ def _families() -> tuple:
     from .jamba import JambaConfig, JambaFamily
     from .mellum import MellumConfig, MellumFamily
     from .ouro import OuroConfig, OuroFamily
+    from .zaya import ZayaConfig, ZayaFamily
 
     return ((TransformerConfig, GPTFamily),
             (DeepseekV3Config, DeepseekV3Family),
             (MellumConfig, MellumFamily),
             (JambaConfig, JambaFamily),
             (OuroConfig, OuroFamily),
-            (ExaoneMoeConfig, ExaoneMoeFamily))
+            (ExaoneMoeConfig, ExaoneMoeFamily),
+            (ZayaConfig, ZayaFamily))
 
 
 def family_of(cfg):

@@ -333,13 +333,15 @@ class _LMServingEntry:
         from ..serving.lm_engine import PagedLMEngine
 
         fam = self._family
-        if draft is not None and fam.state_lines:
+        from .families import kept_state
+
+        stateful = kept_state(fam)
+        if draft is not None and stateful:
             raise NotImplementedError(
                 f"lm_serving: speculative verification (_verify) does not "
                 f"serve the {fam.name} family yet (a rejected draft would "
-                f"have to roll its state layers' state back to the accepted "
-                f"token, and no snapshot of it is kept); build it without "
-                f"draft=")
+                f"have to roll {stateful} back to the accepted token, and "
+                f"no snapshot of it is kept); build it without draft=")
         if draft is not None and fam.drafts:
             raise NotImplementedError(
                 f"lm_serving: the {fam.name} family drafts on the device "

@@ -189,6 +189,28 @@ class PagedLMEngine(DecodeEngine):
       ``projected_page_bytes`` charges no request for it. Prefix sharing
       is refused for such a family: a hit would need the state as it was
       at the prefix's last token.
+    * **attention layers with a state** — a family may also say that its
+      *attention* layers keep a state a slot beside the lines a token
+      keeps there (``family.slot_lines``; ``zaya``: the last rows its
+      projection's convolutions and value shift reach back to). One more
+      array ``(attention layers, slots, *shape)`` per entry, behind the
+      state layers' in ``_states`` and treated as they are everywhere on
+      the host (donated through both programs, zeroed by the launch that
+      starts a sequence, carried by ``preempt`` / ``restore``, counted in
+      ``cache_bytes``, ``memory_bytes()["state"]`` and ``state_stats()``,
+      prefix sharing refused). In the programs the family's ``project``
+      takes a batch entry's rows and how many of the entry's rows are
+      real, and returns them as they stand after the last real one: in
+      ``_step`` one real row for a slot in ``mask`` and none for any other
+      (whose rows come back as read, bit for bit), in ``_prefill_chunk``
+      the launch's ``n_valid``, so that what is stored is the state after
+      row ``n_valid - 1``.
+    * **the stack's carry and merge** — a family may thread one opaque
+      value from each layer's feed-forward to the next's
+      (``family.open_stack``, ``ffn_carry``; ``zaya``'s router
+      activations), and says what the residual stream does with a part's
+      output (``family.merge``; ``x + y`` for every family but ``zaya``).
+      The engine looks into neither.
     * **passes** — a family may run its stack several times a token with
       the same weights (``family.passes``; ``ouro``): it keeps a line for
       every pass of every layer, so wherever this class counts the layers
@@ -312,7 +334,7 @@ class PagedLMEngine(DecodeEngine):
         import jax
         import jax.numpy as jnp
 
-        from ..models.families import family_of
+        from ..models.families import family_of, kept_state
         from ..ops.paged_attention import (
             chunk_block_pages,
             chunk_line_attention,
@@ -345,29 +367,32 @@ class PagedLMEngine(DecodeEngine):
                 f"family yet (a hit would need the pages of both kinds of "
                 f"layer that a registered prefix keeps); build it with "
                 f"share_prefixes=False")
-        if share_prefixes and fam.state_lines:
+        # the state a slot keeps beside its pages, by where it lives: in
+        # state layers (no attention there) or in the attention layers
+        stateful = kept_state(fam)
+        if share_prefixes and stateful:
             raise NotImplementedError(
                 f"lm_engine: prefix sharing does not serve the {fam.name} "
-                f"family yet (a hit would need its state layers' state as "
-                f"it was at the prefix's last token, and no snapshot of it "
-                f"is kept); build it with share_prefixes=False")
-        if fam.passes > 1 and fam.state_lines:
+                f"family yet (a hit would need {stateful} as it was at the "
+                f"prefix's last token, and no snapshot of it is kept); "
+                f"build it with share_prefixes=False")
+        if fam.passes > 1 and stateful:
             raise NotImplementedError(
                 f"lm_engine: the {fam.name} family runs its stack "
-                f"{fam.passes} times a token and has state layers; a state "
+                f"{fam.passes} times a token and keeps {stateful}; a state "
                 f"a pass of a layer is not kept")
         # the tokens a layer of the family drafts a pass (``family.drafts``:
         # its MTP layer's one). ``draft=False`` builds the engine as if the
         # family drafted nothing, ``_step`` and no MTP line: what a test
         # holds the round's stream to, not a serving knob
         D = self.drafts = fam.drafts if draft else 0
-        if D > 1 or (D and (fam.passes > 1 or fam.state_lines)):
+        if D > 1 or (D and (fam.passes > 1 or stateful)):
             raise NotImplementedError(
                 f"lm_engine: the {fam.name} family drafts {D} tokens a pass"
                 f"{', runs its stack several times' * (fam.passes > 1)}"
-                f"{', keeps a state a slot' * bool(fam.state_lines)}; the "
-                f"round verifies one draft of a family with one pass and no "
-                f"state layer")
+                f"{(', keeps ' + stateful) * bool(stateful)}; the round "
+                f"verifies one draft of a family with one pass and no state "
+                f"a slot")
         self.cfg = cfg
         self.family = fam
         self.kinds = kinds
@@ -469,11 +494,19 @@ class PagedLMEngine(DecodeEngine):
         # what a slot keeps in the state layers, whatever its length: one
         # array (state layers, slots, *shape) per entry of the family's
         # ``state_lines``, the i-th state layer's rows at [i]
+        # and what a slot keeps in the attention layers beside its lines
+        # (the family's ``slot_lines``): arrays (attention layers, slots,
+        # *shape) behind the state layers', the i-th attention layer's rows
+        # at [i]. The first ``NM`` arrays are the state layers'
+        self.slot_layers = sum(stack_of.values()) if fam.slot_lines else 0
         self._states = tuple(
-            jnp.zeros((self.state_layers, slots, *shape),
+            jnp.zeros((n, slots, *shape),
                       cache_dtype if dtype is None else dtype)
-            for shape, dtype in fam.state_lines if self.state_layers)
+            for n, lines in ((self.state_layers, fam.state_lines),
+                             (self.slot_layers, fam.slot_lines))
+            for shape, dtype in lines if n)
         NS = len(self._states)
+        NM = len(fam.state_lines) if self.state_layers else 0
         self.state_slot_bytes = int(sum(
             s.nbytes for s in self._states) // slots) if NS else 0
         ctx = NB * page_size  # == max_seq: what a verify round gathers
@@ -568,21 +601,28 @@ class PagedLMEngine(DecodeEngine):
         NC = len(fam.counters)
 
         def _attention(kind, row0, blk, x, pos, dests, offs, pools, unbatch,
-                       attend, write):
+                       attend, write, kept=(), rows=None):
             # one attention layer of ``kind`` whose rows start at ``row0``:
             # write the new lines into its kind's arrays, attend over the
-            # slots' lines; what comes back is the residual stream
+            # slots' lines; what comes back is the residual stream, and
+            # ``kept``, the batch entries' rows of what the family's
+            # attention layers keep a slot (``()`` for most), as the layer
+            # leaves them; ``rows``: how many of each entry's rows are real
             k = kinds.index(kind)
             with jax.named_scope(fam.attention_scopes[kind]):
-                q, lines = fam.project(blk, x, pos, kind)
+                q, lines, kept = fam.project_slot(blk, x, pos, kind, kept,
+                                                  rows)
                 mine = tuple(
                     write(pool, row0, dests[k], offs, unbatch(line))
                     for pool, line in zip(pools[k * P:(k + 1) * P], lines))
                 pools = pools[:k * P] + mine + pools[(k + 1) * P:]
-                return x + attend(kind, row0, blk, q, mine), pools
+                x = fam.merge(blk, x, attend(kind, row0, blk, q, mine),
+                              "attention")
+                return x, pools, kept
 
         def _stack(p, x, pos, live, dests, offs, pools, unbatch, attend,
-                   states=(), mix=None, first=None, write=write_rows):
+                   states=(), mix=None, first=None, write=write_rows,
+                   slot_rows=(None, None, None)):
             # the skeleton every program shares: per layer, by its kind.
             # An attention layer: write the new lines into its kind's
             # arrays (``dests``: the page of each row, by kind), attend
@@ -594,26 +634,43 @@ class PagedLMEngine(DecodeEngine):
             # not have. ``first``: by kind, the first pass-layer of the
             # pass at hand, a traced scalar (``None``: the family's one
             # pass, whose rows are constants of the program). ``write``: the
-            # form of the write, ``write_rows`` or ``write_pages``
+            # form of the write, ``write_rows`` or ``write_pages``.
+            # ``slot_rows``: for a family whose attention layers keep a
+            # state a slot, ``(read, store, rows)``: ``read(array, i)`` the
+            # batch entries' rows of the i-th attention layer, ``store(
+            # array, i, new)`` the array with them put back, ``rows`` how
+            # many of each entry's rows are real. What the family carries
+            # down the stack (``family.open_stack``; ``None`` for most) is
+            # opened here and goes from each feed-forward to the next's
             counts = jnp.zeros((NC,), jnp.int32) if NC else None
+            states, kept = states[:NM], states[NM:]
+            read, store, rows = slot_rows
+            carry = fam.open_stack(p, x)
+            layer = 0  # attention layers so far
             for li, blk in enumerate(fam.blocks(p)):
                 kind = fam.layer_kinds[li]
                 if kind == "state":
                     y, states = mix(index[li], blk, x, states)
-                    x = x + y
+                    x = fam.merge(blk, x, y, "state")
                 else:
                     row0 = index[li] * R[kind] if first is None else (
                         (first[kind] + index[li]) * R[kind])
-                    x, pools = _attention(kind, row0, blk, x, pos, dests,
-                                          offs, pools, unbatch, attend, write)
-                y, c = fam.ffn(blk, x, live)
-                x = x + y
+                    x, pools, left = _attention(
+                        kind, row0, blk, x, pos, dests, offs, pools, unbatch,
+                        attend, write,
+                        tuple(read(a, layer) for a in kept), rows)
+                    kept = tuple(store(a, layer, new)
+                                 for a, new in zip(kept, left))
+                    layer += 1
+                y, c, carry = fam.ffn_carry(blk, x, live, carry)
+                x = fam.merge(blk, x, y, "ffn")
                 if c is not None:
                     counts = counts + c
-            return x, pools, counts, states
+            return x, pools, counts, (*states, *kept)
 
         def _passes(p, x, pos, live, dests, offs, pools, unbatch, attend,
-                    states=(), mix=None, write=write_rows):
+                    states=(), mix=None, write=write_rows,
+                    slot_rows=(None, None, None)):
             # a family whose tokens run the stack several times: ONE loop
             # over the passes in the program, its body the stack. The
             # pools go round as the loop's carry (written and read at the
@@ -683,9 +740,18 @@ class PagedLMEngine(DecodeEngine):
                 y, states = fam.mix_step(blk, x[:, 0], states, i, mask)
                 return y[:, None], states
 
+            # a slot's rows in an attention layer that keeps a state:
+            # every slot's are read and stored, and the family moves those
+            # of the slots in ``mask`` alone (one real row; none for any
+            # other slot, whose rows come back as they were read)
+            slot_rows = (lambda a, i: a[i],
+                         lambda a, i, new: a.at[i].set(new.astype(a.dtype)),
+                         mask.astype(jnp.int32))
+
             x, pools, counts, states = _layers(
                 p, x, lp[:, None], mask[:, None], dests, offs, pools,
-                lambda line: line[:, 0], attend, states, mix)
+                lambda line: line[:, 0], attend, states, mix,
+                slot_rows=slot_rows)
             with jax.named_scope("head"):
                 logits = fam.head(p, x[:, 0])
             out = jnp.argmax(logits, -1).astype(jnp.int32)
@@ -710,10 +776,10 @@ class PagedLMEngine(DecodeEngine):
             u = fam.mtp_input(p, x, toks)
             blk = fam.mtp_block(p)
             with jax.named_scope("mtp.block"):
-                u, pools = _attention(
+                u, pools, _ = _attention(
                     kind, stack_of[kind] * R[kind], blk, u, pos, dests, offs,
                     pools, unbatch, attend, write)
-                y, c = fam.ffn(blk, u, live)
+                y, c, _ = fam.ffn_carry(blk, u, live, fam.open_stack(p, u))
             return u + y, pools, c
 
         def _round(p, carry, mask, *rest):
@@ -812,7 +878,7 @@ class PagedLMEngine(DecodeEngine):
             # ONE slot. C is static — the only compiled prefill shape.
             self.compile_count += 1  # trace-time only: once per engine
             bts, pools = rest[:K], rest[K:K + K * P]
-            # a family with state layers: the slot, then its state arrays
+            # a family that keeps a state a slot: the slot, then the arrays
             slot, states = (rest[K + K * P], rest[K + K * P + 1:]) if NS \
                 else (None, ())
             q_pos = start + jnp.arange(C)
@@ -847,21 +913,33 @@ class PagedLMEngine(DecodeEngine):
                     precision=fam.chunk_precision, pages_per_block=PB)
                 return fam.chunk_output(blk, o.reshape(1, C, KV * G, -1))
 
+            def was(s, i):
+                # the slot's rows of layer ``i`` as the launch finds them:
+                # zero where it starts a sequence, whatever the slot held
+                return jnp.where(start == 0, jnp.zeros_like(s[i, slot]),
+                                 s[i, slot])
+
             def mix(i, blk, x, states):
-                # one slot's launch through the i-th state layer: from
-                # zero where the launch starts a sequence, whatever the
-                # slot held; the family leaves the state at the last real
-                # row (rows past n_valid move neither part of it)
-                old = tuple(jnp.where(start == 0, jnp.zeros_like(s[i, slot]),
-                                      s[i, slot]) for s in states)
+                # one slot's launch through the i-th state layer; the
+                # family leaves the state at the last real row (rows past
+                # n_valid move neither part of it)
+                old = tuple(was(s, i) for s in states)
                 y, new = fam.mix_chunk(blk, x[0], n_valid, old)
                 states = tuple(s.at[i, slot].set(n.astype(s.dtype))
                                for s, n in zip(states, new))
                 return y[None], states
 
+            # an attention layer that keeps a state: the one slot's rows,
+            # of which the family leaves the state after row n_valid - 1
+            slot_rows = (lambda a, i: was(a, i)[None],
+                         lambda a, i, new: a.at[i, slot].set(
+                             new[0].astype(a.dtype)),
+                         n_valid[None])
+
             x, pools, counts, states = _layers(
                 p, x, lp[None], valid[None], dests, offs, pools,
-                unbatch, attend, states, mix, write=write)
+                unbatch, attend, states, mix, write=write,
+                slot_rows=slot_rows)
             if D:
                 # a drafting family's launch also runs its drafting block,
                 # shifted by one token: row i pairs with token i + 1, the
@@ -1786,12 +1864,16 @@ class PagedLMEngine(DecodeEngine):
         return int(self._mask.sum())
 
     def state_stats(self) -> Optional[dict]:
-        """The state layers' cache, a kind of its own beside the pools'
-        ``stats()``: a fixed cost a slot, not a cost a token. ``None`` for
-        a family with no state layer."""
+        """The cache of the state a slot keeps, a kind of its own beside
+        the pools' ``stats()``: a fixed cost a slot, not a cost a token.
+        ``layers`` are the layers that keep one, of which
+        ``attention_layers`` (said where there are any) also keep lines a
+        token. ``None`` for a family that keeps none."""
         if not self._states:
             return None
-        return {"layers": self.state_layers, "slots": self.slots,
+        return {"layers": self.state_layers + self.slot_layers,
+                **({"attention_layers": self.slot_layers}
+                   if self.slot_layers else {}), "slots": self.slots,
                 "slots_live": self.active_slots,
                 "slot_bytes": self.state_slot_bytes,
                 "bytes": self.state_slot_bytes * self.slots,

@@ -1061,6 +1061,55 @@ class TestAttentionByHeadOnTpu:
         assert f"f32[{S},{K * H},{KV * Dh}]" not in hlo
 
 
+class TestStateInAttentionLayersOnTpu:
+    """``tools/serving_programs_ops.py`` on the configuration whose
+    attention layers keep a state a slot (``zaya1_8b_pp2_l20``), at its
+    rehearsal sizes and in the forms a TPU runs, compiled for a described
+    v5e: it builds the family's state arrays for every slot beside the
+    pools, both programs compile with them donated and handed back, and
+    their bytes are printed beside ``weight_copies``."""
+
+    def test_the_tool_builds_and_prints_the_state_a_slot_keeps(
+            self, v5e_chip, tmp_path, monkeypatch):
+        import json
+        import os
+
+        from nnstreamer_tpu.ops import moe_grouped, paged_attention
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        monkeypatch.syspath_prepend(os.path.join(root, "tools"))
+        monkeypatch.syspath_prepend(root)
+        # the tool binds the TPU's forms where the engine looks them up
+        monkeypatch.setattr(paged_attention, "paged_line_attention",
+                            paged_attention.paged_line_attention)
+        monkeypatch.setattr(moe_grouped, "grouped_experts",
+                            moe_grouped.grouped_experts)
+        import serving_programs_ops
+
+        name = "zaya1_8b_pp2_l20"
+        printed = serving_programs_ops.main(root, str(tmp_path), only=[name],
+                                            rehearse=True)
+        assert [(p["config"], p["program"]) for p in printed] == [
+            (name, "_step"), (name, "_prefill_chunk")]
+        with open(os.path.join(root, "benchmark", "configs",
+                               f"{name}.json")) as fh:
+            small = json.load(fh)["rehearsal"]
+        # one float32 line of [p ; a ; shifted value] a slot and layer
+        width = (2 * (small["num_attention_heads"]
+                      + small["num_key_value_heads"]) + 1) * small["head_dim"]
+        state = (small["engine"]["slots"] * small["num_hidden_layers"]
+                 * width * 4)
+        for p in printed:
+            assert p["state_bytes"] == state
+            assert p["weight_copies"] == 0
+            # the pools and the state go in and come out where they lie
+            assert p["alias_bytes"] >= state
+            assert os.path.getsize(os.path.join(
+                tmp_path, f"{name}.{p['program']}.ops")) > 0
+        ops = open(os.path.join(tmp_path, f"{name}._step.ops")).read()
+        assert "custom-call" in ops, "the step holds the TPU's kernels"
+
+
 class TestExpertsStreamOnTpu:
     """What the compiled programs of the two expert families hold on a
     TPU (PR 32): the record of where ``ops/moe_grouped.py``'s kernel

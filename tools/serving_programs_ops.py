@@ -3,9 +3,9 @@
 For every configuration ``BENCHMARK.json`` lists whose driver kind this
 script knows (``lm_serving``, ``lm_serving_moe_mla``,
 ``lm_serving_moe_window``, ``lm_serving_ssm``, ``lm_serving_looped``,
-``lm_serving_moe_mtp``): ``_step`` (``_round`` where the family drafts,
-since PR 47) and ``_prefill_chunk`` of the paged engine at the configuration's
-sizes and engine geometry, a launch of 256 rows, in the forms a TPU runs
+``lm_serving_moe_mtp``, ``lm_serving_moe_cca``): ``_step`` (``_round`` where
+the family drafts, since PR 47) and ``_prefill_chunk`` of the paged engine
+at the configuration's sizes and engine geometry, a launch of 256 rows, in the forms a TPU runs
 (the step's attention kernel, the experts' kernel; a state layer's products
 in their plain form), compiled for a described v5e with no chip attached.
 Writes one
@@ -24,6 +24,10 @@ way round, and does not lie in memory space 1 (no ``S(1)`` in its layout):
 a matrix written to HBM and read again on every call, which storing it
 otherwise would save. A copy into memory space 1 is the compiler's
 prefetch of the matrix into fast memory, its one read, and is not counted.
+``state_bytes`` is what the slots keep beside their pages (a state layer's
+state, an attention layer's kept rows: ``engine._states`` for every slot),
+which both programs are donated and hand back; 0 for a family that keeps
+none.
 A fourth argument ``text`` also
 writes the optimized module whole, ``<config>.<program>.hlo`` (to read,
 not to compare: it holds source lines).
@@ -37,6 +41,9 @@ unpacked parent and on its own tree and compares the files:
     diff -r /root/scratch/ops_parent /root/scratch/ops_change
 
 About two minutes a tree. A compile that passes is not a chip run.
+Further arguments name the configurations to compile (all of them with
+none); ``main(..., rehearse=True)`` compiles at each file's ``rehearsal``
+sizes and a launch of its own width (what the tests do).
 """
 from __future__ import annotations
 
@@ -88,10 +95,16 @@ def _model(config: dict):
 
         return ExaoneMoeConfig.from_published(
             harness.reference_for(config).model_config(config))
+    if kind == "lm_serving_moe_cca":
+        from nnstreamer_tpu.models.zaya import ZayaConfig
+
+        return ZayaConfig.from_published(config)
     return None
 
 
-def main(root: str, out: str, text: bool = False) -> int:
+def main(root: str, out: str, text: bool = False, only=(),
+         rehearse: bool = False) -> list:
+    """Compile and write; returns the lines it printed, as dicts."""
     root = os.path.abspath(root)
     sys.path.insert(0, root)
     os.makedirs(out, exist_ok=True)
@@ -118,10 +131,16 @@ def main(root: str, out: str, text: bool = False) -> int:
     def shape(s, dt):
         return jax.ShapeDtypeStruct(tuple(s), dt, sharding=chip)
 
-    width, i32 = 256, jnp.int32
+    i32, printed = jnp.int32, []
     for entry in harness.load_benchmark()["configs"]:
+        if only and entry["name"] not in only:
+            continue
         with open(os.path.join(root, entry["file"])) as fh:
             config = json.load(fh)
+        width = 256
+        if rehearse:
+            config = {**config, **config["rehearsal"]}
+            width = config["engine"]["chunk"]
         mcfg = _model(config)
         if mcfg is None:
             continue
@@ -153,9 +172,11 @@ def main(root: str, out: str, text: bool = False) -> int:
         pools = [shape((probe.kind_layers[k] * (by_kind[k] + 1),
                         geo["page_size"], w), jnp.bfloat16)
                  for k in probe.kinds for w in probe.line_widths]
-        # what a slot keeps in a state layer, for every slot
+        # what a slot keeps in a state layer and, where the family says
+        # so, in an attention layer, for every slot
         states = [shape((s.shape[0], S, *s.shape[2:]), s.dtype)
                   for s in probe._states]
+        state_bytes = sum(s.size * s.dtype.itemsize for s in states)
         programs = {
             "_step": (shape((S, 1), i32), shape((S,), i32),
                       shape((S,), jnp.bool_), *[shape((S, NB), i32)] * K,
@@ -193,7 +214,7 @@ def main(root: str, out: str, text: bool = False) -> int:
                       "w") as fh:
                 fh.write("\n".join(lines) + "\n")
             m = compiled.memory_analysis()
-            print(json.dumps({
+            printed.append({
                 "config": entry["name"], "program": name,
                 "instructions": len(lines),
                 "argument_bytes": m.argument_size_in_bytes,
@@ -201,11 +222,15 @@ def main(root: str, out: str, text: bool = False) -> int:
                 "temp_bytes": m.temp_size_in_bytes,
                 "code_bytes": m.generated_code_size_in_bytes,
                 "weight_copies": len(copied),
-                "weight_copy_bytes": sum(copied)}), flush=True)
-    return 0
+                "weight_copy_bytes": sum(copied),
+                "state_bytes": state_bytes})
+            print(json.dumps(printed[-1]), flush=True)
+    return printed
 
 
 if __name__ == "__main__":
-    if len(sys.argv) not in (3, 4):
+    if len(sys.argv) < 3:
         raise SystemExit(__doc__)
-    sys.exit(main(sys.argv[1], sys.argv[2], sys.argv[3:] == ["text"]))
+    more = sys.argv[3:]
+    main(sys.argv[1], sys.argv[2], "text" in more,
+         only=[name for name in more if name != "text"])
